@@ -45,7 +45,6 @@ def _setup_logger() -> None:
 
 _setup_logger()
 
-from apex_tpu import _compat  # noqa: E402,F401  — jax-surface polyfills first
 from apex_tpu import multi_tensor  # noqa: E402,F401
 from apex_tpu import amp  # noqa: E402,F401
 from apex_tpu import optimizers  # noqa: E402,F401

@@ -63,6 +63,57 @@ def constrain(x, *spec):
         x, NamedSharding(_mesh.current_mesh(), P(*fitted)))
 
 
+def on_shards(fn, impl, in_specs, out_specs):
+    """``fn`` — a function that holds a Pallas kernel — as a per-shard
+    island under the armed mesh.
+
+    The TPU compiler cannot partition a Mosaic kernel: on more than
+    one device it lowers only inside a ``shard_map`` that is manual
+    over every mesh axis ("Mosaic kernels cannot be automatically
+    partitioned"). So where a >1-device mesh is armed and ``impl``
+    resolves to a kernel, ``fn`` runs under ``jax.shard_map`` with the
+    given ``PartitionSpec``s (one per positional argument, a pytree
+    argument taking one as a prefix; ``out_specs`` shaped like the
+    result) and XLA moves the data into that layout and back — the
+    local island under the one mesh. Everywhere else (no mesh, one
+    device, ``impl="xla"``, inside a legacy ``shard_map`` axis) ``fn``
+    comes back as it is, so those programs do not change.
+
+    An axis that does not divide a dim it is named for is dropped from
+    every spec, as in :func:`constrain`: that dim stays whole on each
+    shard, which any function whose shards are independent tolerates."""
+    from apex_tpu._backend import resolve_impl
+
+    if resolve_impl(impl) == "xla" or not mesh_active():
+        return fn
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from apex_tpu.mesh import mesh as _mesh
+
+    mesh = _mesh.current_mesh()
+    sizes = _mesh.axis_sizes()
+
+    def island(*args):
+        unfit = {a for spec, x in zip(in_specs, args)
+                 for i, a in enumerate(spec)
+                 if a is not None and x.shape[i] % sizes[a]}
+
+        def fit(spec):
+            return P(*[None if a in unfit else a for a in spec])
+
+        def is_spec(x):
+            return isinstance(x, P)
+
+        return jax.shard_map(
+            fn, mesh=mesh,
+            in_specs=tuple(fit(spec) for spec in in_specs),
+            out_specs=jax.tree.map(fit, out_specs, is_leaf=is_spec),
+            check_vma=False)(*args)
+
+    return island
+
+
 # -- the model's hint vocabulary (seq-major (s, b, h) interior) ------------
 
 
@@ -182,6 +233,7 @@ __all__ = [
     "constrain_logits",
     "constrain_replicated",
     "mesh_active",
+    "on_shards",
     "serving_param_shardings",
     "shard_kv_pool",
     "shard_params_for_serving",

@@ -37,7 +37,6 @@ import dataclasses
 import math
 from typing import Any, Dict, Optional, Sequence, Tuple
 
-import numpy as np
 
 BATCH_AXIS = "batch"
 MODEL_AXIS = "model"
@@ -112,18 +111,12 @@ def initialize_mesh(batch: Optional[int] = None, model: int = 1,
         raise ValueError(
             f"batch({batch}) x model({model}) x pipe({pipe}) != "
             f"device count {world}")
-    shape = (batch, pipe, model)
-    arr = None
-    if devices is None:
-        try:
-            from jax.experimental import mesh_utils
+    # topology-aware on TPU (model innermost on ICI neighbours); a plain
+    # reshape of the device list on backends with no topology
+    from jax.experimental import mesh_utils
 
-            arr = mesh_utils.create_device_mesh(
-                shape, devices=devs, allow_split_physical_axes=True)
-        except Exception:  # noqa: BLE001 — no topology (CPU sim): linear
-            arr = None
-    if arr is None:
-        arr = np.asarray(devs).reshape(shape)
+    arr = mesh_utils.create_device_mesh(
+        (batch, pipe, model), devices=devs, allow_split_physical_axes=True)
     _MESH = Mesh(arr, MESH_AXES)
     return _MESH
 
@@ -331,6 +324,19 @@ class MeshTrainStep:
                                   params)
         return self.plan.shard_state(self.opt.init(params))
 
+    def _update(self, state, g):
+        """``opt.step_flat`` on the flat state (call while tracing the
+        step). The state and the reduced gradient are replicated, so
+        where the fused update is a kernel every device runs it whole
+        on its own copy, as an island (``annotate.on_shards``)."""
+        from jax.sharding import PartitionSpec as P
+
+        from apex_tpu.mesh import annotate
+
+        return annotate.on_shards(
+            lambda state, g: self.opt.step_flat(state, g)[1],
+            self.opt.impl, (P(), P()), P())(state, g)
+
     def _jit_for(self, state) -> Any:
         key = (state.space, state.seg_meta)
         jitted = self._jitted.get(key)
@@ -338,20 +344,17 @@ class MeshTrainStep:
             return jitted
         import jax
 
-        opt = self.opt
         vg = state.space.grad_fn(self._loss_fn, with_value=True,
                                  has_aux=self._has_aux)
 
         if self._has_aux:
             def step(state, tokens, labels):
                 (loss, aux), g = vg(state.master, tokens, labels)
-                _, new_state = opt.step_flat(state, g)
-                return new_state, loss, aux
+                return self._update(state, g), loss, aux
         else:
             def step(state, tokens, labels):
                 loss, g = vg(state.master, tokens, labels)
-                _, new_state = opt.step_flat(state, g)
-                return new_state, loss
+                return self._update(state, g), loss
 
         if self.plan.is_identity():
             jitted = jax.jit(step, donate_argnums=(0,))
@@ -373,6 +376,13 @@ class MeshTrainStep:
                              out_shardings=out_sh)
         self._jitted[key] = jitted
         return jitted
+
+    def lower(self, state, tokens, labels):
+        """``jax.jit(...).lower`` passthrough (``TrainStep.lower``'s
+        sibling): the step program's memory analysis and text without
+        running it. Shapes (``jax.ShapeDtypeStruct``) do for every
+        argument, so nothing need be resident to ask."""
+        return self._jit_for(state).lower(state, tokens, labels)
 
     def _apply_moe_faults(self, state):
         """The moe_router_collapse / moe_expert_dead drills

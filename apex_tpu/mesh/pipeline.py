@@ -516,15 +516,13 @@ class MeshPipelineTrainStep(MeshTrainStep):
             return jitted
         import jax
 
-        opt = self.opt
         vg = state.space.grad_fn(self._async_loss, with_value=True,
                                  has_aux=True)
 
         def step(state, tokens, labels, buf, tick0):
             (loss, new_buf), g = vg(state.master, tokens, labels, buf,
                                     tick0)
-            _, new_state = opt.step_flat(state, g)
-            return new_state, loss, new_buf
+            return self._update(state, g), loss, new_buf
 
         if self.plan.is_identity():
             jitted = jax.jit(step, donate_argnums=(0, 3))

@@ -8,8 +8,8 @@ This module is that search for the GSPMD mesh substrate
 (:mod:`~apex_tpu.mesh.mesh`), built from pieces the repo already owns:
 
 - per-chip peak FLOPs come from the MFU plane's table
-  (``backend_guard.chip_peak_tflops`` via ``telemetry/cost.py``'s
-  ``device_kind``), with an explicit ``peak_source: fallback`` marker
+  (``telemetry.cost.chip_peak_tflops`` of the live ``device_kind``),
+  with an explicit ``peak_source: fallback`` marker
   on backends the table doesn't know (the CPU CI);
 - collective traffic is priced with the PR-12 comms wire-bytes model
   (``telemetry.comms.wire_bytes``) — the same analytic column the
@@ -269,11 +269,13 @@ def plan_layout(n_devices: int, *, hidden_size: int, num_layers: int,
 
     peak_source = "table"
     if peak_tflops is None:
-        from apex_tpu.backend_guard import chip_peak_tflops
         from apex_tpu.telemetry import cost as _cost
 
-        peak_tflops = chip_peak_tflops(_cost.device_kind())
-        if peak_tflops is None:
+        try:
+            peak_tflops = _cost.chip_peak_tflops(_cost.device_kind())
+        except ValueError:
+            # ranking layouts off the chip (CPU mesh): any one peak
+            # orders them the same, and the plan names its source
             peak_tflops, peak_source = FALLBACK_PEAK_TFLOPS, "fallback"
     else:
         peak_source = "caller"

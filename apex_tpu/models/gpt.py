@@ -69,10 +69,9 @@ class GPTConfig:
     attention_backend: str = "flash"
     # lax.scan over stacked layer params (one compiled layer body
     # instead of num_layers inlined copies). Compile time and program
-    # size become depth-independent — 24 unrolled BERT/GPT-class layers
-    # overwhelm the Mosaic compile pipeline (docs/HARDWARE_NOTES.md
-    # round-3 bench_bert/gpt compile crashes). False restores per-layer
-    # param names ("layer_{i}") for name-addressed checkpoints.
+    # size become depth-independent (the scanned GPT-2 345M train step
+    # compiles for a v5e in ~10 s). False restores per-layer param
+    # names ("layer_{i}") for name-addressed checkpoints.
     scan_layers: bool = True
     # Mixture-of-Experts (docs/moe.md): num_experts=0 is the dense
     # model — every knob below is inert and the param tree is
@@ -168,6 +167,38 @@ class GPTConfig:
                          max_seq_len=1024, **kw)
 
 
+def _flash(cfg: "GPTConfig", q, k, v, *, kv_segment_ids=None, **kw):
+    """``flash_attention`` over (b, heads, s, head_dim) arrays.
+
+    Every (batch, head) pair is independent, so under an armed GSPMD
+    mesh the kernel runs per shard of them (mesh/annotate.py
+    ``on_shards`` — the compiler cannot partition a Mosaic kernel
+    itself): batch on the ``batch`` axis, heads on ``model``, where the
+    column-parallel qkv left them. A contiguous slice of q heads and
+    the same slice of kv heads are whole GQA groups (``__call__``)."""
+    from apex_tpu.mesh.mesh import BATCH_AXIS, MODEL_AXIS
+    from apex_tpu.ops.attention import flash_attention
+
+    bh = P(BATCH_AXIS, MODEL_AXIS, None, None)
+    args, specs = [q, k, v], [bh, bh, bh]
+    if kv_segment_ids is not None:
+        args.append(kv_segment_ids)
+        specs.append(P(BATCH_AXIS, None))
+
+    def attend(q, k, v, kv_seg=None):
+        return flash_attention(q, k, v, kv_segment_ids=kv_seg,
+                               impl=cfg.softmax_impl, **kw)
+
+    island = _gspmd.on_shards(attend, cfg.softmax_impl, tuple(specs), bh)
+    if island is not attend and kw.get("dropout_rate", 0.0) > 0.0:
+        # the mask is hashed from shard-local (batch, head) indices:
+        # every shard would draw the same one
+        raise NotImplementedError(
+            "attention dropout inside the flash kernel is not supported "
+            "on a >1-device GSPMD mesh")
+    return island(*args)
+
+
 class ParallelAttention(nn.Module):
     """Self attention: column-parallel fused QKV, causal fused softmax,
     row-parallel output projection (ref standalone_transformer_lm.py
@@ -258,8 +289,6 @@ class ParallelAttention(nn.Module):
             if cfg.attention_window is not None:
                 raise NotImplementedError(
                     "kv_ctx decode with attention_window is not supported")
-            from apex_tpu.ops.attention import flash_attention
-
             k_ctx, v_ctx, ctx_mask = kv_ctx
             qb = q.transpose(1, 2, 0, 3)                  # (b, h, s, d)
             k_all = jnp.concatenate([k_ctx.astype(cfg.dtype), kv_new[0]],
@@ -275,9 +304,8 @@ class ParallelAttention(nn.Module):
                 kv_seg = jnp.concatenate(
                     [jnp.where(ctx_mask, 0, 1).astype(jnp.int32),
                      jnp.zeros((b, 1), jnp.int32)], axis=1)
-                ctx = flash_attention(qb, k_all, v_all, causal=False,
-                                      kv_segment_ids=kv_seg,
-                                      impl=cfg.softmax_impl)
+                ctx = _flash(cfg, qb, k_all, v_all, causal=False,
+                             kv_segment_ids=kv_seg)
             else:
                 # chunk-resumable prefill: s chunk queries over the
                 # [ctx | chunk] key layout. causal=True with sk > sq
@@ -289,9 +317,8 @@ class ParallelAttention(nn.Module):
                 kv_seg = jnp.concatenate(
                     [jnp.where(ctx_mask, 0, 1).astype(jnp.int32),
                      jnp.zeros((b, s), jnp.int32)], axis=1)
-                ctx = flash_attention(qb, k_all, v_all, causal=True,
-                                      kv_segment_ids=kv_seg,
-                                      impl=cfg.softmax_impl)
+                ctx = _flash(cfg, qb, k_all, v_all, causal=True,
+                             kv_segment_ids=kv_seg)
             ctx = ctx.transpose(2, 0, 1, 3).reshape(
                 s, b, heads_local * head_dim)
             return _out(ctx)
@@ -308,17 +335,15 @@ class ParallelAttention(nn.Module):
                     q_positions=positions, kv_positions=positions,
                     impl=cfg.softmax_impl)
             else:
-                from apex_tpu.ops.attention import flash_attention
                 drop = (cfg.attention_dropout
                         if cfg.attention_dropout > 0.0 and not deterministic
                         else 0.0)
-                ctx = flash_attention(
-                    qb, kb, vb, causal=True,
+                ctx = _flash(
+                    cfg, qb, kb, vb, causal=True,
                     window_size=cfg.attention_window,
                     dropout_rate=drop,
                     dropout_rng=(self.make_rng("dropout")
-                                 if drop > 0.0 else None),
-                    impl=cfg.softmax_impl)
+                                 if drop > 0.0 else None))
             ctx = ctx.transpose(2, 0, 1, 3).reshape(
                 s, b, heads_local * head_dim)
             return _out(ctx)

@@ -101,8 +101,7 @@ def fused_elementwise(
     the data tile. Per-tensor ops thus keep big-tile grids (32x fewer
     steps than one-id-per-tile tiling) without the kernel ever doing a
     dynamic SMEM gather — stacked dynamic scalar reads are exactly the
-    construct Mosaic's compiler rejects at sub>1 (measured on-chip,
-    docs/HARDWARE_NOTES.md round 3).
+    construct Mosaic's compiler rejects at sub>1.
 
     ``aliases`` maps input position (into ``inputs``) -> output position:
     the output may reuse the input's buffer (the TPU analog of the
@@ -163,8 +162,8 @@ def fused_elementwise(
             "tile_rows": int(tile_rows),
             "per_tensor": len(per_tensor), "sr": bool(sr_outputs)})
     if impl in ("pallas", "interpret"):
-        # 2048x128 engine tiles CRASH the Mosaic compiler (round-3
-        # chip evidence); refuse before the shape reaches it
+        # a tile of 4 MiB or more is refused by the TPU compiler
+        # (ops/mosaic_limits.py); say so before the shape reaches it
         from apex_tpu.ops.mosaic_limits import check_block
 
         check_block(tile_rows, LANES, 4, what="engine tile")

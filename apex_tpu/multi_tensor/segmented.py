@@ -4,18 +4,19 @@ The two-stage flat LAMB (ops.fused_lamb_update) pays ~10 HBM accesses
 per element: stage 1 materializes the update term ``u`` so the
 per-tensor trust ratios can be reduced before stage 2 re-reads ``p``
 and ``u``. XLA gives optax a better deal on VMEM-sized leaves by
-fusing each leaf's two kernels with the leaf resident on-chip
-(docs/HARDWARE_NOTES.md round-3 "optimizer truth"). This kernel takes
-that trick further, TPU-native:
+fusing each leaf's two kernels with the leaf resident on-chip. This
+kernel takes that trick further, TPU-native:
 
 - the flat buffer is laid out in *segments* (flat_buffer.
   segmented_space): every small leaf lives inside one segment, so its
   norm is a segment-local reduction;
 - the grid runs (segment, phase, chunk). Phase 0 streams p/m/v/g
   chunks, writes m'/v' straight out, stashes ``u`` and ``p`` in VMEM
-  scratch, and accumulates per-slot ‖p‖²/‖u‖² via one-hot matmuls
+  scratch, and accumulates per-slot ‖p‖²/‖u‖² through a slot one-hot
   (slot ids are streamed per subtile — NO dynamic gathers, the
-  construct Mosaic's compiler crashes on);
+  construct Mosaic's compiler crashes on — and every intermediate is
+  rank >= 2: a reshape to or from a rank-1 vector aborts its layout
+  inference, which is what kept this kernel off the chip);
 - phase 1 turns the accumulators into trust ratios once, then writes
   p' chunk-by-chunk from scratch. Phase-1 input blocks map to the
   phase-0 resident index (no refetch; pallas skips the DMA when the
@@ -87,7 +88,7 @@ def _small_segment_pass(
 
     ``with_grad_norm=True`` additionally accumulates per-slot sums of
     squares of the RAW streamed gradient through the same phase-0
-    one-hot matmuls that build the ‖p‖²/‖u‖² accumulators (acc row 3),
+    one-hot reductions that build the ‖p‖²/‖u‖² accumulators (acc row 3),
     and dumps them per segment — per-tensor grad norms at zero extra
     HBM passes. Off by default so the flag cannot perturb the
     chip-validated default schedule.
@@ -154,6 +155,18 @@ def _small_segment_pass(
                 jnp.int32, (sub_chunk, ms), 1)
             return (ids == slots).astype(jnp.float32)
 
+        def slot_sums(sq, oh):
+            """(1, ms) per-slot sums of the (CHUNK_ROWS, LANES) block
+            ``sq``: row-group then lane reduction per subtile, routed
+            to slots through the one-hot. Every intermediate stays
+            rank >= 2 — Mosaic's layout inference aborts the compiler
+            on a reshape to or from a rank-1 vector."""
+            per_sub = jnp.sum(
+                jnp.sum(sq.reshape(sub_chunk, PER_TENSOR_TILE_ROWS,
+                                   LANES), axis=1),
+                axis=1, keepdims=True)               # (sub_chunk, 1)
+            return jnp.sum(per_sub * oh, axis=0, keepdims=True)
+
         @pl.when((s == 0) & (ph == 0) & (c == 0))
         def _():
             found_ref[0, 0] = jnp.float32(0.0)
@@ -182,25 +195,12 @@ def _small_segment_pass(
             if stash_p:
                 p_buf[pl.ds(row0, CHUNK_ROWS), :] = p_
             oh = slot_one_hot()                      # (sub_chunk, ms)
-            pp = jnp.sum(
-                (p_ * p_).reshape(sub_chunk, PER_TENSOR_TILE_ROWS,
-                                  LANES), axis=(1, 2))
-            uu = jnp.sum(
-                (u * u).reshape(sub_chunk, PER_TENSOR_TILE_ROWS,
-                                LANES), axis=(1, 2))
-            both = jnp.stack([pp, uu])               # (2, sub_chunk)
-            acc_ref[0:2, :] = acc_ref[0:2, :] + jax.lax.dot_general(
-                both, oh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            acc_ref[0:1, :] = acc_ref[0:1, :] + slot_sums(p_ * p_, oh)
+            acc_ref[1:2, :] = acc_ref[1:2, :] + slot_sums(u * u, oh)
             if with_grad_norm:
-                # raw-grad sumsq rides the same one-hot matmul; row 3
-                # keeps clear of the ratio slot (row 2, phase 1)
-                gg = jnp.sum(
-                    (g_ * g_).reshape(sub_chunk, PER_TENSOR_TILE_ROWS,
-                                      LANES), axis=(1, 2))
-                acc_ref[3:4, :] = acc_ref[3:4, :] + jax.lax.dot_general(
-                    gg[None, :], oh, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
+                # raw-grad sumsq rides the same per-slot reduction; row
+                # 3 keeps clear of the ratio slot (row 2, phase 1)
+                acc_ref[3:4, :] = acc_ref[3:4, :] + slot_sums(g_ * g_, oh)
 
         @pl.when((ph == 1) & (c == 0))
         def _():
@@ -218,10 +218,13 @@ def _small_segment_pass(
         @pl.when(ph == 1)
         def _():
             oh = slot_one_hot()                      # (sub_chunk, ms)
-            rr = jax.lax.dot_general(
-                oh, acc_ref[2:3, :], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)  # (sub_chunk, 1)
-            rr_rows = jnp.repeat(rr, PER_TENSOR_TILE_ROWS, axis=0)
+            # each subtile's ratio: its slot's entry of the ratio row
+            rr = jnp.sum(oh * acc_ref[2:3, :], axis=1,
+                         keepdims=True)              # (sub_chunk, 1)
+            rr_rows = jnp.broadcast_to(
+                jnp.broadcast_to(rr, (sub_chunk, LANES))[:, None, :],
+                (sub_chunk, PER_TENSOR_TILE_ROWS, LANES),
+            ).reshape(CHUNK_ROWS, LANES)
             row0 = c * CHUNK_ROWS
             u = u_buf[pl.ds(row0, CHUNK_ROWS), :].astype(jnp.float32)
             if stash_p:
@@ -364,7 +367,7 @@ def fused_lamb_segmented_update(
     is what CPU tests compare the kernel against.
 
     ``with_grad_norm=True`` appends per-tensor L2 norms of the RAW
-    gradient, accumulated through the phase-0 one-hot matmuls (small
+    gradient, accumulated through the phase-0 one-hot reductions (small
     segments) and the stage-1 sumsq ride-along (large leaves) — no
     standalone norm pass over the buffer.
 
@@ -519,7 +522,7 @@ def segmented_per_leaf_sumsq(buf, space: FlatSpace,
     This is the resilience watchdog's localization primitive
     (apex_tpu/resilience/watchdog.py): a NaN/Inf gradient makes exactly
     its own leaf's sum nonfinite. The reduction is therefore routed
-    per-slot via ``segment_sum`` (not the kernel's one-hot matmul,
+    per-slot via ``segment_sum`` (not the kernel's one-hot product,
     whose ``0 * NaN`` contributions would bleed a NaN across every slot
     in the segment) so localization stays leaf-exact.
     """

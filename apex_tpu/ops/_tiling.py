@@ -3,7 +3,7 @@
 One source of truth for the tiling rule every row-tiled kernel
 (softmax family, xentropy, layer/rms norm) must satisfy on TPU: the
 last-two block dims must be divisible by (8, 128) or equal the array
-dims (empirically pinned by tools/mosaic_probe.py). A returned tile
+dims (the Pallas TPU lowering refuses anything else). A returned tile
 divides ``rows``, is a multiple of 8 (or equals ``rows``), and keeps
 the (tile, cols) fp32 block inside the VMEM ``budget``; ``None`` means
 no legal tile exists — callers fall back to their XLA paths (ragged
@@ -17,15 +17,13 @@ from typing import Optional
 
 def row_tile(rows: int, cols: int, cap: int = 256,
              budget: int = 2 * 1024 * 1024) -> Optional[int]:
-    from apex_tpu.ops.mosaic_limits import (MAX_BLOCK_BYTES,
-                                            MAX_BLOCK_SUBLANES, block_ok)
+    from apex_tpu.ops.mosaic_limits import MAX_BLOCK_BYTES, block_ok
 
     if rows <= 0:
         return None
-    # clamp caller-supplied cap/budget to the known Mosaic crash region
-    # (LN tiles >= 256x4096 fp32 crash the compiler — round-3 chip
-    # evidence; a tuner or caller can never push a selector past it)
-    cap = min(cap, MAX_BLOCK_SUBLANES)
+    # clamp a caller-supplied budget below the block size the compiler
+    # refuses (ops/mosaic_limits.py): a tuner or caller can never push
+    # a selector past it
     budget = min(budget, MAX_BLOCK_BYTES - cols * 4)
     want = min(cap, budget // max(cols * 4, 1))
     if rows <= want:

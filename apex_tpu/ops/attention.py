@@ -56,14 +56,35 @@ def _pick_block(seq: int, want: int) -> int:
     return max(b, 1)
 
 
+def _kv_pad(sk: int, want: int) -> int:
+    """Keys to append so the k block obeys the TPU tiling rule.
+
+    A block's last two dims must be multiples of (8, 128) or span the
+    whole array, and the k block size sits in the LANE dim of the
+    segment-id / position / bias blocks. ``_pick_block`` shrinks the
+    block until it divides ``sk``; for an ``sk`` like 1025 (a decode
+    step's cached prefix + the new token) that ends at 1, which the
+    Pallas TPU lowering refuses. Such an ``sk`` is padded up to the
+    next multiple of 128 instead, and the kernels mask the tail by
+    index (``_mask_block``'s ``k_len``). A caller-chosen block that is
+    itself not lane-aligned (interpret-mode tests) is left alone."""
+    blk = _pick_block(sk, want)
+    if blk == sk or blk % 128 == 0 or want % 128:
+        return 0
+    return -sk % 128
+
+
 def _mask_block(iq, ik, bq, bk, sq, sk, causal, window, q_seg, k_seg,
-                q_pos=None, k_pos=None):
+                q_pos=None, k_pos=None, k_len=None):
     """fp32 additive mask (bq, bk) for the (iq, ik) block pair.
 
     ``q_seg``/``k_seg`` are column (bq, 1) / row (1, bk) int32 blocks
     (the kernel segment layouts); the XLA path masks segments itself.
     ``q_pos``/``k_pos`` (same layouts) carry global token positions for
     ring/blockwise chunks, replacing the static causal/window geometry.
+    ``sk`` is the number of REAL keys (the causal offset's geometry);
+    ``k_len`` is set to it when the k axis was padded past it
+    (``_kv_pad``), and masks the padded tail by key index.
     """
     if q_pos is not None:
         # dynamic GLOBAL positions (ring/blockwise chunks): causal and
@@ -83,6 +104,9 @@ def _mask_block(iq, ik, bq, bk, sq, sk, causal, window, q_seg, k_seg,
         neg = jnp.where(col <= row + off - window, NEG_INF, neg)
     if q_seg is not None:
         neg = jnp.where(q_seg != k_seg, NEG_INF, neg)
+    if k_len is not None:
+        kidx = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        neg = jnp.where(kidx >= k_len, NEG_INF, neg)
     return neg
 
 
@@ -183,7 +207,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, qs_ref, ks_ref, seed_ref,
                 qp_ref, kp_ref,
                 o_ref, lse_ref, acc_sc, m_sc, l_sc,
                 *, scale, causal, window, rate, nk, n_inner, banded,
-                bq, bk, sq, sk):
+                bq, bk, sq, sk, k_len=None):
     j = pl.program_id(2)
     iq = pl.program_id(1)
     bh = pl.program_id(0)   # hoisted: program_id inside a pl.when branch
@@ -228,7 +252,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, qs_ref, ks_ref, seed_ref,
         s = s + _mask_block(
             iq, ik, bq, bk, sq, sk, causal, window, q_seg, k_seg,
             q_pos=qp_ref[...] if qp_ref is not None else None,
-            k_pos=kp_ref[...] if kp_ref is not None else None)
+            k_pos=kp_ref[...] if kp_ref is not None else None,
+            k_len=k_len)
 
         m_prev = m_sc[:, :1]                       # (bq, 1)
         l_prev = l_sc[:, :1]
@@ -271,14 +296,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, qs_ref, ks_ref, seed_ref,
 
 def _flash_fwd_pallas(q, k, v, bias, q_seg, k_seg, seed, scale, causal,
                       window, rate, bq, bk, interpret,
-                      q_pos=None, k_pos=None):
+                      q_pos=None, k_pos=None, kv_len=None):
     b, h, sq, d = q.shape
     hk = k.shape[1]
     group = h // hk          # GQA: q heads per shared kv head
-    sk = k.shape[2]
+    skp = k.shape[2]         # k axis as stored: padded when kv_len is set
+    sk = skp if kv_len is None else kv_len
+    k_len = None if sk == skp else sk
     bq = _pick_block(sq, bq)
-    bk = _pick_block(sk, bk)
-    nq, nk = sq // bq, sk // bk
+    bk = _pick_block(skp, bk)
+    nq, nk = sq // bq, skp // bk
     # banded sliding window: the inner grid dim covers only the k blocks
     # a q block's window can touch, so DMA traffic is O(S*w) not O(S^2)
     banded, n_inner = _band(window, bq, bk, nk, dynamic=q_pos is not None)
@@ -288,8 +315,8 @@ def _flash_fwd_pallas(q, k, v, bias, q_seg, k_seg, seed, scale, causal,
             return j
         return _band_pos(_band_k_lo(iq, bq, bk, sk - sq, window), j, nk)[0]
     qf = q.reshape(b * h, sq, d)
-    kf = k.reshape(b * hk, sk, d)
-    vf = v.reshape(b * hk, sk, d)
+    kf = k.reshape(b * hk, skp, d)
+    vf = v.reshape(b * hk, skp, d)
 
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda bh, iq, j: (bh, iq, 0)),
@@ -343,7 +370,7 @@ def _flash_fwd_pallas(q, k, v, bias, q_seg, k_seg, seed, scale, causal,
         in_specs.append(
             pl.BlockSpec((1, bk), lambda bh, iq, j: (0, ik_of(iq, j))))
         args += [jnp.asarray(q_pos, jnp.int32).reshape(sq, 1),
-                 jnp.asarray(k_pos, jnp.int32).reshape(1, sk)]
+                 jnp.asarray(k_pos, jnp.int32).reshape(1, skp)]
     else:
         in_specs += [None, None]
         args += [None, None]
@@ -368,7 +395,7 @@ def _flash_fwd_pallas(q, k, v, bias, q_seg, k_seg, seed, scale, causal,
                     o_ref, lse_ref, acc_sc, m_sc, l_sc,
                     scale=scale, causal=causal, window=window, rate=rate,
                     nk=nk, n_inner=n_inner, banded=banded,
-                    bq=bq, bk=bk, sq=sq, sk=sk)
+                    bq=bq, bk=bk, sq=sq, sk=sk, k_len=k_len)
 
     out, lse = pl.pallas_call(
         kernel,
@@ -403,7 +430,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                    bias_ref, qs_ref, ks_ref, seed_ref, glse_ref,
                    qp_ref, kp_ref, dq_ref, dq_sc,
                    *, scale, causal, window, rate, nk, n_inner, banded,
-                   bq, bk, sq, sk):
+                   bq, bk, sq, sk, k_len=None):
     j = pl.program_id(2)
     iq = pl.program_id(1)
     bh = pl.program_id(0)   # hoisted out of the pl.when branch (see fwd)
@@ -441,7 +468,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         s = s + _mask_block(
             iq, ik, bq, bk, sq, sk, causal, window, q_seg, k_seg,
             q_pos=qp_ref[...] if qp_ref is not None else None,
-            k_pos=kp_ref[...] if kp_ref is not None else None)
+            k_pos=kp_ref[...] if kp_ref is not None else None,
+            k_len=k_len)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -471,7 +499,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                     bias_ref, qs_ref, ks_ref, seed_ref, glse_ref,
                     qp_ref, kp_ref, dk_ref, dv_ref, dk_sc, dv_sc,
                     *, scale, causal, window, rate, nq, nq_inner, banded,
-                    h, hk, bq, bk, sq, sk):
+                    h, hk, bq, bk, sq, sk, k_len=None):
     # inner grid dim sweeps (q-head of the GQA group) x (q block):
     # t = g * nq_inner + j. The kv block stays resident; dk/dv accumulate
     # in VMEM across the whole group — no materialized kv repeat. With a
@@ -513,7 +541,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         s = s + _mask_block(
             iq, ik, bq, bk, sq, sk, causal, window, q_seg, k_seg,
             q_pos=qp_ref[...] if qp_ref is not None else None,
-            k_pos=kp_ref[...] if kp_ref is not None else None)
+            k_pos=kp_ref[...] if kp_ref is not None else None,
+            k_len=k_len)
         p = jnp.exp(s - lse)                       # (bq, bk)
         p_v = p                                    # what multiplied V
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
@@ -546,19 +575,21 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
 def _flash_bwd_pallas(res, g, delta, seed, scale, causal, window, rate,
                       bq, bk, interpret, glse=None,
-                      q_pos=None, k_pos=None):
+                      q_pos=None, k_pos=None, kv_len=None):
     q, k, v, bias, q_seg, k_seg, out, lse = res
     b, h, sq, d = q.shape
     hk = k.shape[1]
     group = h // hk          # GQA: q heads per shared kv head
-    sk = k.shape[2]
+    skp = k.shape[2]         # see _flash_fwd_pallas
+    sk = skp if kv_len is None else kv_len
+    k_len = None if sk == skp else sk
     bq = _pick_block(sq, bq)
-    bk = _pick_block(sk, bk)
-    nq, nk = sq // bq, sk // bk
+    bk = _pick_block(skp, bk)
+    nq, nk = sq // bq, skp // bk
 
     qf = q.reshape(b * h, sq, d)
-    kf = k.reshape(b * hk, sk, d)
-    vf = v.reshape(b * hk, sk, d)
+    kf = k.reshape(b * hk, skp, d)
+    vf = v.reshape(b * hk, skp, d)
     dof = g.reshape(b * h, sq, d)
     lsef = lse.reshape(b * h, sq, 1)     # column layout (Mosaic tiling)
     dlf = delta.reshape(b * h, sq, 1)
@@ -614,7 +645,7 @@ def _flash_bwd_pallas(res, g, delta, seed, scale, causal, window, rate,
             specs.append(pl.BlockSpec(
                 (1, bk), lambda *g_: (0, ik_of(*g_))))
             arr += [jnp.asarray(q_pos, jnp.int32).reshape(sq, 1),
-                    jnp.asarray(k_pos, jnp.int32).reshape(1, sk)]
+                    jnp.asarray(k_pos, jnp.int32).reshape(1, skp)]
         return specs, arr
 
     # banded sliding window (see _flash_fwd_pallas): inner dims walk only
@@ -652,7 +683,8 @@ def _flash_bwd_pallas(res, g, delta, seed, scale, causal, window, rate,
                        qp_ref, kp_ref, dq_ref, dq_sc,
                        scale=scale, causal=causal, window=window,
                        rate=rate, nk=nk, n_inner=nk_inner,
-                       banded=dq_banded, bq=bq, bk=bk, sq=sq, sk=sk)
+                       banded=dq_banded, bq=bq, bk=bk, sq=sq, sk=sk,
+                       k_len=k_len)
 
     dq = pl.pallas_call(
         dq_kernel,
@@ -706,7 +738,7 @@ def _flash_bwd_pallas(res, g, delta, seed, scale, causal, window, rate,
                         scale=scale, causal=causal, window=window,
                         rate=rate, nq=nq, nq_inner=nq_inner,
                         banded=dkv_banded, h=h, hk=hk,
-                        bq=bq, bk=bk, sq=sq, sk=sk)
+                        bq=bq, bk=bk, sq=sq, sk=sk, k_len=k_len)
 
     dk, dv = pl.pallas_call(
         dkv_kernel,
@@ -717,8 +749,8 @@ def _flash_bwd_pallas(res, g, delta, seed, scale, causal, window, rate,
             pl.BlockSpec((1, bk, d), lambda bhk, ik, t: (bhk, ik, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * hk, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * hk, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((b * hk, skp, d), k.dtype),
+            jax.ShapeDtypeStruct((b * hk, skp, d), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
@@ -730,8 +762,8 @@ def _flash_bwd_pallas(res, g, delta, seed, scale, causal, window, rate,
     )(*arr)
 
     return (dq.reshape(b, h, sq, d),
-            dk.reshape(b, hk, sk, d),
-            dv.reshape(b, hk, sk, d))
+            dk.reshape(b, hk, skp, d),
+            dv.reshape(b, hk, skp, d))
 
 
 # --------------------------------------------------------------------------
@@ -806,34 +838,38 @@ def _attention_xla(q, k, v, bias, q_seg, k_seg, scale, causal,
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(7, 8, 9, 10, 11, 12, 13, 14, 15))
+                   nondiff_argnums=(7, 8, 9, 10, 11, 12, 13, 14, 15, 16))
 def _flash(q, k, v, bias, q_seg, k_seg, seed, scale, causal, window, rate,
-           bq, bk, bbq, bbk, interpret):
+           bq, bk, bbq, bbk, interpret, kv_len):
     out, _ = _flash_fwd_pallas(q, k, v, bias, q_seg, k_seg, seed, scale,
-                               causal, window, rate, bq, bk, interpret)
+                               causal, window, rate, bq, bk, interpret,
+                               kv_len=kv_len)
     return out
 
 
 def _flash_fwd_rule(q, k, v, bias, q_seg, k_seg, seed, scale, causal,
-                    window, rate, bq, bk, bbq, bbk, interpret):
+                    window, rate, bq, bk, bbq, bbk, interpret, kv_len):
     out, lse = _flash_fwd_pallas(q, k, v, bias, q_seg, k_seg, seed, scale,
-                                 causal, window, rate, bq, bk, interpret)
+                                 causal, window, rate, bq, bk, interpret,
+                                 kv_len=kv_len)
     return out, (q, k, v, bias, q_seg, k_seg, seed, out, lse)
 
 
 def _flash_bwd_rule(scale, causal, window, rate, bq, bk, bbq, bbk,
-                    interpret, res, g):
+                    interpret, kv_len, res, g):
     q, k, v, bias, q_seg, k_seg, seed, out, lse = res
     core = (q, k, v, bias, q_seg, k_seg, out, lse)
     delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
     dq, dk, dv = _flash_bwd_pallas(core, g, delta, seed, scale, causal,
-                                   window, rate, bbq, bbk, interpret)
+                                   window, rate, bbq, bbk, interpret,
+                                   kv_len=kv_len)
     return _finish_bwd(core, g, delta, dq, dk, dv, seed, scale, causal,
-                       window, rate)
+                       window, rate, kv_len=kv_len)
 
 
 def _finish_bwd(res, g, delta, dq, dk, dv, seed, scale, causal, window,
-                rate, glse=None, q_pos=None, k_pos=None, with_pos=False):
+                rate, glse=None, q_pos=None, k_pos=None, with_pos=False,
+                kv_len=None):
     """Shared tail of the backward rule: bias cotangent by recompute
     plus the integer (segment-id / seed) cotangents."""
     q, k, v, bias, q_seg, k_seg, out, lse = res
@@ -843,7 +879,9 @@ def _finish_bwd(res, g, delta, dq, dk, dv, seed, scale, causal, window,
         # O(sq*sk) live memory, scatter-added into the (possibly
         # broadcast-shaped) bias cotangent.
         b, h, sq, _ = q.shape
-        sk = k.shape[2]
+        skp = k.shape[2]                # see _flash_fwd_pallas
+        sk = skp if kv_len is None else kv_len
+        k_len = None if sk == skp else sk
         group = h // k.shape[1]         # GQA: kv head shared per group
         b_b, h_b, sq_b, sk_b = bias.shape
         bmap = _bias_index_map(b_b, h_b, h)
@@ -856,13 +894,14 @@ def _finish_bwd(res, g, delta, dq, dk, dv, seed, scale, causal, window,
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
             s = s + bias[ib % b_b, ih % h_b].astype(jnp.float32)
-            if causal or window is not None:
+            if causal or window is not None or k_len is not None:
                 s = s + _mask_block(
-                    0, 0, sq, sk, sq, sk, causal, window, None, None,
+                    0, 0, sq, skp, sq, sk, causal, window, None, None,
                     q_pos=(q_pos.reshape(sq, 1)
                            if q_pos is not None else None),
-                    k_pos=(k_pos.reshape(1, sk)
-                           if k_pos is not None else None))
+                    k_pos=(k_pos.reshape(1, skp)
+                           if k_pos is not None else None),
+                    k_len=k_len)
             if q_seg is not None:
                 seg = q_seg[ib][:, None] != k_seg[ib][None, :]
                 s = jnp.where(seg, NEG_INF, s)
@@ -874,8 +913,8 @@ def _finish_bwd(res, g, delta, dq, dk, dv, seed, scale, causal, window,
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
             if rate > 0.0:
-                row = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
-                col = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
+                row = jax.lax.broadcasted_iota(jnp.int32, (sq, skp), 0)
+                col = jax.lax.broadcasted_iota(jnp.int32, (sq, skp), 1)
                 keep = _dropout_keep(seed, bh, row, col, rate)
                 dp = jnp.where(keep, dp / (1.0 - rate), 0.0)
             ds = p * (dp - delta[ib, ih][:, None])
@@ -906,41 +945,42 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(9, 10, 11, 12, 13, 14, 15, 16, 17))
+                   nondiff_argnums=(9, 10, 11, 12, 13, 14, 15, 16, 17, 18))
 def _flash_with_lse(q, k, v, bias, q_seg, k_seg, seed, q_pos, k_pos,
                     scale, causal, window, rate, bq, bk, bbq, bbk,
-                    interpret):
+                    interpret, kv_len):
     """Like ``_flash`` but also returns the per-row logsumexp (fp32,
     (b, h, sq); NEG_INF on fully-masked rows) as a differentiable
     output — the merge signal for ring/blockwise attention. Accepts
     dynamic global positions for chunked causal masking."""
     return _flash_fwd_pallas(q, k, v, bias, q_seg, k_seg, seed, scale,
                              causal, window, rate, bq, bk, interpret,
-                             q_pos=q_pos, k_pos=k_pos)
+                             q_pos=q_pos, k_pos=k_pos, kv_len=kv_len)
 
 
 def _flash_lse_fwd_rule(q, k, v, bias, q_seg, k_seg, seed, q_pos, k_pos,
                         scale, causal, window, rate, bq, bk, bbq, bbk,
-                        interpret):
+                        interpret, kv_len):
     out, lse = _flash_fwd_pallas(q, k, v, bias, q_seg, k_seg, seed, scale,
                                  causal, window, rate, bq, bk, interpret,
-                                 q_pos=q_pos, k_pos=k_pos)
+                                 q_pos=q_pos, k_pos=k_pos, kv_len=kv_len)
     return (out, lse), (q, k, v, bias, q_seg, k_seg, seed, q_pos, k_pos,
                         out, lse)
 
 
 def _flash_lse_bwd_rule(scale, causal, window, rate, bq, bk, bbq, bbk,
-                        interpret, res, gs):
+                        interpret, kv_len, res, gs):
     g, glse = gs
     q, k, v, bias, q_seg, k_seg, seed, q_pos, k_pos, out, lse = res
     core = (q, k, v, bias, q_seg, k_seg, out, lse)
     delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
     dq, dk, dv = _flash_bwd_pallas(core, g, delta, seed, scale, causal,
                                    window, rate, bbq, bbk, interpret,
-                                   glse=glse, q_pos=q_pos, k_pos=k_pos)
+                                   glse=glse, q_pos=q_pos, k_pos=k_pos,
+                                   kv_len=kv_len)
     return _finish_bwd(core, g, delta, dq, dk, dv, seed, scale, causal,
                        window, rate, glse=glse, q_pos=q_pos, k_pos=k_pos,
-                       with_pos=True)
+                       with_pos=True, kv_len=kv_len)
 
 
 _flash_with_lse.defvjp(_flash_lse_fwd_rule, _flash_lse_bwd_rule)
@@ -1052,31 +1092,42 @@ def flash_attention(
     # (the dq/dkv kernels have different reuse patterns than the fwd)
     bbq = bwd_block_q if bwd_block_q is not None else block_q
     bbk = bwd_block_k if bwd_block_k is not None else block_k
-    if impl != "xla":
-        # blocks of 2048 CRASH the Mosaic compiler (round-3 chip
-        # evidence); refuse before the shape reaches it
-        from apex_tpu.ops.mosaic_limits import check_block
-
-        isz = jnp.dtype(q.dtype).itemsize
-        d_head = q.shape[-1]
-        for nm, blk in (("block_q", block_q), ("block_k", block_k),
-                        ("bwd_block_q", bbq), ("bwd_block_k", bbk)):
-            check_block(blk, d_head, isz, what=f"flash {nm}")
     if impl == "xla":
         return _attention_xla(q, k, v, bias, segment_ids, kv_segment_ids,
                               softmax_scale, causal, window_size,
                               dropout_rate, seed, return_lse=return_lse,
                               q_pos=q_positions, k_pos=kv_positions)
+    # a key count no lane-aligned block divides: pad the k axis, mask
+    # the tail in the kernels (_kv_pad). jnp.pad's transpose slices the
+    # padded rows off dk/dv/dbias again.
+    sk = k.shape[2]
+    pad = max(_kv_pad(sk, block_k), _kv_pad(sk, bbk))
+    kv_len = None
+    if pad:
+        kv_len = sk
+
+        def pad_keys(x, axis):
+            widths = [(0, 0)] * x.ndim
+            widths[axis] = (0, pad)
+            return jnp.pad(x, widths)
+
+        k, v = pad_keys(k, 2), pad_keys(v, 2)
+        if kv_segment_ids is not None:
+            kv_segment_ids = pad_keys(kv_segment_ids, 1)
+        if kv_positions is not None:
+            kv_positions = pad_keys(jnp.asarray(kv_positions), 0)
+        if bias is not None and bias.shape[3] == sk:
+            bias = pad_keys(bias, 3)
     if return_lse or q_positions is not None:
         out = _flash_with_lse(
             q, k, v, bias, segment_ids, kv_segment_ids, seed,
             q_positions, kv_positions,
             softmax_scale, causal, window_size, float(dropout_rate),
-            block_q, block_k, bbq, bbk, interpret_flag(impl))
+            block_q, block_k, bbq, bbk, interpret_flag(impl), kv_len)
         return out if return_lse else out[0]
     return _flash(q, k, v, bias, segment_ids, kv_segment_ids, seed,
                   softmax_scale, causal, window_size, float(dropout_rate),
-                  block_q, block_k, bbq, bbk, interpret_flag(impl))
+                  block_q, block_k, bbq, bbk, interpret_flag(impl), kv_len)
 
 
 __all__ = ["flash_attention"]

@@ -25,7 +25,7 @@ donation-aware program:
   factor is a global function of every element — so the clip path is
   update+1 passes; everything else is zero-extra-pass.)
 - per-tensor grad norms (``with_grad_norm=True``) ride the update
-  itself: the segmented kernel's phase-0 one-hot matmul accumulators
+  itself: the segmented kernel's phase-0 one-hot accumulators
   and the two-stage stage-1 sumsq partials (multi_tensor/segmented.py,
   multi_tensor/ops.py) — monitoring at zero extra HBM passes.
 

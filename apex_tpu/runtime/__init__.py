@@ -14,14 +14,17 @@ The reference's host-side C++ runtime maps here (SURVEY.md §2.1/§2.8):
     ref examples/imagenet/main_amp.py:256-300): while the device runs
     step N, worker threads stage and ``jax.device_put`` batch N+1.
 
-The C++ library is compiled on first use with g++ (cached under
-``apex_tpu/_build``); every entry point falls back to numpy when the
-toolchain is unavailable, so behavior is identical either way.
+The C++ library is compiled on first use with g++ (kept under
+``apex_tpu/_build``, which git ignores: a checkout never carries a
+binary). Where it cannot be built or loaded, one warning says why and
+every entry point runs its numpy substitute, so behavior is identical
+either way; :func:`native_available` says which of the two is in use.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import queue
 import subprocess
@@ -53,7 +56,8 @@ def _build_dir() -> str:
 
 
 def _load_library():
-    """Compile (once) and dlopen the native library; None on failure."""
+    """Compile (once) and dlopen the native library; None — after one
+    warning that names the cause — where that fails."""
     global _lib, _lib_tried
     if _lib_tried:
         return _lib
@@ -77,7 +81,7 @@ def _load_library():
         lib = ctypes.CDLL(lib_path)
         lib.apex_host_runtime_abi_version.restype = ctypes.c_int
         if lib.apex_host_runtime_abi_version() != 1:
-            return None
+            raise OSError(f"{lib_path} has another ABI version")
         lib.apex_flatten.argtypes = [
             ctypes.c_char_p, ctypes.POINTER(ctypes.c_char_p),
             ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
@@ -91,7 +95,14 @@ def _load_library():
         lib.apex_cast_bf16_f32.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64]
         _lib = lib
-    except Exception:
+    except (OSError, subprocess.CalledProcessError) as e:
+        # g++'s own last line where it ran and failed, else the error
+        said = (getattr(e, "stderr", None) or b"").decode(
+            errors="replace").strip().splitlines()
+        logging.getLogger("apex_tpu").warning(
+            "native host runtime not available (%s: %s) — the numpy "
+            "substitute runs instead", type(e).__name__,
+            said[-1] if said else e)
         _lib = None
     return _lib
 

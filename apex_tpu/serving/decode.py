@@ -272,6 +272,39 @@ class DecodeStep:
             out[key[0]] += 1
         return out
 
+    def lower(self, fn: str, params, state: KVCacheState, batch: int,
+              table_width: int, seq: int = 1):
+        """``jax.jit(...).lower`` passthrough for one bucketed program
+        (``TrainStep.lower``'s sibling): ``fn`` is ``"decode_step"``,
+        ``"prefill_step"`` or ``"prefill_chunk"`` (the last two take
+        the ``seq`` bucket). Built from shapes alone — ``params`` and
+        ``state`` may be ``jax.ShapeDtypeStruct`` trees — so a
+        program's memory analysis and text can be had without a
+        dispatch."""
+        import jax
+
+        jnp = self._jnp
+
+        def ints(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        sampling = (jax.ShapeDtypeStruct((batch,), jnp.float32), ints(batch),
+                    jax.ShapeDtypeStruct((batch,), jnp.float32),
+                    jax.ShapeDtypeStruct((batch,), jnp.uint32))
+        tables = ints(batch, table_width)
+        if fn == "decode_step":
+            return self._decode_jit.lower(
+                params, state, ints(batch), ints(batch), tables, *sampling)
+        if fn == "prefill_step":
+            return self._prefill_jit.lower(
+                params, state, ints(batch, seq), ints(batch), tables,
+                *sampling)
+        if fn == "prefill_chunk":
+            return self._prefill_chunk_jit.lower(
+                params, state, ints(batch, seq), ints(batch), ints(batch),
+                tables, *sampling)
+        raise ValueError(f"unknown serving program {fn!r}")
+
     # -- dispatchers ---------------------------------------------------------
 
     def _sampling_arrays(self, b: int, sampling):

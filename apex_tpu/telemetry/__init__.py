@@ -73,8 +73,6 @@ Who publishes here (the instrumentation pass):
 - ``runtime.PrefetchLoader``: queue depth, device_put retries, worker
   deaths, degrade flag (+ ``data_wait`` spans when the global
   timeline is on).
-- ``backend_guard``: probe verdicts and cache hits — what
-  ``bench.py`` reads instead of an ad-hoc module global.
 - ``records.latest_record``: corrupt/unreadable record files skipped.
 
 Everything is host-side; nothing here adds arguments to, or changes

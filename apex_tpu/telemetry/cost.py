@@ -6,7 +6,7 @@ accounting of a compiled program — model FLOPs and HBM bytes accessed
 a measured step time gives:
 
 - **MFU** (model FLOPs utilization) against the chip's published peak
-  (``backend_guard.chip_peak_tflops``), the TorchTitan-style headline
+  (:func:`chip_peak_tflops`), the TorchTitan-style headline
   efficiency number;
 - **achieved HBM bandwidth** for the memory-bound phases (the fused
   optimizer step's real ceiling — see docs/train_step.md's
@@ -112,6 +112,30 @@ def device_kind() -> str:
         return "unknown"
 
 
+def chip_peak_tflops(device_kind: str) -> float:
+    """Peak dense bf16-matmul TFLOP/s per chip for MFU accounting, by
+    the ``device_kind`` jax reports (a v5e says ``TPU v5 lite``).
+
+    bf16 only — the dtype every bench mode computes in. An unknown
+    kind raises: a measurement never gets a made-up denominator.
+    """
+    kind = device_kind.lower()
+    table = [
+        ("v6", 918.0),           # Trillium / v6e
+        ("v5p", 459.0),
+        ("v5", 197.0),           # v5 lite / v5e
+        ("v4", 275.0),
+        ("v3", 123.0),
+        ("v2", 45.0),
+    ]
+    for pat, peak in table:
+        if pat in kind:
+            return peak
+    raise ValueError(
+        f"no peak-TFLOPs entry for device kind {device_kind!r} — mfu "
+        "denominator unknown")
+
+
 def mfu_estimate(cost: Optional[Dict[str, float]], seconds: float,
                  kind: Optional[str] = None) -> Dict[str, Any]:
     """MFU + bandwidth accounting for one timed step.
@@ -120,13 +144,16 @@ def mfu_estimate(cost: Optional[Dict[str, float]], seconds: float,
     when None ``mfu_reason`` names exactly why (no cost model, unknown
     chip, bad timing) so downstream JSON consumers never guess.
     """
-    from apex_tpu.backend_guard import chip_peak_tflops
-
     kind = kind if kind is not None else device_kind()
+    try:
+        peak = chip_peak_tflops(kind)
+        peak_reason = None
+    except ValueError as e:
+        peak, peak_reason = None, str(e)
     out: Dict[str, Any] = {
         "flops_per_step": None, "bytes_per_step": None,
         "tflops_per_sec": None, "hbm_gb_per_sec": None,
-        "chip": kind, "chip_peak_tflops": chip_peak_tflops(kind),
+        "chip": kind, "chip_peak_tflops": peak,
         "mfu": None, "mfu_reason": None,
     }
     if cost is None:
@@ -146,10 +173,8 @@ def mfu_estimate(cost: Optional[Dict[str, float]], seconds: float,
         return out
     tflops = out["flops_per_step"] / seconds / 1e12
     out["tflops_per_sec"] = round(tflops, 4)
-    peak = out["chip_peak_tflops"]
-    if not peak:
-        out["mfu_reason"] = (f"no peak-TFLOPs entry for device kind "
-                             f"{kind!r} — mfu denominator unknown")
+    if peak is None:
+        out["mfu_reason"] = peak_reason
         return out
     out["mfu"] = round(tflops / peak, 6)
     return out
@@ -213,6 +238,7 @@ def publish_mfu_window(cost: Optional[Dict[str, float]], seconds: float,
 
 __all__ = [
     "bytes_per_element",
+    "chip_peak_tflops",
     "compiled_cost",
     "device_kind",
     "jitted_cost",

@@ -40,10 +40,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-
-from apex_tpu._compat import shard_map
 
 from apex_tpu.ops.attention import NEG_INF, flash_attention
 from apex_tpu.transformer.parallel_state import CONTEXT_AXIS, DATA_AXIS

@@ -100,4 +100,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from apex_tpu import compile_cache
+
+    compile_cache.enable()
     main()

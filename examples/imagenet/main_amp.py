@@ -208,4 +208,7 @@ class _null:
 
 
 if __name__ == "__main__":
+    from apex_tpu import compile_cache
+
+    compile_cache.enable()
     main()

@@ -130,4 +130,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from apex_tpu import compile_cache
+
+    compile_cache.enable()
     sys.exit(0 if np.isfinite(main()) else 1)

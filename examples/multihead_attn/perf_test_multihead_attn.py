@@ -109,4 +109,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from apex_tpu import compile_cache
+
+    compile_cache.enable()
     sys.exit(0 if main() else 1)

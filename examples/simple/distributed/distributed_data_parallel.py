@@ -113,4 +113,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from apex_tpu import compile_cache
+
+    compile_cache.enable()
     main()

@@ -4,7 +4,7 @@ extension wiring — setup.py:247-855).
 The TPU build needs none of that: the compute kernels are Pallas
 (compiled by XLA at trace time) and the only native artifact is the
 host-runtime shared library, which apex_tpu.runtime compiles lazily
-with g++ on first use and caches under apex_tpu/_build/. ``--cpp_ext``
+with g++ on first use and keeps under apex_tpu/_build/. ``--cpp_ext``
 is accepted for reference-CLI parity and pre-builds that library
 eagerly."""
 
@@ -29,9 +29,10 @@ setup(
         "kernels, and a full mesh-parallelism stack (JAX/XLA/Pallas)"
     ),
     packages=find_packages(include=["apex_tpu", "apex_tpu.*"]),
-    # ship the source and any pre-built library; read-only installs
-    # fall back to compiling into ~/.cache/apex_tpu (runtime._build_dir)
-    package_data={"apex_tpu": ["csrc/*.cpp", "_build/*.so"]},
+    # ship the source, never a binary: the library is built on first
+    # use (read-only installs compile into ~/.cache/apex_tpu,
+    # runtime._build_dir)
+    package_data={"apex_tpu": ["csrc/*.cpp"]},
     python_requires=">=3.10",
     install_requires=["jax", "flax", "numpy"],
     extras_require={"test": ["pytest", "optax", "orbax-checkpoint", "torch"]},

@@ -227,34 +227,34 @@ class TestProfiler:
             ps.destroy_model_parallel()
 
 
-class TestBackendProbe:
-    """Runtime Mosaic probe (the reference's multi_tensor_applier.available
-    analog): a working backend reports available; the default degrades to
-    xla rather than erroring when kernels can't compile."""
+class TestDefaultImpl:
+    """``_backend.default_impl``: Pallas on a TPU, XLA elsewhere, the
+    env override first. Nothing probes, nothing downgrades — a kernel
+    that does not compile on the chip raises where it is called."""
 
-    def test_probe_runs_and_caches(self):
+    def test_default_follows_the_backend(self, monkeypatch):
         from apex_tpu import _backend
 
-        _backend.pallas_available.cache_clear()
+        monkeypatch.delenv("APEX_TPU_IMPL", raising=False)
+        _backend.default_impl.cache_clear()
         try:
-            # CPU: interpret=False pallas lowers via the CPU backend in
-            # current jax — either outcome is valid, but it must not raise
-            # and must be memoized
-            r1 = _backend.pallas_available()
-            r2 = _backend.pallas_available()
-            assert isinstance(r1, bool) and r1 == r2
-            assert _backend.pallas_available.cache_info().hits == 1
+            assert _backend.is_tpu() is False          # tier-1 runs on CPU
+            assert _backend.default_impl() == "xla"
+            _backend.default_impl.cache_clear()
+            monkeypatch.setattr(_backend, "is_tpu", lambda: True)
+            assert _backend.default_impl() == "pallas"
         finally:
-            _backend.pallas_available.cache_clear()
+            _backend.default_impl.cache_clear()
 
-    def test_default_impl_env_override_skips_probe(self, monkeypatch):
+    def test_default_impl_env_override_wins(self, monkeypatch):
         from apex_tpu import _backend
 
         def boom():
-            raise AssertionError("probe must not run under env override")
+            raise AssertionError("backend must not be asked under the "
+                                 "env override")
 
         monkeypatch.setenv("APEX_TPU_IMPL", "xla")
-        monkeypatch.setattr(_backend, "pallas_available", boom)
+        monkeypatch.setattr(_backend, "is_tpu", boom)
         _backend.default_impl.cache_clear()
         try:
             assert _backend.default_impl() == "xla"

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from apex_tpu import telemetry
+from apex_tpu.telemetry import cost as tcost
 from apex_tpu.telemetry import metrics as tmetrics
 from apex_tpu.telemetry import timeline as ttimeline
 
@@ -660,25 +661,28 @@ class TestInstrumentationPass:
         assert reg.counter("telemetry_events").value(
             event="record_corrupt_skipped") == 1.0
 
-    def test_backend_report_published_and_read_back(self):
-        from apex_tpu import backend_guard
+    def test_chip_peak_by_reported_device_kind(self):
+        # the string a v5e reports, and its neighbours in the table
+        assert tcost.chip_peak_tflops("TPU v5 lite") == 197.0
+        assert tcost.chip_peak_tflops("TPU v5p") == 459.0
+        assert tcost.chip_peak_tflops("TPU v4") == 275.0
+        # an unknown kind is an error on a measurement path ...
+        with pytest.raises(ValueError, match="no peak-TFLOPs entry"):
+            tcost.chip_peak_tflops("cpu")
+        # ... and the telemetry estimate's null-with-reason
+        est = tcost.mfu_estimate({"flops": 1e9, "bytes_accessed": 1e6},
+                                 1e-3, kind="cpu")
+        assert est["mfu"] is None and est["chip_peak_tflops"] is None
+        assert "no peak-TFLOPs entry" in est["mfu_reason"]
 
-        report = backend_guard.BackendReport(
-            "cpu", 1, fallback=True, note="probe timed out",
-            probe={"ok": False, "error": "timeout", "cached": True,
-                   "age_s": 3.0})
-        report.publish()
-        det = backend_guard.published_report_detail()
-        assert det["backend"] == "cpu"
-        assert det["backend_fallback"] == "probe timed out"
-        assert det["backend_probe"]["cached"] is True
-        reg = telemetry.registry()
-        assert reg.counter("backend_probe_cache_hits").value() == 1.0
-        assert reg.counter("backend_fallbacks").value() == 1.0
-        # bench reads the same verdict through the registry
+    def test_bench_names_the_device_that_ran(self):
+        # bench reads jax.devices() itself: no guard report in between
         import bench
 
-        assert bench.backend_detail()["backend"] == "cpu"
+        det = bench.backend_detail()
+        assert det["backend"] == "cpu"
+        assert det["n_devices"] == jax.device_count()
+        assert det["device_kind"] == jax.devices()[0].device_kind
 
     def test_timers_publish_into_global_timeline(self):
         from apex_tpu.transformer.pipeline_parallel import Timers

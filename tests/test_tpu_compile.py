@@ -1,0 +1,226 @@
+"""Ask the TPU compiler, without a TPU.
+
+The chip's compiler is installed beside jax and compiles for a chip
+that is described and not attached, so what it refuses costs no chip
+time: a block shape the Pallas lowering will not take, a construct the
+Mosaic layout pass aborts on, a program that does not fit the device's
+memory. Interpret-mode tests see none of these. The cases are the
+kernels of the two main paths at the widths ``chip_smoke.py`` runs
+them — the GPT-2 345M trainer and the paged-KV server — then the whole
+train step at the smoke's batch. Nothing runs here, so nothing is said
+about results or times.
+
+``default_impl()`` sees the CPU in such a compile, so the kernels get
+``impl="pallas"`` and the whole step the ``APEX_TPU_IMPL`` override.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import chip_smoke
+from apex_tpu import _backend
+
+#: what chip_smoke.py's trainer settles on for a v5e, and the memory
+#: that chip reports (15.75 GiB of its 16 GB)
+SMOKE_BATCH = 2
+V5E_BYTES = 15.75 * 2**30
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """``shape, dtype -> ShapeDtypeStruct`` on one described v5e chip
+    (``chip.device``); skipped where the topology cannot be described."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler here: skip
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    chip.device = topo.devices[0]
+    return chip
+
+
+@pytest.fixture(autouse=True)
+def _no_compilation_cache():
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without a chip (the next one warns)
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _flash(sq, sk, *, causal, segs, b=16, h=16, hk=4, grad=False):
+    from apex_tpu.ops.attention import flash_attention
+
+    def build(chip):
+        q = chip((b, h, sq, 64), jnp.bfloat16)
+        k = chip((b, hk, sk, 64), jnp.bfloat16)
+        seg = chip((b, sk), jnp.int32)
+
+        def fwd(q, k, v, seg):
+            return flash_attention(q, k, v, causal=causal,
+                                   kv_segment_ids=seg if segs else None,
+                                   impl="pallas")
+
+        def fwd_bwd(q, k, v, seg):
+            return jax.grad(lambda *a: jnp.sum(
+                fwd(*a, seg).astype(jnp.float32)), argnums=(0, 1, 2))(
+                    q, k, v)
+
+        return (fwd_bwd if grad else fwd), (q, k, k, seg)
+
+    return build
+
+
+def _layer_norm(chip):
+    from apex_tpu.ops.layer_norm import fused_layer_norm
+
+    def fwd_bwd(x, w, b):
+        return jax.grad(lambda *a: jnp.sum(fused_layer_norm(
+            *a, impl="pallas").astype(jnp.float32)), argnums=(0, 1, 2))(
+                x, w, b)
+
+    w = chip((1024,), jnp.float32)
+    return fwd_bwd, (chip((SMOKE_BATCH * 1024, 1024), jnp.bfloat16), w, w)
+
+
+def _xentropy(chip):
+    from apex_tpu.ops.xentropy import softmax_cross_entropy_loss
+
+    def fwd_bwd(logits, labels):
+        return jax.grad(lambda lg: jnp.sum(softmax_cross_entropy_loss(
+            lg, labels, impl="pallas")))(logits)
+
+    rows = SMOKE_BATCH * 1024
+    return fwd_bwd, (chip((rows, 50304), jnp.float32),
+                     chip((rows,), jnp.int32))
+
+
+def _fused_adam(chip):
+    from apex_tpu import multi_tensor as mt
+
+    buf = chip((8 * 2**20,), jnp.float32)
+    return (lambda p, m, v, g: mt.fused_adam_update(
+        p, m, v, g, lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-8, step=1,
+        weight_decay=0.01, impl="pallas")), (buf, buf, buf, buf)
+
+
+def _lamb_tree(dtype):
+    # a layer's worth of GPT-2 345M leaves: small ones that share a
+    # segment, and a kernel too large for one (the two-stage slice)
+    shapes = [(1024,), (1024,), (3072, 1024), (3072,), (1024, 1024),
+              (1024,), (4096, 1024), (4096,)]
+    return {f"p{i}": jax.ShapeDtypeStruct(s, dtype)
+            for i, s in enumerate(shapes)}
+
+
+def _two_stage_lamb(chip):
+    from apex_tpu import multi_tensor as mt
+
+    space = mt.FlatSpace.create(_lamb_tree(jnp.float32))
+    buf = chip((space.total,), jnp.float32)
+    return (lambda p, m, v, g: mt.fused_lamb_update(
+        p, m, v, g, space, lr=1e-3, step=1, weight_decay=0.01,
+        impl="pallas")), (buf, buf, buf, buf)
+
+
+def _segmented_lamb(dtype, sr_seed):
+    from apex_tpu.multi_tensor.flat_buffer import segmented_space
+    from apex_tpu.multi_tensor.segmented import (
+        CHUNK,
+        fused_lamb_segmented_update,
+    )
+
+    def build(chip):
+        space, meta = segmented_space(_lamb_tree(dtype),
+                                      seg_elems=16 * CHUNK)
+        assert meta.small_segments and meta.large    # both paths in it
+        f32 = chip((space.total,), jnp.float32)
+        return (lambda p, m, v, g: fused_lamb_segmented_update(
+            p, m, v, g, space, meta, lr=1e-3, step=1, weight_decay=0.01,
+            use_nvlamb=True, impl="pallas", sr_seed=sr_seed)), (
+                chip((space.total,), dtype), f32, f32, f32)
+
+    return build
+
+
+KERNELS = {
+    "flash fwd+bwd (b,16,1024,64) causal": _flash(
+        1024, 1024, causal=True, segs=False, b=SMOKE_BATCH, hk=16,
+        grad=True),
+    # one query over a cached prefix plus itself: sk = L + 1
+    "flash decode sk=513": _flash(1, 513, causal=False, segs=True),
+    "flash decode sk=1025": _flash(1, 1025, causal=False, segs=True),
+    "flash decode sk=2049": _flash(1, 2049, causal=False, segs=True),
+    # a 32-token chunk over 1024 cached tokens: sk = L + s
+    "flash chunked prefill sq=32 sk=1056": _flash(
+        32, 1056, causal=True, segs=True, b=2),
+    "layer norm fwd+bwd": _layer_norm,
+    "xentropy fwd+bwd vocab 50304": _xentropy,
+    "fused adam, flat 8M": _fused_adam,
+    "two-stage lamb": _two_stage_lamb,
+    "segmented lamb fp32": _segmented_lamb(jnp.float32, None),
+    "segmented lamb bf16 + stochastic rounding": _segmented_lamb(
+        jnp.bfloat16, 7),
+}
+
+
+@pytest.mark.parametrize("name", list(KERNELS))
+def test_kernel_compiles_for_v5e(chip, name):
+    fn, args = KERNELS[name](chip)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") > 0
+
+
+def test_gpt2_345m_train_step_fits_and_holds_kernels(chip, monkeypatch):
+    """The whole single-chip train step, as ``chip_smoke.py`` builds
+    it, at the smoke's batch: it fits the device with headroom and
+    every fused op is a kernel in it."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from apex_tpu import mesh as gmesh
+    from apex_tpu.models.gpt import GPTConfig, GPTModel
+    from apex_tpu.models.pretrain import init_gpt_pretrain_params
+    from apex_tpu.optimizers import FusedAdam
+
+    monkeypatch.setenv("APEX_TPU_IMPL", "pallas")
+    _backend.default_impl.cache_clear()
+    try:
+        cfg = GPTConfig.gpt2_345m(attention_backend="flash",
+                                  dtype=jnp.bfloat16)
+        shapes = jax.eval_shape(
+            lambda key: init_gpt_pretrain_params(cfg, key),
+            jax.random.PRNGKey(0))
+        opt = FusedAdam(lr=3e-4, weight_decay=0.01)
+        one = Mesh(np.asarray([chip.device]).reshape(1, 1, 1),
+                   gmesh.MESH_AXES)
+        step = gmesh.make_mesh_train_step(
+            GPTModel(cfg), opt, gmesh.plan_gpt(shapes, mesh=one))
+        state = jax.tree.map(lambda x: chip(x.shape, x.dtype),
+                             jax.eval_shape(opt.init, shapes))
+        tok = chip((SMOKE_BATCH, cfg.max_seq_len), jnp.int32)
+        compiled = step.lower(state, tok, tok).compile()
+    finally:
+        _backend.default_impl.cache_clear()
+    # flash fwd + 2 bwd, 3 layer norms fwd + bwd, the fused Adam sweep
+    assert compiled.as_text().count("tpu_custom_call") == 10
+    assert (chip_smoke.program_bytes(compiled)
+            <= chip_smoke.HEADROOM * V5E_BYTES)

@@ -31,12 +31,13 @@ Usage (see check_resilience.sh for the orchestration)::
 import os
 import sys
 
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from _cpu_mode import force_cpu  # noqa: E402
-
-force_cpu()
+# a multi-process CPU drill: each rank is one CPU process and none may
+# take a chip (set before jax is imported)
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault(
+    "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import numpy as np  # noqa: E402
 
