@@ -37,6 +37,7 @@ import dataclasses
 import math
 from typing import Any, Dict, Optional, Sequence, Tuple
 
+import numpy as np
 
 BATCH_AXIS = "batch"
 MODEL_AXIS = "model"
@@ -111,12 +112,18 @@ def initialize_mesh(batch: Optional[int] = None, model: int = 1,
         raise ValueError(
             f"batch({batch}) x model({model}) x pipe({pipe}) != "
             f"device count {world}")
-    # topology-aware on TPU (model innermost on ICI neighbours); a plain
-    # reshape of the device list on backends with no topology
-    from jax.experimental import mesh_utils
+    shape = (batch, pipe, model)
+    if devices is None:
+        # topology-aware on TPU (model innermost on ICI neighbours); a
+        # plain reshape on backends with no topology. An error here is
+        # the caller's to see.
+        from jax.experimental import mesh_utils
 
-    arr = mesh_utils.create_device_mesh(
-        (batch, pipe, model), devices=devs, allow_split_physical_axes=True)
+        arr = mesh_utils.create_device_mesh(
+            shape, devices=devs, allow_split_physical_axes=True)
+    else:
+        # the caller's own list, in the caller's order
+        arr = np.asarray(devs).reshape(shape)
     _MESH = Mesh(arr, MESH_AXES)
     return _MESH
 

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from apex_tpu.normalization import FusedLayerNorm
+from apex_tpu.normalization import fused_layer_norm
 from apex_tpu.transformer.functional import AttnMaskType, FusedScaleMaskSoftmax
 from apex_tpu.transformer.parallel_state import TENSOR_AXIS
 from apex_tpu.transformer.tensor_parallel import (
@@ -197,6 +197,27 @@ def _flash(cfg: "GPTConfig", q, k, v, *, kv_segment_ids=None, **kw):
             "attention dropout inside the flash kernel is not supported "
             "on a >1-device GSPMD mesh")
     return island(*args)
+
+
+class _LayerNorm(nn.Module):
+    """``FusedLayerNorm``'s parameters and kernel over the seq-major
+    (s, b, hidden) interior. Rows are independent, so under an armed
+    GSPMD mesh the kernel runs per shard of them (``on_shards``, as
+    ``_flash``): batch on the ``batch`` axis, where ``constrain_hidden``
+    left it, hidden whole, scale and bias replicated."""
+
+    hidden_size: int
+
+    @nn.compact
+    def __call__(self, x):
+        from apex_tpu.mesh.mesh import BATCH_AXIS
+
+        shape = (self.hidden_size,)
+        w = self.param("scale", nn.initializers.ones, shape, jnp.float32)
+        b = self.param("bias", nn.initializers.zeros, shape, jnp.float32)
+        rows = P(None, BATCH_AXIS, None)
+        return _gspmd.on_shards(
+            fused_layer_norm, None, (rows, P(), P()), rows)(x, w, b)
 
 
 class ParallelAttention(nn.Module):
@@ -423,7 +444,7 @@ class GPTLayer(nn.Module):
                  kv_ctx=None, return_kv=False):
         cfg = self.config
         a = ParallelAttention(cfg, name="attention")(
-            FusedLayerNorm(cfg.hidden_size, name="input_norm")(x),
+            _LayerNorm(cfg.hidden_size, name="input_norm")(x),
             positions=positions, deterministic=deterministic,
             kv_ctx=kv_ctx, return_kv=return_kv,
         )
@@ -439,11 +460,11 @@ class GPTLayer(nn.Module):
             from apex_tpu.moe import MoEMLP
 
             m = MoEMLP(cfg.moe_cfg(), impl=cfg.moe_impl, name="mlp")(
-                FusedLayerNorm(cfg.hidden_size, name="post_norm")(x)
+                _LayerNorm(cfg.hidden_size, name="post_norm")(x)
             )
         else:
             m = ParallelMLP(cfg, name="mlp")(
-                FusedLayerNorm(cfg.hidden_size, name="post_norm")(x)
+                _LayerNorm(cfg.hidden_size, name="post_norm")(x)
             )
         if cfg.hidden_dropout > 0.0 and not deterministic:
             m = nn.Dropout(rate=cfg.hidden_dropout)(m, deterministic=False)
@@ -572,7 +593,7 @@ class GPTModel(nn.Module):
             if serving:
                 kvs = (jnp.stack([kv[0] for kv in per_layer]),
                        jnp.stack([kv[1] for kv in per_layer]))
-        x = FusedLayerNorm(cfg.hidden_size, name="final_norm")(x)
+        x = _LayerNorm(cfg.hidden_size, name="final_norm")(x)
 
         if cfg.sequence_parallel and _inside_axis(TENSOR_AXIS):
             from apex_tpu.transformer.tensor_parallel import (

@@ -245,34 +245,6 @@ def _normalize_args(x, normalized_ndim):
     return x.reshape(-1, hidden), shape
 
 
-def _norm_rows(x, weight, bias, eps, rms, impl):
-    """Flatten to (rows, hidden), normalize, restore the shape. Rows
-    are independent, so under an armed GSPMD mesh the kernel runs per
-    shard of them (mesh/annotate.py ``on_shards`` — the compiler cannot
-    partition a Mosaic kernel itself), split the way the model's hint
-    vocabulary lays an activation out: the batch dim — dim 1 of a
-    seq-major (s, b, hidden) array, dim 0 of a 2-D one — on the
-    ``batch`` axis, hidden whole."""
-    from jax.sharding import PartitionSpec as P
-
-    from apex_tpu.mesh import annotate
-    from apex_tpu.mesh.mesh import BATCH_AXIS
-
-    impl = resolve_impl(impl)
-    ndim = weight.ndim if weight is not None else 1
-    params = [p for p in (weight, bias) if p is not None]
-
-    def local(x, w=None, b=None):
-        x2, shape = _normalize_args(x, ndim)
-        w, b = (p if p is None else p.reshape(1, -1) for p in (w, b))
-        return _norm(x2, w, b, eps, rms, impl).reshape(shape)
-
-    rows = {2: P(BATCH_AXIS, None), 3: P(None, BATCH_AXIS, None)}.get(
-        x.ndim if ndim == 1 else None, P())
-    return annotate.on_shards(
-        local, impl, (rows,) + (P(),) * len(params), rows)(x, *params)
-
-
 def fused_layer_norm(
     x: jax.Array,
     weight: Optional[jax.Array] = None,
@@ -288,7 +260,13 @@ def fused_layer_norm(
     compute is fp32, output dtype follows ``x`` — the reference's
     ``MixedFusedLayerNorm`` semantics (fused_layer_norm.py:204-433).
     """
-    return _norm_rows(x, weight, bias, eps, False, impl)
+    impl = resolve_impl(impl)
+    ndim = weight.ndim if weight is not None else 1
+    x2, shape = _normalize_args(x, ndim)
+    w = weight.reshape(1, -1) if weight is not None else None
+    b = bias.reshape(1, -1) if bias is not None else None
+    y = _norm(x2, w, b, eps, False, impl)
+    return y.reshape(shape)
 
 
 def fused_rms_norm(
@@ -300,4 +278,9 @@ def fused_rms_norm(
 ) -> jax.Array:
     """Fused RMS norm (ref: apex.normalization.FusedRMSNorm,
     fused_layer_norm.py rms_forward_* bindings)."""
-    return _norm_rows(x, weight, None, eps, True, impl)
+    impl = resolve_impl(impl)
+    ndim = weight.ndim if weight is not None else 1
+    x2, shape = _normalize_args(x, ndim)
+    w = weight.reshape(1, -1) if weight is not None else None
+    y = _norm(x2, w, None, eps, True, impl)
+    return y.reshape(shape)

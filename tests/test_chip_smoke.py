@@ -53,7 +53,33 @@ def test_server_phase(watch, capsys):
         requests=[("short", 6, 5), ("chunked", 40, 3), ("long", 60, 10)])
     assert out["tokens"] == {"short": 5, "chunked": 3, "long": 10}
     assert out["programs"]["prefill_chunk"] >= 1
-    assert "5 of 5 served tokens" in capsys.readouterr().out
+    said = capsys.readouterr().out
+    # every request is held to model.apply, the chunked and the long too
+    for exact in ("5 of 5", "3 of 3", "10 of 10"):
+        assert f"{exact} served tokens" in said
+
+
+def test_greedy_reference_refuses_a_wrong_token():
+    import numpy as np
+
+    from apex_tpu.models.gpt import GPTModel
+
+    cfg = tiny(max_seq_len=128)
+    model = GPTModel(cfg)
+    prompt = np.arange(1, 7)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.asarray(prompt[None], jnp.int32))
+    served = []
+    for _ in range(2):      # the reference's own greedy continuation
+        toks = np.concatenate([prompt, served]).astype(np.int32)[None]
+        served.append(int(jnp.argmax(model.apply(params, toks)[-1, 0])))
+    served = np.asarray(served)
+    assert chip_smoke.greedy_reference(
+        model, params, prompt, served, cfg.dtype)[0] == 2
+    served[1] = (served[1] + 1) % cfg.vocab_size
+    with pytest.raises(AssertionError, match="token 1 trails"):
+        chip_smoke.greedy_reference(model, params, prompt, served,
+                                    cfg.dtype)
 
 
 def test_server_phase_fails_on_an_unanswered_request(watch):

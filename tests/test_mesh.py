@@ -58,6 +58,24 @@ class TestMeshLifecycle:
         assert gmesh.mesh_initialized()
         assert gmesh.mesh_size() == 1
 
+    def test_explicit_devices_keep_the_callers_order(self):
+        # only the default list is laid out by topology; a caller's own
+        # list (a subset, a chosen order) is reshaped as it was given
+        devs = jax.devices()[4:0:-1]
+        mesh = gmesh.initialize_mesh(batch=2, model=2, devices=devs)
+        assert list(mesh.devices.flatten()) == devs
+
+    def test_default_layout_error_is_not_swallowed(self, monkeypatch):
+        from jax.experimental import mesh_utils
+
+        def refuse(*a, **kw):
+            raise NotImplementedError("no such topology")
+
+        monkeypatch.setattr(mesh_utils, "create_device_mesh", refuse)
+        with pytest.raises(NotImplementedError, match="no such topology"):
+            gmesh.initialize_mesh(model=2)
+        assert not gmesh.mesh_initialized()
+
     def test_bad_factorization_raises(self):
         with pytest.raises(ValueError):
             gmesh.initialize_mesh(model=3)

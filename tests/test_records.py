@@ -7,7 +7,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from apex_tpu.ops.mosaic_limits import block_ok, check_block, max_rows
+from apex_tpu.ops.mosaic_limits import (
+    MAX_BLOCK_BYTES,
+    block_ok,
+    check_block,
+    max_rows,
+)
 
 
 class TestRecords:
@@ -425,6 +430,12 @@ class TestMosaicLimits:
     def test_row_tile_stays_under_the_limit(self):
         from apex_tpu.ops._tiling import row_tile
 
-        # a caller's cap/budget can never push the selector past it
-        tile = row_tile(8192, 4096, cap=4096, budget=1 << 30)
-        assert tile is not None and block_ok(tile, 4096, 4)
+        rng = np.random.RandomState(0)
+        for _ in range(200):
+            rows = int(rng.randint(1, 1 << 14))
+            cols = int(rng.choice([128, 512, 1024, 4096, 8192, 32768]))
+            # adversarial caller: huge cap/budget must still be clamped
+            t = row_tile(rows, cols, cap=1 << 20, budget=1 << 30)
+            if t is not None:
+                assert block_ok(t, cols, 4), (rows, cols, t)
+        assert MAX_BLOCK_BYTES == 4 * 1024 * 1024

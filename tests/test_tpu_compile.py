@@ -34,7 +34,8 @@ V5E_BYTES = 15.75 * 2**30
 @pytest.fixture(scope="module")
 def chip():
     """``shape, dtype -> ShapeDtypeStruct`` on one described v5e chip
-    (``chip.device``); skipped where the topology cannot be described."""
+    (``chip.devices`` lists all four); skipped where the topology
+    cannot be described."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
@@ -48,7 +49,7 @@ def chip():
     def chip(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    chip.device = topo.devices[0]
+    chip.devices = list(topo.devices)
     return chip
 
 
@@ -189,10 +190,16 @@ def test_kernel_compiles_for_v5e(chip, name):
     assert compiled.as_text().count("tpu_custom_call") > 0
 
 
-def test_gpt2_345m_train_step_fits_and_holds_kernels(chip, monkeypatch):
-    """The whole single-chip train step, as ``chip_smoke.py`` builds
-    it, at the smoke's batch: it fits the device with headroom and
-    every fused op is a kernel in it."""
+@pytest.mark.parametrize("chips", [1, 4])
+def test_gpt2_345m_train_step_fits_and_holds_kernels(chip, monkeypatch,
+                                                     chips):
+    """The whole train step, as ``chip_smoke.py`` builds it, at the
+    smoke's batch — on one described chip, and on the 2x2 mesh of all
+    four (``--chips 4``): it fits the device with headroom and every
+    fused op is a kernel in it. On the mesh each kernel must sit in a
+    ``shard_map`` island (mesh/annotate.py ``on_shards``), or the
+    compiler refuses the program: "Mosaic kernels cannot be
+    automatically partitioned"."""
     import numpy as np
     from jax.sharding import Mesh
 
@@ -210,17 +217,30 @@ def test_gpt2_345m_train_step_fits_and_holds_kernels(chip, monkeypatch):
             lambda key: init_gpt_pretrain_params(cfg, key),
             jax.random.PRNGKey(0))
         opt = FusedAdam(lr=3e-4, weight_decay=0.01)
-        one = Mesh(np.asarray([chip.device]).reshape(1, 1, 1),
-                   gmesh.MESH_AXES)
+        if chips == 1:
+            mesh = Mesh(np.asarray(chip.devices[:1]).reshape(1, 1, 1),
+                        gmesh.MESH_AXES)
+        else:
+            mesh = gmesh.initialize_mesh(batch=2, model=2,
+                                         devices=chip.devices)
+        # what ``make_gpt_pretrain_step`` builds for a dense config;
+        # its ``step.init`` places arrays, so the program is compiled
+        # from shapes, which the plan's shardings put on the mesh
         step = gmesh.make_mesh_train_step(
-            GPTModel(cfg), opt, gmesh.plan_gpt(shapes, mesh=one))
-        state = jax.tree.map(lambda x: chip(x.shape, x.dtype),
-                             jax.eval_shape(opt.init, shapes))
-        tok = chip((SMOKE_BATCH, cfg.max_seq_len), jnp.int32)
+            GPTModel(cfg), opt, gmesh.plan_gpt(shapes, mesh=mesh))
+        state = jax.eval_shape(opt.init, shapes)
+        tok = jax.ShapeDtypeStruct((SMOKE_BATCH, cfg.max_seq_len),
+                                   jnp.int32)
+        if chips == 1:
+            state, tok = jax.tree.map(
+                lambda x: chip(x.shape, x.dtype), (state, tok))
         compiled = step.lower(state, tok, tok).compile()
     finally:
+        gmesh.destroy_mesh()
         _backend.default_impl.cache_clear()
+    text = compiled.as_text()
     # flash fwd + 2 bwd, 3 layer norms fwd + bwd, the fused Adam sweep
-    assert compiled.as_text().count("tpu_custom_call") == 10
+    assert text.count("tpu_custom_call") == 10
+    assert ("all-reduce" in text) == (chips == 4)
     assert (chip_smoke.program_bytes(compiled)
             <= chip_smoke.HEADROOM * V5E_BYTES)
