@@ -13,9 +13,10 @@ examples/imagenet/main_amp.py:401 ``--prof``). The TPU equivalents:
 - :func:`start_trace` / :func:`stop_trace` / :func:`trace` —
   ``jax.profiler`` capture to a TensorBoard-loadable directory
   (replaces ``torch.cuda.profiler.start/stop`` + nsys).
-- :func:`annotate` — named_scope as a decorator; when the global
-  telemetry timeline is enabled it ALSO records each call as a
-  host-side span, so one decorator feeds both the XLA trace and the
+- :func:`annotate` — named_scope as a decorator; each call is ALSO a
+  host span opened through ``telemetry.timeline.span``, so one
+  decorator feeds the HLO metadata, the profiler's host plane and,
+  when the global telemetry timeline is enabled, the
   :class:`~apex_tpu.telemetry.StepTimeline` spine.
 - Host-side step timing lives in ``apex_tpu.telemetry.timeline``
   (:class:`StepTimeline`); the legacy
@@ -72,27 +73,21 @@ def trace(log_dir: str = "/tmp/apex_tpu_trace",
 
 def annotate(name: Optional[str] = None):
     """Decorator form: name a function's ops in traces (ref:
-    nvtx.range_push/pop pairs around functions) AND — when the global
-    telemetry timeline is on — record each call as a host-side span,
-    so `annotate`d regions appear in ``export_trace()`` output next to
-    the step phases. The timeline-off path adds one boolean check."""
+    nvtx.range_push/pop pairs around functions) AND record each call
+    as a host span through :func:`apex_tpu.telemetry.timeline.span`:
+    on the profiler's clock whenever a trace is being captured, and in
+    ``export_trace()`` output next to the step phases when the global
+    telemetry timeline is on."""
     def wrap(fn):
+        from apex_tpu.telemetry import timeline as _timeline
+
         scoped = jax.named_scope(name or fn.__qualname__)(fn)
         span_name = name or fn.__qualname__
 
         @functools.wraps(fn)
         def inner(*args, **kwargs):
-            from apex_tpu.telemetry import timeline as _timeline
-
-            if not _timeline.global_enabled():
+            with _timeline.span(span_name, category="annotate"):
                 return scoped(*args, **kwargs)
-            tl = _timeline.get_timeline()
-            t0 = tl.clock()
-            try:
-                return scoped(*args, **kwargs)
-            finally:
-                tl.record_span(span_name, t0, tl.clock() - t0,
-                               category="annotate")
         return inner
     return wrap
 

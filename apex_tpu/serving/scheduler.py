@@ -37,8 +37,11 @@ subsystem, not a demo loop). Every engine ``step()``:
 Telemetry (the PR-4/5 spine, docs/serving.md metric table):
 ``serving_queue_depth`` / ``serving_batch_size`` /
 ``serving_kv_blocks_in_use`` gauges per step, per-request TTFT/TPOT
-latency histograms, ``prefill`` / ``decode`` timeline spans (category
-``serving``), ``serving_requests{outcome=}`` / ``serving_tokens``
+latency histograms, the step's own ``apex.serve.*`` spans (on the
+profiler's clock whenever a trace is captured: docs/serving.md "The
+engine step's spans") and one ``prefill`` / ``prefill_chunk`` /
+``decode`` timeline span per dispatch (category ``serving``),
+``serving_requests{outcome=}`` / ``serving_tokens``
 counters, and ``serving_request_error`` / ``serving_pool_exhausted``
 structured events that double as flight-recorder triggers — a crash
 mid-serve leaves a postmortem bundle naming the request.
@@ -111,6 +114,7 @@ hook site (the ``disabled is step`` discipline).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -121,7 +125,10 @@ import numpy as np
 
 from apex_tpu.serving.decode import DecodeStep, make_decode_step
 from apex_tpu.serving.kv_cache import KVCache, PoolExhausted, bucket
+from apex_tpu.telemetry import timeline as _timeline
 from apex_tpu.telemetry.metrics import TOKEN_COUNT_BUCKETS
+
+_NO_SPAN = contextlib.nullcontext()
 
 
 @dataclasses.dataclass
@@ -339,9 +346,22 @@ class ContinuousBatcher:
     def _tl(self):
         if self._timeline is not None:
             return self._timeline
-        from apex_tpu.telemetry import timeline as _timeline
-
         return _timeline.get_timeline()
+
+    def _span(self, name: str):
+        """One of the engine's ``apex.serve.*`` spans (the table in
+        docs/serving.md): on the profiler's clock whenever a trace is
+        being captured, and nowhere else."""
+        return _timeline.span(name, ring=False)
+
+    def _ring_dispatch(self, name: str):
+        """The timeline ring's one span per dispatch, around the jitted
+        call and the wait for it; nothing at all while no timeline is
+        on."""
+        tl = self._tl()
+        if not tl.enabled:
+            return _NO_SPAN
+        return _timeline.span(name, category="serving", timeline=tl)
 
     def _publish_gauges(self) -> None:
         r = self._registry
@@ -1090,40 +1110,46 @@ class ContinuousBatcher:
         is quarantined by the caller before it joins ``running``."""
         import jax
 
-        b = bucket(len(admitted))
-        s = bucket(max(len(f.req.prompt) for f in admitted),
-                   self.min_seq_bucket)
-        tokens = np.zeros((b, s), np.int32)
-        lengths = np.zeros((b,), np.int32)
-        for i, f in enumerate(admitted):
-            tokens[i, :len(f.req.prompt)] = f.req.prompt
-            lengths[i] = len(f.req.prompt)
-        tables = self._tables_for(admitted, b)
-        t0 = self.clock()
-        with self._tl().phase("prefill", category="serving"):
-            out = self.step_fn.prefill(
-                self.params, state, tokens, lengths, tables,
-                sampling=self._sampling_for(admitted, b))
-            jax.block_until_ready(out.next_token)
-        now = self.clock()
-        ids = np.asarray(out.next_token)
-        finite = (np.asarray(out.finite)[:len(admitted)]
-                  if out.finite is not None
-                  else np.ones(len(admitted), bool))
-        tr = self.tracer
-        traced = tr is not None and tr.enabled
-        for i, f in enumerate(admitted):
-            if traced:
-                tr.span(f.req.id, "prefill", t0, now - t0,
-                        tokens=len(f.req.prompt))
-            if finite[i]:
-                f.generated.append(int(ids[i]))
-                f.prefilled = len(f.req.prompt)
-                f.t_first = f.t_last = now
-                self.cache.publish_prefix(f.seq_id, f.req.prompt)
+        with self._span("apex.serve.prefill"):
+            with self._span("apex.serve.prefill.build"):
+                b = bucket(len(admitted))
+                s = bucket(max(len(f.req.prompt) for f in admitted),
+                           self.min_seq_bucket)
+                tokens = np.zeros((b, s), np.int32)
+                lengths = np.zeros((b,), np.int32)
+                for i, f in enumerate(admitted):
+                    tokens[i, :len(f.req.prompt)] = f.req.prompt
+                    lengths[i] = len(f.req.prompt)
+                tables = self._tables_for(admitted, b)
+                sampling = self._sampling_for(admitted, b)
+            t0 = self.clock()
+            with self._ring_dispatch("prefill"):
+                with self._span("apex.serve.prefill.dispatch"):
+                    out = self.step_fn.prefill(
+                        self.params, state, tokens, lengths, tables,
+                        sampling=sampling)
+                with self._span("apex.serve.prefill.wait"):
+                    jax.block_until_ready(out.next_token)
+            now = self.clock()
+            with self._span("apex.serve.prefill.fetch"):
+                ids = np.asarray(out.next_token)
+                finite = (np.asarray(out.finite)[:len(admitted)]
+                          if out.finite is not None
+                          else np.ones(len(admitted), bool))
+            tr = self.tracer
+            traced = tr is not None and tr.enabled
+            for i, f in enumerate(admitted):
                 if traced:
-                    tr.mark(f.req.id, "first_token", now)
-        return out.cache, finite
+                    tr.span(f.req.id, "prefill", t0, now - t0,
+                            tokens=len(f.req.prompt))
+                if finite[i]:
+                    f.generated.append(int(ids[i]))
+                    f.prefilled = len(f.req.prompt)
+                    f.t_first = f.t_last = now
+                    self.cache.publish_prefix(f.seq_id, f.req.prompt)
+                    if traced:
+                        tr.mark(f.req.id, "first_token", now)
+            return out.cache, finite
 
     # -- chunked prefill (the PREFILLING state) ------------------------------
 
@@ -1142,28 +1168,34 @@ class ContinuousBatcher:
 
         from apex_tpu.resilience import faults
 
-        tokens = np.zeros((b, s), np.int32)
-        starts = np.zeros((b,), np.int32)
-        lengths = np.zeros((b,), np.int32)
-        for i, (f, cs) in enumerate(batchees):
-            tokens[i, :cs] = f.req.prompt[f.prefilled:f.prefilled + cs]
-            starts[i] = f.prefilled
-            lengths[i] = cs
-        tables = self.cache.table_array(
-            [f.seq_id for f, _ in batchees], width, batch=b)
-        with self._tl().phase("prefill_chunk", category="serving"):
-            faults.maybe_prefill_chunk_exception(cidx)
-            faults.check("prefill_chunk")
-            out = self.step_fn.prefill_chunk(
-                self.params, state, tokens, starts, lengths, tables,
-                sampling=self._sampling_for([f for f, _ in batchees], b))
-            jax.block_until_ready(out.next_token)
-        now = self.clock()
-        ids = np.asarray(out.next_token)
-        finite = (np.asarray(out.finite)[:len(batchees)]
-                  if out.finite is not None
-                  else np.ones(len(batchees), bool))
-        return out.cache, ids, finite, now
+        with self._span("apex.serve.chunk"):
+            with self._span("apex.serve.chunk.build"):
+                tokens = np.zeros((b, s), np.int32)
+                starts = np.zeros((b,), np.int32)
+                lengths = np.zeros((b,), np.int32)
+                for i, (f, cs) in enumerate(batchees):
+                    tokens[i, :cs] = f.req.prompt[f.prefilled:f.prefilled + cs]
+                    starts[i] = f.prefilled
+                    lengths[i] = cs
+                tables = self.cache.table_array(
+                    [f.seq_id for f, _ in batchees], width, batch=b)
+                sampling = self._sampling_for([f for f, _ in batchees], b)
+            with self._ring_dispatch("prefill_chunk"):
+                with self._span("apex.serve.chunk.dispatch"):
+                    faults.maybe_prefill_chunk_exception(cidx)
+                    faults.check("prefill_chunk")
+                    out = self.step_fn.prefill_chunk(
+                        self.params, state, tokens, starts, lengths, tables,
+                        sampling=sampling)
+                with self._span("apex.serve.chunk.wait"):
+                    jax.block_until_ready(out.next_token)
+            now = self.clock()
+            with self._span("apex.serve.chunk.fetch"):
+                ids = np.asarray(out.next_token)
+                finite = (np.asarray(out.finite)[:len(batchees)]
+                          if out.finite is not None
+                          else np.ones(len(batchees), bool))
+            return out.cache, ids, finite, now
 
     def _isolate_chunks(self, state, batchees, cidx: int, b: int,
                         s: int, width: int):
@@ -1332,27 +1364,33 @@ class ContinuousBatcher:
 
         from apex_tpu.resilience import faults
 
-        b = self.max_batch          # fixed: one program per width bucket
-        tokens = np.zeros((b,), np.int32)
-        positions = np.zeros((b,), np.int32)
-        for i, f in enumerate(flights):
-            tokens[i] = f.generated[-1]
-            positions[i] = f.position
-        tables = self.cache.table_array([f.seq_id for f in flights],
-                                        width, batch=b)
-        with self._tl().phase("decode", category="serving"):
-            faults.maybe_decode_exception(idx)
-            faults.check("decode_step")
-            out = self.step_fn.decode(
-                self.params, state, tokens, positions, tables,
-                sampling=self._sampling_for(flights, b))
-            jax.block_until_ready(out.next_token)
-        now = self.clock()
-        ids = np.asarray(out.next_token)
-        finite = (np.asarray(out.finite)[:len(flights)]
-                  if out.finite is not None
-                  else np.ones(len(flights), bool))
-        return out.cache, ids, finite, now
+        with self._span("apex.serve.decode"):
+            with self._span("apex.serve.decode.build"):
+                b = self.max_batch      # fixed: one program per width bucket
+                tokens = np.zeros((b,), np.int32)
+                positions = np.zeros((b,), np.int32)
+                for i, f in enumerate(flights):
+                    tokens[i] = f.generated[-1]
+                    positions[i] = f.position
+                tables = self.cache.table_array([f.seq_id for f in flights],
+                                                width, batch=b)
+                sampling = self._sampling_for(flights, b)
+            with self._ring_dispatch("decode"):
+                with self._span("apex.serve.decode.dispatch"):
+                    faults.maybe_decode_exception(idx)
+                    faults.check("decode_step")
+                    out = self.step_fn.decode(
+                        self.params, state, tokens, positions, tables,
+                        sampling=sampling)
+                with self._span("apex.serve.decode.wait"):
+                    jax.block_until_ready(out.next_token)
+            now = self.clock()
+            with self._span("apex.serve.decode.fetch"):
+                ids = np.asarray(out.next_token)
+                finite = (np.asarray(out.finite)[:len(flights)]
+                          if out.finite is not None
+                          else np.ones(len(flights), bool))
+            return out.cache, ids, finite, now
 
     def _isolate(self, state, flights: List[_InFlight], idx: int,
                  width: int):
@@ -1422,46 +1460,53 @@ class ContinuousBatcher:
         work starts, pending block scrubs land before admission can
         reuse the blocks, and both the decode and the chunk-prefill
         dispatch run under per-request fault isolation."""
+        with self._span("apex.serve.step"):
+            return self._step(state)
+
+    def _step(self, state) -> Tuple[Any, Dict[str, Any]]:
         from apex_tpu.resilience import faults
         from apex_tpu.telemetry import flight as _flight
 
-        idx = self.step_idx
-        self.step_idx += 1
-        self._install_pending_params(idx)
-        faults.maybe_sigterm(idx)       # the preemption drill site
-        report: Dict[str, Any] = {
-            "step": idx,
-            "admitted": [],
-            "prefilled": [],
-            "decoded": [],
-            "finished": [],
-            "expired": self._reap_deadlines(idx, self.clock()),
-        }
-        if (not self.draining and self.preemption is not None
-                and self.preemption.should_stop()):
-            self._enter_drain(idx, report)
-            if self.drained_snapshot is not None:
-                # snapshot mode: queued + in-flight are persisted, the
-                # engine is done — nothing left to prefill or decode
-                report["queued"] = 0
-                report["blocks_in_use"] = self.cache.blocks_in_use
-                self._publish_gauges()
-                return state, report
-        state = self._scrub_pending(state)
-        exhausted = faults.should_pool_exhaust(idx)
-        if exhausted:
-            self._registry.event("serving_pool_exhausted", step=idx,
-                                 injected=True,
-                                 queued=len(self.queue),
-                                 in_flight=len(self.running))
-            if not self._pool_exhausted_dumped:
-                self._pool_exhausted_dumped = True
-                _flight.notify(
-                    "serving_pool_exhausted", fleet=False,
-                    extra={"step": idx, "queued": len(self.queue),
-                           "blocks_in_use": self.cache.blocks_in_use,
-                           "prefix_cache": self.cache.prefix_stats()})
-        direct, chunked = self._admit(exhausted)
+        with self._span("apex.serve.housekeep"):
+            idx = self.step_idx
+            self.step_idx += 1
+            self._install_pending_params(idx)
+            faults.maybe_sigterm(idx)       # the preemption drill site
+            report: Dict[str, Any] = {
+                "step": idx,
+                "admitted": [],
+                "prefilled": [],
+                "decoded": [],
+                "finished": [],
+                "expired": self._reap_deadlines(idx, self.clock()),
+            }
+            if (not self.draining and self.preemption is not None
+                    and self.preemption.should_stop()):
+                self._enter_drain(idx, report)
+                if self.drained_snapshot is not None:
+                    # snapshot mode: queued + in-flight are persisted,
+                    # the engine is done — nothing left to prefill or
+                    # decode
+                    report["queued"] = 0
+                    report["blocks_in_use"] = self.cache.blocks_in_use
+                    self._publish_gauges()
+                    return state, report
+            state = self._scrub_pending(state)
+            exhausted = faults.should_pool_exhaust(idx)
+            if exhausted:
+                self._registry.event("serving_pool_exhausted", step=idx,
+                                     injected=True,
+                                     queued=len(self.queue),
+                                     in_flight=len(self.running))
+                if not self._pool_exhausted_dumped:
+                    self._pool_exhausted_dumped = True
+                    _flight.notify(
+                        "serving_pool_exhausted", fleet=False,
+                        extra={"step": idx, "queued": len(self.queue),
+                               "blocks_in_use": self.cache.blocks_in_use,
+                               "prefix_cache": self.cache.prefix_stats()})
+        with self._span("apex.serve.admit"):
+            direct, chunked = self._admit(exhausted)
         report["admitted"] = [f.req.id for f in direct + chunked]
         report["queued"] = len(self.queue)
         self.prefilling.extend(chunked)
@@ -1506,14 +1551,15 @@ class ContinuousBatcher:
             if quarantined:
                 state = self._quarantine(state, quarantined, idx,
                                          report)
-        report["finished"].extend(self._reap())
-        report["blocks_in_use"] = self.cache.blocks_in_use
-        self._publish_gauges()
-        if self.slo is not None:
-            now = self.clock()
-            self.slo.observe("queue_depth", float(report["queued"]),
-                             t=now)
-            self.slo.tick(now=now, step=idx)
+        with self._span("apex.serve.finish"):
+            report["finished"].extend(self._reap())
+            report["blocks_in_use"] = self.cache.blocks_in_use
+            self._publish_gauges()
+            if self.slo is not None:
+                now = self.clock()
+                self.slo.observe("queue_depth", float(report["queued"]),
+                                 t=now)
+                self.slo.tick(now=now, step=idx)
         return state, report
 
 

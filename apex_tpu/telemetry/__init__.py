@@ -14,7 +14,9 @@ The observability layer the rest of the runtime reports through
   buffered per-phase host-loop spans (data wait, H2D, step,
   checkpoint, collective) with Chrome-trace/perfetto export; the one
   spine the legacy ``pipeline_parallel.Timers`` and
-  ``profiler.annotate`` now publish into.
+  ``profiler.annotate`` now publish into, and ``timeline.span``: the
+  one span primitive, a profiler ``TraceAnnotation`` always and a ring
+  span when a timeline is enabled.
 - :mod:`~apex_tpu.telemetry.cost` — static FLOPs/bytes from
   ``jit(...).lower().compile().cost_analysis()`` and the MFU / HBM-
   bandwidth estimates bench records carry (``None`` **with a reason**

@@ -9,14 +9,19 @@ checkpoint writes, collectives — lands in a ring buffer as a
 window as Chrome-trace / perfetto JSON (load it at ``chrome://tracing``
 or ui.perfetto.dev).
 
-This is the ONE spine the previously-duplicated host timers now ride:
+This is the ONE spine the previously-duplicated host timers now ride,
+and :func:`span` is the one way the program opens a span: it always
+enters a ``jax.profiler.TraceAnnotation`` (so the span lies on the
+device trace's clock whenever a profiler is recording, and costs that
+annotation alone when none is), and records into the ring as well when
+the timeline it is bound to is enabled.
 
 - ``transformer.pipeline_parallel.Timers`` (the reference's
   ``_Timers`` port) publishes each stop() into the global timeline —
   new code should use :class:`StepTimeline` directly (see
   docs/transformer.md deprecation note);
-- ``profiler.annotate`` adds a host-side span alongside its
-  ``jax.named_scope`` HLO annotation when the global timeline is on;
+- :meth:`StepTimeline.phase`, ``profiler.annotate`` and the serving
+  engine's ``apex.serve.*`` spans are all calls of :func:`span`;
 - the fused train step takes a ``telemetry=`` timeline and times each
   dispatch under phase ``"step"`` (host-side only — the jitted
   program is byte-identical with telemetry on or off).
@@ -42,6 +47,9 @@ import threading
 import time
 from collections import deque
 from typing import Any, Callable, Dict, Iterable, NamedTuple, Optional
+
+import jax
+from jax.profiler import TraceAnnotation
 
 # canonical phase names the instrumented layers use; arbitrary names
 # are fine — these exist so dashboards agree on spelling
@@ -117,25 +125,15 @@ class StepTimeline:
             except Exception:  # noqa: BLE001 — observers never take down the loop
                 pass
 
-    @contextlib.contextmanager
     def phase(self, name: str, *, sync_on: Any = None,
               category: str = "phase"):
-        """``with tl.phase("h2d"): ...`` — record the block as a span.
+        """``with tl.phase("h2d"): ...`` — :func:`span` bound to this
+        timeline: the block lands in a profiler trace when one is
+        recording and in the ring when this timeline is enabled.
         ``sync_on`` blocks on a jax value before the clock stops, so
         the span covers device completion, not just dispatch."""
-        if not self.enabled:
-            yield
-            return
-        t0 = self.clock()
-        try:
-            yield
-        finally:
-            if sync_on is not None:
-                import jax
-
-                jax.block_until_ready(sync_on)
-            self.record_span(name, t0, self.clock() - t0,
-                             category=category)
+        return span(name, category=category, timeline=self,
+                    sync_on=sync_on)
 
     # -- step scopes -------------------------------------------------------
 
@@ -373,6 +371,61 @@ def global_enabled() -> bool:
     return tl.enabled
 
 
+class _RingSpan:
+    """:func:`span` with a timeline listening: the profiler annotation
+    plus one ``record_span`` at exit."""
+
+    __slots__ = ("_annotation", "_name", "_category", "_timeline",
+                 "_sync_on", "_t0")
+
+    def __init__(self, name, category, timeline, sync_on):
+        self._annotation = TraceAnnotation(name)
+        self._name = name
+        self._category = category
+        self._timeline = timeline
+        self._sync_on = sync_on
+
+    def __enter__(self):
+        self._t0 = self._timeline.clock()
+        self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        try:
+            if self._sync_on is not None:
+                jax.block_until_ready(self._sync_on)
+        finally:
+            # a failing sync still closes the annotation (the profiler's
+            # span stack stays balanced) and leaves its span in the ring
+            self._annotation.__exit__(*exc)
+            tl = self._timeline
+            tl.record_span(self._name, self._t0, tl.clock() - self._t0,
+                           category=self._category)
+        return False
+
+
+def span(name: str, *, category: str = "phase",
+         timeline: Optional[StepTimeline] = None, sync_on: Any = None,
+         ring: bool = True):
+    """``with span("apex.serve.admit"): ...`` — the program's one span
+    primitive. The block is always a ``jax.profiler.TraceAnnotation``
+    named ``name`` (pass a constant string: the name is the event's
+    name in the trace, where readers match it), which is the whole
+    cost while no profiler records. When ``timeline`` (the global one
+    by default) is enabled the block is also recorded into its ring
+    under the same name, as :meth:`StepTimeline.phase` always did;
+    ``sync_on`` then blocks on a jax value before the clock stops.
+    ``ring=False`` keeps a span finer than a phase (the serving
+    engine's ``apex.serve.*``) out of the ring: the ring holds 4096
+    spans and feeds a gauge per name, a profiler trace holds them all."""
+    if not ring:
+        return TraceAnnotation(name)
+    tl = timeline if timeline is not None else get_timeline()
+    if not tl.enabled:
+        return TraceAnnotation(name)
+    return _RingSpan(name, category, tl, sync_on)
+
+
 def record_global_span(name: str, t0: float, dur: float, *,
                        category: str = "phase",
                        args: Optional[Dict[str, Any]] = None) -> None:
@@ -396,4 +449,5 @@ __all__ = [
     "global_enabled",
     "record_global_span",
     "set_span_observer",
+    "span",
 ]
