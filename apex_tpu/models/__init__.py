@@ -7,11 +7,12 @@ packaging both want the full tree importable); reach for a submodule
 directly if import cost matters.
 """
 
-from apex_tpu.models import bert, gpt, migrate, pretrain, resnet, t5  # noqa: F401
+from apex_tpu.models import (  # noqa: F401
+    bert, decoder, gpt, migrate, pretrain, resnet, t5)
 from apex_tpu.models.migrate import (  # noqa: F401
     stack_scan_params,
     unstack_scan_params,
 )
 
-__all__ = ["bert", "gpt", "migrate", "pretrain", "resnet", "t5",
+__all__ = ["bert", "decoder", "gpt", "migrate", "pretrain", "resnet", "t5",
            "stack_scan_params", "unstack_scan_params"]

@@ -1,0 +1,261 @@
+"""A decoder built from a per-layer pattern.
+
+``models/gpt.py`` is one block repeated (and scanned). The models this
+file serves are not: window and full attention alternate, a leading
+dense layer precedes expert layers. So the layers are data
+(``DecoderConfig.layers``: for each layer its attention kind and its
+MLP kind) and the model is unrolled over them.
+
+The block is the ``afmoe`` one (Arcee Trinity; ``docs/serving.md``
+"Window layers", ``docs/moe.md`` "Held experts"):
+
+- embedding scaled by ``sqrt(hidden)``, an untied head;
+- sandwich norm, four RMSNorms a layer:
+  ``y = x + n2(Attn(n1(x)))``, ``x' = y + n4(MLP(n3(y)))``;
+- attention with ``head_dim`` of its own (not ``hidden / heads``),
+  GQA, QK-norm (an RMSNorm over the head), a sigmoid output gate;
+  *window* layers carry rotary positions and see the last ``window``
+  keys, *full* layers carry no positional embedding and see every key;
+- a SiLU-gated dense MLP, or ``moe.held.HeldMoEMLP``.
+
+It offers the serving hooks ``GPTModel`` offers (``positions``,
+``kv_ctx``, ``return_kv``; ``config.kv_heads`` / ``head_dim`` /
+``max_seq_len`` / ``num_layers`` / ``attention_window``), so
+``serving.make_decode_step`` and ``ContinuousBatcher`` take it as they
+take a ``GPTModel``. Single device: no mesh annotations.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from apex_tpu.models.cached_attention import cached_attention
+from apex_tpu.moe.held import HeldMoEConfig, HeldMoEMLP, gated_mlp
+from apex_tpu.ops.layer_norm import fused_rms_norm
+from apex_tpu.ops.rope import fused_apply_rotary_pos_emb_cached
+
+ATTENTION_KINDS = ("full", "window")
+MLP_KINDS = ("dense", "experts")
+
+
+@dataclasses.dataclass(frozen=True)
+class DecoderConfig:
+    vocab_size: int
+    hidden_size: int
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    max_seq_len: int
+    # one (attention kind, MLP kind) a layer
+    layers: Tuple[Tuple[str, str], ...]
+    ffn_hidden_size: int                      # the dense MLP's width
+    attention_window: Optional[int] = None    # of the "window" layers
+    expert_ffn_size: int = 0
+    num_experts: int = 0                      # the router's width
+    experts_per_token: int = 0
+    held_experts: Optional[Tuple[int, int]] = None   # (first, count)
+    shared_ffn_size: int = 0
+    route_scale: float = 1.0
+    rms_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    softmax_impl: Optional[str] = None        # the kernels' impl
+
+    def __post_init__(self):
+        for attention, mlp in self.layers:
+            if attention not in ATTENTION_KINDS or mlp not in MLP_KINDS:
+                raise ValueError(
+                    f"a layer is (one of {ATTENTION_KINDS}, one of "
+                    f"{MLP_KINDS}), got {(attention, mlp)}")
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(f"num_kv_heads ({self.num_kv_heads}) must "
+                             f"divide num_heads ({self.num_heads})")
+        windowed = any(a == "window" for a, _ in self.layers)
+        if windowed != (self.attention_window is not None):
+            raise ValueError("attention_window is set exactly when some "
+                             "layer is a window layer")
+        if any(m == "experts" for _, m in self.layers):
+            self.moe_cfg()                    # validates the expert sizes
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layers)
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads
+
+    def moe_cfg(self) -> HeldMoEConfig:
+        return HeldMoEConfig(
+            hidden_size=self.hidden_size,
+            expert_ffn_size=self.expert_ffn_size,
+            num_experts=self.num_experts, top_k=self.experts_per_token,
+            held=self.held_experts, route_scale=self.route_scale,
+            shared_ffn_size=self.shared_ffn_size, dtype=self.dtype,
+            param_dtype=self.param_dtype)
+
+
+def _flash(cfg: DecoderConfig, *args, **kw):
+    from apex_tpu.ops.attention import flash_attention
+
+    return flash_attention(*args, impl=cfg.softmax_impl, **kw)
+
+
+def _gather(cfg: DecoderConfig, *args):
+    from apex_tpu.ops.kv_gather import kv_gather
+
+    return kv_gather(*args, impl=cfg.softmax_impl)
+
+
+class RMSNorm(nn.Module):
+    """``x * rsqrt(mean(x^2) + eps) * g`` in float32 over the last dim
+    (the XLA form of ``ops/layer_norm.py``: it fuses into its
+    neighbours, and a decode step's 16 rows are no kernel's tile)."""
+
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        g = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                       jnp.float32)
+        return fused_rms_norm(x, g, eps=self.eps, impl="xla")
+
+
+def rotary(t, positions, theta: float):
+    """Rotary embedding over the whole head, rotate-half convention:
+    ``t`` (b, s, heads, d) at ``positions`` (b, s) in the sequence."""
+    d = t.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq
+    angles = jnp.concatenate([angles, angles], axis=-1)[:, :, None, :]
+    return fused_apply_rotary_pos_emb_cached(
+        t, jnp.cos(angles), jnp.sin(angles), impl="xla")
+
+
+class DecoderAttention(nn.Module):
+    config: DecoderConfig
+    kind: str
+
+    @nn.compact
+    def __call__(self, x, positions, *, kv_ctx=None):
+        """``x`` (b, s, hidden) -> (b, s, hidden) and this call's K/V
+        in the kernel layout (b, kv_heads, s, head_dim)."""
+        cfg = self.config
+        b, s, _ = x.shape
+        nh, nkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        window = cfg.attention_window if self.kind == "window" else None
+        init = nn.initializers.normal(stddev=0.02)
+        # one product for q, k, v and the gate: [q | k | v | g]
+        w = self.param("qkvg", init, (cfg.hidden_size, (2 * nh + 2 * nkv) * d),
+                       cfg.param_dtype)
+        qkvg = jnp.dot(x, w.astype(cfg.dtype))
+        q, k, v, g = jnp.split(
+            qkvg, [nh * d, (nh + nkv) * d, (nh + 2 * nkv) * d], axis=-1)
+        q = RMSNorm(cfg.rms_eps, name="q_norm")(q.reshape(b, s, nh, d))
+        k = RMSNorm(cfg.rms_eps, name="k_norm")(k.reshape(b, s, nkv, d))
+        v = v.reshape(b, s, nkv, d)
+        if window is not None:
+            q = rotary(q, positions, cfg.rope_theta)
+            k = rotary(k, positions, cfg.rope_theta)
+        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        if kv_ctx is not None:
+            o = cached_attention(
+                q, k, v, kv_ctx, window=window, dtype=cfg.dtype,
+                flash=lambda *a, **kw: _flash(cfg, *a, **kw),
+                gather=lambda *a: _gather(cfg, *a))
+        elif window is not None:
+            with jax.named_scope("attention_window"):
+                o = _flash(cfg, q, k, v, causal=True, window_size=window)
+        else:
+            o = _flash(cfg, q, k, v, causal=True)
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, nh * d)
+        o = o * jax.nn.sigmoid(g.astype(jnp.float32)).astype(cfg.dtype)
+        wo = self.param("proj", init, (nh * d, cfg.hidden_size),
+                        cfg.param_dtype)
+        return jnp.dot(o, wo.astype(cfg.dtype)), (k, v)
+
+
+class DenseMLP(nn.Module):
+    config: DecoderConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        h, f = cfg.hidden_size, cfg.ffn_hidden_size
+        init = nn.initializers.normal(stddev=0.02)
+        return gated_mlp(
+            x, self.param("gate", init, (h, f), cfg.param_dtype),
+            self.param("up", init, (h, f), cfg.param_dtype),
+            self.param("down", init, (f, h), cfg.param_dtype), cfg.dtype)
+
+
+class DecoderLayer(nn.Module):
+    config: DecoderConfig
+    attention: str
+    mlp: str
+
+    @nn.compact
+    def __call__(self, x, positions, *, kv_ctx=None):
+        cfg = self.config
+        norm = lambda name: RMSNorm(cfg.rms_eps, name=name)  # noqa: E731
+        a, kv = DecoderAttention(cfg, self.attention, name="attention")(
+            norm("input_norm")(x), positions, kv_ctx=kv_ctx)
+        y = x + norm("post_attention_norm")(a)
+        m = norm("pre_mlp_norm")(y)
+        if self.mlp == "experts":
+            m = HeldMoEMLP(cfg.moe_cfg(), name="mlp")(m)
+        else:
+            m = DenseMLP(cfg, name="mlp")(m)
+        return y + norm("post_mlp_norm")(m), kv
+
+
+class PatternDecoder(nn.Module):
+    """Token ids (b, s) -> logits (s, b, vocab) in float32, the layout
+    ``GPTModel`` returns them in."""
+
+    config: DecoderConfig
+
+    @nn.compact
+    def __call__(self, tokens, *, positions=None, kv_ctx=None,
+                 return_kv=False):
+        """``positions`` (b, s) or (s,): positions in the sequence
+        (default ``arange(s)``); they turn the window layers' rotary
+        embedding and nothing else. ``kv_ctx = (k_pool, v_pool, tables,
+        ctx_lens[, win])`` runs the cached paths
+        (``models/cached_attention.py``); ``return_kv=True`` also
+        returns this call's K and V, each stacked (num_layers, b,
+        kv_heads, s, head_dim)."""
+        cfg = self.config
+        b, s = tokens.shape
+        init = nn.initializers.normal(stddev=0.02)
+        table = self.param("embedding", init,
+                           (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
+        x = (table[tokens].astype(jnp.float32)
+             * (cfg.hidden_size ** 0.5)).astype(cfg.dtype)
+        if positions is None:
+            positions = jnp.arange(s, dtype=jnp.int32)
+        positions = jnp.broadcast_to(jnp.asarray(positions), (b, s))
+        kvs = []
+        for i, (attention, mlp) in enumerate(cfg.layers):
+            x, kv = DecoderLayer(cfg, attention, mlp, name=f"layer_{i}")(
+                x, positions,
+                kv_ctx=None if kv_ctx is None else (i, *kv_ctx))
+            kvs.append(kv)
+        x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
+        head = self.param("head", init, (cfg.vocab_size, cfg.hidden_size),
+                          cfg.param_dtype)
+        logits = jnp.einsum("bsh,vh->sbv", x, head.astype(cfg.dtype),
+                            preferred_element_type=jnp.float32)
+        if return_kv:
+            return logits, (jnp.stack([k for k, _ in kvs]),
+                            jnp.stack([v for _, v in kvs]))
+        return logits
+
+
+__all__ = ["DecoderConfig", "PatternDecoder"]

@@ -251,8 +251,10 @@ class ParallelAttention(nn.Module):
     - ``return_kv=True`` additionally returns this call's K/V in the
       kernel ``(b, kv_local, s, head_dim)`` layout — what a prefill
       step writes into the paged cache.
-    - ``kv_ctx=(layer, k_pool, v_pool, tables, ctx_lens)`` is the
-      cached path. ``k_pool``/``v_pool`` are the paged pools, whole
+    - ``kv_ctx=(layer, k_pool, v_pool, tables, ctx_lens[, win])`` is the
+      cached path (``models/cached_attention.py``; with
+      ``attention_window`` the layer gathers through ``win``, the tail
+      of each lane's table, and masks by true positions). ``k_pool``/``v_pool`` are the paged pools, whole
       (layers, blocks, block_size, kv_local, head_dim), ``layer`` this
       layer's index into them, ``tables`` (b, w) the block tables and
       ``ctx_lens`` (b,) how many cached positions of each lane are
@@ -337,54 +339,16 @@ class ParallelAttention(nn.Module):
             if cfg.attention_backend == "ring":
                 raise ValueError(
                     "kv_ctx decode is not supported by the ring backend")
-            if cfg.attention_window is not None:
-                raise NotImplementedError(
-                    "kv_ctx decode with attention_window is not supported")
-            layer, k_pool, v_pool, tables, ctx_lens = kv_ctx
-            k_all, v_all = _gather_ctx(cfg, k_pool, v_pool, layer, tables)
-            # tests read the gathered context back through this
-            # (a no-op unless "intermediates" is mutable)
-            self.sow("intermediates", "kv_ctx", (k_all, v_all))
-            k_all, v_all = k_all.astype(cfg.dtype), v_all.astype(cfg.dtype)
-            qb = q.transpose(1, 2, 0, 3)                  # (b, h, s, d)
-            slot = jnp.arange(k_all.shape[2], dtype=jnp.int32)[None, :]
-            if s == 1:
-                # decode: one query per sequence, its own K/V in slot
-                # ctx_lens[b] of the gathered context (where append_kv
-                # puts it once the layers have run): an update in
-                # place a lane — one scatter for all lanes makes the
-                # TPU compiler transpose the whole context there and
-                # back. Segment masking only: the written prefix and
-                # the token itself are 0, everything else 1 (flash
-                # zero-fills q-side segments).
-                def into_slot(ctx, new):
-                    for i in range(b):
-                        at = lax.index_in_dim(ctx_lens, i, keepdims=False)
-                        ctx = lax.dynamic_update_slice(
-                            ctx, lax.slice_in_dim(new, i, i + 1),
-                            (i, 0, at, 0))
-                    return ctx
+            from apex_tpu.models.cached_attention import cached_attention
 
-                k_all = into_slot(k_all, kv_new[0])
-                v_all = into_slot(v_all, kv_new[1])
-                kv_seg = (slot > ctx_lens[:, None]).astype(jnp.int32)
-                ctx = _flash(cfg, qb, k_all, v_all, causal=False,
-                             kv_segment_ids=kv_seg)
-            else:
-                # chunk-resumable prefill: s chunk queries over the
-                # [ctx | chunk] key layout. causal=True with sk > sq
-                # gives query i the keys j <= i + L (all of ctx + the
-                # chunk's own causal prefix); the per-lane segment ids
-                # drop ctx slots past the written prefix — chunk
-                # padding keys sit AFTER every real query, so the
-                # causal offset already masks them.
-                k_all = jnp.concatenate([k_all, kv_new[0]], axis=2)
-                v_all = jnp.concatenate([v_all, kv_new[1]], axis=2)
-                kv_seg = jnp.concatenate(
-                    [(slot >= ctx_lens[:, None]).astype(jnp.int32),
-                     jnp.zeros((b, s), jnp.int32)], axis=1)
-                ctx = _flash(cfg, qb, k_all, v_all, causal=True,
-                             kv_segment_ids=kv_seg)
+            # tests read the gathered context back through the sow
+            # (a no-op unless "intermediates" is mutable)
+            ctx = cached_attention(
+                q.transpose(1, 2, 0, 3), *kv_new, kv_ctx,
+                flash=lambda *a, **kw: _flash(cfg, *a, **kw),
+                gather=lambda *a: _gather_ctx(cfg, *a),
+                dtype=cfg.dtype, window=cfg.attention_window,
+                sow=lambda kv: self.sow("intermediates", "kv_ctx", kv))
             ctx = ctx.transpose(2, 0, 1, 3).reshape(
                 s, b, heads_local * head_dim)
             return _out(ctx)
@@ -569,7 +533,7 @@ class GPTModel(nn.Module):
         single-token forward at position t needs only ``positions`` and
         the cache, never the full prefix.
 
-        ``kv_ctx=(k_pool, v_pool, tables, ctx_lens)`` — the paged
+        ``kv_ctx=(k_pool, v_pool, tables, ctx_lens[, win])`` — the paged
         pools (num_layers, blocks, block_size, kv_heads, head_dim),
         the block tables (b, w) and the cached length of each lane
         (b,) — runs the cached paths: every layer gathers its own

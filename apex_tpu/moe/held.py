@@ -1,0 +1,170 @@
+"""An expert layer that is told which experts it holds.
+
+Expert parallelism gives each chip a contiguous share of a layer's
+routed experts. The layer here is that chip's part (docs/moe.md "Held
+experts"): the router keeps its published width and its experts per
+token, every token is routed over ALL the experts, and the chip
+computes what its own ``held = (first, count)`` experts add to the
+result. Token-expert pairs whose expert lives elsewhere are dropped
+before the grouped products, so the products and the expert weights
+read scale with the pairs held; among the held nothing is dropped. On
+one chip the layer runs without its exchange: what the absent experts
+would add is left out, and nothing stands in for them.
+
+The router is the sigmoid kind (DeepSeek-V3 / ``afmoe``): scores
+``s = sigmoid(x W_r)`` in float32, the choice by ``s + b`` with ``b`` a
+per-expert selection bias that takes no part in the weights, the
+weights ``s[chosen]`` renormalised over the chosen and scaled.
+Experts are SiLU-gated three-matrix MLPs; a shared expert of the same
+kind, where the model has one, is computed by every chip alike.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+
+@dataclasses.dataclass(frozen=True)
+class HeldMoEConfig:
+    hidden_size: int
+    expert_ffn_size: int
+    num_experts: int                 # the router's width
+    top_k: int
+    # (first, count): the contiguous experts this chip holds; None = all
+    held: Optional[Tuple[int, int]] = None
+    route_scale: float = 1.0
+    shared_ffn_size: int = 0         # 0 = no shared expert
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    def __post_init__(self):
+        first, count = self.held_range
+        if not (0 <= first and count >= 1
+                and first + count <= self.num_experts):
+            raise ValueError(
+                f"held experts {self.held} do not lie within the router's "
+                f"{self.num_experts}")
+        if not (1 <= self.top_k <= self.num_experts):
+            raise ValueError(f"top_k ({self.top_k}) must be in "
+                             f"[1, num_experts={self.num_experts}]")
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.held if self.held is not None else (0, self.num_experts)
+
+
+def sigmoid_router(x, gate_kernel, select_bias, k: int, *,
+                   route_scale: float = 1.0):
+    """x (n, h), gate (h, E), bias (E,) -> (weights (n, k) float32,
+    expert ids (n, k) int32, scores (n, E) float32).
+
+    The product runs in float32 at the highest precision (a TPU's
+    default float32 product is a bf16 one): the choice is a comparison
+    of scores, and the 4th and 5th lie close."""
+    logits = jnp.dot(x.astype(jnp.float32), gate_kernel.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, ids = lax.top_k(scores + select_bias.astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(scores, ids, axis=-1)
+    weights = chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * route_scale
+    return weights, ids.astype(jnp.int32), scores
+
+
+def held_experts(x, weights, ids, w_gate, w_up, w_down,
+                 held: Tuple[int, int], dtype):
+    """What the held experts add: ``sum_e w_e Expert_e(x)`` over the
+    chosen experts ``e`` in ``[first, first + count)``.
+
+    x (n, h); weights / ids (n, k); w_gate / w_up (count, h, f) and
+    w_down (count, f, h), the held experts' own. Pairs are sorted by
+    local expert with the absent ones last, and the group sizes count
+    the held pairs only: the grouped products (``lax.ragged_dot``, on
+    a TPU the compiler's grouped matmul, which visits the tiles its
+    group sizes cover) stop there."""
+    first, count = held
+    n, h = x.shape
+    k = ids.shape[1]
+    local = ids - first
+    flat = jnp.where((local >= 0) & (local < count), local,
+                     count).reshape(-1)                   # (n*k,)
+    order = jnp.argsort(flat, stable=True)
+    inv = jnp.argsort(order)
+    rows = x.astype(dtype)[order // k]                    # (n*k, h) sorted
+    sizes = jnp.bincount(flat, length=count + 1)[:count].astype(jnp.int32)
+    with jax.named_scope("moe_experts"):
+        gate = lax.ragged_dot(rows, w_gate.astype(dtype), sizes)
+        up = lax.ragged_dot(rows, w_up.astype(dtype), sizes)
+        out = lax.ragged_dot(jax.nn.silu(gate) * up, w_down.astype(dtype),
+                             sizes)
+    # rows past the held pairs were never computed: whatever lies there
+    # is dropped by the mask, not multiplied by a zero weight
+    w_sorted = weights.reshape(-1)[order]
+    out = jnp.where((flat[order] < count)[:, None],
+                    out.astype(jnp.float32) * w_sorted[:, None], 0.0)
+    return out[inv].reshape(n, k, h).sum(axis=1).astype(dtype)
+
+
+def gated_mlp(x, w_gate, w_up, w_down, dtype):
+    """``(silu(x W_gate) * (x W_up)) W_down``."""
+    x = x.astype(dtype)
+    return jnp.dot(jax.nn.silu(jnp.dot(x, w_gate.astype(dtype)))
+                   * jnp.dot(x, w_up.astype(dtype)), w_down.astype(dtype))
+
+
+class HeldMoEMLP(nn.Module):
+    """Router over all experts, the held experts' part of the result,
+    and the shared expert (module docstring). Input and output
+    ``(..., hidden)``. ``return_routing=True`` also returns ``(weights,
+    ids, scores)`` for tests. Applied with ``mutable=["routing"]`` the
+    layer also leaves its choice there (``ids`` (n, k) and the
+    ``biased`` scores (n, experts) it was made from), for a reference
+    that is to be given the program's choice; otherwise that costs
+    nothing and changes no program."""
+
+    config: HeldMoEConfig
+
+    @nn.compact
+    def __call__(self, x, *, return_routing: bool = False):
+        cfg = self.config
+        h, f = cfg.hidden_size, cfg.expert_ffn_size
+        first, count = cfg.held_range
+        init = nn.initializers.normal(stddev=0.02)
+        gate = self.param("router", init, (h, cfg.num_experts),
+                          cfg.param_dtype)
+        bias = self.param("select_bias", nn.initializers.zeros,
+                          (cfg.num_experts,), jnp.float32)
+        w_gate = self.param("w_gate", init, (count, h, f), cfg.param_dtype)
+        w_up = self.param("w_up", init, (count, h, f), cfg.param_dtype)
+        w_down = self.param("w_down", init, (count, f, h), cfg.param_dtype)
+        lead = x.shape[:-1]
+        x2 = x.reshape(-1, h)
+        weights, ids, scores = sigmoid_router(
+            x2, gate, bias, cfg.top_k, route_scale=cfg.route_scale)
+        if (self.is_mutable_collection("routing")
+                and not self.is_initializing()):
+            self.sow("routing", "ids", ids)
+            self.sow("routing", "biased", scores + bias.astype(jnp.float32))
+        out = held_experts(x2, weights, ids, w_gate, w_up, w_down,
+                           (first, count), cfg.dtype)
+        if cfg.shared_ffn_size:
+            fs = cfg.shared_ffn_size
+            out = out + gated_mlp(
+                x2,
+                self.param("shared_gate", init, (h, fs), cfg.param_dtype),
+                self.param("shared_up", init, (h, fs), cfg.param_dtype),
+                self.param("shared_down", init, (fs, h), cfg.param_dtype),
+                cfg.dtype)
+        out = out.reshape(*lead, h)
+        if return_routing:
+            return out, (weights, ids, scores)
+        return out
+
+
+__all__ = ["HeldMoEConfig", "HeldMoEMLP", "gated_mlp", "held_experts",
+           "sigmoid_router"]

@@ -161,6 +161,15 @@ def _block_live_dynamic(qp_ref, kp_ref, causal, window):
     return run
 
 
+def _pos_block(ref):
+    """A position block as `_mask_block` takes it: (bq, 1) / (1, bk).
+    Per-lane positions (the serving paths) come as (1, bq, 1) /
+    (1, 1, bk) blocks of a (batch, ...) array."""
+    if ref is None:
+        return None
+    return ref[0] if len(ref.shape) == 3 else ref[...]
+
+
 def _band_k_lo(iq, bq, bk, off, window):
     """First k-block index intersecting q-block ``iq``'s sliding window."""
     return jnp.maximum(0, (iq * bq + off - (window - 1)) // bk)
@@ -251,8 +260,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, qs_ref, ks_ref, seed_ref,
         k_seg = ks_ref[0] if ks_ref is not None else None
         s = s + _mask_block(
             iq, ik, bq, bk, sq, sk, causal, window, q_seg, k_seg,
-            q_pos=qp_ref[...] if qp_ref is not None else None,
-            k_pos=kp_ref[...] if kp_ref is not None else None,
+            q_pos=_pos_block(qp_ref), k_pos=_pos_block(kp_ref),
             k_len=k_len)
 
         m_prev = m_sc[:, :1]                       # (bq, 1)
@@ -364,7 +372,17 @@ def _flash_fwd_pallas(q, k, v, bias, q_seg, k_seg, seed, scale, causal,
     else:
         in_specs.append(None)
         args.append(None)
-    if q_pos is not None:
+    if q_pos is not None and jnp.ndim(q_pos) == 2:
+        # a lane's own positions (batch, seq), read per grid step via
+        # bh // h as the segment ids are, in their layouts
+        in_specs.append(
+            pl.BlockSpec((1, bq, 1), lambda bh, iq, j: (bh // h, iq, 0)))
+        in_specs.append(
+            pl.BlockSpec((1, 1, bk),
+                         lambda bh, iq, j: (bh // h, 0, ik_of(iq, j))))
+        args += [jnp.asarray(q_pos, jnp.int32).reshape(b, sq, 1),
+                 jnp.asarray(k_pos, jnp.int32).reshape(b, 1, skp)]
+    elif q_pos is not None:
         # global positions: q as an (sq, 1) column, k as a (1, sk) row
         in_specs.append(pl.BlockSpec((bq, 1), lambda bh, iq, j: (iq, 0)))
         in_specs.append(
@@ -794,12 +812,20 @@ def _attention_xla(q, k, v, bias, q_seg, k_seg, scale, causal,
         s = s + bias.astype(jnp.float32)
     if causal or window is not None:
         # one (sq, sk) block = the full matrix; same mask code as the kernel
-        s = s + _mask_block(
-            0, 0, sq, sk, sq, sk, causal, window, None, None,
-            q_pos=(jnp.asarray(q_pos, jnp.int32).reshape(sq, 1)
-                   if q_pos is not None else None),
-            k_pos=(jnp.asarray(k_pos, jnp.int32).reshape(1, sk)
-                   if k_pos is not None else None))[None, None]
+        if q_pos is not None and jnp.ndim(q_pos) == 2:
+            # per-lane positions: the mask broadcasts to (b, sq, sk)
+            s = s + _mask_block(
+                0, 0, sq, sk, sq, sk, causal, window, None, None,
+                q_pos=jnp.asarray(q_pos, jnp.int32).reshape(b, sq, 1),
+                k_pos=jnp.asarray(k_pos, jnp.int32).reshape(b, 1, sk),
+            )[:, None]
+        else:
+            s = s + _mask_block(
+                0, 0, sq, sk, sq, sk, causal, window, None, None,
+                q_pos=(jnp.asarray(q_pos, jnp.int32).reshape(sq, 1)
+                       if q_pos is not None else None),
+                k_pos=(jnp.asarray(k_pos, jnp.int32).reshape(1, sk)
+                       if k_pos is not None else None))[None, None]
     if q_seg is not None:
         seg = q_seg[:, None, :, None] != k_seg[:, None, None, :]
         s = jnp.where(seg, NEG_INF, s)
@@ -1021,6 +1047,12 @@ def flash_attention(
     inner dimension walks only the k (resp. q) blocks each band
     touches, so both FLOPs and DMA traffic scale O(S·w), not O(S²).
 
+    ``q_positions`` / ``kv_positions`` replace the static causal and
+    window geometry by token positions: (seq,) arrays shared by the
+    batch (ring / blockwise chunks), or (batch, seq) arrays a lane
+    (the serving paths, where each lane's keys start at a position of
+    its own; forward only).
+
     ``return_lse=True`` additionally returns the per-row logsumexp
     (fp32, (batch, heads, seq_q); NEG_INF on fully-masked rows) as a
     differentiable output — chunk results merge exactly via
@@ -1051,6 +1083,10 @@ def flash_attention(
     if q_positions is not None and not causal:
         raise ValueError("positions only affect causal/window masking; "
                          "pass causal=True")
+    per_lane = q_positions is not None and jnp.ndim(q_positions) == 2
+    if per_lane and jnp.ndim(kv_positions) != 2:
+        raise ValueError("per-lane q_positions (batch, seq_q) need "
+                         "per-lane kv_positions (batch, seq_k)")
     if q_positions is not None and dropout_rate > 0.0:
         # the dropout counter hashes block-LOCAL row/col indices, so a
         # chunked (ring/blockwise) call would sample a different mask
@@ -1115,9 +1151,18 @@ def flash_attention(
         if kv_segment_ids is not None:
             kv_segment_ids = pad_keys(kv_segment_ids, 1)
         if kv_positions is not None:
-            kv_positions = pad_keys(jnp.asarray(kv_positions), 0)
+            kv_positions = pad_keys(jnp.asarray(kv_positions),
+                                    jnp.ndim(kv_positions) - 1)
         if bias is not None and bias.shape[3] == sk:
             bias = pad_keys(bias, 3)
+    if per_lane:
+        # the serving paths' masks: forward only, no gradient rule
+        out = _flash_fwd_pallas(
+            q, k, v, bias, segment_ids, kv_segment_ids, seed,
+            softmax_scale, causal, window_size, float(dropout_rate),
+            block_q, block_k, interpret_flag(impl),
+            q_pos=q_positions, k_pos=kv_positions, kv_len=kv_len)
+        return out if return_lse else out[0]
     if return_lse or q_positions is not None:
         out = _flash_with_lse(
             q, k, v, bias, segment_ids, kv_segment_ids, seed,

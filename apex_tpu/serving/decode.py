@@ -7,7 +7,10 @@ and the hot loop allocates nothing. The per-shape compile cache is an
 eviction-free dict keyed on the bucketed shapes:
 
 - decode: ``(batch_bucket, table_width)`` — the only dynamic shapes a
-  decode dispatch has;
+  decode dispatch has (a model with window layers carries a second
+  table a lane, the tail its window layers gather through, and every
+  key ends in that table's width too: docs/serving.md "Window
+  layers");
 - prefill: ``(batch_bucket, seq_bucket, table_width)``;
 - prefill_chunk: ``(batch_bucket, chunk_bucket, table_width)`` — the
   chunk-resumable prefill (chunked prefill / prefix-cache resume),
@@ -51,7 +54,7 @@ forward (tests/test_serving.py).
 
 from __future__ import annotations
 
-from typing import Any, Dict, NamedTuple, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -106,6 +109,11 @@ class DecodeStep:
         self._compiled: Dict[Tuple, Any] = {}
         cfg = model.config
         max_pos = cfg.max_seq_len - 1
+
+        def tail(win):
+            # the window layers' tables, where the model has such
+            # layers: one more element of the kv_ctx hook
+            return () if win is None else (win,)
 
         def select_token(out, sampling, fold_pos):
             """Fused in-program token selection over the (b, vocab)
@@ -170,7 +178,8 @@ class DecodeStep:
                            jnp.all(jnp.isfinite(out), axis=-1))
 
         def prefill_chunk_fn(params, state, tokens, starts, lengths,
-                             tables, temps, top_ks, top_ps, seeds):
+                             tables, temps, top_ks, top_ps, seeds,
+                             win=None):
             b, s = tokens.shape
             # the pools are read BEFORE the chunk's writes: each layer
             # gathers its own context, every previously-written
@@ -181,7 +190,8 @@ class DecodeStep:
                 0, max_pos)
             logits, (k_new, v_new) = model.apply(
                 params, tokens, positions=pos,
-                kv_ctx=(state.k, state.v, tables, starts), return_kv=True)
+                kv_ctx=(state.k, state.v, tables, starts, *tail(win)),
+                return_kv=True)
             state = append_kv_chunk(state, k_new, v_new, tables, starts,
                                     lengths)
             last = jnp.clip(lengths - 1, 0, s - 1)
@@ -194,14 +204,14 @@ class DecodeStep:
                            jnp.all(jnp.isfinite(out), axis=-1))
 
         def decode_fn(params, state, tokens, positions, tables, temps,
-                      top_ks, top_ps, seeds):
+                      top_ks, top_ps, seeds, win=None):
             pos2 = jnp.clip(positions, 0, max_pos)[:, None]   # (b, 1)
             # each layer gathers its own context from the pools and
             # attends with the token's K/V in slot positions[b] of it:
             # the slot append_kv writes below, which the table covers
             logits, (k_new, v_new) = model.apply(
                 params, tokens[:, None], positions=pos2,
-                kv_ctx=(state.k, state.v, tables, positions),
+                kv_ctx=(state.k, state.v, tables, positions, *tail(win)),
                 return_kv=True)
             state = append_kv(state, k_new[:, :, :, 0], v_new[:, :, :, 0],
                               tables, positions)
@@ -226,8 +236,12 @@ class DecodeStep:
         sig: Dict[str, Any] = {"fn": fn}
         if fn in ("prefill_step", "prefill_chunk"):
             sig.update(batch=key[1], seq=key[2], table_width=key[3])
+            rest = key[4:]
         else:
             sig.update(batch=key[1], table_width=key[2])
+            rest = key[3:]
+        if rest:
+            sig.update(window_table_width=rest[0])
         sig.update(block_size=self.cache.block_size,
                    kv_heads=self.cache.kv_heads,
                    head_dim=self.cache.head_dim,
@@ -274,14 +288,16 @@ class DecodeStep:
         return out
 
     def lower(self, fn: str, params, state: KVCacheState, batch: int,
-              table_width: int, seq: int = 1):
+              table_width: int, seq: int = 1,
+              window_table_width: Optional[int] = None):
         """``jax.jit(...).lower`` passthrough for one bucketed program
         (``TrainStep.lower``'s sibling): ``fn`` is ``"decode_step"``,
         ``"prefill_step"`` or ``"prefill_chunk"`` (the last two take
         the ``seq`` bucket). Built from shapes alone — ``params`` and
         ``state`` may be ``jax.ShapeDtypeStruct`` trees — so a
         program's memory analysis and text can be had without a
-        dispatch."""
+        dispatch. ``window_table_width`` is the second table's, for a
+        model with window layers."""
         import jax
 
         jnp = self._jnp
@@ -293,9 +309,12 @@ class DecodeStep:
                     jax.ShapeDtypeStruct((batch,), jnp.float32),
                     jax.ShapeDtypeStruct((batch,), jnp.uint32))
         tables = ints(batch, table_width)
+        win = (None if window_table_width is None
+               else (ints(batch, window_table_width), ints(batch)))
         if fn == "decode_step":
             return self._decode_jit.lower(
-                params, state, ints(batch), ints(batch), tables, *sampling)
+                params, state, ints(batch), ints(batch), tables, *sampling,
+                win)
         if fn == "prefill_step":
             return self._prefill_jit.lower(
                 params, state, ints(batch, seq), ints(batch), tables,
@@ -303,7 +322,7 @@ class DecodeStep:
         if fn == "prefill_chunk":
             return self._prefill_chunk_jit.lower(
                 params, state, ints(batch, seq), ints(batch), ints(batch),
-                tables, *sampling)
+                tables, *sampling, win)
         raise ValueError(f"unknown serving program {fn!r}")
 
     # -- dispatchers ---------------------------------------------------------
@@ -341,9 +360,19 @@ class DecodeStep:
             tokens, lengths, tables,
             *self._sampling_arrays(tokens.shape[0], sampling))
 
+    def _window_arrays(self, window):
+        """``window = (tables (b, ww), first (b,))`` as device arrays,
+        and the key's tail: the second table's width."""
+        if window is None:
+            return None, ()
+        jnp = self._jnp
+        tables, first = window
+        tables = jnp.asarray(tables, jnp.int32)
+        return (tables, jnp.asarray(first, jnp.int32)), (tables.shape[1],)
+
     def prefill_chunk(self, params, state: KVCacheState, tokens,
                       starts, lengths, tables,
-                      sampling=None) -> StepOut:
+                      sampling=None, window=None) -> StepOut:
         """Resume prefill with one CHUNK per sequence: row ``i`` of
         lane ``b`` is the prompt token at global position
         ``starts[b] + i`` (``lengths[b]`` real rows, the rest pad).
@@ -352,22 +381,25 @@ class DecodeStep:
         offset positions, and emits the last real row's logits — the
         first-token distribution when the chunk completes the prompt.
         One program, cache donated; the chunked-prefill hot path
-        (docs/serving.md "Chunked prefill").
+        (docs/serving.md "Chunked prefill"). ``window`` is ``(tables,
+        first)`` of ``KVCache.window_table_array`` for a model with
+        window layers (here and in :meth:`decode`).
         """
         jnp = self._jnp
         tokens = jnp.asarray(tokens, jnp.int32)
         starts = jnp.asarray(starts, jnp.int32)
         lengths = jnp.asarray(lengths, jnp.int32)
         tables = jnp.asarray(tables, jnp.int32)
+        win, widths = self._window_arrays(window)
         key = ("prefill_chunk", tokens.shape[0], tokens.shape[1],
-               tables.shape[1])
+               tables.shape[1], *widths)
         return self._dispatch(
             "prefill_chunk", key, self._prefill_chunk_jit, params,
             state, tokens, starts, lengths, tables,
-            *self._sampling_arrays(tokens.shape[0], sampling))
+            *self._sampling_arrays(tokens.shape[0], sampling), win)
 
     def decode(self, params, state: KVCacheState, tokens, positions,
-               tables, sampling=None) -> StepOut:
+               tables, sampling=None, window=None) -> StepOut:
         """One token per sequence: gather each sequence's cache view,
         attend (single query, per-sequence length via the mask), emit
         logits + the selected next token, and append the new K/V at
@@ -383,16 +415,18 @@ class DecodeStep:
         tokens = jnp.asarray(tokens, jnp.int32)
         positions = jnp.asarray(positions, jnp.int32)
         tables = jnp.asarray(tables, jnp.int32)
-        key = ("decode_step", tokens.shape[0], tables.shape[1])
+        win, widths = self._window_arrays(window)
+        key = ("decode_step", tokens.shape[0], tables.shape[1], *widths)
         return self._dispatch(
             "decode_step", key, self._decode_jit, params, state,
             tokens, positions, tables,
-            *self._sampling_arrays(tokens.shape[0], sampling))
+            *self._sampling_arrays(tokens.shape[0], sampling), win)
 
 
 def make_decode_step(model, cache: KVCache) -> DecodeStep:
     """Build the compiled serving steps for ``model`` (a
-    :class:`~apex_tpu.models.gpt.GPTModel`) over ``cache``.
+    :class:`~apex_tpu.models.gpt.GPTModel`, a
+    :class:`~apex_tpu.models.decoder.PatternDecoder`) over ``cache``.
 
     The returned :class:`DecodeStep` donates the cache state on every
     dispatch and keeps an eviction-free per-shape compile cache

@@ -167,9 +167,12 @@ class KVCache:
                    dtype: Any = None) -> "KVCache":
         """Size the cache from a ``GPTConfig``-shaped model config:
         ``kv_heads`` (the GQA-narrowed count) x ``head_dim`` blocks —
-        GQA pays GQA-sized blocks, never ``num_heads``-sized ones."""
+        GQA pays GQA-sized blocks, never ``num_heads``-sized ones. The
+        head size is the config's own ``head_dim`` where it has one
+        (it need not be ``hidden_size / num_heads``)."""
+        head_dim = getattr(cfg, "head_dim", None)
         return cls(cfg.num_layers, cfg.kv_heads,
-                   cfg.hidden_size // cfg.num_heads,
+                   head_dim or cfg.hidden_size // cfg.num_heads,
                    num_blocks=num_blocks, block_size=block_size,
                    dtype=dtype if dtype is not None else cfg.dtype)
 
@@ -572,6 +575,45 @@ class KVCache:
                         f"sequence {sid!r}")
                 out[i, :len(t)] = t
         return out
+
+    def window_width(self, window: int, width: int) -> int:
+        """Blocks in a window layer's table when the full table has
+        ``width``: the ``window`` positions plus one block for the
+        edge, rounded up to a quarter of the window (4096 positions
+        gather 5120: five of the flash kernel's key blocks of 1024,
+        where 4112 would leave it blocks of 16), and never more than
+        the full table."""
+        bs = self.block_size
+        quarter = max(1, window // (4 * bs))
+        blocks = -(-(window + bs) // bs)
+        return min(int(width), -(-blocks // quarter) * quarter)
+
+    def window_table_array(self, seq_ids: Sequence[Any], positions,
+                           window: int, width: int,
+                           batch: Optional[int] = None):
+        """The tails of the batch's block tables for a window layer:
+        ``(tables (batch, width), first (batch,))``. Lane ``i`` is
+        about to attend from position ``positions[i]`` on (a decode
+        token's own position, a chunk's start); the oldest key any of
+        its queries sees is ``positions[i] - window + 1``, in block
+        ``first[i]`` of the lane's own table, and the tail holds that
+        block and the ``width - 1`` after it. What the sequence has
+        not reached, and dummy rows, point at the trash block."""
+        b = len(seq_ids) if batch is None else int(batch)
+        out = np.full((b, int(width)), TRASH_BLOCK, np.int32)
+        first = np.zeros((b,), np.int32)
+        with self._lock:
+            for i, sid in enumerate(seq_ids):
+                t = self._tables[sid]
+                lo = max(0, int(positions[i]) - window + 1) // self.block_size
+                if int(positions[i]) // self.block_size >= lo + width:
+                    raise ValueError(
+                        f"window table width {width} does not reach "
+                        f"position {int(positions[i])} of sequence {sid!r}")
+                tail = t[lo:lo + width]
+                out[i, :len(tail)] = tail
+                first[i] = lo
+        return out, first
 
     # -- disaggregated handoff (serving/fleet.py) --------------------------
 

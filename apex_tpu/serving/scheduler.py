@@ -276,6 +276,11 @@ class ContinuousBatcher:
         self.max_prefill_batch = int(max_prefill_batch)
         self.min_width_bucket = int(min_width_bucket)
         self.min_seq_bucket = int(min_seq_bucket)
+        # a model with window layers (docs/serving.md "Window layers"):
+        # every dispatch over the cache carries a second table a lane,
+        # the tail its window layers gather through
+        self.attention_window = getattr(
+            getattr(model, "config", None), "attention_window", None)
         # chunked prefill (docs/serving.md): prompts longer than
         # `prefill_chunk` advance one bucketed chunk per engine step,
         # co-scheduled with the decode dispatch, instead of one
@@ -321,6 +326,8 @@ class ContinuousBatcher:
         self._chunk_dispatches = 0        # prefill_chunk_exception idx
         self._pending_copies: Dict[Any, List[Tuple[int, int, int]]] = {}
         self._pool_exhausted_dumped = False
+        # positions gathered so far, a layer of each kind (host side)
+        self.gathered = {"full": 0, "window": 0}
         # resilience plane (serving/resilience.py)
         self.preemption = preemption          # guard.PreemptionHandler
         self.snapshot_dir = snapshot_dir
@@ -489,11 +496,20 @@ class ContinuousBatcher:
             b *= 2
         batches.append(bucket(self.max_prefill_batch))
         out = None
+
+        def window(nb, w):
+            if self.attention_window is None:
+                return {}
+            ww = self.cache.window_width(self.attention_window, w)
+            return {"window": (np.zeros((nb, ww), np.int32),
+                               np.zeros((nb,), np.int32))}
+
         for w in widths:
             out = self.step_fn.decode(
                 self.params, state, np.zeros(self.max_batch, np.int32),
                 np.zeros(self.max_batch, np.int32),
-                np.zeros((self.max_batch, w), np.int32))
+                np.zeros((self.max_batch, w), np.int32),
+                **window(self.max_batch, w))
             state = out.cache
             for nb in batches:
                 for s in seqs:
@@ -507,7 +523,7 @@ class ContinuousBatcher:
                         self.params, state, np.zeros((nb, s), np.int32),
                         np.zeros((nb,), np.int32),
                         np.zeros((nb,), np.int32),
-                        np.zeros((nb, w), np.int32))
+                        np.zeros((nb, w), np.int32), **window(nb, w))
                     state = out.cache
         if out is not None:
             jax.block_until_ready(out.next_token)
@@ -1081,6 +1097,21 @@ class ContinuousBatcher:
                             matched=fl.prefilled)
         return direct, chunked
 
+    def _window_tables(self, seq_ids, positions, width: int,
+                       batch: int) -> Dict[str, Any]:
+        """The ``window=`` argument of a dispatch over the cache: the
+        tails of the lanes' tables, where the model has window layers
+        (nothing otherwise, and the dispatch is what it always was).
+        Counts the positions each kind of layer gathers in this
+        dispatch, from the widths and the lanes alone."""
+        if self.attention_window is None:
+            return {}
+        ww = self.cache.window_width(self.attention_window, width)
+        self.gathered["full"] += batch * width * self.cache.block_size
+        self.gathered["window"] += batch * ww * self.cache.block_size
+        return {"window": self.cache.window_table_array(
+            seq_ids, positions, self.attention_window, ww, batch=batch)}
+
     def _tables_for(self, flights: List[_InFlight], batch: int):
         widths = [len(self.cache.table(f.seq_id)) for f in flights]
         w = bucket(max(widths), self.min_width_bucket)
@@ -1177,8 +1208,9 @@ class ContinuousBatcher:
                     tokens[i, :cs] = f.req.prompt[f.prefilled:f.prefilled + cs]
                     starts[i] = f.prefilled
                     lengths[i] = cs
-                tables = self.cache.table_array(
-                    [f.seq_id for f, _ in batchees], width, batch=b)
+                seqs = [f.seq_id for f, _ in batchees]
+                tables = self.cache.table_array(seqs, width, batch=b)
+                window = self._window_tables(seqs, starts, width, b)
                 sampling = self._sampling_for([f for f, _ in batchees], b)
             with self._ring_dispatch("prefill_chunk"):
                 with self._span("apex.serve.chunk.dispatch"):
@@ -1186,7 +1218,7 @@ class ContinuousBatcher:
                     faults.check("prefill_chunk")
                     out = self.step_fn.prefill_chunk(
                         self.params, state, tokens, starts, lengths, tables,
-                        sampling=sampling)
+                        sampling=sampling, **window)
                 with self._span("apex.serve.chunk.wait"):
                     jax.block_until_ready(out.next_token)
             now = self.clock()
@@ -1372,8 +1404,9 @@ class ContinuousBatcher:
                 for i, f in enumerate(flights):
                     tokens[i] = f.generated[-1]
                     positions[i] = f.position
-                tables = self.cache.table_array([f.seq_id for f in flights],
-                                                width, batch=b)
+                seqs = [f.seq_id for f in flights]
+                tables = self.cache.table_array(seqs, width, batch=b)
+                window = self._window_tables(seqs, positions, width, b)
                 sampling = self._sampling_for(flights, b)
             with self._ring_dispatch("decode"):
                 with self._span("apex.serve.decode.dispatch"):
@@ -1381,7 +1414,7 @@ class ContinuousBatcher:
                     faults.check("decode_step")
                     out = self.step_fn.decode(
                         self.params, state, tokens, positions, tables,
-                        sampling=sampling)
+                        sampling=sampling, **window)
                 with self._span("apex.serve.decode.wait"):
                     jax.block_until_ready(out.next_token)
             now = self.clock()

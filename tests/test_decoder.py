@@ -1,0 +1,458 @@
+"""The pattern decoder (``models/decoder.py``), the held-experts layer
+(``moe/held.py``) and window layers over the paged cache, against the
+plain float32 reference (``models/decoder_reference.py``), at a small
+size on the CPU with seeded random weights: hidden 64, 4 heads / 2 KV
+heads of 32, window 8, block 4, 16 experts top-2 with 4 held,
+vocabulary 64, pattern dense + window, then window, window, full.
+"""
+
+import zlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from apex_tpu import serving
+from apex_tpu.models import decoder_reference as ref
+from apex_tpu.models.decoder import DecoderConfig, PatternDecoder
+from apex_tpu.moe.held import HeldMoEConfig, HeldMoEMLP, sigmoid_router
+
+LAYERS = (("window", "dense"), ("window", "experts"), ("window", "experts"),
+          ("window", "experts"), ("full", "experts"))
+WINDOW, BLOCK, VOCAB, EXPERTS, HELD = 8, 4, 64, 16, (4, 4)
+BF16_EPS = float(jnp.finfo(jnp.bfloat16).eps)
+
+
+def config(dtype=jnp.float32, held=HELD, layers=LAYERS):
+    return DecoderConfig(
+        vocab_size=VOCAB, hidden_size=64, num_heads=4, num_kv_heads=2,
+        head_dim=32, max_seq_len=128, layers=layers, ffn_hidden_size=128,
+        attention_window=WINDOW, expert_ffn_size=48, num_experts=EXPERTS,
+        experts_per_token=2, held_experts=held, shared_ffn_size=48,
+        route_scale=2.448, dtype=dtype, param_dtype=jnp.float32)
+
+
+def arch(held=HELD, layers=LAYERS):
+    return ref.Arch(4, 2, 32, layers, WINDOW, top_k=2, route_scale=2.448,
+                    held=held)
+
+
+def seeded(shapes, seed=0):
+    """Weights that make every part matter: gains off 1, a selection
+    bias that changes choices, a router that spreads its scores."""
+    def leaf(path, x):
+        name = "/".join(str(getattr(k, "key", k)) for k in path)
+        key = jax.random.fold_in(jax.random.PRNGKey(seed),
+                                 zlib.crc32(name.encode()) % 2**31)
+        draw = jax.random.normal(key, x.shape, jnp.float32)
+        if name.endswith("scale"):
+            return 1.0 + 0.1 * draw
+        if name.endswith("select_bias"):
+            return 0.3 * draw
+        return (0.4 if name.endswith("router") else 0.06) * draw
+    return jax.tree_util.tree_map_with_path(leaf, shapes)
+
+
+@pytest.fixture(scope="module")
+def model_and_params():
+    model = PatternDecoder(config())
+    shapes = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 8), jnp.int32)),
+        jax.random.PRNGKey(0))
+    return model, seeded(shapes)
+
+
+def tokens(n, seed=0):
+    return np.random.default_rng(seed).integers(0, VOCAB, n).astype(np.int32)
+
+
+def test_full_forward_matches_the_reference(model_and_params):
+    model, params = model_and_params
+    toks = tokens(40)
+    got = model.apply(params, toks[None])[:, 0]
+    want, routing = ref.forward(params, toks, np.arange(40), arch(),
+                                row_block=16)
+    assert float(jnp.abs(got - want).max()) < 2e-5 * float(
+        jnp.abs(want).max())
+    # the cut really leaves experts out, and really keeps some
+    assert all(0 < f["held_pairs"] < f["pairs"] for f in routing)
+
+
+def _engine(model, params, cfg, **kw):
+    cache = serving.KVCache.for_config(cfg, num_blocks=96, block_size=BLOCK)
+    engine = serving.ContinuousBatcher(
+        model, params, cache, max_batch=4, min_width_bucket=2,
+        min_seq_bucket=4, **kw)
+    return engine, cache
+
+
+def _serve(engine, cache, requests):
+    state = cache.init_state()
+    for r in requests:
+        engine.submit(r)
+    done = {}
+    while not engine.idle():
+        state, _ = engine.step(state)
+        for res in engine.drain():
+            done[res.id] = res
+    return done
+
+
+@pytest.mark.parametrize("chunk", [None, 8, 5])
+def test_served_through_the_cache_matches_the_reference(model_and_params,
+                                                        chunk):
+    """Prefill (whole, or in chunks that cross the window's edge at 8)
+    then decode, several lengths in one batch, short of the window and
+    past it: every served token is the reference's argmax of a full
+    pass, and the pool is empty after the drain."""
+    model, params = model_and_params
+    engine, cache = _engine(model, params, config(), prefill_chunk=chunk)
+    requests = [serving.Request(id=i, prompt=tokens(n, 10 + i),
+                                max_new_tokens=m)
+                for i, (n, m) in enumerate([(5, 6), (13, 9), (30, 12),
+                                            (21, 5), (7, 10)])]
+    done = _serve(engine, cache, requests)
+    for r in requests:
+        out = ref.check_served(params, arch(), r.prompt, done[r.id].tokens,
+                               ulps=0.01, dtype_eps=BF16_EPS)
+        assert out["ok"] and out["exact"] == r.max_new_tokens, (r.id, out)
+    assert cache.blocks_in_use == 0
+    # window layers gathered less than the full layer did
+    assert 0 < engine.gathered["window"] < engine.gathered["full"]
+    keys = sorted(engine.step_fn._compiled)
+    assert all(len(k) == (4 if k[0] == "decode_step" else 5)
+               for k in keys if k[0] != "prefill_step"), keys
+
+
+@pytest.mark.parametrize("start,length", [(0, 8), (4, 8), (7, 3), (8, 8),
+                                          (9, 8), (13, 6), (26, 8)])
+def test_chunk_logits_across_the_window_edge(model_and_params, start,
+                                             length):
+    """``prefill_chunk`` at every kind of start around the window's
+    edge (8) and a block's edge (4): the last row's logits against the
+    reference's full pass (logits, not tokens)."""
+    model, params = model_and_params
+    cfg = config()
+    cache = serving.KVCache.for_config(cfg, num_blocks=32, block_size=BLOCK)
+    step = serving.make_decode_step(model, cache)
+    state = cache.init_state()
+    toks = tokens(start + length, 5)
+    cache.allocate("s", start + length)
+    width = 16
+    table = cache.table_array(["s"], width)
+
+    def tail(position):
+        ww = cache.window_width(WINDOW, width)
+        return cache.window_table_array(["s"], [position], WINDOW, ww)
+
+    if start:
+        out = step.prefill_chunk(
+            params, state, toks[None, :start], np.zeros(1, np.int32),
+            np.array([start], np.int32), table, window=tail(0))
+        state = out.cache
+    pad = -length % 8
+    chunk = np.pad(toks[start:], (0, pad))[None]
+    out = step.prefill_chunk(
+        params, state, chunk, np.array([start], np.int32),
+        np.array([length], np.int32), table, window=tail(start))
+    want, _ = ref.forward(params, toks, [start + length - 1], arch())
+    assert float(jnp.abs(out.logits[0] - want[0]).max()) < 2e-5 * float(
+        jnp.abs(want).max())
+
+
+@pytest.mark.parametrize("position", [0, 3, 4, 7, 8, 9, 11, 12, 13, 30])
+def test_tail_tables(position):
+    """The window layers' table holds the blocks of the last ``window``
+    positions at every position around a block's edge and the window's
+    edge, and the trash block elsewhere."""
+    cache = serving.KVCache(1, 2, 32, num_blocks=32, block_size=BLOCK)
+    cache.allocate("s", 40)
+    own = cache.table("s")
+    width = cache.window_width(WINDOW, 16)
+    assert width == 3                       # 8 positions + the edge's block
+    tables, first = cache.window_table_array(
+        ["s"], [position], WINDOW, width, batch=2)
+    lo = max(0, position - WINDOW + 1)
+    assert first[0] == lo // BLOCK
+    want = own[lo // BLOCK:lo // BLOCK + width]
+    assert tables[0, :len(want)].tolist() == want
+    assert position // BLOCK - first[0] < width      # the token's own block
+    assert (tables[0, len(want):] == 0).all() and (tables[1] == 0).all()
+    assert first[1] == 0
+    cache.free("s")
+    assert cache.blocks_in_use == 0
+
+
+def test_window_width_never_passes_the_full_table():
+    cache = serving.KVCache(1, 8, 128, num_blocks=8, block_size=16)
+    assert cache.window_width(4096, 1024) == 320     # 5120 positions
+    assert cache.window_width(4096, 512) == 320
+    assert cache.window_width(4096, 256) == 256
+
+
+def test_router_chooses_by_biased_scores_and_weighs_by_plain_ones():
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(6, 8)), jnp.float32)
+    gate = jnp.asarray(np.random.default_rng(1).normal(size=(8, 5)),
+                       jnp.float32)
+    bias = jnp.asarray([0.0, 2.0, 0.0, -2.0, 0.0])
+    w, ids, s = sigmoid_router(x, gate, bias, 2, route_scale=2.448)
+    s, ids = np.asarray(s), np.asarray(ids)
+    np.testing.assert_allclose(s, 1 / (1 + np.exp(-np.asarray(x @ gate))),
+                               rtol=1e-5)
+    assert (ids[:, 0] == 1).all() and (ids != 3).all()     # the bias decides
+    chosen = np.take_along_axis(s, ids, 1)
+    np.testing.assert_allclose(
+        np.asarray(w), chosen / chosen.sum(1, keepdims=True) * 2.448,
+        rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(w).sum(1), 2.448, rtol=1e-5)
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """The routed parts that all ``16 / 4`` shares compute, plus the
+    shared expert once, equal the uncut layer of the reference."""
+    full = HeldMoEConfig(hidden_size=64, expert_ffn_size=48,
+                         num_experts=EXPERTS, top_k=2, route_scale=2.448,
+                         shared_ffn_size=48, dtype=jnp.float32)
+    x = jnp.asarray(np.random.default_rng(2).normal(size=(3, 11, 64)),
+                    jnp.float32)
+    whole = seeded(jax.eval_shape(
+        lambda k: HeldMoEMLP(full).init(k, x), jax.random.PRNGKey(0)), 7)
+    want, facts = ref._experts(
+        x.reshape(-1, 64), whole["params"],
+        ref.Arch(4, 2, 32, (), None, top_k=2, route_scale=2.448), None, ())
+    assert facts["held_pairs"] == facts["pairs"]
+    shared_only = dict(whole["params"])
+    total = 0.0
+    for first in range(0, EXPERTS, 4):
+        share = dict(whole["params"])
+        for name in ("w_gate", "w_up", "w_down"):
+            share[name] = whole["params"][name][first:first + 4]
+        for name in ("shared_gate", "shared_up", "shared_down"):
+            share[name] = jnp.zeros_like(share[name])       # counted once
+        cfg = HeldMoEConfig(**{**full.__dict__, "held": (first, 4)})
+        total = total + HeldMoEMLP(cfg).apply({"params": share}, x)
+    for name in ("w_gate", "w_up", "w_down"):
+        shared_only[name] = jnp.zeros_like(shared_only[name][:1])
+    cfg = HeldMoEConfig(**{**full.__dict__, "held": (0, 1)})
+    total = total + HeldMoEMLP(cfg).apply({"params": shared_only}, x)
+    np.testing.assert_allclose(np.asarray(total).reshape(-1, 64),
+                               np.asarray(want), atol=2e-5)
+
+
+def test_absent_pairs_are_dropped_before_the_grouped_products(monkeypatch):
+    """The grouped products' group sizes count the held pairs only."""
+    cfg = HeldMoEConfig(hidden_size=64, expert_ffn_size=48,
+                        num_experts=EXPERTS, top_k=2, held=HELD,
+                        dtype=jnp.float32)
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(40, 64)),
+                    jnp.float32)
+    params = seeded(jax.eval_shape(
+        lambda k: HeldMoEMLP(cfg).init(k, x), jax.random.PRNGKey(0)), 1)
+    from apex_tpu.moe import held as held_module
+
+    sizes = []
+    real = held_module.lax.ragged_dot
+    monkeypatch.setattr(
+        held_module.lax, "ragged_dot",
+        lambda x, w, group_sizes: (sizes.append(np.asarray(group_sizes)),
+                                   real(x, w, group_sizes))[1])
+    _, (w, ids, _) = HeldMoEMLP(cfg).apply(params, x, return_routing=True)
+    held = (np.asarray(ids) >= 4) & (np.asarray(ids) < 8)
+    assert 0 < held.sum() < held.size
+    assert len(sizes) == 3 and all(
+        g.shape == (4,) and g.sum() == held.sum() for g in sizes)
+    # an expert outside the held range changes nothing: zero its pairs'
+    # weights by hand and the result is the same
+    out = HeldMoEMLP(cfg).apply(params, x)
+    from apex_tpu.moe.held import held_experts
+    p = params["params"]
+    again = held_experts(x, jnp.where(held, w, 0.0), ids, p["w_gate"],
+                         p["w_up"], p["w_down"], HELD, jnp.float32)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(again), atol=1e-6)
+
+
+@pytest.mark.parametrize("window", [None, 8])
+def test_gpt_config_with_a_window_decodes(window):
+    """``GPTConfig(attention_window=...)`` is served: the cached paths
+    share ``models/cached_attention.py``."""
+    from apex_tpu.models.gpt import GPTConfig, GPTModel
+
+    cfg = GPTConfig(vocab_size=VOCAB, max_seq_len=64, hidden_size=64,
+                    num_layers=2, num_heads=4, num_kv_heads=2,
+                    attention_window=window, dtype=jnp.float32)
+    model = GPTModel(cfg)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    engine, cache = _engine(model, params, cfg, prefill_chunk=8)
+    requests = [serving.Request(id=i, prompt=tokens(n, 20 + i),
+                                max_new_tokens=m)
+                for i, (n, m) in enumerate([(5, 6), (19, 9), (30, 7)])]
+    done = _serve(engine, cache, requests)
+    for r in requests:
+        seq = np.concatenate([r.prompt, done[r.id].tokens])
+        logits = model.apply(params, seq[None, :-1])[:, 0]
+        rows = np.asarray(logits[len(r.prompt) - 1:])
+        gap = rows.max(-1) - rows[np.arange(len(rows)), done[r.id].tokens]
+        assert float(gap.max()) < 1e-4, (r.id, gap)
+    assert cache.blocks_in_use == 0
+    assert (engine.gathered["window"] > 0) == (window is not None)
+
+
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
+def test_flash_attention_takes_a_lanes_own_positions(impl):
+    from apex_tpu.ops.attention import flash_attention
+
+    rng = np.random.default_rng(0)
+    b, h, hk, sq, sk, d = 3, 4, 2, 8, 24, 32
+    q, k, v = (jnp.asarray(rng.normal(size=s), jnp.float32)
+               for s in ((b, h, sq, d), (b, hk, sk, d), (b, hk, sk, d)))
+    q_pos = jnp.asarray([[10 + i for i in range(sq)],
+                         [3 + i for i in range(sq)],
+                         [40 + i for i in range(sq)]], jnp.int32)
+    base = jnp.asarray([2, 0, 30], jnp.int32)
+    k_pos = base[:, None] + jnp.arange(sk, dtype=jnp.int32)[None]
+    got = flash_attention(q, k, v, causal=True, window_size=6,
+                          q_positions=q_pos, kv_positions=k_pos, impl=impl,
+                          block_q=8, block_k=8)
+    see = (k_pos[:, None, :] <= q_pos[:, :, None]) & (
+        k_pos[:, None, :] > q_pos[:, :, None] - 6)
+    s = jnp.einsum("bkgqd,bkcd->bkgqc", q.reshape(b, hk, 2, sq, d), k) \
+        * d ** -0.5
+    p = jax.nn.softmax(jnp.where(see[:, None, None], s, -jnp.inf), -1)
+    want = jnp.einsum("bkgqc,bkcd->bkgqd", p, v).reshape(b, h, sq, d)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+def _choice(model, params, toks):
+    """The program's own routing over ``toks``, as the benchmark's
+    driver reads it: ``(ids, biased scores)`` an expert layer."""
+    _, sown = model.apply(params, toks[None], mutable=["routing"])
+    return [(np.asarray(sown["routing"][f"layer_{i}"]["mlp"]["ids"][0]),
+             np.asarray(sown["routing"][f"layer_{i}"]["mlp"]["biased"][0]))
+            for i, (_, mlp) in enumerate(LAYERS) if mlp == "experts"]
+
+
+@pytest.fixture(scope="module")
+def served(model_and_params):
+    """Four requests served through the cache, and the program's own
+    routing over each one's teacher-forced tokens."""
+    model, params = model_and_params
+    engine, cache = _engine(model, params, config(), prefill_chunk=8)
+    requests = [serving.Request(id=i, prompt=tokens(n, 40 + i),
+                                max_new_tokens=90)
+                for i, n in enumerate([6, 11, 19, 30])]
+    done = _serve(engine, cache, requests)
+    return [(r.prompt, done[r.id].tokens,
+             _choice(model, params,
+                     ref.teacher_forced(r.prompt, done[r.id].tokens, 1)[0]))
+            for r in requests]
+
+
+# the program here is float32, so its choice may differ from the
+# reference's by float32 rounding and no more: a band of 1e-5, nothing
+# excused. (The cell serves bf16 and allows what bf16 moves.)
+LIMITS = dict(ulps=4.0, band=1e-5, slack=0.0, pad_to=1, dtype_eps=BF16_EPS)
+
+
+def test_the_program_leaves_its_choice_only_when_asked(model_and_params):
+    model, params = model_and_params
+    toks = tokens(24)
+    plain = model.apply(params, toks[None])
+    logits, sown = model.apply(params, toks[None], mutable=["routing"])
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(logits))
+    got = sown["routing"]
+    assert sorted(got) == ["layer_1", "layer_2", "layer_3", "layer_4"]
+    ids, biased = (got["layer_1"]["mlp"][k][0] for k in ("ids", "biased"))
+    assert ids.shape == (24, 2) and biased.shape == (24, EXPERTS)
+    np.testing.assert_array_equal(
+        np.sort(np.asarray(ids), -1),
+        np.sort(np.argsort(-np.asarray(biased), -1)[:, :2], -1))
+    assert "routing" not in model.init(jax.random.PRNGKey(0), toks[None])
+
+
+@pytest.mark.parametrize("gap,band,followed,refused", [
+    (0.003, 0.005, True, False),     # a swap at the cut, within the band
+    (0.003, 0.001, False, True),     # the same swap, outside it
+    (0.0, 0.005, False, False)])     # the reference's own choice
+def test_the_reference_follows_a_choice_within_the_band(gap, band, followed,
+                                                        refused):
+    """Scores 0.9, 0.7, 0.5, 0.5 - gap, 0.1, ...: top-3. A program
+    that took the 4th for the 3rd is followed if the two lie within
+    the band, and refused (the reference keeps its own) if not; a
+    program that took the last expert is refused at any band here."""
+    scores = np.full((2, 8), 0.1, np.float32)
+    scores[:, :4] = [0.9, 0.7, 0.5, 0.5 - gap]
+    logits = np.log(scores / (1 - scores))           # sigmoid's inverse
+    a = ref.Arch(1, 1, 8, (), None, top_k=3, route_scale=1.0)
+    theirs = np.array([[0, 1, 3 if gap else 2], [0, 1, 7]], np.int32)
+    w, chosen, misfit = ref._route(
+        jnp.asarray(logits), np.eye(8, dtype=np.float32),
+        np.zeros(8, np.float32), jnp.asarray(theirs), arch=a, band=band,
+        round_to=None, faults=())
+    assert float(misfit[0]) == pytest.approx(gap, abs=1e-6)
+    assert (0 < misfit[0] <= band, misfit[0] > band) == (followed, refused)
+    assert sorted(np.asarray(chosen[0])) == (
+        [0, 1, 3] if followed else [0, 1, 2])
+    assert float(misfit[1]) == pytest.approx(0.4, abs=1e-6)
+    assert sorted(np.asarray(chosen[1])) == [0, 1, 2]
+    np.testing.assert_allclose(np.asarray(w).sum(-1), 1.0, rtol=1e-6)
+
+
+def test_held_margin_watches_the_held_experts_only():
+    biased = np.array([[0.9, 0.8, 0.5, 0.49, 0.3, 0.1]], np.float32)
+    # top-3; expert 3 (held) would come in at 0.01; expert 1 (held)
+    # would drop out at 0.8 - 0.49
+    assert ref.held_margin(biased, 3, (3, 1)) == pytest.approx(0.01)
+    assert ref.held_margin(biased, 3, (1, 1)) == pytest.approx(0.31)
+    assert ref.held_margin(biased, 3, (4, 2)) == pytest.approx(0.2)
+    assert ref.held_margin(biased, 3, (0, 6)) == pytest.approx(0.01)
+
+
+def test_what_was_served_passes_given_the_programs_choice(served,
+                                                          model_and_params):
+    _, params = model_and_params
+    for prompt, toks, choice in served:
+        out = ref.check_served(params, arch(), prompt, toks, choice=choice,
+                               **LIMITS)
+        assert out["ok"] and out["exact"] == out["rows"] == 90, out
+        assert out["refused"] == out["excused"] == out["may_differ"] == 0, out
+
+
+@pytest.mark.parametrize("margins,ok,excused", [
+    ([0.1, 0.1, 0.1, 0.1], False, 0),     # a row trails, nothing explains it
+    ([0.1, 0.1, 1e-4, 0.1], True, 1),     # the program's own cut is close
+    ([1e-4, 1e-4, 0.1, 1e-4], False, 0)])  # close elsewhere: no excuse
+def test_a_row_is_excused_only_if_it_trails_and_may_differ(margins, ok,
+                                                           excused):
+    logits = np.zeros((4, 8), np.float32)
+    logits[:, 0] = 1.0
+    logits[2, 1] = 1.05                   # row 2 serves token 0, 0.05 behind
+    out = ref.judge(logits, np.zeros(4, int), np.asarray(margins) < 1e-3,
+                    ulps=4.0, dtype_eps=BF16_EPS)
+    assert (out["ok"], out["excused"]) == (ok, excused)
+    assert out["exact"] == 3 and out["rows"] == 4
+
+
+@pytest.mark.parametrize("fault", [
+    "window_edge", "rope_on_full", "weight_by_biased", "bf16_router",
+    "choice_without_bias", "fp8_everywhere"])
+def test_the_comparison_refuses_a_wrong_model(model_and_params, served,
+                                              fault):
+    """A reference that is wrong in one way (a window one key too wide,
+    rotary positions on the full layer, weights from ``s + b``, the
+    choice made without ``b``, the router's product on bf16 inputs,
+    everything in fp8) against what the true program served, at the
+    cell's 4 bf16 ulps and given the program's choice: every request
+    is refused. (The router's product on bf16 inputs moves a score by
+    1e-3 or so, which at 16 experts and 64 wide changes a choice in a
+    row or two of a hundred: the four requests are refused as the one
+    run they are, by the two of them that meet such a row.)"""
+    _, params = model_and_params
+    wrong = dict(round_to=jnp.float8_e4m3fn) if fault == "fp8_everywhere" \
+        else dict(faults=(fault,))
+    verdicts = [ref.check_served(params, arch(), prompt, toks, choice=choice,
+                                 **LIMITS, **wrong)
+                for prompt, toks, choice in served]
+    passed = [v["ok"] for v in verdicts]
+    assert not (all(passed) if fault == "bf16_router" else any(passed)), [
+        (v["worst_ulps"], v["refused"]) for v in verdicts]

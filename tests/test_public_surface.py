@@ -145,6 +145,13 @@ SURFACE = {
         "note_restored",
     ],
     "apex_tpu.models.gpt": ["GPTConfig", "GPTModel", "gpt_loss_fn"],
+    # PR-28: the pattern decoder and the held-experts layer
+    "apex_tpu.models.decoder": ["DecoderConfig", "PatternDecoder"],
+    "apex_tpu.models.decoder_reference": ["Arch", "forward", "judge",
+                                          "check_served", "held_margin",
+                                          "teacher_forced"],
+    "apex_tpu.moe.held": ["HeldMoEConfig", "HeldMoEMLP", "sigmoid_router",
+                          "held_experts"],
     "apex_tpu.models.bert": None,     # module presence only
     "apex_tpu.models.t5": None,
     "apex_tpu.models.resnet": None,

@@ -454,8 +454,10 @@ class TestDecodeParity:
         state = jax.eval_shape(fresh_cache().init_state)
         text = step_fn.lower(fn, params, state, b, w, s).as_text()
         keys = L if s == 1 else L + s
-        # (the layer scan traces its body more than once)
-        assert set(calls) == {((b, HEADS, s, d), (b, KV, keys, d),
+        # (the layer scan traces its body more than once); a decode
+        # call carries the query heads of a kv head as one block's rows
+        q = (b, KV, HEADS // KV, d) if s == 1 else (b, HEADS, s, d)
+        assert set(calls) == {(q, (b, KV, keys, d),
                                (b, KV, keys, d), (b, keys))}
         for dtype in ("f32", "i32", "i1"):
             assert f"tensor<{LAYERS}x{b}x{KV}x{L}x{d}x{dtype}>" not in text

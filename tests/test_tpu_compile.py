@@ -314,3 +314,63 @@ def test_decode_program_gathers_once_a_layer(chip, monkeypatch):
     assert not re.search(r"bf16\[513,16,4,128\]", text)       # a pool slice
     assert not re.search(r"bf16\[8,4,10(25|88|\d\d),128\]\S* concatenate",
                          text)
+
+
+@pytest.mark.parametrize("fn,batch,seq", [
+    ("decode_step", 16, 1), ("prefill_chunk", 4, 1024)])
+def test_trinity_cut_programs_fit_and_gather_the_window(chip, monkeypatch,
+                                                        fn, batch, seq):
+    """``Trinity-Large-Preview``'s five-layer cut at the benchmark's own
+    sizes (``benchmark/configs/trinity-large-preview.json``: 4.32B
+    parameters in bf16, a pool of 8192 blocks), its decode program at
+    16 lanes and its chunk program at 4 x 1024, at the widest tables
+    (1024 blocks, 16k positions): they fit a v5e beside nothing else,
+    the four window layers gather and attend 5120 positions whatever
+    the full table's width, the full layer 16384, and the expert
+    layers' grouped products are the compiler's own kernels."""
+    import json
+    import re
+
+    from apex_tpu import serving
+    from apex_tpu.models.decoder import PatternDecoder
+    from benchmark.drivers import serve_pattern
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "trinity-large-preview.json")) as f:
+        config = json.load(f)
+    monkeypatch.setenv("APEX_TPU_IMPL", "pallas")
+    _backend.default_impl.cache_clear()
+    try:
+        cfg = serve_pattern.decoder_config(config)
+        model = PatternDecoder(cfg)
+        cache = serving.KVCache.for_config(
+            cfg, num_blocks=config["engine"]["num_blocks"],
+            block_size=config["engine"]["block_size"])
+        shapes = jax.eval_shape(
+            lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32)),
+            jax.random.PRNGKey(0))
+        params, state = jax.tree.map(
+            lambda x: chip(x.shape, x.dtype),
+            (shapes, jax.eval_shape(cache.init_state)))
+        width = 1024
+        tail = cache.window_width(cfg.attention_window, width)
+        compiled = serving.make_decode_step(model, cache).lower(
+            fn, params, state, batch, width, seq=seq,
+            window_table_width=tail).compile()
+    finally:
+        _backend.default_impl.cache_clear()
+    assert tail * 16 == 5120
+    assert chip_smoke.program_bytes(compiled) <= 0.8 * V5E_BYTES
+    text = compiled.as_text()
+    calls = re.findall(
+        r"%([\w-]+)\.?\d* = (\([^=]*?\)|\S+) custom-call\([^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"", text)
+    gathers = [res for name, res in calls if name == "kv_gather"]
+    assert sorted(g.count(f"bf16[{batch},8,5120,128]") for g in gathers) \
+        == [0, 2, 2, 2, 2]
+    assert sum(g.count(f"bf16[{batch},8,16384,128]") for g in gathers) == 2
+    names = [name for name, _ in calls]
+    assert names.count("attention_window") == 4
+    assert names.count("attention") == 1
+    assert names.count("ragged-dot-none") == 12       # 3 products x 4 layers
