@@ -34,7 +34,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.models.cached_attention import cached_attention
+from apex_tpu.models.cached_attention import (
+    cached_attention, first_context)
 from apex_tpu.moe.held import HeldMoEConfig, HeldMoEMLP, gated_mlp
 from apex_tpu.ops.layer_norm import fused_rms_norm
 from apex_tpu.ops.rope import fused_apply_rotary_pos_emb_cached
@@ -113,6 +114,12 @@ def _gather(cfg: DecoderConfig, *args):
     return kv_gather(*args, impl=cfg.softmax_impl)
 
 
+def _zero_ctx(cfg: DecoderConfig, *args):
+    from apex_tpu.ops.kv_gather import zero_context
+
+    return zero_context(*args, impl=cfg.softmax_impl)
+
+
 class RMSNorm(nn.Module):
     """``x * rsqrt(mean(x^2) + eps) * g`` in float32 over the last dim
     (the XLA form of ``ops/layer_norm.py``: it fuses into its
@@ -144,8 +151,10 @@ class DecoderAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, *, kv_ctx=None):
-        """``x`` (b, s, hidden) -> (b, s, hidden) and this call's K/V
-        in the kernel layout (b, kv_heads, s, head_dim)."""
+        """``x`` (b, s, hidden) -> (b, s, hidden), this call's K/V in
+        the kernel layout (b, kv_heads, s, head_dim), and what the next
+        layer of this kind gathers its context into (``kv_ctx[1]`` is
+        what this one does: ``cached_attention``)."""
         cfg = self.config
         b, s, _ = x.shape
         nh, nkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -164,8 +173,9 @@ class DecoderAttention(nn.Module):
             q = rotary(q, positions, cfg.rope_theta)
             k = rotary(k, positions, cfg.rope_theta)
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        held = ()
         if kv_ctx is not None:
-            o = cached_attention(
+            o, held = cached_attention(
                 q, k, v, kv_ctx, window=window, dtype=cfg.dtype,
                 flash=lambda *a, **kw: _flash(cfg, *a, **kw),
                 gather=lambda *a: _gather(cfg, *a))
@@ -178,7 +188,7 @@ class DecoderAttention(nn.Module):
         o = o * jax.nn.sigmoid(g.astype(jnp.float32)).astype(cfg.dtype)
         wo = self.param("proj", init, (nh * d, cfg.hidden_size),
                         cfg.param_dtype)
-        return jnp.dot(o, wo.astype(cfg.dtype)), (k, v)
+        return jnp.dot(o, wo.astype(cfg.dtype)), (k, v), held
 
 
 class DenseMLP(nn.Module):
@@ -204,7 +214,7 @@ class DecoderLayer(nn.Module):
     def __call__(self, x, positions, *, kv_ctx=None):
         cfg = self.config
         norm = lambda name: RMSNorm(cfg.rms_eps, name=name)  # noqa: E731
-        a, kv = DecoderAttention(cfg, self.attention, name="attention")(
+        a, kv, held = DecoderAttention(cfg, self.attention, name="attention")(
             norm("input_norm")(x), positions, kv_ctx=kv_ctx)
         y = x + norm("post_attention_norm")(a)
         m = norm("pre_mlp_norm")(y)
@@ -212,7 +222,7 @@ class DecoderLayer(nn.Module):
             m = HeldMoEMLP(cfg.moe_cfg(), name="mlp")(m)
         else:
             m = DenseMLP(cfg, name="mlp")(m)
-        return y + norm("post_mlp_norm")(m), kv
+        return y + norm("post_mlp_norm")(m), kv, held
 
 
 class PatternDecoder(nn.Module):
@@ -242,10 +252,20 @@ class PatternDecoder(nn.Module):
             positions = jnp.arange(s, dtype=jnp.int32)
         positions = jnp.broadcast_to(jnp.asarray(positions), (b, s))
         kvs = []
+        # what the next layer of each kind gathers its context into
+        # (cached_attention): zeros before the first
+        into = {}
+        if kv_ctx is not None:
+            windows = {"full": None, "window": cfg.attention_window}
+            into = {kind: first_context(kv_ctx, window=windows[kind],
+                                        zero=lambda *a: _zero_ctx(cfg, *a))
+                    for kind in dict.fromkeys(a for a, _ in cfg.layers)}
         for i, (attention, mlp) in enumerate(cfg.layers):
-            x, kv = DecoderLayer(cfg, attention, mlp, name=f"layer_{i}")(
+            x, kv, into[attention] = DecoderLayer(
+                cfg, attention, mlp, name=f"layer_{i}")(
                 x, positions,
-                kv_ctx=None if kv_ctx is None else (i, *kv_ctx))
+                kv_ctx=(None if kv_ctx is None
+                        else (i, into[attention], *kv_ctx)))
             kvs.append(kv)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
         head = self.param("head", init, (cfg.vocab_size, cfg.hidden_size),

@@ -199,25 +199,49 @@ def _flash(cfg: "GPTConfig", q, k, v, *, kv_segment_ids=None, **kw):
     return island(*args)
 
 
-def _gather_ctx(cfg: "GPTConfig", k_pool, v_pool, layer, tables):
+def _paged_specs():
+    """Where the paged pools, the block tables and a gathered context
+    lie under the mesh: kv heads on ``model``, lanes on ``batch``."""
+    from apex_tpu.mesh.mesh import BATCH_AXIS, MODEL_AXIS
+
+    return (P(None, None, None, MODEL_AXIS, None), P(BATCH_AXIS, None),
+            P(BATCH_AXIS, MODEL_AXIS, None, None))
+
+
+def _gather_ctx(cfg: "GPTConfig", k_pool, v_pool, layer, tables, lens, into):
     """Layer ``layer`` of the paged pools (layers, blocks, block_size,
     kv_heads, head_dim) through the block ``tables`` (b, w), as K and
-    V in ``_flash``'s (b, kv_heads, w * block_size, head_dim) layout:
-    ``ops/kv_gather.py``, one pass over the context. Lanes and kv
-    heads are independent, so under an armed GSPMD mesh the kernel
-    runs per shard of them, as ``_flash``'s does."""
-    from apex_tpu.mesh.mesh import BATCH_AXIS, MODEL_AXIS
+    V in ``_flash``'s (b, kv_heads, w * block_size, head_dim) layout,
+    as far as each lane's ``lens`` (b,) and into the pair ``into``
+    (``_zero_ctx``'s or the last layer's): ``ops/kv_gather.py``, one
+    pass over the live context. Lanes and kv heads are independent, so
+    under an armed GSPMD mesh the kernel runs per shard of them, as
+    ``_flash``'s does."""
     from apex_tpu.ops.kv_gather import kv_gather
 
-    def gather(k_pool, v_pool, layer, tables):
-        return kv_gather(k_pool, v_pool, layer, tables,
+    def gather(k_pool, v_pool, layer, tables, lens, *into):
+        return kv_gather(k_pool, v_pool, layer, tables, lens, into,
                          impl=cfg.softmax_impl)
 
-    pool = P(None, None, None, MODEL_AXIS, None)
-    ctx = P(BATCH_AXIS, MODEL_AXIS, None, None)
+    pool, rows, ctx = _paged_specs()
     return _gspmd.on_shards(
-        gather, cfg.softmax_impl, (pool, pool, P(), P(BATCH_AXIS, None)),
-        (ctx, ctx))(k_pool, v_pool, layer, tables)
+        gather, cfg.softmax_impl,
+        (pool, pool, P(), rows, P(rows[0])) + (ctx,) * len(into),
+        (ctx, ctx))(k_pool, v_pool, layer, tables, lens, *into)
+
+
+def _zero_ctx(cfg: "GPTConfig", k_pool, tables):
+    """What a program call's first layer gathers its context into
+    (``ops/kv_gather.py`` ``zero_context``), made per shard like the
+    gather that writes into it."""
+    from apex_tpu.ops.kv_gather import zero_context
+
+    def zero(k_pool, tables):
+        return zero_context(k_pool, tables, impl=cfg.softmax_impl)
+
+    pool, rows, ctx = _paged_specs()
+    return _gspmd.on_shards(
+        zero, cfg.softmax_impl, (pool, rows), ctx)(k_pool, tables)
 
 
 class _LayerNorm(nn.Module):
@@ -250,11 +274,15 @@ class ParallelAttention(nn.Module):
 
     - ``return_kv=True`` additionally returns this call's K/V in the
       kernel ``(b, kv_local, s, head_dim)`` layout — what a prefill
-      step writes into the paged cache.
-    - ``kv_ctx=(layer, k_pool, v_pool, tables, ctx_lens[, win])`` is the
-      cached path (``models/cached_attention.py``; with
+      step writes into the paged cache — and, third, what the next
+      layer gathers its context into (``()`` without ``kv_ctx``).
+    - ``kv_ctx=(layer, into, k_pool, v_pool, tables, ctx_lens[, win])``
+      is the cached path (``models/cached_attention.py``; with
       ``attention_window`` the layer gathers through ``win``, the tail
-      of each lane's table, and masks by true positions). ``k_pool``/``v_pool`` are the paged pools, whole
+      of each lane's table, and masks by true positions). ``into`` is
+      the (K, V) this layer gathers into: ``_zero_ctx``'s for the
+      first, then the last layer's third result.
+      ``k_pool``/``v_pool`` are the paged pools, whole
       (layers, blocks, block_size, kv_local, head_dim), ``layer`` this
       layer's index into them, ``tables`` (b, w) the block tables and
       ``ctx_lens`` (b,) how many cached positions of each lane are
@@ -321,14 +349,14 @@ class ParallelAttention(nn.Module):
         # kernel-layout K/V of THIS call's tokens — the cache payload
         kv_new = (k.transpose(1, 2, 0, 3), v.transpose(1, 2, 0, 3))
 
-        def _out(ctx):
+        def _out(ctx, held=()):
             out = RowParallelLinear(
                 output_size=h, input_is_parallel=True,
                 sequence_parallel_enabled=cfg.sequence_parallel,
                 param_dtype=cfg.param_dtype, dtype=cfg.dtype, name="proj",
             )(ctx)
             out = _gspmd.constrain_hidden(out)
-            return (out, kv_new) if return_kv else out
+            return (out, kv_new, held) if return_kv else out
 
         if kv_ctx is not None:
             # the cached paths: queries against this layer's gathered
@@ -343,7 +371,7 @@ class ParallelAttention(nn.Module):
 
             # tests read the gathered context back through the sow
             # (a no-op unless "intermediates" is mutable)
-            ctx = cached_attention(
+            ctx, held = cached_attention(
                 q.transpose(1, 2, 0, 3), *kv_new, kv_ctx,
                 flash=lambda *a, **kw: _flash(cfg, *a, **kw),
                 gather=lambda *a: _gather_ctx(cfg, *a),
@@ -351,7 +379,7 @@ class ParallelAttention(nn.Module):
                 sow=lambda kv: self.sow("intermediates", "kv_ctx", kv))
             ctx = ctx.transpose(2, 0, 1, 3).reshape(
                 s, b, heads_local * head_dim)
-            return _out(ctx)
+            return _out(ctx, held)
 
         if cfg.attention_backend in ("flash", "ring"):
             # (s, b, heads, d) -> (b, heads, s, d)
@@ -457,9 +485,8 @@ class GPTLayer(nn.Module):
             positions=positions, deterministic=deterministic,
             kv_ctx=kv_ctx, return_kv=return_kv,
         )
-        kv = None
         if return_kv:
-            a, kv = a
+            a, kv, held = a
         if cfg.hidden_dropout > 0.0 and not deterministic:
             a = nn.Dropout(rate=cfg.hidden_dropout)(a, deterministic=False)
         x = x + a
@@ -478,7 +505,7 @@ class GPTLayer(nn.Module):
         if cfg.hidden_dropout > 0.0 and not deterministic:
             m = nn.Dropout(rate=cfg.hidden_dropout)(m, deterministic=False)
         y = x + m
-        return (y, kv) if return_kv else y
+        return (y, kv, held) if return_kv else y
 
 
 class _GPTScanBlock(nn.Module):
@@ -503,18 +530,21 @@ class _GPTScanBlockKV(nn.Module):
     context out of the paged pools — ``layer`` is the scanned input;
     the pools, block tables and lengths in ``kv_ctx`` are the same
     for every layer (None for prefill) — and emits its new K/V as a
-    stacked scan output."""
+    stacked scan output. The carry holds, beside the hidden states,
+    the pair the next layer gathers its context into
+    (``cached_attention``'s ``into``)."""
 
     config: GPTConfig
     deterministic: bool = True
 
     @nn.compact
-    def __call__(self, x, layer, kv_ctx, positions):
-        y, kv = GPTLayer(self.config, name="layer")(
+    def __call__(self, carry, layer, kv_ctx, positions):
+        x, into = carry
+        y, kv, into = GPTLayer(self.config, name="layer")(
             x, positions=positions, deterministic=self.deterministic,
-            kv_ctx=None if kv_ctx is None else (layer, *kv_ctx),
+            kv_ctx=None if kv_ctx is None else (layer, into, *kv_ctx),
             return_kv=True)
-        return y, kv
+        return (y, into), kv
 
 
 class GPTModel(nn.Module):
@@ -572,6 +602,15 @@ class GPTModel(nn.Module):
 
         serving = return_kv or kv_ctx is not None
         kvs = None
+        # what the first layer gathers its context into, and each
+        # later one what the last gave back (cached_attention)
+        into = ()
+        if kv_ctx is not None:
+            from apex_tpu.models.cached_attention import first_context
+
+            into = first_context(
+                kv_ctx, window=cfg.attention_window,
+                zero=lambda *a: _zero_ctx(cfg, *a))
         if cfg.scan_layers:
             if serving:
                 scan = nn.scan(
@@ -581,8 +620,8 @@ class GPTModel(nn.Module):
                     length=cfg.num_layers,
                     in_axes=(0, nn.broadcast, nn.broadcast),
                 )
-                x, kvs = scan(cfg, deterministic, name="layers")(
-                    x, jnp.arange(cfg.num_layers, dtype=jnp.int32),
+                (x, _), kvs = scan(cfg, deterministic, name="layers")(
+                    (x, into), jnp.arange(cfg.num_layers, dtype=jnp.int32),
                     kv_ctx, positions)
             else:
                 scan = nn.scan(
@@ -596,13 +635,13 @@ class GPTModel(nn.Module):
         else:
             per_layer = []
             for i in range(cfg.num_layers):
-                ctx = None if kv_ctx is None else (i, *kv_ctx)
+                ctx = None if kv_ctx is None else (i, into, *kv_ctx)
                 x = GPTLayer(cfg, moe=cfg.is_moe_layer(i),
                              name=f"layer_{i}")(
                     x, positions=positions, deterministic=deterministic,
                     kv_ctx=ctx, return_kv=serving)
                 if serving:
-                    x, kv = x
+                    x, kv, into = x
                     per_layer.append(kv)
             if serving:
                 kvs = (jnp.stack([kv[0] for kv in per_layer]),

@@ -14,6 +14,17 @@ pool is an operand as a whole and ``layer`` picks the slice inside the
 kernel, so a layer scan that closes over the pools slices nothing out
 of them.
 
+It copies as far as each lane's keys go and no further. A table is as
+wide as the largest reservation's power-of-two bucket, trash-padded
+and filled out with dummy lanes, and the attention mask hides every
+position at or past a lane's length: for a dead block no copy is
+started or waited for, nothing is turned, and a grid step past the
+lane's last live group revisits that group's output block, which is
+then written back once and the rest of the lane's row not at all. The
+results alias the arrays the caller hands in, so what is not written
+keeps their bytes. That makes the tail's content the caller's to
+answer for, and it has to be finite (``kv_gather``'s docstring).
+
 The result is pinned to HBM. Left to itself the TPU compiler keeps a
 context that fits (8 lanes of 1024 positions: 34 MB) in the v5e's
 128 MiB of VMEM, and the attention kernel then reads it faster than
@@ -31,8 +42,8 @@ from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -52,96 +63,207 @@ def _whole_tiles(pool) -> bool:
 def _gather_xla(pool, layer, tables):
     # the layer's slice first: indexing the pool by (layer, block) at
     # once makes the TPU compiler re-lay the whole pool out, every layer
-    g = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+    g = lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
     g = g.at[tables].get(mode="promise_in_bounds")   # (b, w, bs, kv, d)
     b, w, bs, kv, d = g.shape
     return g.transpose(0, 3, 1, 2, 4).reshape(b, kv, w * bs, d)
 
 
-def _kernel(tbl_ref, lyr_ref, k_pool, v_pool, k_out, v_out, k_buf, v_buf,
-            sem, *, group):
+def live_blocks(lens, block_size, width):
+    """How many blocks of a lane's ``width``-block table hold keys,
+    ``lens`` positions of it being written: the blocks the kernel
+    copies. The one statement of the rule, for arrays on the device
+    (``kv_gather``) and numpy's on the host (the engine's count)."""
+    return (-(-lens // block_size)).clip(0, width)
+
+
+def _live_groups(blocks, group):
+    """How many groups of a lane's table the kernel writes: those that
+    hold a live block, and the first one always (the output block a
+    lane's dead grid steps revisit has to be some block of the lane).
+    ``lax`` calls here and in the kernel, not operators: every program
+    traces them, and through ``jnp``'s operators on traced scalars this
+    kernel took the host twice as long to lower."""
+    return lax.max(lax.div(lax.add(blocks, group - 1), group), 1)
+
+
+def _kernel(tbl_ref, lyr_ref, live_ref, k_pool, v_pool, k_into, v_into,
+            k_out, v_out, k_buf, v_buf, sem, *, group):
     # grid step n gathers blocks tbl[n * group : (n + 1) * group] of the
-    # flattened table: lane n // (w / group), its next `group` blocks.
+    # flattened table: lane n // (w / group), its next `group` blocks,
+    # as many of them as are live (live_ref[lane] blocks from the
+    # lane's first). k_into / v_into are the arrays the results alias:
+    # what this kernel does not write keeps their bytes.
     # Loops, not unrolled copies: tracing an operation costs the host
     # more than a loop costs the chip, and every program traces this
     # body twice (the layer scan does).
-    n = pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)
-    last = pl.num_programs(0) * pl.num_programs(1) - 1
+    del k_into, v_into
+    lane, j, groups = pl.program_id(0), pl.program_id(1), pl.num_programs(1)
+    n = lax.add(lax.mul(lane, groups), j)
+    ahead = lax.add(n, 1)
+    last = lax.mul(pl.num_programs(0), groups)
     layer, bs = lyr_ref[0], k_buf.shape[2]
 
-    def each_copy(step, slot, act):
+    def live(lane, j):
+        return lax.clamp(0, lax.sub(live_ref[lane], lax.mul(j, group)), group)
+
+    def each_copy(step, slot, count, act):
+        first = lax.mul(step, group)
+
         def body(t, carry):
-            blk = tbl_ref[step * group + t]
+            blk = tbl_ref[lax.add(first, t)]
             for pool, buf, which in ((k_pool, k_buf, 0), (v_pool, v_buf, 1)):
                 act(pltpu.make_async_copy(pool.at[layer, blk],
                                           buf.at[slot, t],
                                           sem.at[which, slot]))
             return carry
-        jax.lax.fori_loop(0, group, body, None)
+        lax.fori_loop(0, count, body, None)
+
+    slot, mine = lax.rem(n, 2), live(lane, j)
 
     @pl.when(n == 0)
     def _first():
-        each_copy(n, 0, lambda c: c.start())
+        each_copy(n, 0, mine, lambda c: c.start())
 
-    @pl.when(n < last)
+    # step n + 1's copies by step n + 1's liveness: what is started
+    # here is what that step waits for
+    @pl.when(ahead < last)
     def _ahead():
-        each_copy(n + 1, (n + 1) % 2, lambda c: c.start())
+        each_copy(ahead, lax.rem(ahead, 2),
+                  live(lax.div(ahead, groups), lax.rem(ahead, groups)),
+                  lambda c: c.start())
 
-    slot = n % 2
-    each_copy(n, slot, lambda c: c.wait())
+    each_copy(n, slot, mine, lambda c: c.wait())
+
+    def at(t):
+        return pl.ds(pl.multiple_of(lax.mul(t, bs), bs), bs)
 
     def turn(t, carry):
         # (block_size, kv, d) -> (kv, block_size, d): the block's rows
         # of one head become contiguous keys of that head
-        at = pl.ds(pl.multiple_of(t * bs, bs), bs)
         for buf, out in ((k_buf, k_out), (v_buf, v_out)):
-            out[0, :, at, :] = jnp.swapaxes(buf[slot, t], 0, 1)
+            out[0, :, at(t), :] = jnp.swapaxes(buf[slot, t], 0, 1)
         return carry
-    jax.lax.fori_loop(0, group, turn, None)
+
+    def blank(t, carry):
+        for out in (k_out, v_out):
+            out[0, :, at(t), :] = jnp.zeros(
+                (out.shape[1], bs, out.shape[3]), out.dtype)
+        return carry
+
+    # a step past the lane's live groups revisits the last of them
+    # (out_spec) and leaves it as it is; the last live group's dead
+    # blocks would go out as whatever VMEM held
+    lax.fori_loop(0, mine, turn, None)
+    owns = j < _live_groups(live_ref[lane], group)
+    lax.fori_loop(mine, lax.select(owns, group, 0), blank, None)
 
 
-def kv_gather(k_pool, v_pool, layer, tables, *, impl=None, group: int = 16):
+def _uses_kernel(pool, impl) -> bool:
+    """Whether ``kv_gather`` runs the kernel for this pool (and so
+    writes into the arrays it is handed), not XLA's gather."""
+    impl = resolve_impl(impl)
+    return impl == "interpret" or (impl == "pallas" and _whole_tiles(pool))
+
+
+def _zero(k_out, v_out):
+    k_out[...] = jnp.zeros(k_out.shape, k_out.dtype)
+    v_out[...] = jnp.zeros(v_out.shape, v_out.dtype)
+
+
+def zero_context(k_pool, tables, *, impl=None):
+    """What the first ``kv_gather`` of a program call writes into: a K
+    and a V of zeros in the gathered layout for ``tables``; nothing,
+    ``()``, where XLA gathers (it writes into nothing). From a kernel,
+    because its results are pinned to HBM as the gather's are: a layer
+    scan that starts from XLA's own zeros keeps the arrays it carries
+    in VMEM and copies them out and back around every layer's gather.
+    Under a mesh it needs an ``on_shards`` island, as the gather does."""
+    if not _uses_kernel(k_pool, impl):
+        return ()
+    _, _, bs, kv, d = k_pool.shape
+    b, w = tables.shape
+    # blocks of about 2 MB
+    rows = _pick_block(
+        w * bs, max(bs, 2**21 // (kv * d * k_pool.dtype.itemsize)))
+    spec = pl.BlockSpec((1, kv, rows, d), lambda i, j: (i, 0, j, 0))
+    out = pltpu.HBM((b, kv, w * bs, d), k_pool.dtype)
+    return tuple(pl.pallas_call(
+        _zero,
+        grid=(b, w * bs // rows),
+        out_specs=[spec, spec],
+        out_shape=[out, out],
+        interpret=interpret_flag(resolve_impl(impl)),
+        name="zero_context",
+    )())
+
+
+def kv_gather(k_pool, v_pool, layer, tables, lens, into=(), *, impl=None,
+              group: int = 16):
     """``k_pool[layer, tables]`` and ``v_pool[layer, tables]``, each as
-    ``(batch, kv_heads, width * block_size, head_dim)``.
+    ``(batch, kv_heads, width * block_size, head_dim)``, as far as each
+    lane's keys go.
 
     The pools (layers, blocks, block_size, kv_heads, head_dim);
     ``layer`` an int32 scalar (traced inside a layer scan); ``tables``
     (batch, width) int32 block ids, every one of them a block of the
-    pool (nothing is clamped or filled). Pure data movement: the bytes
-    come back bitwise. ``group`` blocks are gathered a grid step.
+    pool (nothing is clamped or filled); ``lens`` (batch,) int32, how
+    many positions of each lane's table hold keys some query may see.
+    ``group`` blocks are gathered a grid step.
+
+    Pure data movement. The *live* blocks of lane ``i``, the first
+    ``ceil(lens[i] / block_size)`` of its table, come back bitwise.
+    The rest is zeros or stale K/V and has to be masked by the caller:
+    the kernel neither reads nor writes the groups past a lane's last
+    live one (a lane's first group counts as live whatever its length),
+    so they keep the bytes of ``into``, the pair of arrays the results
+    are written into in place (``zero_context``'s where none is given;
+    a caller with several layers makes that pair itself and hands each
+    layer the last one's results, ``models/cached_attention.py``), and
+    it zeroes the dead blocks of the last live group. Never-written
+    memory must not come back: a masked key still meets the attention
+    kernel's ``p @ v`` as ``0 x v``, and a NaN there poisons the row.
 
     Pools whose blocks are not whole tiles in HBM (a head size that
-    is no multiple of 128, say) are gathered by XLA, in two passes: the
+    is no multiple of 128, say) are gathered by XLA, in two passes and
+    through the whole table (``lens`` and ``into`` unused): the
     compiler refuses the kernel's copies of such blocks.
     """
     impl = resolve_impl(impl)
     layer = jnp.asarray(layer, jnp.int32)
-    if impl == "xla" or (impl == "pallas" and not _whole_tiles(k_pool)):
+    if not _uses_kernel(k_pool, impl):
         return (_gather_xla(k_pool, layer, tables),
                 _gather_xla(v_pool, layer, tables))
     _, _, bs, kv, d = k_pool.shape
     b, w = tables.shape
     group = _pick_block(w, group)
-    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
-    out_spec = pl.BlockSpec((1, kv, group * bs, d),
-                            lambda i, j, tbl, lyr: (i, 0, j, 0))
-    buf = pltpu.VMEM((2, group, bs, kv, d), k_pool.dtype)
     out = pltpu.HBM((b, kv, w * bs, d), k_pool.dtype)
+    if not into:
+        into = zero_context(k_pool, tables, impl=impl)
+    blocks = live_blocks(lens.astype(jnp.int32), bs, w)
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    out_spec = pl.BlockSpec(
+        (1, kv, group * bs, d),
+        lambda i, j, tbl, lyr, blocks: (
+            i, 0, lax.min(j, lax.sub(_live_groups(blocks[i], group), 1)), 0))
+    buf = pltpu.VMEM((2, group, bs, kv, d), k_pool.dtype)
     return pl.pallas_call(
         functools.partial(_kernel, group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b, w // group),
-            in_specs=[pool_spec, pool_spec],
+            in_specs=[anywhere] * 4,
             out_specs=[out_spec, out_spec],
             scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))]),
         out_shape=[out, out],
+        # operands count the prefetched scalars: into -> the results
+        input_output_aliases={5: 0, 6: 1},
         # the next group's copies are started a step ahead: in order
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret_flag(impl),
         name="kv_gather",
-    )(tables.reshape(-1), layer.reshape(1), k_pool, v_pool)
+    )(tables.reshape(-1), layer.reshape(1), blocks, k_pool, v_pool, *into)
 
 
-__all__ = ["kv_gather"]
+__all__ = ["kv_gather", "live_blocks", "zero_context"]

@@ -124,6 +124,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from apex_tpu.serving.decode import DecodeStep, make_decode_step
+from apex_tpu.ops.kv_gather import live_blocks
 from apex_tpu.serving.kv_cache import KVCache, PoolExhausted, bucket
 from apex_tpu.telemetry import timeline as _timeline
 from apex_tpu.telemetry.metrics import TOKEN_COUNT_BUCKETS
@@ -326,8 +327,12 @@ class ContinuousBatcher:
         self._chunk_dispatches = 0        # prefill_chunk_exception idx
         self._pending_copies: Dict[Any, List[Tuple[int, int, int]]] = {}
         self._pool_exhausted_dumped = False
-        # positions gathered so far, a layer of each kind (host side)
-        self.gathered = {"full": 0, "window": 0}
+        # positions gathered so far, a layer of each kind (host side):
+        # those its tables address, and those of them in blocks that
+        # hold a lane's keys (ops/kv_gather.py live_blocks: what its
+        # kernel copies; XLA's gather, where that runs, copies them all)
+        self.gathered = {"full": 0, "window": 0,
+                         "full_live": 0, "window_live": 0}
         # resilience plane (serving/resilience.py)
         self.preemption = preemption          # guard.PreemptionHandler
         self.snapshot_dir = snapshot_dir
@@ -1103,14 +1108,23 @@ class ContinuousBatcher:
         tails of the lanes' tables, where the model has window layers
         (nothing otherwise, and the dispatch is what it always was).
         Counts the positions each kind of layer gathers in this
-        dispatch, from the widths and the lanes alone."""
+        dispatch, from the widths, the lanes and their ``positions``
+        (``batch`` of them, 0 for a dummy lane) alone."""
+        bs = self.cache.block_size
+
+        def count(kind, lens, width):
+            self.gathered[kind] += batch * width * bs
+            self.gathered[kind + "_live"] += int(
+                live_blocks(lens, bs, width).sum()) * bs
+
+        count("full", positions, width)
         if self.attention_window is None:
             return {}
         ww = self.cache.window_width(self.attention_window, width)
-        self.gathered["full"] += batch * width * self.cache.block_size
-        self.gathered["window"] += batch * ww * self.cache.block_size
-        return {"window": self.cache.window_table_array(
-            seq_ids, positions, self.attention_window, ww, batch=batch)}
+        tables, first = self.cache.window_table_array(
+            seq_ids, positions, self.attention_window, ww, batch=batch)
+        count("window", positions - first * bs, ww)
+        return {"window": (tables, first)}
 
     def _tables_for(self, flights: List[_InFlight], batch: int):
         widths = [len(self.cache.table(f.seq_id)) for f in flights]

@@ -6,6 +6,7 @@ heads of 32, window 8, block 4, 16 experts top-2 with 4 held,
 vocabulary 64, pattern dense + window, then window, window, full.
 """
 
+import dataclasses
 import zlib
 
 import jax
@@ -296,6 +297,126 @@ def test_gpt_config_with_a_window_decodes(window):
         assert float(gap.max()) < 1e-4, (r.id, gap)
     assert cache.blocks_in_use == 0
     assert (engine.gathered["window"] > 0) == (window is not None)
+
+
+def _dispatches(engine):
+    """Every dispatch the engine makes from here on, as (program,
+    logits, token ids, finite flags) on the host."""
+    log = []
+    for name in ("prefill", "prefill_chunk", "decode"):
+        def recorded(*args, _real=getattr(engine.step_fn, name), _name=name,
+                     **kw):
+            out = _real(*args, **kw)
+            log.append((_name, *(np.asarray(x) for x in (
+                out.logits, out.next_token, out.finite))))
+            return out
+        setattr(engine.step_fn, name, recorded)
+    return log
+
+
+def _kernel_model(kind):
+    """A model whose gather is the kernel (interpreted): GPT, its layers
+    scanned or unrolled, or a pattern decoder with window and full
+    layers."""
+    if kind == "pattern":
+        cfg = dataclasses.replace(
+            config(layers=(("window", "dense"), ("full", "dense"),
+                           ("window", "dense"), ("full", "dense"))),
+            softmax_impl="interpret")
+        model = PatternDecoder(cfg)
+    else:
+        from apex_tpu.models.gpt import GPTConfig, GPTModel
+
+        cfg = GPTConfig(vocab_size=VOCAB, max_seq_len=64, hidden_size=64,
+                        num_layers=3, num_heads=4, num_kv_heads=2,
+                        scan_layers=kind == "gpt-scan", dtype=jnp.float32,
+                        softmax_impl="interpret")
+        model = GPTModel(cfg)
+    shapes = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 8), jnp.int32)),
+        jax.random.PRNGKey(0))
+    return model, seeded(shapes), cfg
+
+
+@pytest.mark.parametrize("kind", ["gpt-scan", "gpt-unrolled", "pattern"])
+def test_gather_to_each_lanes_length_serves_the_whole_tables_answers(
+        kind, monkeypatch):
+    """A deck with a lane at a block's edge (8), a lane past the window
+    (21, in chunks of 8) and, three requests on four lanes, a dummy
+    lane: every dispatch's logits, tokens and finite flags are bitwise
+    those of the same deck with every gather forced through the whole
+    table."""
+    from apex_tpu.ops import kv_gather as gather_module
+
+    model, params, cfg = _kernel_model(kind)
+
+    def served():
+        engine, cache = _engine(model, params, cfg, prefill_chunk=8)
+        log = _dispatches(engine)
+        done = _serve(engine, cache, [
+            serving.Request(id=i, prompt=tokens(n, 30 + i), max_new_tokens=m)
+            for i, (n, m) in enumerate([(8, 6), (21, 7), (5, 4)])])
+        assert cache.blocks_in_use == 0
+        return log, done, dict(engine.gathered)
+
+    got, done, gathered = served()
+    real = gather_module.kv_gather
+    monkeypatch.setattr(
+        gather_module, "kv_gather",
+        lambda k, v, layer, tables, lens, into=(), **kw: real(
+            k, v, layer, tables,
+            jnp.full_like(lens, tables.shape[1] * k.shape[2]), into, **kw))
+    want, whole, _ = served()
+    assert len(got) == len(want) > 10
+    assert {name for name, *_ in got} == {"prefill", "prefill_chunk",
+                                          "decode"}
+    for (name, *outs), (name_w, *outs_w) in zip(got, want):
+        assert name == name_w
+        for a, b in zip(outs, outs_w):
+            np.testing.assert_array_equal(a, b)
+    assert all(flags.all() for *_, flags in got)
+    assert {i: r.tokens for i, r in done.items()} \
+        == {i: r.tokens for i, r in whole.items()}
+    assert 0 < gathered["full_live"] < gathered["full"]
+    assert (0 < gathered["window_live"] < gathered["window"]) \
+        == (kind == "pattern")
+
+
+def test_gathered_counts_the_live_blocks():
+    """``ContinuousBatcher.gathered``, one request on one lane: a lane
+    that fills its table has every addressed position live, and past
+    the window a window layer's live positions stop growing while the
+    full layer's go on."""
+    model, params, cfg = _kernel_model("pattern")
+
+    def deltas(prompt, new):
+        cache = serving.KVCache.for_config(cfg, num_blocks=96,
+                                           block_size=BLOCK)
+        engine = serving.ContinuousBatcher(
+            model, params, cache, max_batch=1, min_width_bucket=2,
+            min_seq_bucket=4)
+        state = cache.init_state()
+        engine.submit(serving.Request(id=0, prompt=tokens(prompt),
+                                      max_new_tokens=new))
+        seen = [dict(engine.gathered)]
+        while not engine.idle():
+            state, _ = engine.step(state)
+            seen.append(dict(engine.gathered))
+        return [{k: b[k] - a[k] for k in a} for a, b in zip(seen, seen[1:])
+                if b != a]
+
+    # 6 + 2 tokens reserve two blocks, the width's least bucket: the
+    # one decode dispatch, at position 6, finds both of them live
+    (only,) = deltas(6, 2)
+    assert only["full_live"] == only["full"] == 2 * BLOCK
+    assert only["window_live"] == only["window"] == 2 * BLOCK
+    steps = deltas(30, 12)
+    assert len(steps) == 11
+    for a, b in zip(steps, steps[1:]):
+        assert b["full_live"] >= a["full_live"] >= 28
+        assert BLOCK * (WINDOW // BLOCK) <= b["window_live"] <= WINDOW + BLOCK
+        assert b["full_live"] <= b["full"] and b["window_live"] <= b["window"]
+    assert steps[-1]["full_live"] > steps[0]["full_live"]
 
 
 @pytest.mark.parametrize("impl", ["xla", "interpret"])

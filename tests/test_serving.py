@@ -280,33 +280,108 @@ def _written_pool(seed, num_blocks=BLOCKS):
     return state, jnp.asarray(tables)
 
 
+def _assert_gathered(got, want, lens, *, group=None, handed=None):
+    """``got`` (b, kv, L, d) is ``want`` in each lane's live blocks.
+    From the kernel (``group`` blocks a grid step) the rest of a lane's
+    last live group is zeros and everything past it is ``handed``, the
+    array it wrote into; otherwise the rest is only finite."""
+    got, want = np.asarray(got), np.asarray(want)
+    for i, n in enumerate(np.asarray(lens)):
+        blocks = -(-int(n) // BS)
+        live = blocks * BS
+        np.testing.assert_array_equal(got[i, :, :live], want[i, :, :live])
+        if group is None:
+            assert np.isfinite(got[i, :, live:]).all()
+            continue
+        edge = max(-(-blocks // group), 1) * group * BS
+        assert not got[i, :, live:edge].any()
+        np.testing.assert_array_equal(got[i, :, edge:],
+                                      np.asarray(handed)[i, :, edge:])
+
+
 class TestLayerGather:
     """What the compiled programs read the pool through: each layer
-    gathers its own context (ops/kv_gather.py inside the model), and
-    it is layer by layer what ``gather_kv`` gives for all at once."""
+    gathers its own context (ops/kv_gather.py inside the model), as far
+    as each lane's keys go, and that far it is layer by layer what
+    ``gather_kv`` gives for all at once."""
 
+    @pytest.mark.parametrize("lens", [
+        [0, 0, 0, 0], [6, 1, 7, 3],             # nothing; mid-block
+        [BS, BS, 3 * BS, BS],                   # a block edge
+        [2 * BS, 2 * BS, 2 * BS, 2 * BS],       # a group's edge, at 2
+        [4 * BS, 4 * BS, 4 * BS, 4 * BS],       # the whole width
+        [9, BS, 4 * BS, 0]],
+        ids=["none", "mid-block", "block-edge", "group-edge", "whole",
+             "mixed"])
     @pytest.mark.parametrize("impl,group", [("xla", 16), ("interpret", 16),
                                             ("interpret", 2),
                                             ("interpret", 1)])
-    def test_kv_gather_is_gather_kv_layer_by_layer(self, impl, group):
+    def test_kv_gather_is_gather_kv_layer_by_layer(self, impl, group, lens):
         from apex_tpu.ops.kv_gather import kv_gather
 
         state, tables = _written_pool(4)
         want_k, want_v = gather_kv(state, tables)
+        rng = np.random.RandomState(5)
+        handed = tuple(jnp.asarray(rng.randn(*want_k.shape[1:]), jnp.float32)
+                       for _ in range(2))
+        lens = jnp.asarray(lens, jnp.int32)
         for layer in range(LAYERS):
             # a traced layer index, as the layer scan passes it
-            got_k, got_v = jax.jit(lambda st, l: kv_gather(
-                st.k, st.v, l, tables, impl=impl, group=group))(
-                    state, jnp.int32(layer))
-            np.testing.assert_array_equal(np.asarray(got_k),
-                                          np.asarray(want_k)[layer])
-            np.testing.assert_array_equal(np.asarray(got_v),
-                                          np.asarray(want_v)[layer])
+            got = jax.jit(lambda st, l: kv_gather(
+                st.k, st.v, l, tables, lens, handed, impl=impl,
+                group=group))(state, jnp.int32(layer))
+            for g, want, into in zip(got, (want_k, want_v), handed):
+                _assert_gathered(
+                    g, want[layer], lens, handed=into,
+                    group=None if impl == "xla" else min(group, 4))
 
+    @pytest.mark.parametrize("lens", [
+        [0, 0, 0, 0], [6, 1, 7, 3], [BS, BS, 3 * BS, BS],
+        [9, BS, 4 * BS, 0], [5 * BS, 4 * BS + 1, 4 * BS - 1, 2]],
+        ids=["none", "mid-block", "block-edge", "mixed", "past-the-table"])
+    def test_live_blocks_counts_what_the_kernel_writes(self, lens):
+        """The engine's count (``ContinuousBatcher.gathered``, numpy on
+        the host) and the kernel's liveness are one function: a block a
+        grid step at a time, the blocks of a lane that come back other
+        than they were handed in are its ``live_blocks``, and its first
+        block whatever its length."""
+        from apex_tpu.ops.kv_gather import kv_gather, live_blocks
+
+        state, tables = _written_pool(4)
+        width = tables.shape[1]
+        want = live_blocks(np.asarray(lens, np.int32), BS, width)
+        np.testing.assert_array_equal(
+            want, live_blocks(jnp.asarray(lens, jnp.int32), BS, width))
+        assert want.dtype == np.int32 and want.max() <= width
+        kv, d = state.k.shape[3:]
+        handed = jnp.full((len(lens), kv, width * BS, d), 7.0)
+        got, _ = kv_gather(state.k, state.v, 1, tables,
+                           jnp.asarray(lens, jnp.int32), (handed, handed),
+                           impl="interpret", group=1)
+        written = (np.asarray(got) != 7.0).any(axis=(1, 3))   # (b, w * BS)
+        written = written.reshape(len(lens), width, BS).any(axis=2)
+        np.testing.assert_array_equal(written.sum(axis=1),
+                                      np.maximum(want, 1))
+
+    @pytest.mark.parametrize("impl", ["xla", "interpret"])
+    def test_kv_gather_handed_nothing_writes_into_zeros(self, impl):
+        from apex_tpu.ops.kv_gather import kv_gather
+
+        state, tables = _written_pool(4)
+        want = gather_kv(state, tables)
+        lens = jnp.asarray([9, BS, 4 * BS, 0], jnp.int32)
+        got = kv_gather(state.k, state.v, 1, tables, lens, impl=impl,
+                        group=1)
+        for g, w in zip(got, want):
+            _assert_gathered(g, w[1], lens, handed=jnp.zeros_like(g),
+                             group=None if impl == "xla" else 1)
+
+    @pytest.mark.parametrize("impl", ["xla", "interpret"])
     @pytest.mark.parametrize("scan_layers", [True, False])
     @pytest.mark.parametrize("s", [1, 3])
-    def test_model_gathers_each_layer_bitwise(self, scan_layers, s):
-        model = GPTModel(tiny_config(scan_layers=scan_layers))
+    def test_model_gathers_each_layer_bitwise(self, scan_layers, s, impl):
+        model = GPTModel(tiny_config(scan_layers=scan_layers,
+                                     softmax_impl=impl))
         params = model.init(jax.random.PRNGKey(0),
                             jnp.zeros((1, 8), jnp.int32))
         state, tables = _written_pool(5)
@@ -327,8 +402,13 @@ class TestLayerGather:
             got_k, got_v = (jnp.stack([kv[j] for kv in per])
                             for j in (0, 1))
         want_k, want_v = gather_kv(state, tables)
-        np.testing.assert_array_equal(np.asarray(got_k), np.asarray(want_k))
-        np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+        # past a lane's live blocks: zeros, or what an earlier layer
+        # left there (its context, its token's own K/V)
+        bound = max(float(jnp.abs(t).max()) for t in (state.k, state.v)) + 1e3
+        for got, want in ((got_k, want_k), (got_v, want_v)):
+            for layer in range(LAYERS):
+                _assert_gathered(got[layer], want[layer], lens)
+            assert float(jnp.abs(got).max()) <= bound
 
 
 # ---------------------------------------------------------------------------

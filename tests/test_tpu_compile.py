@@ -90,17 +90,22 @@ def _flash(sq, sk, *, causal, segs, b=16, h=16, hk=4, grad=False):
     return build
 
 
-def _kv_gather(width):
+def _kv_gather(width, lanes=8, kv_heads=16, blocks=1025, layers=24):
     from apex_tpu.ops.kv_gather import kv_gather
 
     def build(chip):
-        pool = chip((24, 1025, 16, 16, 128), jnp.bfloat16)
+        pool = chip((layers, blocks, 16, kv_heads, 128), jnp.bfloat16)
 
-        def gather(k_pool, v_pool, layer, tables):
-            return kv_gather(k_pool, v_pool, layer, tables, impl="pallas")
+        def gather(k_pool, v_pool, layer, tables, lens):
+            # two layers: the second writes into the first one's result
+            first = kv_gather(k_pool, v_pool, layer, tables, lens,
+                              impl="pallas")
+            return kv_gather(k_pool, v_pool, layer + 1, tables, lens, first,
+                             impl="pallas")
 
         return gather, (pool, pool, chip((), jnp.int32),
-                        chip((8, width), jnp.int32))
+                        chip((lanes, width), jnp.int32),
+                        chip((lanes,), jnp.int32))
 
     return build
 
@@ -194,6 +199,11 @@ KERNELS = {
     # the benchmark's pool, 8 lanes at both of its width buckets
     "kv gather width 64": _kv_gather(64),
     "kv gather width 128": _kv_gather(128),
+    # serve-longmix's pool and lanes, the full table and the window's
+    "kv gather width 1024, 16 lanes": _kv_gather(
+        1024, lanes=16, kv_heads=8, blocks=8192, layers=5),
+    "kv gather width 320, 16 lanes": _kv_gather(
+        320, lanes=16, kv_heads=8, blocks=8192, layers=5),
     "layer norm fwd+bwd": _layer_norm,
     "xentropy fwd+bwd vocab 50304": _xentropy,
     "fused adam, flat 8M": _fused_adam,
@@ -274,7 +284,11 @@ def test_decode_program_gathers_once_a_layer(chip, monkeypatch):
     for K and V, the attention kernel's keys are the
     ``[b * kv_heads, width * block_size, head_dim]`` it wrote, and
     nothing holds every layer's context, slices a layer out of the
-    pool or concatenates along the key axis."""
+    pool or concatenates along the key axis. The gather writes into
+    the arrays the scan carries, in place: the body holds no copy of
+    the context, nor any pass over it but the gather (the token's own
+    K/V goes in by updates in place), and the program zeroes it once,
+    K and V, outside the scan and pinned to HBM."""
     import re
 
     from apex_tpu import serving
@@ -314,6 +328,65 @@ def test_decode_program_gathers_once_a_layer(chip, monkeypatch):
     assert not re.search(r"bf16\[513,16,4,128\]", text)       # a pool slice
     assert not re.search(r"bf16\[8,4,10(25|88|\d\d),128\]\S* concatenate",
                          text)
+    ctx = re.escape(f"bf16[{b},4,{w * 16},128]")
+    (body,) = [c for c in text.split("\n\n") if "%kv_gather." in c]
+    made = re.findall(rf"%([\w.-]+) = {ctx}\S* ([\w-]+)\(", body)
+    assert {op for _, op in made} <= {"get-tuple-element",
+                                      "dynamic-update-slice"}, made
+    assert len(by_name["zero_context"]) == 1
+    assert "%zero_context." not in body
+    assert not re.search(rf"{ctx}\S* (copy|copy-start|broadcast)\(", text)
+
+
+@pytest.mark.parametrize("fn,seq", [("decode_step", 1),
+                                    ("prefill_chunk", 128)])
+def test_scanned_serving_programs_lower_on_the_mesh(chip, monkeypatch, fn,
+                                                    seq):
+    """The scanned GPT's cached programs on the described 2x2 mesh
+    (``batch`` 2 x ``model`` 2), the checkpoint model-sharded and the
+    pool split on its kv heads (docs/serving.md): every kernel that
+    touches the gathered context sits in an ``on_shards`` island (the
+    zeroing before the scan as well as the gather in it), or the
+    compiler refuses the program ("Mosaic kernels cannot be
+    automatically partitioned"). Each shard gathers its own lanes and
+    heads: 4 of 8 lanes, 2 of 4 kv heads."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from apex_tpu import mesh as gmesh, serving
+    from apex_tpu.mesh import annotate
+    from apex_tpu.models.gpt import GPTConfig, GPTModel
+
+    monkeypatch.setenv("APEX_TPU_IMPL", "pallas")
+    _backend.default_impl.cache_clear()
+    try:
+        cfg = GPTConfig(vocab_size=512, max_seq_len=1024, hidden_size=512,
+                        num_layers=2, num_heads=4, attention_backend="flash",
+                        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+        model = GPTModel(cfg)
+        cache = serving.KVCache.for_config(cfg, num_blocks=512,
+                                           block_size=16)
+        mesh = gmesh.initialize_mesh(batch=2, model=2, devices=chip.devices)
+        shapes = jax.eval_shape(
+            lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32)),
+            jax.random.PRNGKey(0))
+        params = jax.tree.map(
+            lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+            shapes, annotate.serving_param_shardings(shapes, mesh=mesh))
+        pool = NamedSharding(mesh, P(None, None, None, gmesh.MODEL_AXIS, None))
+        state = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=pool),
+            jax.eval_shape(cache.init_state))
+        text = serving.make_decode_step(model, cache).lower(
+            fn, params, state, 8, 64, seq).compile().as_text()
+    finally:
+        gmesh.destroy_mesh()
+        _backend.default_impl.cache_clear()
+    for kernel in ("zero_context", "kv_gather"):
+        (call,) = re.findall(
+            rf"%{kernel}\.\d+ = (\([^\n]*?\)) custom-call\(", text)
+        assert call.count("bf16[4,2,1024,128]") == 2, call
 
 
 @pytest.mark.parametrize("fn,batch,seq", [
