@@ -2,8 +2,8 @@
 
 Compiling is a large part of a cold run on the chip (the GPT-2 345M
 train step and each serving program take 10-20 s apiece), so every
-entry point — ``chip_smoke.py``, ``bench.py``, the examples, the
-``tools/tpu_*`` scripts — calls :func:`enable` first thing.
+entry point — ``chip_smoke.py``, ``benchmark/run.py``, the examples,
+the ``tools/tpu_*`` scripts — calls :func:`enable` first thing.
 
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and
 this module sets no other directory. Otherwise the cache lives at one

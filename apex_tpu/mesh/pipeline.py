@@ -603,7 +603,7 @@ class MeshPipelineTrainStep(MeshTrainStep):
         completed step. Span geometry is the schedule's analytic
         activity map scaled by the measured wall time (see module
         docstring); the gauges and the ``pipeline`` info blob are what
-        ``bench.py multichip`` and ``tools/telemetry_dump.py`` read."""
+        ``tools/check_mesh.sh`` and ``tools/telemetry_dump.py`` read."""
         from apex_tpu.telemetry import metrics as _metrics
         from apex_tpu.telemetry import timeline as _timeline
 

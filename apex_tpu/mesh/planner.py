@@ -21,8 +21,8 @@ The model is deliberately coarse (roofline compute + linear wire time
 activation memory budget): its job is ORDERING layouts, not predicting
 milliseconds. The golden tests pin the orderings that matter (tp-heavy
 above dp-heavy when per-chip memory is tight; pure-dp degenerate on
-one device) and ``bench.py multichip`` records the planner's top
-choice against a hand-picked layout on a real forced-8-device run.
+one device); the planner's top choice has not been timed against a
+hand-picked layout on a chip.
 """
 
 from __future__ import annotations

@@ -1,13 +1,14 @@
-"""Persistent measurement records (``bench_records/``).
+"""Persistent records (``bench_records/``).
 
-Measurement persistence as a side effect of measuring: every tool that
-measures on the chip calls :func:`write_record` — a dated, git-stamped
-JSON file under ``bench_records/`` at the repo root (the stamp reads
-``unknown`` in a copy that is not a git repository) — and
-:func:`latest_record` reads the newest one of a kind back. ``bench.py``
-compares a value against the newest prior record of its metric; the
-flight recorder and the resilience plane keep their bundles here under
-keep-last-k pruning (:func:`prune_records`).
+Persistence as a side effect of the event: :func:`write_record` puts a
+dated, git-stamped JSON file under ``bench_records/`` at the repo root
+(the stamp reads ``unknown`` in a copy that is not a git repository;
+the directory is made on demand) and :func:`latest_record` reads the
+newest one of a kind back. The watchdog, the guard and the checkpoint
+manager write their resilience events here, and the flight recorder
+keeps its bundles here under keep-last-k pruning
+(:func:`prune_records`). Speed is not recorded here: the benchmark
+(``benchmark/run.py``) and the driver's ledger hold it.
 
 The reference has no analog (its benches print and forget).
 """
@@ -32,23 +33,18 @@ def _git_sha() -> str:
             ["git", "rev-parse", "--short", "HEAD"],
             cwd=os.path.dirname(RECORDS_DIR), capture_output=True,
             text=True, timeout=10).stdout.strip() or "unknown"
-    except Exception:  # noqa: BLE001 — records must never break a bench
+    except Exception:  # noqa: BLE001 — records must never break a run
         return "unknown"
 
 
 def write_record(kind: str, payload: Dict[str, Any],
-                 backend: Optional[str] = None,
-                 captured: bool = True) -> Optional[str]:
-    """Persist one measurement under ``bench_records/``.
+                 backend: Optional[str] = None) -> Optional[str]:
+    """Persist one record under ``bench_records/``.
 
-    ``kind`` groups records for retrieval (e.g. ``"headline"``,
-    ``"attn"``, ``"smoke"``, ``"optdiag"``, ``"tune_ln"``,
-    ``"resilience"``).
-    ``captured=False`` marks a hand-transcribed record (evidence copied
-    from session notes, not written by the measuring process itself);
-    it is stored top-level so consumers cannot miss it. Returns the
-    written path, or None if persistence failed (never raises — a
-    failed disk write must not kill a measurement run).
+    ``kind`` groups records for retrieval (e.g. ``"resilience"``,
+    ``"flightrec"``, ``"smoke"``, ``"tune_ln"``). Returns the written
+    path, or None if persistence failed (never raises: a failed disk
+    write must not kill the run that reports it).
 
     The filename stamp has 1-second resolution, so same-second writes
     collide: the name is claimed with ``O_CREAT|O_EXCL`` (an
@@ -73,7 +69,6 @@ def write_record(kind: str, payload: Dict[str, Any],
             "utc": stamp,
             "git_sha": _git_sha(),
             **({"backend": backend} if backend else {}),
-            "captured": bool(captured),
             "payload": payload,
         }
         base = f"{kind}_{stamp}_{rec['git_sha']}"
@@ -139,14 +134,6 @@ def _uniquifier(name: str) -> int:
 _STAMP_RE = re.compile(r"\d{8}T\d{6}Z_")
 
 
-def is_transcribed(rec: Dict[str, Any]) -> bool:
-    """True when a record is hand-transcribed evidence, not written by
-    the measuring process itself (top-level ``captured: false`` or the
-    legacy ``"tpu-transcribed"`` backend tag)."""
-    return (rec.get("captured") is False
-            or str(rec.get("backend", "")).endswith("-transcribed"))
-
-
 def prune_records(kind: str, keep: int) -> list:
     """Keep only the newest ``keep`` records of ``kind``; returns the
     removed paths. Never raises.
@@ -207,8 +194,7 @@ def prune_records(kind: str, keep: int) -> list:
 
 
 def latest_record(kind: str,
-                  require_backend: Optional[str] = "tpu",
-                  allow_transcribed: bool = True
+                  require_backend: Optional[str] = "tpu"
                   ) -> Optional[Dict[str, Any]]:
     """Newest record of ``kind``, optionally filtered to a backend.
 
@@ -219,10 +205,6 @@ def latest_record(kind: str,
     shape ``write_record`` produces, so prefix kinds still cannot
     cross-match. Recency comes from the record's
     ``utc`` field with the filename uniquifier as tiebreaker.
-    Driver-captured records always win over transcribed ones of the
-    same kind regardless of age; ``allow_transcribed=False`` excludes
-    transcribed records entirely. ``require_backend="tpu"`` also admits
-    the ``"tpu-transcribed"`` tag (subject to ``allow_transcribed``).
     None when there is no matching record.
     """
     try:
@@ -241,7 +223,7 @@ def latest_record(kind: str,
         except (OSError, ValueError) as e:
             # corrupt/unreadable record files are skipped, but never
             # silently: a structured telemetry event + counter names
-            # each one once per lookup (the bench-record analog of
+            # each one once per lookup (the record-store analog of
             # latest_valid's corrupt_checkpoint record)
             try:
                 from apex_tpu.telemetry import metrics as _metrics
@@ -263,19 +245,12 @@ def latest_record(kind: str,
             # accept them when the filename is exactly this kind plus a
             # stamp (ADVICE round 5: they silently vanished before)
             continue
-        transcribed = is_transcribed(rec)
-        if transcribed and not allow_transcribed:
+        if require_backend and rec.get("backend") != require_backend:
             continue
-        if require_backend:
-            accepted = {require_backend, f"{require_backend}-transcribed"}
-            if rec.get("backend") not in accepted:
-                continue
-        matches.append((not transcribed, str(rec.get("utc", "")),
-                        _uniquifier(name), rec))
+        matches.append((str(rec.get("utc", "")), _uniquifier(name), rec))
     if not matches:
         return None
-    return max(matches, key=lambda t: t[:3])[3]
+    return max(matches, key=lambda t: t[:2])[2]
 
 
-__all__ = ["write_record", "latest_record", "prune_records",
-           "is_transcribed", "RECORDS_DIR"]
+__all__ = ["write_record", "latest_record", "prune_records", "RECORDS_DIR"]

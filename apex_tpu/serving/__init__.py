@@ -18,9 +18,9 @@ flight-recorder triggers for its degradation paths.
     batcher = ContinuousBatcher(model, params, cache)
     state, results = serve_loop(batcher, state, requests)
 
-``bench.py serving`` drives the same loop under synthetic many-client
-load (Poisson arrivals, mixed lengths) against a static-batch
-baseline.
+The benchmark's serving cells (``benchmark/drivers/serve_closed.py``)
+step a ``ContinuousBatcher`` under closed-loop clients dealt from
+committed decks; ``static_batch_generate`` is the static-batch baseline.
 
 The resilience plane (``serving/resilience.py``, docs/serving.md
 "Failure modes & recovery") makes the engine degrade per-request:

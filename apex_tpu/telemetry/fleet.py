@@ -14,7 +14,7 @@ apex/parallel/distributed.py:360-561); the production-stack answer
   ``telemetry.snapshot_detail()`` over the SAME 4-method
   :class:`~apex_tpu.resilience.guard.Collective` abstraction the guard
   rides (ProcessCollective on a real ``jax.distributed`` cluster, the
-  threaded LocalCollective sim in tests and ``bench.py fleet``,
+  threaded LocalCollective sim in tests,
   NullCollective for one host). Snapshots are variable-length JSON, so
   the gather is two fixed-shape collectives: lengths first, then the
   right-padded utf-8 payloads.

@@ -20,8 +20,8 @@ Design:
   rebucketing) keep ``observe`` O(len(buckets)) with zero allocation
   on the hot path.
 - **One snapshot.** :meth:`MetricsRegistry.snapshot` returns a single
-  JSON-able dict of every series — what ``bench.py`` folds into each
-  record's ``detail.telemetry`` and what tests assert against.
+  JSON-able dict of every series — what the flight recorder folds into
+  each bundle's ``payload.telemetry`` and what tests assert against.
 - **Structured events.** :meth:`MetricsRegistry.event` routes a
   discrete occurrence (probe verdict, corrupt record skipped,
   watchdog escalation) to every attached sink and counts it under

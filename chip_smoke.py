@@ -19,7 +19,7 @@ One chip:
 - *headline optimizer* — ``FusedLAMB`` as a user gets it by default,
   two steps on the same parameter tree with seeded gradients, and
   against the plain XLA two-stage schedule on a small tree.
-- *server* — the serving configuration ``bench.py`` names (vocab 32768,
+- *server* — a GQA serving configuration of its own (vocab 32768,
   context 2048, hidden 1024, 12 layers, 16 heads, 4 KV heads, bf16)
   through ``KVCache.for_config``, ``make_decode_step`` and the
   ``ContinuousBatcher`` submit/step loop: short requests, one prefilled

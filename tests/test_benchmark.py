@@ -1,0 +1,2 @@
+from benchmark.tests.test_benchmark import *  # noqa: F401,F403
+from benchmark.tests.test_program_span import *  # noqa: F401,F403

@@ -1,4 +1,4 @@
-"""bench_records persistence + the Mosaic block-size guard rails."""
+"""Record-store persistence + the Mosaic block-size guard rails."""
 
 import json
 import os
@@ -123,46 +123,12 @@ class TestRecords:
         assert records.latest_record("resilience")["payload"] == {"v": 4}
         assert records.latest_record("notes") is None
 
-    def test_seeded_round3_records_parse(self):
-        """The transcribed round-3 evidence must stay loadable and
-        clearly marked as transcribed at top level. Loaded by explicit
-        filename: once genuine driver-captured records land they (by
-        design) become the latest of each kind."""
-        from apex_tpu.records import RECORDS_DIR, is_transcribed
-
-        assert os.path.isdir(RECORDS_DIR)
-        for kind in ("optdiag", "attn", "smoke"):
-            path = os.path.join(
-                RECORDS_DIR, f"{kind}_20260731T050000Z_32bcda6.json")
-            with open(path) as f:
-                rec = json.load(f)
-            assert "provenance" in rec["payload"], kind
-            assert is_transcribed(rec), kind
-            assert rec["captured"] is False, kind
-
-    def test_captured_beats_transcribed_and_kind_is_exact(
+    def test_kind_matches_exactly_never_by_prefix(
             self, tmp_path, monkeypatch):
         from apex_tpu import records
 
         monkeypatch.setattr(records, "RECORDS_DIR", str(tmp_path))
-        # a transcribed record written later must NOT shadow a captured
-        # one of the same kind
         records.write_record("tune", {"v": "real"}, backend="tpu")
-        records.write_record("tune", {"v": "notes"},
-                             backend="tpu-transcribed", captured=False)
-        rec = records.latest_record("tune", require_backend="tpu")
-        assert rec["payload"] == {"v": "real"}
-        # transcribed surfaces only when nothing captured exists...
-        rec = records.latest_record("tune2", require_backend="tpu")
-        assert rec is None
-        records.write_record("tune2", {"v": "notes"},
-                             backend="tpu-transcribed", captured=False)
-        rec = records.latest_record("tune2", require_backend="tpu")
-        assert rec["payload"] == {"v": "notes"}
-        # ...and can be excluded outright
-        assert records.latest_record(
-            "tune2", require_backend="tpu",
-            allow_transcribed=False) is None
         # kind match is exact against the record field: 'tune' must not
         # swallow 'tune_ln' records (filename-prefix cross-match bug)
         records.write_record("tune_ln", {"v": "ln"}, backend="tpu")
@@ -268,41 +234,6 @@ class TestRecords:
         assert json.loads(victim.read_text())["payload"] == {"n": "first"}
         # ...and the new write still wins recency via the disambiguator
         assert records.latest_record("k")["payload"] == {"n": "second"}
-
-    def test_bench_emit_names_an_off_tpu_run(self, tmp_path, monkeypatch,
-                                             capsys):
-        import bench
-        from apex_tpu import records
-
-        monkeypatch.setattr(records, "RECORDS_DIR", str(tmp_path))
-        records.write_record("unit_kind", {"real": 1}, backend="tpu")
-        bench.emit({"metric": "m", "value": 1.0,
-                    "detail": {"backend": "cpu"}}, "unit_kind")
-        out = json.loads(capsys.readouterr().out.strip())
-        assert out["detail"]["headline_valid"] is False
-        # one field says where it ran; no older TPU record is borrowed
-        assert "'cpu'" in out["detail"]["off_tpu"]
-        assert not [k for k in out["detail"] if k.startswith("last_tpu")]
-        # and the off-chip record is not persisted next to the real one
-        assert records.latest_record("unit_kind")["payload"] == {"real": 1}
-
-    def test_bench_emit_persists_tpu(self, tmp_path, monkeypatch, capsys):
-        import bench
-        from apex_tpu import records
-
-        monkeypatch.setattr(records, "RECORDS_DIR", str(tmp_path))
-        bench.emit({"metric": "m", "value": 2.0,
-                    "detail": {"backend": "tpu"}}, "unit_kind2")
-        out = json.loads(capsys.readouterr().out.strip())
-        assert out["detail"]["headline_valid"] is True
-        rec = records.latest_record("unit_kind2")
-        assert rec["payload"]["value"] == 2.0
-        # an error record on tpu is NOT persisted and not headline
-        bench.emit({"metric": "m_err", "value": None,
-                    "detail": {"backend": "tpu"}}, "unit_kind3")
-        out = json.loads(capsys.readouterr().out.strip())
-        assert out["detail"]["headline_valid"] is False
-        assert records.latest_record("unit_kind3") is None
 
 
 class TestPruneRecords:

@@ -675,15 +675,6 @@ class TestInstrumentationPass:
         assert est["mfu"] is None and est["chip_peak_tflops"] is None
         assert "no peak-TFLOPs entry" in est["mfu_reason"]
 
-    def test_bench_names_the_device_that_ran(self):
-        # bench reads jax.devices() itself: no guard report in between
-        import bench
-
-        det = bench.backend_detail()
-        assert det["backend"] == "cpu"
-        assert det["n_devices"] == jax.device_count()
-        assert det["device_kind"] == jax.devices()[0].device_kind
-
     def test_timers_publish_into_global_timeline(self):
         from apex_tpu.transformer.pipeline_parallel import Timers
 
@@ -706,36 +697,3 @@ class TestInstrumentationPass:
         assert f(2) == 3
         spans = [s for s in tl.spans() if s.name == "my_region"]
         assert len(spans) == 1 and spans[0].category == "annotate"
-
-
-class TestBenchTelemetryDetail:
-    def test_emit_folds_snapshot_into_every_record(self, tmp_path,
-                                                   monkeypatch, capsys):
-        import bench
-        from apex_tpu import records
-
-        monkeypatch.setattr(records, "RECORDS_DIR", str(tmp_path))
-        telemetry.registry().counter("prefetch_batches").inc(7)
-        bench.emit({"metric": "m", "value": 1.0,
-                    "detail": {"backend": "cpu"}}, "tele_kind")
-        out = json.loads(capsys.readouterr().out.strip())
-        t = out["detail"]["telemetry"]
-        # mfu is present and explicitly null WITH a reason
-        assert "mfu" in t and t["mfu"] is None and t["mfu_reason"]
-        assert t["registry"]["counters"]["prefetch_batches"] == 7.0
-        assert "step_timeline" in t
-
-    def test_emit_keeps_bench_supplied_block(self, tmp_path, monkeypatch,
-                                             capsys):
-        import bench
-        from apex_tpu import records
-
-        monkeypatch.setattr(records, "RECORDS_DIR", str(tmp_path))
-        block = {"mfu": 0.42, "step_timeline": {"phases": {}}}
-        bench.emit({"metric": "m", "value": 1.0,
-                    "detail": {"backend": "cpu", "telemetry": block}},
-                   "tele_kind2")
-        out = json.loads(capsys.readouterr().out.strip())
-        t = out["detail"]["telemetry"]
-        assert t["mfu"] == 0.42                 # not overwritten
-        assert "registry" in t                  # snapshot still folded

@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # Observability smoke (CI / pre-merge, next to check_telemetry.sh and
 # check_resilience.sh): the fleet-aggregation / flight-recorder /
-# compile-tracker / devmem / bench-baseline unit tier, the
+# compile-tracker / devmem / records unit tier, the
 # disabled-telemetry structural guarantee (the disabled path IS the
 # cached raw step object), the COMPILE-TRACKER smoke (one forced
 # re-trace of the train step must emit exactly ONE `recompile` event
@@ -25,7 +25,7 @@ export JAX_PLATFORMS=cpu
 rc=0
 
 python -m pytest tests/test_telemetry.py tests/test_fleet.py \
-    tests/test_flight.py tests/test_bench_baseline.py \
+    tests/test_flight.py \
     tests/test_records.py tests/test_compiled.py tests/test_devmem.py \
     tests/test_comms.py tests/test_goodput.py \
     "$@" -q -p no:cacheprovider || rc=1

@@ -22,8 +22,8 @@ its manifest ``extra["goodput"]`` pack re-derived into the full table
 (fraction, unattributed, effective tok/s are computed here; the pack
 stores only raw buckets + wall).  File arguments are resolved by
 shape, not name: a flight-recorder bundle (``payload.goodput``), a
-telemetry dump (``goodput`` section), a bench record, a serving drain
-snapshot, or a bare pack/summary all work.
+telemetry dump (``goodput`` section), a serving drain snapshot, or a
+bare pack/summary all work.
 """
 
 import argparse
@@ -87,9 +87,8 @@ def extract(obj):
     """The goodput blob inside any JSON shape this repo writes, or
     None.  Checked shapes: a bare pack/summary, a flight bundle
     (``payload.goodput``), a telemetry dump / snapshot_detail
-    (``goodput``), a bench record (``payload.detail.telemetry`` has no
-    goodput key, but ``payload.detail.telemetry`` dumps do), a serving
-    drain snapshot (``goodput`` pack alongside the request log)."""
+    (``goodput``), a serving drain snapshot (``goodput`` pack alongside
+    the request log)."""
     if not isinstance(obj, dict):
         return None
     if "seconds" in obj and "wall_seconds" in obj:
@@ -98,8 +97,6 @@ def extract(obj):
                  ("payload", "goodput"),
                  ("telemetry", "goodput"),
                  ("payload", "telemetry", "goodput"),
-                 ("detail", "telemetry", "goodput"),
-                 ("payload", "detail", "telemetry", "goodput"),
                  ("extra", "goodput")):
         cur = obj
         for key in path:
@@ -212,7 +209,7 @@ def main(argv=None):
         if gp is None:
             raise SystemExit(
                 f"{args.source!r} holds no goodput section in any known "
-                "shape (bundle / dump / bench record / snapshot / pack)")
+                "shape (bundle / dump / snapshot / pack)")
 
     summary = normalize(gp)
     summary["source"] = origin

@@ -1,5 +1,5 @@
 """Print a telemetry snapshot — Prometheus text or JSON — from the
-live process registry, a flight-recorder bundle, or a bench record.
+live process registry or a flight-recorder bundle.
 
 The scrape-shaped view of the observability layer
 (docs/observability.md): the same ``to_prometheus_text()`` rendering a
@@ -9,12 +9,11 @@ box a dead run left behind::
     python tools/telemetry_dump.py                      # live registry
     python tools/telemetry_dump.py --format json
     python tools/telemetry_dump.py bench_records/flightrec_*.json
-    python tools/telemetry_dump.py --format json some_headline.json
+    python tools/telemetry_dump.py --format json some_snapshot.json
 
 File arguments are resolved by shape, not by name: a flight-recorder
-bundle (``payload.telemetry.registry``), a bench record
-(``payload.detail.telemetry.registry``), a raw emitted bench line
-(``detail.telemetry.registry``), or a bare registry snapshot all work.
+bundle (``payload.telemetry.registry``), a ``snapshot_detail()`` dump
+(``registry``) or a bare registry snapshot all work.
 
 Both formats carry the COMPILE and DEVMEM planes
 (docs/observability.md "compile & memory plane"): JSON output appends
@@ -86,7 +85,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 def extract_registry_snapshot(obj):
     """The registry snapshot inside any of the JSON shapes this repo
-    writes (flight bundle, bench record, emitted line, bare snapshot);
+    writes (flight bundle, snapshot_detail dump, bare snapshot);
     None when the object holds no registry."""
     if not isinstance(obj, dict):
         return None
@@ -94,8 +93,6 @@ def extract_registry_snapshot(obj):
     if {"counters", "gauges", "histograms"} <= set(obj):
         return obj
     for path in (("payload", "telemetry", "registry"),
-                 ("payload", "detail", "telemetry", "registry"),
-                 ("detail", "telemetry", "registry"),
                  ("telemetry", "registry"),
                  ("registry",)):
         node = obj
@@ -456,10 +453,10 @@ def _emit(snap, fmt, help_source=None) -> None:
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(
         description="print a telemetry snapshot (live registry, "
-                    "flight-recorder bundle, or bench record)")
+                    "or flight-recorder bundle)")
     parser.add_argument("path", nargs="?", default=None,
                         help="JSON file holding a registry snapshot "
-                             "(flightrec bundle / bench record); "
+                             "(flightrec bundle / snapshot); "
                              "default: the live process registry")
     parser.add_argument("--format", choices=("prom", "json"),
                         default="prom",
