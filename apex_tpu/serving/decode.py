@@ -42,9 +42,20 @@ key (``fold_in(PRNGKey(seed), emitted_token_index)`` — pure function
 of the request seed and the token's sequence index, so a drain/resume
 replay regenerates the identical stream), gated by ``lax.cond`` so an
 all-greedy batch never pays the sort. ``temperature == 0`` lanes take
-the greedy argmax — bitwise the pre-sampling behavior. Nothing
-round-trips to the host but the (b,) next-token ids, the (b,) finite
-flags, and the (b, vocab) logits.
+the greedy argmax — bitwise the pre-sampling behavior.
+
+The host-device boundary of a dispatch is one array each way. In:
+every small host argument of the call (tokens, lengths / starts /
+positions, block tables, the window layers' tables, the four sampling
+arrays) goes into ONE int32 numpy buffer whose layout is a function of
+the program's key alone (:func:`packed_layout`), so it is one transfer
+and a program warmed on zeros is the program traffic runs; the program
+takes it apart by static slices and bit casts (float32 and uint32
+arrive bit for bit). Out: ``StepOut.packed``, a (2, b) int32 array of
+the token ids over the finite flags, so the engine reads both in one
+fetch (:func:`host_tokens`) with no wait before it. The (b,) ids, the
+(b,) flags and the (b, vocab) logits stay on ``StepOut`` for whoever
+wants them. ``DecodeStep.transfers`` counts both sides.
 
 Both steps are teacher-forcing-friendly: they return the raw last
 logits next to the selected ids, so the parity suite replays a known
@@ -54,6 +65,7 @@ forward (tests/test_serving.py).
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
@@ -82,6 +94,22 @@ class StepOut(NamedTuple):
     # bool pull instead of the full (b, vocab) logits
     # (serving/resilience.py quarantine path). None on older callers.
     finite: Any = None
+    # (2, batch) int32 — ``next_token`` over ``finite`` as 0 / 1: what
+    # the engine reads, in one fetch. None from a stand-in step_fn.
+    packed: Any = None
+
+
+def host_tokens(out: StepOut) -> np.ndarray:
+    """A dispatch's token ids over its finite flags, (2, batch) int32
+    on the host: the one blocking read of a dispatch, of
+    ``out.packed``. A stand-in step_fn's ``StepOut`` has none: its
+    ``next_token`` and ``finite`` (every lane finite where None) are
+    read as they always were."""
+    if out.packed is not None:
+        return np.asarray(out.packed)
+    ids = np.asarray(out.next_token, np.int32)
+    return np.stack([ids, np.ones_like(ids) if out.finite is None
+                     else np.asarray(out.finite, np.int32)])
 
 
 def greedy_sampling(b: int) -> Tuple[np.ndarray, ...]:
@@ -90,6 +118,72 @@ def greedy_sampling(b: int) -> Tuple[np.ndarray, ...]:
     (temperature 0, no top-k, top-p 1, seed 0)."""
     return (np.zeros(b, np.float32), np.zeros(b, np.int32),
             np.ones(b, np.float32), np.zeros(b, np.uint32))
+
+
+# the fields of a dispatch's one host argument that are not int32;
+# they ride the int32 buffer as their bits (``ndarray.view`` on the
+# host, ``lax.bitcast_convert_type`` in the program)
+_FIELD_DTYPES = {"temps": np.float32, "top_ps": np.float32,
+                 "seeds": np.uint32}
+_LANE_FIELDS = {"decode_step": ("positions",),
+                "prefill_step": ("lengths",),
+                "prefill_chunk": ("starts", "lengths")}
+
+Layout = Tuple[Tuple[str, Tuple[int, ...]], ...]
+
+
+def packed_layout(fn: str, batch: int, table_width: int, seq: int = 1,
+                  window_table_width: Optional[int] = None) -> Layout:
+    """``(name, shape)`` of every field of program ``fn``'s one host
+    argument, in the order they lie in the buffer: a function of the
+    program's key alone, the same with and without sampling arrays."""
+    if fn not in _LANE_FIELDS:
+        raise ValueError(f"unknown serving program {fn!r}")
+    b = batch
+    fields = [("tokens", (b,) if fn == "decode_step" else (b, seq))]
+    fields += [(name, (b,)) for name in _LANE_FIELDS[fn]]
+    fields.append(("tables", (b, table_width)))
+    if window_table_width is not None:
+        fields += [("window_tables", (b, window_table_width)),
+                   ("window_first", (b,))]
+    fields += [(name, (b,))
+               for name in ("temps", "top_ks", "top_ps", "seeds")]
+    return tuple(fields)
+
+
+def packed_size(layout: Layout) -> int:
+    """The buffer's length, in int32 words."""
+    return sum(math.prod(shape) for _, shape in layout)
+
+
+def pack(layout: Layout, fields: Dict[str, Any]) -> np.ndarray:
+    """The one int32 buffer of a dispatch, from its host arrays by
+    name. Numpy only: a ``jnp`` operator here would be a transfer, and
+    a jit, of its own."""
+    parts = []
+    for name, shape in layout:
+        a = np.asarray(fields[name], _FIELD_DTYPES.get(name, np.int32))
+        if a.shape != shape:
+            raise ValueError(f"{name}: shape {a.shape}, the program's "
+                             f"key says {shape}")
+        parts.append(a.view(np.int32).ravel())
+    return np.concatenate(parts)
+
+
+def unpack(packed, layout: Layout) -> Dict[str, Any]:
+    """Inside the program: the fields of ``packed`` by name, by static
+    slices, reshapes and bit casts."""
+    import jax
+
+    fields, at = {}, 0
+    for name, shape in layout:
+        n = math.prod(shape)
+        x = packed[at:at + n].reshape(shape)
+        if name in _FIELD_DTYPES:
+            x = jax.lax.bitcast_convert_type(x, _FIELD_DTYPES[name])
+        fields[name] = x
+        at += n
+    return fields
 
 
 class DecodeStep:
@@ -106,14 +200,20 @@ class DecodeStep:
 
         self.model = model
         self.cache = cache
-        self._compiled: Dict[Tuple, Any] = {}
+        # key -> (layout of its host argument, its jitted program)
+        self._compiled: Dict[Tuple, Tuple[Layout, Any]] = {}
+        # what crosses the host-device boundary: the host arrays
+        # handed to a program and the arrays it makes for the host to
+        # read, counted where a dispatch makes them (one of each)
+        self.transfers = {"dispatches": 0, "host_arrays_in": 0,
+                          "host_arrays_out": 0}
         cfg = model.config
         max_pos = cfg.max_seq_len - 1
 
-        def tail(win):
+        def tail(tables, first):
             # the window layers' tables, where the model has such
             # layers: one more element of the kv_ctx hook
-            return () if win is None else (win,)
+            return () if tables is None else ((tables, first),)
 
         def select_token(out, sampling, fold_pos):
             """Fused in-program token selection over the (b, vocab)
@@ -163,7 +263,12 @@ class DecodeStep:
             return jax.lax.cond(jnp.any(temps > 0), sample,
                                 lambda _: greedy, None)
 
-        def prefill_fn(params, state, tokens, lengths, tables, temps,
+        def step_out(out, nxt, state):
+            finite = jnp.all(jnp.isfinite(out), axis=-1)
+            return StepOut(out, nxt, state, finite,
+                           jnp.stack([nxt, finite.astype(jnp.int32)]))
+
+        def prefill_fn(params, state, *, tokens, lengths, tables, temps,
                        top_ks, top_ps, seeds):
             b, s = tokens.shape
             logits, (k_new, v_new) = model.apply(
@@ -174,12 +279,11 @@ class DecodeStep:
             # the emitted token lands at sequence index == prompt len
             nxt = select_token(out, (temps, top_ks, top_ps, seeds),
                                lengths)
-            return StepOut(out, nxt, state,
-                           jnp.all(jnp.isfinite(out), axis=-1))
+            return step_out(out, nxt, state)
 
-        def prefill_chunk_fn(params, state, tokens, starts, lengths,
+        def prefill_chunk_fn(params, state, *, tokens, starts, lengths,
                              tables, temps, top_ks, top_ps, seeds,
-                             win=None):
+                             window_tables=None, window_first=None):
             b, s = tokens.shape
             # the pools are read BEFORE the chunk's writes: each layer
             # gathers its own context, every previously-written
@@ -190,7 +294,8 @@ class DecodeStep:
                 0, max_pos)
             logits, (k_new, v_new) = model.apply(
                 params, tokens, positions=pos,
-                kv_ctx=(state.k, state.v, tables, starts, *tail(win)),
+                kv_ctx=(state.k, state.v, tables, starts,
+                        *tail(window_tables, window_first)),
                 return_kv=True)
             state = append_kv_chunk(state, k_new, v_new, tables, starts,
                                     lengths)
@@ -200,18 +305,19 @@ class DecodeStep:
             # emitted token's index is starts + chunk length
             nxt = select_token(out, (temps, top_ks, top_ps, seeds),
                                starts + lengths)
-            return StepOut(out, nxt, state,
-                           jnp.all(jnp.isfinite(out), axis=-1))
+            return step_out(out, nxt, state)
 
-        def decode_fn(params, state, tokens, positions, tables, temps,
-                      top_ks, top_ps, seeds, win=None):
+        def decode_fn(params, state, *, tokens, positions, tables, temps,
+                      top_ks, top_ps, seeds, window_tables=None,
+                      window_first=None):
             pos2 = jnp.clip(positions, 0, max_pos)[:, None]   # (b, 1)
             # each layer gathers its own context from the pools and
             # attends with the token's K/V in slot positions[b] of it:
             # the slot append_kv writes below, which the table covers
             logits, (k_new, v_new) = model.apply(
                 params, tokens[:, None], positions=pos2,
-                kv_ctx=(state.k, state.v, tables, positions, *tail(win)),
+                kv_ctx=(state.k, state.v, tables, positions,
+                        *tail(window_tables, window_first)),
                 return_kv=True)
             state = append_kv(state, k_new[:, :, :, 0], v_new[:, :, :, 0],
                               tables, positions)
@@ -219,15 +325,28 @@ class DecodeStep:
             # the emitted token lands at positions + 1
             nxt = select_token(out, (temps, top_ks, top_ps, seeds),
                                positions + 1)
-            return StepOut(out, nxt, state,
-                           jnp.all(jnp.isfinite(out), axis=-1))
+            return step_out(out, nxt, state)
 
-        # cache state donated (argnums 1): appends run in place
-        self._prefill_jit = jax.jit(prefill_fn, donate_argnums=(1,))
-        self._prefill_chunk_jit = jax.jit(prefill_chunk_fn,
-                                          donate_argnums=(1,))
-        self._decode_jit = jax.jit(decode_fn, donate_argnums=(1,))
-        self._jnp = jnp
+        # what each program computes from its fields, by name
+        bodies = self._bodies = {"prefill_step": prefill_fn,
+                                 "prefill_chunk": prefill_chunk_fn,
+                                 "decode_step": decode_fn}
+
+        def program(fn: str, layout: Layout):
+            """The jitted program of one key: ``(params, state,
+            packed)``, cache state donated (argnums 1: appends run in
+            place)."""
+            body = bodies[fn]
+
+            def run(params, state, packed):
+                return body(params, state, **unpack(packed, layout))
+
+            # the program's name in a device trace (jit_decode_fn,
+            # jit_prefill_fn, ...): what a trace's readers find it by
+            run.__name__ = body.__name__
+            return jax.jit(run, donate_argnums=(1,))
+
+        self._program = program
 
     # -- compile-plane bookkeeping ------------------------------------------
 
@@ -248,17 +367,37 @@ class DecodeStep:
                    num_layers=cfg.num_layers)
         return sig
 
-    def _track(self, fn: str, key: Tuple) -> bool:
-        """True when ``key`` is NEW — the dispatch about to run will
-        trace+compile (the train-step ``_track`` discipline: hits are
-        one dict lookup and never reach the tracker)."""
-        if key in self._compiled:
-            return False
-        self._compiled[key] = True
-        return True
-
-    def _dispatch(self, fn: str, key: Tuple, jitted, *args) -> StepOut:
-        if self._track(fn, key):
+    def _dispatch(self, fn: str, params, state, fields: Dict[str, Any],
+                  sampling, window=None) -> StepOut:
+        """Pack ``fields`` (host arrays by name) with the sampling and
+        window arrays into the program's one argument and run it. Hits
+        are one dict lookup and never reach the compile tracker (the
+        train-step ``_track`` discipline)."""
+        tokens = fields["tokens"] = np.asarray(fields["tokens"], np.int32)
+        tables = fields["tables"] = np.asarray(fields["tables"], np.int32)
+        b, width = tokens.shape[0], tables.shape[1]
+        seq = tokens.shape[1:]                  # () for a decode
+        widths: Tuple[int, ...] = ()
+        if window is not None:
+            win_tables, fields["window_first"] = window
+            fields["window_tables"] = np.asarray(win_tables, np.int32)
+            widths = (fields["window_tables"].shape[1],)
+        (fields["temps"], fields["top_ks"], fields["top_ps"],
+         fields["seeds"]) = (greedy_sampling(b) if sampling is None
+                             else sampling)
+        key = (fn, b, *seq, width, *widths)
+        entry = self._compiled.get(key)
+        new = entry is None
+        if new:
+            layout = packed_layout(fn, b, width, *(seq or (1,)), *widths)
+            entry = (layout, self._program(fn, layout))
+        layout, program = entry
+        packed = pack(layout, fields)
+        self.transfers["dispatches"] += 1
+        self.transfers["host_arrays_in"] += 1       # packed
+        self.transfers["host_arrays_out"] += 1      # StepOut.packed
+        if new:
+            self._compiled[key] = entry
             from apex_tpu.telemetry import compiled as _compiled
 
             _compiled.observe(fn, self._signature(fn, key))
@@ -272,11 +411,11 @@ class DecodeStep:
                 # compile per NEW key, only when a real mesh is live
                 from apex_tpu.telemetry import sharding as _sharding
 
-                _sharding.publish_shardings(
-                    _sharding.jitted_shardings(jitted, *args, fn=fn))
+                _sharding.publish_shardings(_sharding.jitted_shardings(
+                    program, params, state, packed, fn=fn))
             with _compiled.label(fn):
-                return jitted(*args)
-        return jitted(*args)
+                return program(params, state, packed)
+        return program(params, state, packed)
 
     def compile_keys(self) -> Dict[str, int]:
         """Distinct compiled shapes per step kind (the bench/smoke
@@ -300,42 +439,13 @@ class DecodeStep:
         model with window layers."""
         import jax
 
-        jnp = self._jnp
-
-        def ints(*shape):
-            return jax.ShapeDtypeStruct(shape, jnp.int32)
-
-        sampling = (jax.ShapeDtypeStruct((batch,), jnp.float32), ints(batch),
-                    jax.ShapeDtypeStruct((batch,), jnp.float32),
-                    jax.ShapeDtypeStruct((batch,), jnp.uint32))
-        tables = ints(batch, table_width)
-        win = (None if window_table_width is None
-               else (ints(batch, window_table_width), ints(batch)))
-        if fn == "decode_step":
-            return self._decode_jit.lower(
-                params, state, ints(batch), ints(batch), tables, *sampling,
-                win)
-        if fn == "prefill_step":
-            return self._prefill_jit.lower(
-                params, state, ints(batch, seq), ints(batch), tables,
-                *sampling)
-        if fn == "prefill_chunk":
-            return self._prefill_chunk_jit.lower(
-                params, state, ints(batch, seq), ints(batch), ints(batch),
-                tables, *sampling, win)
-        raise ValueError(f"unknown serving program {fn!r}")
+        layout = packed_layout(fn, batch, table_width, seq,
+                               window_table_width)
+        return self._program(fn, layout).lower(
+            params, state,
+            jax.ShapeDtypeStruct((packed_size(layout),), np.int32))
 
     # -- dispatchers ---------------------------------------------------------
-
-    def _sampling_arrays(self, b: int, sampling):
-        jnp = self._jnp
-        if sampling is None:
-            sampling = greedy_sampling(b)
-        temps, top_ks, top_ps, seeds = sampling
-        return (jnp.asarray(temps, jnp.float32),
-                jnp.asarray(top_ks, jnp.int32),
-                jnp.asarray(top_ps, jnp.float32),
-                jnp.asarray(seeds, jnp.uint32))
 
     def prefill(self, params, state: KVCacheState, tokens, lengths,
                 tables, sampling=None) -> StepOut:
@@ -349,26 +459,10 @@ class DecodeStep:
         (None = all-greedy). Dummy batch rows use length 0 and an
         all-trash table.
         """
-        jnp = self._jnp
-        tokens = jnp.asarray(tokens, jnp.int32)
-        lengths = jnp.asarray(lengths, jnp.int32)
-        tables = jnp.asarray(tables, jnp.int32)
-        key = ("prefill_step", tokens.shape[0], tokens.shape[1],
-               tables.shape[1])
         return self._dispatch(
-            "prefill_step", key, self._prefill_jit, params, state,
-            tokens, lengths, tables,
-            *self._sampling_arrays(tokens.shape[0], sampling))
-
-    def _window_arrays(self, window):
-        """``window = (tables (b, ww), first (b,))`` as device arrays,
-        and the key's tail: the second table's width."""
-        if window is None:
-            return None, ()
-        jnp = self._jnp
-        tables, first = window
-        tables = jnp.asarray(tables, jnp.int32)
-        return (tables, jnp.asarray(first, jnp.int32)), (tables.shape[1],)
+            "prefill_step", params, state,
+            {"tokens": tokens, "lengths": lengths, "tables": tables},
+            sampling)
 
     def prefill_chunk(self, params, state: KVCacheState, tokens,
                       starts, lengths, tables,
@@ -385,18 +479,10 @@ class DecodeStep:
         first)`` of ``KVCache.window_table_array`` for a model with
         window layers (here and in :meth:`decode`).
         """
-        jnp = self._jnp
-        tokens = jnp.asarray(tokens, jnp.int32)
-        starts = jnp.asarray(starts, jnp.int32)
-        lengths = jnp.asarray(lengths, jnp.int32)
-        tables = jnp.asarray(tables, jnp.int32)
-        win, widths = self._window_arrays(window)
-        key = ("prefill_chunk", tokens.shape[0], tokens.shape[1],
-               tables.shape[1], *widths)
         return self._dispatch(
-            "prefill_chunk", key, self._prefill_chunk_jit, params,
-            state, tokens, starts, lengths, tables,
-            *self._sampling_arrays(tokens.shape[0], sampling), win)
+            "prefill_chunk", params, state,
+            {"tokens": tokens, "starts": starts, "lengths": lengths,
+             "tables": tables}, sampling, window)
 
     def decode(self, params, state: KVCacheState, tokens, positions,
                tables, sampling=None, window=None) -> StepOut:
@@ -411,16 +497,10 @@ class DecodeStep:
         all-greedy). Dummy batch rows use position 0 and an all-trash
         table.
         """
-        jnp = self._jnp
-        tokens = jnp.asarray(tokens, jnp.int32)
-        positions = jnp.asarray(positions, jnp.int32)
-        tables = jnp.asarray(tables, jnp.int32)
-        win, widths = self._window_arrays(window)
-        key = ("decode_step", tokens.shape[0], tables.shape[1], *widths)
         return self._dispatch(
-            "decode_step", key, self._decode_jit, params, state,
-            tokens, positions, tables,
-            *self._sampling_arrays(tokens.shape[0], sampling), win)
+            "decode_step", params, state,
+            {"tokens": tokens, "positions": positions, "tables": tables},
+            sampling, window)
 
 
 def make_decode_step(model, cache: KVCache) -> DecodeStep:
@@ -434,4 +514,6 @@ def make_decode_step(model, cache: KVCache) -> DecodeStep:
     return DecodeStep(model, cache)
 
 
-__all__ = ["DecodeStep", "StepOut", "greedy_sampling", "make_decode_step"]
+__all__ = ["DecodeStep", "StepOut", "greedy_sampling", "host_tokens",
+           "make_decode_step", "pack", "packed_layout", "packed_size",
+           "unpack"]

@@ -123,7 +123,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from apex_tpu.serving.decode import DecodeStep, make_decode_step
+from apex_tpu.serving.decode import (DecodeStep, host_tokens,
+                                     make_decode_step)
 from apex_tpu.ops.kv_gather import live_blocks
 from apex_tpu.serving.kv_cache import KVCache, PoolExhausted, bucket
 from apex_tpu.telemetry import timeline as _timeline
@@ -1153,8 +1154,6 @@ class ContinuousBatcher:
         all-finite flag of lane ``i``'s first-token logits. Only
         finite lanes get their first token recorded — a nonfinite lane
         is quarantined by the caller before it joins ``running``."""
-        import jax
-
         with self._span("apex.serve.prefill"):
             with self._span("apex.serve.prefill.build"):
                 b = bucket(len(admitted))
@@ -1174,13 +1173,10 @@ class ContinuousBatcher:
                         self.params, state, tokens, lengths, tables,
                         sampling=sampling)
                 with self._span("apex.serve.prefill.wait"):
-                    jax.block_until_ready(out.next_token)
+                    host = host_tokens(out)
             now = self.clock()
             with self._span("apex.serve.prefill.fetch"):
-                ids = np.asarray(out.next_token)
-                finite = (np.asarray(out.finite)[:len(admitted)]
-                          if out.finite is not None
-                          else np.ones(len(admitted), bool))
+                ids, finite = host[0], host[1, :len(admitted)] != 0
             tr = self.tracer
             traced = tr is not None and tr.enabled
             for i, f in enumerate(admitted):
@@ -1209,8 +1205,6 @@ class ContinuousBatcher:
         index, so the clause fails every sub-dispatch — the whole
         batch quarantines), ``io:prefill_chunk`` counts calls (one
         transient index is absorbed by the retry)."""
-        import jax
-
         from apex_tpu.resilience import faults
 
         with self._span("apex.serve.chunk"):
@@ -1234,13 +1228,10 @@ class ContinuousBatcher:
                         self.params, state, tokens, starts, lengths, tables,
                         sampling=sampling, **window)
                 with self._span("apex.serve.chunk.wait"):
-                    jax.block_until_ready(out.next_token)
+                    host = host_tokens(out)
             now = self.clock()
             with self._span("apex.serve.chunk.fetch"):
-                ids = np.asarray(out.next_token)
-                finite = (np.asarray(out.finite)[:len(batchees)]
-                          if out.finite is not None
-                          else np.ones(len(batchees), bool))
+                ids, finite = host[0], host[1, :len(batchees)] != 0
             return out.cache, ids, finite, now
 
     def _isolate_chunks(self, state, batchees, cidx: int, b: int,
@@ -1406,8 +1397,6 @@ class ContinuousBatcher:
         sites live here, so the split retries re-traverse them —
         step-indexed clauses fail every sub-dispatch, call-indexed
         ``io:decode_step`` faults are absorbed by the retry."""
-        import jax
-
         from apex_tpu.resilience import faults
 
         with self._span("apex.serve.decode"):
@@ -1430,13 +1419,10 @@ class ContinuousBatcher:
                         self.params, state, tokens, positions, tables,
                         sampling=sampling, **window)
                 with self._span("apex.serve.decode.wait"):
-                    jax.block_until_ready(out.next_token)
+                    host = host_tokens(out)
             now = self.clock()
             with self._span("apex.serve.decode.fetch"):
-                ids = np.asarray(out.next_token)
-                finite = (np.asarray(out.finite)[:len(flights)]
-                          if out.finite is not None
-                          else np.ones(len(flights), bool))
+                ids, finite = host[0], host[1, :len(flights)] != 0
             return out.cache, ids, finite, now
 
     def _isolate(self, state, flights: List[_InFlight], idx: int,

@@ -20,7 +20,11 @@ Anchors:
 - compile plane: chunking mints one program per (batch bucket, chunk
   bucket, width) at warmup and ZERO hot-loop recompiles;
 - the ``prefill_chunk_exception`` clause quarantines the chunk batch
-  and the engine keeps serving; ``io:prefill_chunk`` is absorbed.
+  and the engine keeps serving; ``io:prefill_chunk`` is absorbed;
+- the host-device boundary of a dispatch is one array each way: the
+  packed argument carries every field bit for bit, the programs give
+  what they gave from separate arrays, and the engine reads
+  ``StepOut.packed`` and nothing else.
 """
 
 import numpy as np
@@ -33,6 +37,7 @@ from apex_tpu import serving, telemetry  # noqa: E402
 from apex_tpu.models.gpt import GPTConfig, GPTModel  # noqa: E402
 from apex_tpu.resilience import faults  # noqa: E402
 from apex_tpu.resilience.guard import PreemptionHandler  # noqa: E402
+from apex_tpu.serving import decode as sdecode  # noqa: E402
 from apex_tpu.serving import resilience as sresil  # noqa: E402
 from apex_tpu.serving.kv_cache import KVCache  # noqa: E402
 
@@ -567,6 +572,305 @@ class TestChunkCompilePlane:
             assert step.compile_keys() == keys
         finally:
             _compiled.disable()
+
+
+# ---------------------------------------------------------------------------
+# one host array in, one host array out
+# ---------------------------------------------------------------------------
+
+LAYOUTS = [("decode_step", 1, None), ("decode_step", 1, 3),
+           ("prefill_step", 8, None), ("prefill_chunk", 8, None),
+           ("prefill_chunk", 8, 3)]
+
+
+# the program keys boundary_requests() reaches at commit 24b2cc4,
+# before the packed argument
+PARENT_KEYS = [("decode_step", 4, 4), ("decode_step", 4, 8),
+               ("prefill_chunk", 1, 8, 4), ("prefill_chunk", 1, 8, 8),
+               ("prefill_step", 2, 8, 4)]
+
+
+def host_unpack(packed, layout):
+    """The packed argument taken apart on the host, with numpy views:
+    what the program's static slices and bit casts must give."""
+    fields, at = {}, 0
+    for name, shape in layout:
+        n = int(np.prod(shape))
+        dtype = {"temps": np.float32, "top_ps": np.float32,
+                 "seeds": np.uint32}.get(name, np.int32)
+        fields[name] = packed[at:at + n].view(dtype).reshape(shape)
+        at += n
+    assert at == len(packed)
+    return fields
+
+
+def sampled_lanes(b):
+    """Per-lane sampling arrays with lane 0 greedy, floats that are
+    not round numbers and seeds above 2^31."""
+    temps = np.array([0.0, 0.8, 1.3, 0.0][:b], np.float32)
+    top_ks = np.array([0, 5, 0, 0][:b], np.int32)
+    top_ps = np.array([1.0, 0.9, 1.0, 1.0][:b], np.float32)
+    seeds = np.array([0, 3_000_000_000, 2 ** 32 - 1, 0][:b], np.uint32)
+    return temps, top_ks, top_ps, seeds
+
+
+class _Unreadable:
+    def __array__(self, *a, **kw):
+        raise AssertionError("the engine read more than StepOut.packed")
+
+
+class OnlyPacked:
+    """A step_fn whose results can be read through ``packed`` alone;
+    counts the dispatches."""
+
+    def __init__(self, inner):
+        self.inner, self.calls = inner, 0
+
+    def _strip(self, out):
+        self.calls += 1
+        return out._replace(logits=_Unreadable(), next_token=_Unreadable(),
+                            finite=_Unreadable())
+
+    def prefill(self, *a, **kw):
+        return self._strip(self.inner.prefill(*a, **kw))
+
+    def prefill_chunk(self, *a, **kw):
+        return self._strip(self.inner.prefill_chunk(*a, **kw))
+
+    def decode(self, *a, **kw):
+        return self._strip(self.inner.decode(*a, **kw))
+
+
+class WithoutPacked(OnlyPacked):
+    """The benchmark's ``Recorder`` shape, ``StepOut(None, ids, state,
+    None)``, or an older step_fn's, with the finite flags."""
+
+    def __init__(self, inner, flags):
+        super().__init__(inner)
+        self.flags = flags
+
+    def _strip(self, out):
+        return sdecode.StepOut(None, out.next_token, out.cache,
+                               out.finite if self.flags else None)
+
+
+def boundary_requests():
+    """Prompts prefilled whole (5, 3) and in chunks of 8 (20), greedy
+    and sampled lanes."""
+    rng = np.random.RandomState(31)
+    return [serving.Request(
+        id=i, prompt=rng.randint(0, VOCAB, (n,)), max_new_tokens=5, **kw)
+        for i, (n, kw) in enumerate([
+            (5, {}), (20, dict(temperature=0.8, top_k=5, top_p=0.9,
+                               seed=3_000_000_000)), (3, {})])]
+
+
+def serve_boundary(model, params, step_fn):
+    cache = fresh_cache()
+    eng, reg, _ = make_batcher(model, params, step_fn, cache,
+                               prefill_chunk=8)
+    res = run_to_completion(eng, cache, boundary_requests())
+    assert cache.blocks_in_use == 0
+    return {i: (r.finish_reason, r.tokens) for i, r in res.items()}, reg
+
+
+@pytest.fixture(scope="module")
+def plain(model_and_params):
+    """What the engine serves through a bare ``DecodeStep``."""
+    model, params = model_and_params
+    return serve_boundary(
+        model, params, serving.make_decode_step(model, fresh_cache()))[0]
+
+
+@pytest.fixture(scope="module", params=[None, 8], ids=["full", "window"])
+def windowed(request):
+    """A model without and with window layers: the second carries the
+    window tables through the packed argument."""
+    model = GPTModel(tiny_config(attention_window=request.param))
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    return model, params, request.param
+
+
+class TestOneArrayBoundary:
+    @pytest.mark.parametrize("fn,seq,window_width", LAYOUTS)
+    def test_packed_layout_round_trips_every_field(self, fn, seq,
+                                                   window_width):
+        b, width = 4, 6
+        layout = sdecode.packed_layout(fn, b, width, seq, window_width)
+        rng = np.random.default_rng(5)
+        fields = {}
+        for name, shape in layout:
+            if name in ("temps", "top_ps"):
+                fields[name] = np.array([0.7, -0.0, 1e-40, np.inf],
+                                        np.float32)
+            elif name == "seeds":
+                fields[name] = np.array([0, 2 ** 31, 3_000_000_000,
+                                         2 ** 32 - 1], np.uint32)
+            else:
+                fields[name] = rng.integers(-5, 2 ** 31 - 1, shape,
+                                            dtype=np.int64).astype(np.int32)
+        packed = sdecode.pack(layout, fields)
+        assert packed.dtype == np.int32
+        assert packed.shape == (sdecode.packed_size(layout),)
+        on_device = jax.jit(
+            lambda p: sdecode.unpack(p, layout))(packed)
+        on_host = host_unpack(packed, layout)
+        assert sorted(on_device) == sorted(name for name, _ in layout)
+        for name, shape in layout:
+            got = np.asarray(on_device[name])
+            assert got.dtype == fields[name].dtype, name
+            assert got.shape == shape
+            for other in (on_host[name], fields[name]):
+                assert got.tobytes() == other.tobytes(), name
+        # the layout is the key's alone: sampling given or not, window
+        # tables given or not
+        assert (window_width is not None) == any(
+            name == "window_tables" for name, _ in layout)
+        with pytest.raises(ValueError, match=layout[1][0]):
+            sdecode.pack(layout, {**fields, layout[1][0]: np.zeros(b + 1)})
+
+    @pytest.mark.parametrize("mode", ["greedy", "sampled"])
+    @pytest.mark.parametrize("fn", ["prefill_step", "prefill_chunk",
+                                    "decode_step"])
+    def test_programs_match_a_reference_unpacked_on_the_host(
+            self, windowed, fn, mode):
+        """Each program over the packed argument against its body
+        jitted over separate arrays, taken out of the same buffer on
+        the host: the same token ids, finite flags, logits and cache,
+        bit for bit; ``packed`` is the ids over the flags."""
+        model, params, window = windowed
+        cache = fresh_cache()
+        step = serving.make_decode_step(model, cache)
+        b, width, lens = 4, 8, (5, 9, 12)
+        seqs = list(range(len(lens)))
+        for sid in seqs:
+            cache.allocate(sid, 24)
+        tables = cache.table_array(seqs, width, batch=b)
+        rng = np.random.RandomState(3)
+        prompt = np.zeros((b, 16), np.int32)
+        for i, n in enumerate(lens):
+            prompt[i, :n] = rng.randint(0, VOCAB, (n,))
+        at = np.array([*lens, 0], np.int32)          # lane 3 is a dummy
+
+        def tail(positions):
+            if window is None:
+                return None
+            return cache.window_table_array(
+                seqs, positions, window, cache.window_width(window, width),
+                batch=b)
+
+        def prefilled():
+            return step.prefill(params, cache.init_state(), prompt, at,
+                                tables).cache
+
+        sampling = sampled_lanes(b) if mode == "sampled" else None
+        if fn == "prefill_step":
+            states = cache.init_state(), cache.init_state()
+            args = (prompt, at, tables)
+            kw = dict(sampling=sampling)
+        elif fn == "prefill_chunk":
+            states = prefilled(), prefilled()
+            chunk = rng.randint(0, VOCAB, (b, 8)).astype(np.int32)
+            args = (chunk, at, np.array([3, 8, 1, 0], np.int32), tables)
+            kw = dict(sampling=sampling, window=tail(at))
+        else:
+            states = prefilled(), prefilled()
+            args = (rng.randint(0, VOCAB, (b,)).astype(np.int32), at, tables)
+            kw = dict(sampling=sampling, window=tail(at))
+        if window is None or fn == "prefill_step":
+            kw.pop("window", None)
+        method = {"prefill_step": step.prefill,
+                  "prefill_chunk": step.prefill_chunk,
+                  "decode_step": step.decode}[fn]
+        before = dict(step.transfers)
+        got = method(params, states[0], *args, **kw)
+        assert {k: step.transfers[k] - before[k] for k in before} == {
+            "dispatches": 1, "host_arrays_in": 1, "host_arrays_out": 1}
+
+        (key,) = [k for k in step._compiled if k[0] == fn]
+        layout, _ = step._compiled[key]
+        fields = {"tokens": args[0], "tables": tables}
+        names = {"prefill_step": ("lengths",),
+                 "prefill_chunk": ("starts", "lengths"),
+                 "decode_step": ("positions",)}[fn]
+        fields.update(zip(names, args[1:]))
+        if kw.get("window") is not None:
+            fields["window_tables"], fields["window_first"] = kw["window"]
+        fields.update(zip(("temps", "top_ks", "top_ps", "seeds"),
+                          sampling or sdecode.greedy_sampling(b)))
+        apart = host_unpack(sdecode.pack(layout, fields), layout)
+        want = jax.jit(step._bodies[fn], donate_argnums=(1,))(
+            params, states[1], **apart)
+
+        for name in ("logits", "next_token", "finite", "packed"):
+            np.testing.assert_array_equal(np.asarray(getattr(got, name)),
+                                          np.asarray(getattr(want, name)))
+        np.testing.assert_array_equal(np.asarray(got.cache.k),
+                                      np.asarray(want.cache.k))
+        np.testing.assert_array_equal(np.asarray(got.cache.v),
+                                      np.asarray(want.cache.v))
+        np.testing.assert_array_equal(
+            np.asarray(got.packed),
+            np.stack([np.asarray(got.next_token),
+                      np.asarray(got.finite).astype(np.int32)]))
+        assert np.asarray(got.packed).dtype == np.int32
+        np.testing.assert_array_equal(sdecode.host_tokens(got),
+                                      np.asarray(got.packed))
+
+    def test_engine_moves_one_array_each_way_a_dispatch(
+            self, model_and_params, plain):
+        """A short run with whole prefills, chunks and decodes, greedy
+        and sampled: one host array in and one out a dispatch, the
+        engine reads ``packed`` alone, and serves what it served."""
+        model, params = model_and_params
+        step = serving.make_decode_step(model, fresh_cache())
+        only = OnlyPacked(step)
+        served, _ = serve_boundary(model, params, only)
+        assert served == plain
+        assert all(reason == "length" for reason, _ in served.values())
+        assert only.calls > 8
+        assert step.transfers == {"dispatches": only.calls,
+                                  "host_arrays_in": only.calls,
+                                  "host_arrays_out": only.calls}
+
+    @pytest.mark.parametrize("flags", [False, True],
+                             ids=["recorder", "older"])
+    def test_step_fn_without_the_packed_field_still_serves(
+            self, model_and_params, plain, flags):
+        model, params = model_and_params
+        served, _ = serve_boundary(model, params, WithoutPacked(
+            serving.make_decode_step(model, fresh_cache()), flags))
+        assert served == plain
+
+    @pytest.mark.parametrize("stand_in", ["packed", "older"])
+    def test_nonfinite_lane_quarantined_from_the_flags_read(
+            self, model_and_params, plain, stand_in):
+        model, params = model_and_params
+        step = serving.make_decode_step(model, fresh_cache())
+        step_fn = (OnlyPacked(step) if stand_in == "packed"
+                   else WithoutPacked(step, True))
+        with faults.inject(decode_nonfinite_steps=frozenset({2}),
+                           decode_nonfinite_lane=1):
+            served, reg = serve_boundary(model, params, step_fn)
+        bad = [i for i, (reason, _) in served.items() if reason == "error"]
+        assert len(bad) == 1
+        assert reg.counter("serving_quarantined").value(
+            reason="nonfinite") == 1
+        for i, (reason, toks) in served.items():
+            if i in bad:
+                assert toks == plain[i][1][:len(toks)]
+            else:
+                assert (reason, toks) == plain[i]
+
+    def test_compile_keys_over_a_fixed_deck(self, model_and_params):
+        """The programs a fixed deck reaches, by kind: the counts of
+        the engine before the packed argument (read at its commit)."""
+        model, params = model_and_params
+        step = serving.make_decode_step(model, fresh_cache())
+        serve_boundary(model, params, step)
+        assert sorted(step._compiled) == PARENT_KEYS
+        assert step.compile_keys() == {
+            "prefill_step": 1, "prefill_chunk": 2, "decode_step": 2}
 
 
 # ---------------------------------------------------------------------------

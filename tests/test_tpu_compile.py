@@ -288,7 +288,8 @@ def test_decode_program_gathers_once_a_layer(chip, monkeypatch):
     the arrays the scan carries, in place: the body holds no copy of
     the context, nor any pass over it but the gather (the token's own
     K/V goes in by updates in place), and the program zeroes it once,
-    K and V, outside the scan and pinned to HBM."""
+    K and V, outside the scan and pinned to HBM. The host's arguments
+    arrive as one int32 buffer."""
     import re
 
     from apex_tpu import serving
@@ -336,6 +337,12 @@ def test_decode_program_gathers_once_a_layer(chip, monkeypatch):
     assert len(by_name["zero_context"]) == 1
     assert "%zero_context." not in body
     assert not re.search(rf"{ctx}\S* (copy|copy-start|broadcast)\(", text)
+    # what the host hands the program is one int32 buffer
+    from apex_tpu.serving.decode import packed_layout, packed_size
+
+    size = packed_size(packed_layout("decode_step", b, w))
+    assert re.findall(r"= s32\[([\d,]*)\]\S* parameter\(",
+                      text[text.index("ENTRY"):]) == [str(size)]
 
 
 @pytest.mark.parametrize("fn,seq", [("decode_step", 1),
