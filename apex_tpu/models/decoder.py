@@ -6,17 +6,30 @@ dense layer precedes expert layers. So the layers are data
 (``DecoderConfig.layers``: for each layer its attention kind and its
 MLP kind) and the model is unrolled over them.
 
-The block is the ``afmoe`` one (Arcee Trinity; ``docs/serving.md``
-"Window layers", ``docs/moe.md`` "Held experts"):
+The block is data too (``DecoderConfig``), because the models differ
+in it (``docs/serving.md`` "Window layers", ``docs/moe.md`` "Held
+experts"):
 
-- embedding scaled by ``sqrt(hidden)``, an untied head;
-- sandwich norm, four RMSNorms a layer:
-  ``y = x + n2(Attn(n1(x)))``, ``x' = y + n4(MLP(n3(y)))``;
+- the embedding scaled by ``sqrt(hidden)`` or not; an untied head;
+- where the norms stand: *sandwich*, four RMSNorms a layer
+  (``y = x + n2(Attn(n1(x)))``, ``x' = y + n4(MLP(n3(y)))``), or *pre*,
+  two (``y = x + Attn(n1(x))``, ``x' = y + MLP(n2(y))``);
 - attention with ``head_dim`` of its own (not ``hidden / heads``),
-  GQA, QK-norm (an RMSNorm over the head), a sigmoid output gate;
-  *window* layers carry rotary positions and see the last ``window``
-  keys, *full* layers carry no positional embedding and see every key;
-- a SiLU-gated dense MLP, or ``moe.held.HeldMoEMLP``.
+  GQA and QK-norm (an RMSNorm over the head; both blocks have it, so
+  it is no option); a sigmoid output gate or none; *window* layers see
+  the last ``window`` keys, *full* layers every key;
+- a rotary scheme *for each kind of layer* (:class:`Rotary`): none,
+  plain, or YaRN's blend of scaled and unscaled frequencies with its
+  factor on cos and sin;
+- a SiLU-gated dense MLP, or ``moe.held.HeldMoEMLP`` under the sigmoid
+  or the softmax router, holding a share of the experts or all.
+
+What a configuration does not say is Arcee's ``afmoe`` (Trinity): the
+scaled embedding, sandwich norm, the gate, plain rotary on the
+window layers and none on the full ones, the sigmoid router.
+JetBrains' ``mellum`` (``benchmark/drivers/serve_mellum.py``) is
+pre-norm with no scale and no gate, plain rotary on its window layers
+and YaRN on its full ones, the softmax router over experts all held.
 
 It offers the serving hooks ``GPTModel`` offers (``positions``,
 ``kv_ctx``, ``return_kv``; ``config.kv_heads`` / ``head_dim`` /
@@ -28,6 +41,7 @@ take a ``GPTModel``. Single device: no mesh annotations.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -42,6 +56,46 @@ from apex_tpu.ops.rope import fused_apply_rotary_pos_emb_cached
 
 ATTENTION_KINDS = ("full", "window")
 MLP_KINDS = ("dense", "experts")
+NORM_PLACEMENTS = ("sandwich", "pre")
+
+
+@dataclasses.dataclass(frozen=True)
+class Rotary:
+    """One kind of layer's rotary embedding: plain at ``theta``, or,
+    with ``factor`` over 1, YaRN: position interpolation by ``factor``
+    for the slow frequencies, none for the fast, a linear ramp between
+    the dimensions that turn ``beta_fast`` and ``beta_slow`` times over
+    the ``original_max_position`` the model was trained at (truncated
+    to whole dimensions), and ``attention_factor`` on cos and sin."""
+
+    theta: float
+    factor: float = 1.0
+    original_max_position: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def __post_init__(self):
+        if self.factor != 1.0 and self.original_max_position <= 0:
+            raise ValueError("YaRN needs original_max_position, the "
+                             "positions the model was trained at")
+
+    def inv_freq(self, d: int):
+        """(d / 2,) float32: the angle a position turns each pair by."""
+        i = jnp.arange(0, d, 2, dtype=jnp.float32)
+        plain = self.theta ** (-i / d)
+        if self.factor == 1.0:
+            return plain
+
+        def dim_of(turns):            # the pair that turns so many times
+            return (d * math.log(self.original_max_position
+                                 / (turns * 2 * math.pi))
+                    / (2 * math.log(self.theta)))
+
+        low = max(math.floor(dim_of(self.beta_fast)), 0)
+        high = min(math.ceil(dim_of(self.beta_slow)), d - 1)
+        ramp = jnp.clip((i / 2 - low) / max(high - low, 1e-3), 0.0, 1.0)
+        return plain / self.factor * ramp + plain * (1.0 - ramp)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,8 +121,25 @@ class DecoderConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     softmax_impl: Optional[str] = None        # the kernels' impl
+    # the block (module docstring)
+    norms: str = "sandwich"                   # one of NORM_PLACEMENTS
+    embedding_scale: bool = True              # x0 = E[tokens] sqrt(hidden)
+    output_gate: bool = True
+    # ((attention kind, Rotary or None), ...), every kind the layers
+    # have; None: plain at rope_theta on window layers, none on full
+    rotary: Optional[Tuple[Tuple[str, Optional[Rotary]], ...]] = None
+    router: str = "sigmoid"                   # one of moe.held.ROUTERS
 
     def __post_init__(self):
+        if self.norms not in NORM_PLACEMENTS:
+            raise ValueError(f"norms is one of {NORM_PLACEMENTS}, "
+                             f"got {self.norms!r}")
+        if self.rotary is not None:
+            kinds = {a for a, _ in self.layers}
+            if {k for k, _ in self.rotary} != kinds:
+                raise ValueError(
+                    f"rotary names each kind of layer once ({sorted(kinds)}"
+                    f"), got {[k for k, _ in self.rotary]}")
         for attention, mlp in self.layers:
             if attention not in ATTENTION_KINDS or mlp not in MLP_KINDS:
                 raise ValueError(
@@ -92,6 +163,11 @@ class DecoderConfig:
     def kv_heads(self) -> int:
         return self.num_kv_heads
 
+    def rotary_of(self, kind: str) -> Optional[Rotary]:
+        if self.rotary is None:
+            return Rotary(self.rope_theta) if kind == "window" else None
+        return dict(self.rotary)[kind]
+
     def moe_cfg(self) -> HeldMoEConfig:
         return HeldMoEConfig(
             hidden_size=self.hidden_size,
@@ -99,7 +175,7 @@ class DecoderConfig:
             num_experts=self.num_experts, top_k=self.experts_per_token,
             held=self.held_experts, route_scale=self.route_scale,
             shared_ffn_size=self.shared_ffn_size, dtype=self.dtype,
-            param_dtype=self.param_dtype)
+            param_dtype=self.param_dtype, router=self.router)
 
 
 def _flash(cfg: DecoderConfig, *args, **kw):
@@ -134,15 +210,17 @@ class RMSNorm(nn.Module):
         return fused_rms_norm(x, g, eps=self.eps, impl="xla")
 
 
-def rotary(t, positions, theta: float):
+def rotary(t, positions, inv_freq, factor: float = 1.0):
     """Rotary embedding over the whole head, rotate-half convention:
-    ``t`` (b, s, heads, d) at ``positions`` (b, s) in the sequence."""
-    d = t.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ``t`` (b, s, heads, d) at ``positions`` (b, s) in the sequence,
+    ``inv_freq`` (d / 2,) a position's angle for each pair, ``factor``
+    on cos and sin."""
     angles = positions.astype(jnp.float32)[..., None] * inv_freq
     angles = jnp.concatenate([angles, angles], axis=-1)[:, :, None, :]
-    return fused_apply_rotary_pos_emb_cached(
-        t, jnp.cos(angles), jnp.sin(angles), impl="xla")
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    return fused_apply_rotary_pos_emb_cached(t, cos, sin, impl="xla")
 
 
 class DecoderAttention(nn.Module):
@@ -160,18 +238,23 @@ class DecoderAttention(nn.Module):
         nh, nkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         window = cfg.attention_window if self.kind == "window" else None
         init = nn.initializers.normal(stddev=0.02)
-        # one product for q, k, v and the gate: [q | k | v | g]
-        w = self.param("qkvg", init, (cfg.hidden_size, (2 * nh + 2 * nkv) * d),
+        # one product for q, k, v and, where the output is gated, the
+        # gate: [q | k | v | g]
+        gated = cfg.output_gate
+        cuts = [nh * d, (nh + nkv) * d, (nh + 2 * nkv) * d]
+        w = self.param("qkvg" if gated else "qkv", init,
+                       (cfg.hidden_size, cuts[2] + gated * nh * d),
                        cfg.param_dtype)
-        qkvg = jnp.dot(x, w.astype(cfg.dtype))
-        q, k, v, g = jnp.split(
-            qkvg, [nh * d, (nh + nkv) * d, (nh + 2 * nkv) * d], axis=-1)
+        q, k, v, *g = jnp.split(jnp.dot(x, w.astype(cfg.dtype)),
+                                cuts if gated else cuts[:2], axis=-1)
         q = RMSNorm(cfg.rms_eps, name="q_norm")(q.reshape(b, s, nh, d))
         k = RMSNorm(cfg.rms_eps, name="k_norm")(k.reshape(b, s, nkv, d))
         v = v.reshape(b, s, nkv, d)
-        if window is not None:
-            q = rotary(q, positions, cfg.rope_theta)
-            k = rotary(k, positions, cfg.rope_theta)
+        rope = cfg.rotary_of(self.kind)
+        if rope is not None:
+            inv_freq = rope.inv_freq(d)
+            q = rotary(q, positions, inv_freq, rope.attention_factor)
+            k = rotary(k, positions, inv_freq, rope.attention_factor)
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         held = ()
         if kv_ctx is not None:
@@ -185,7 +268,9 @@ class DecoderAttention(nn.Module):
         else:
             o = _flash(cfg, q, k, v, causal=True)
         o = o.transpose(0, 2, 1, 3).reshape(b, s, nh * d)
-        o = o * jax.nn.sigmoid(g.astype(jnp.float32)).astype(cfg.dtype)
+        if gated:
+            gate = jax.nn.sigmoid(g[0].astype(jnp.float32))
+            o = o * gate.astype(cfg.dtype)
         wo = self.param("proj", init, (nh * d, cfg.hidden_size),
                         cfg.param_dtype)
         return jnp.dot(o, wo.astype(cfg.dtype)), (k, v), held
@@ -214,15 +299,18 @@ class DecoderLayer(nn.Module):
     def __call__(self, x, positions, *, kv_ctx=None):
         cfg = self.config
         norm = lambda name: RMSNorm(cfg.rms_eps, name=name)  # noqa: E731
+        # a sandwich also norms what each half adds to the stream
+        after = norm if cfg.norms == "sandwich" else (
+            lambda name: lambda t: t)
         a, kv, held = DecoderAttention(cfg, self.attention, name="attention")(
             norm("input_norm")(x), positions, kv_ctx=kv_ctx)
-        y = x + norm("post_attention_norm")(a)
+        y = x + after("post_attention_norm")(a)
         m = norm("pre_mlp_norm")(y)
         if self.mlp == "experts":
             m = HeldMoEMLP(cfg.moe_cfg(), name="mlp")(m)
         else:
             m = DenseMLP(cfg, name="mlp")(m)
-        return y + norm("post_mlp_norm")(m), kv, held
+        return y + after("post_mlp_norm")(m), kv, held
 
 
 class PatternDecoder(nn.Module):
@@ -235,8 +323,8 @@ class PatternDecoder(nn.Module):
     def __call__(self, tokens, *, positions=None, kv_ctx=None,
                  return_kv=False):
         """``positions`` (b, s) or (s,): positions in the sequence
-        (default ``arange(s)``); they turn the window layers' rotary
-        embedding and nothing else. ``kv_ctx = (k_pool, v_pool, tables,
+        (default ``arange(s)``); they turn the rotary embeddings and
+        nothing else. ``kv_ctx = (k_pool, v_pool, tables,
         ctx_lens[, win])`` runs the cached paths
         (``models/cached_attention.py``); ``return_kv=True`` also
         returns this call's K and V, each stacked (num_layers, b,
@@ -246,8 +334,10 @@ class PatternDecoder(nn.Module):
         init = nn.initializers.normal(stddev=0.02)
         table = self.param("embedding", init,
                            (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
-        x = (table[tokens].astype(jnp.float32)
-             * (cfg.hidden_size ** 0.5)).astype(cfg.dtype)
+        x = table[tokens]
+        if cfg.embedding_scale:
+            x = x.astype(jnp.float32) * (cfg.hidden_size ** 0.5)
+        x = x.astype(cfg.dtype)
         if positions is None:
             positions = jnp.arange(s, dtype=jnp.int32)
         positions = jnp.broadcast_to(jnp.asarray(positions), (b, s))
@@ -278,4 +368,4 @@ class PatternDecoder(nn.Module):
         return logits
 
 
-__all__ = ["DecoderConfig", "PatternDecoder"]
+__all__ = ["DecoderConfig", "PatternDecoder", "Rotary"]

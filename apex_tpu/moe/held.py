@@ -11,12 +11,19 @@ read scale with the pairs held; among the held nothing is dropped. On
 one chip the layer runs without its exchange: what the absent experts
 would add is left out, and nothing stands in for them.
 
-The router is the sigmoid kind (DeepSeek-V3 / ``afmoe``): scores
-``s = sigmoid(x W_r)`` in float32, the choice by ``s + b`` with ``b`` a
-per-expert selection bias that takes no part in the weights, the
-weights ``s[chosen]`` renormalised over the chosen and scaled.
+The router is one of two, by configuration (``HeldMoEConfig.router``).
+The *sigmoid* kind (DeepSeek-V3 / ``afmoe``): scores ``s = sigmoid(x
+W_r)`` in float32, the choice by ``s + b`` with ``b`` a per-expert
+selection bias that takes no part in the weights, the weights
+``s[chosen]`` renormalised over the chosen and scaled. The *softmax*
+kind (the Qwen3-MoE family, ``mellum``): ``p = softmax(x W_r)`` over
+all the experts in float32, the choice the top-k of ``p``, the weights
+``p[chosen]`` renormalised over the chosen; no bias and no scale.
 Experts are SiLU-gated three-matrix MLPs; a shared expert of the same
 kind, where the model has one, is computed by every chip alike.
+
+``held=None`` is the layer whole: every expert is here, no pair is
+dropped, and the grouped products see ``tokens x top_k`` rows.
 """
 
 from __future__ import annotations
@@ -28,6 +35,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+
+ROUTERS = ("sigmoid", "softmax")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,8 +52,14 @@ class HeldMoEConfig:
     shared_ffn_size: int = 0         # 0 = no shared expert
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    router: str = "sigmoid"          # one of ROUTERS
 
     def __post_init__(self):
+        if self.router not in ROUTERS:
+            raise ValueError(f"router is one of {ROUTERS}, "
+                             f"got {self.router!r}")
+        if self.router == "softmax" and self.route_scale != 1.0:
+            raise ValueError("the softmax router has no route_scale")
         first, count = self.held_range
         if not (0 <= first and count >= 1
                 and first + count <= self.num_experts):
@@ -59,21 +75,34 @@ class HeldMoEConfig:
         return self.held if self.held is not None else (0, self.num_experts)
 
 
+def _router_logits(x, gate_kernel):
+    """The router's product in float32 at the highest precision (a
+    TPU's default float32 product is a bf16 one): the choice is a
+    comparison of scores, and those at the cut lie close."""
+    return jnp.dot(x.astype(jnp.float32), gate_kernel.astype(jnp.float32),
+                   precision=lax.Precision.HIGHEST)
+
+
 def sigmoid_router(x, gate_kernel, select_bias, k: int, *,
                    route_scale: float = 1.0):
     """x (n, h), gate (h, E), bias (E,) -> (weights (n, k) float32,
-    expert ids (n, k) int32, scores (n, E) float32).
-
-    The product runs in float32 at the highest precision (a TPU's
-    default float32 product is a bf16 one): the choice is a comparison
-    of scores, and the 4th and 5th lie close."""
-    logits = jnp.dot(x.astype(jnp.float32), gate_kernel.astype(jnp.float32),
-                     precision=lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
+    expert ids (n, k) int32, scores (n, E) float32)."""
+    scores = jax.nn.sigmoid(_router_logits(x, gate_kernel))
     _, ids = lax.top_k(scores + select_bias.astype(jnp.float32), k)
     chosen = jnp.take_along_axis(scores, ids, axis=-1)
     weights = chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * route_scale
     return weights, ids.astype(jnp.int32), scores
+
+
+def softmax_router(x, gate_kernel, k: int):
+    """x (n, h), gate (h, E) -> (weights (n, k) float32, expert ids
+    (n, k) int32, probabilities (n, E) float32): the softmax over ALL
+    the experts first, then the top-k of it, renormalised over the
+    chosen."""
+    probs = jax.nn.softmax(_router_logits(x, gate_kernel), axis=-1)
+    chosen, ids = lax.top_k(probs, k)
+    weights = chosen / chosen.sum(-1, keepdims=True)
+    return weights, ids.astype(jnp.int32), probs
 
 
 def held_experts(x, weights, ids, w_gate, w_up, w_down,
@@ -122,10 +151,11 @@ class HeldMoEMLP(nn.Module):
     and the shared expert (module docstring). Input and output
     ``(..., hidden)``. ``return_routing=True`` also returns ``(weights,
     ids, scores)`` for tests. Applied with ``mutable=["routing"]`` the
-    layer also leaves its choice there (``ids`` (n, k) and the
-    ``biased`` scores (n, experts) it was made from), for a reference
-    that is to be given the program's choice; otherwise that costs
-    nothing and changes no program."""
+    layer also leaves its choice there (``ids`` (n, k) and what it was
+    made from, (n, experts): the sigmoid router's ``biased`` scores,
+    the softmax router's ``probs``), for a reference that is to be
+    given the program's choice; otherwise that costs nothing and
+    changes no program."""
 
     config: HeldMoEConfig
 
@@ -137,19 +167,28 @@ class HeldMoEMLP(nn.Module):
         init = nn.initializers.normal(stddev=0.02)
         gate = self.param("router", init, (h, cfg.num_experts),
                           cfg.param_dtype)
-        bias = self.param("select_bias", nn.initializers.zeros,
-                          (cfg.num_experts,), jnp.float32)
         w_gate = self.param("w_gate", init, (count, h, f), cfg.param_dtype)
         w_up = self.param("w_up", init, (count, h, f), cfg.param_dtype)
         w_down = self.param("w_down", init, (count, f, h), cfg.param_dtype)
         lead = x.shape[:-1]
         x2 = x.reshape(-1, h)
-        weights, ids, scores = sigmoid_router(
-            x2, gate, bias, cfg.top_k, route_scale=cfg.route_scale)
+        softmax = cfg.router == "softmax"
+        with jax.named_scope("moe_router"):
+            if softmax:
+                weights, ids, scores = softmax_router(x2, gate, cfg.top_k)
+            else:
+                bias = self.param("select_bias", nn.initializers.zeros,
+                                  (cfg.num_experts,), jnp.float32)
+                weights, ids, scores = sigmoid_router(
+                    x2, gate, bias, cfg.top_k, route_scale=cfg.route_scale)
         if (self.is_mutable_collection("routing")
                 and not self.is_initializing()):
             self.sow("routing", "ids", ids)
-            self.sow("routing", "biased", scores + bias.astype(jnp.float32))
+            if softmax:
+                self.sow("routing", "probs", scores)
+            else:
+                self.sow("routing", "biased",
+                         scores + bias.astype(jnp.float32))
         out = held_experts(x2, weights, ids, w_gate, w_up, w_down,
                            (first, count), cfg.dtype)
         if cfg.shared_ffn_size:
@@ -167,4 +206,4 @@ class HeldMoEMLP(nn.Module):
 
 
 __all__ = ["HeldMoEConfig", "HeldMoEMLP", "gated_mlp", "held_experts",
-           "sigmoid_router"]
+           "sigmoid_router", "softmax_router"]

@@ -281,8 +281,15 @@ class ContinuousBatcher:
         # a model with window layers (docs/serving.md "Window layers"):
         # every dispatch over the cache carries a second table a lane,
         # the tail its window layers gather through
-        self.attention_window = getattr(
-            getattr(model, "config", None), "attention_window", None)
+        config = getattr(model, "config", None)
+        self.attention_window = getattr(config, "attention_window", None)
+        # how many of the pool's layers are window layers: those a
+        # layer pattern names, or all of them where the model has a
+        # window and no pattern
+        kinds = [kind for kind, _ in getattr(config, "layers", ())]
+        self._window_layers = (
+            kinds.count("window") if kinds
+            else cache.num_layers * (self.attention_window is not None))
         # chunked prefill (docs/serving.md): prompts longer than
         # `prefill_chunk` advance one bucketed chunk per engine step,
         # co-scheduled with the decode dispatch, instead of one
@@ -334,6 +341,11 @@ class ContinuousBatcher:
         # kernel copies; XLA's gather, where that runs, copies them all)
         self.gathered = {"full": 0, "window": 0,
                          "full_live": 0, "window_live": 0}
+        # what the live sequences hold at the end of a step, summed
+        # over the steps so far (host side): blocks times the pool's
+        # layers, and those of them that lie wholly behind a window
+        # layer's window, which no later query reads (ROADMAP.md R3)
+        self.held = {"block_layers": 0, "behind_window": 0}
         # resilience plane (serving/resilience.py)
         self.preemption = preemption          # guard.PreemptionHandler
         self.snapshot_dir = snapshot_dir
@@ -1127,6 +1139,22 @@ class ContinuousBatcher:
         count("window", positions - first * bs, ww)
         return {"window": (tables, first)}
 
+    def _count_held(self) -> None:
+        """Add this step's end to ``held``, from the sequences' lengths,
+        the window and the layer pattern alone: a sequence whose next
+        query stands at position ``t`` reads, in a window layer, no key
+        before ``t - window + 1``, so the blocks that end there or
+        earlier are held for nothing in each such layer."""
+        bs, window = self.cache.block_size, self.attention_window
+        nexts = ([(f, f.position) for f in self.running]
+                 + [(f, f.prefilled) for f in self.prefilling])
+        for f, t in nexts:
+            blocks = len(self.cache.table(f.seq_id))
+            self.held["block_layers"] += blocks * self.cache.num_layers
+            if window is not None:
+                self.held["behind_window"] += self._window_layers * min(
+                    blocks, max(0, t - window + 1) // bs)
+
     def _tables_for(self, flights: List[_InFlight], batch: int):
         widths = [len(self.cache.table(f.seq_id)) for f in flights]
         w = bucket(max(widths), self.min_width_bucket)
@@ -1587,6 +1615,7 @@ class ContinuousBatcher:
         with self._span("apex.serve.finish"):
             report["finished"].extend(self._reap())
             report["blocks_in_use"] = self.cache.blocks_in_use
+            self._count_held()
             self._publish_gauges()
             if self.slo is not None:
                 now = self.clock()

@@ -1,9 +1,19 @@
 """The pattern decoder (``models/decoder.py``), the held-experts layer
 (``moe/held.py``) and window layers over the paged cache, against the
-plain float32 reference (``models/decoder_reference.py``), at a small
-size on the CPU with seeded random weights: hidden 64, 4 heads / 2 KV
-heads of 32, window 8, block 4, 16 experts top-2 with 4 held,
-vocabulary 64, pattern dense + window, then window, window, full.
+plain float32 references, at a small size on the CPU with seeded
+random weights: hidden 64, 4 heads / 2 KV heads of 32, window 8, block
+4, vocabulary 64. Two blocks go through the one ``PatternDecoder``
+(``BLOCKS``), and a test that is the same test for both is
+parametrised over them:
+
+- ``afmoe`` (``models/decoder_reference.py``): 16 experts top-2 with 4
+  held, pattern dense + window, then window, window, full;
+- ``mellum`` (``models/decoder_reference_mellum.py``): pre-norm, no
+  gate, no embedding scale, 16 experts top-4 all held under the
+  softmax router, window, window, window, full; plain rotary on the
+  window layers and YaRN on the full one, trained (so the toy says) at
+  16 positions, so that the scaled and the unscaled frequencies are
+  both in play within 40 tokens.
 """
 
 import dataclasses
@@ -16,8 +26,10 @@ import pytest
 
 from apex_tpu import serving
 from apex_tpu.models import decoder_reference as ref
-from apex_tpu.models.decoder import DecoderConfig, PatternDecoder
-from apex_tpu.moe.held import HeldMoEConfig, HeldMoEMLP, sigmoid_router
+from apex_tpu.models import decoder_reference_mellum as mref
+from apex_tpu.models.decoder import DecoderConfig, PatternDecoder, Rotary
+from apex_tpu.moe.held import (HeldMoEConfig, HeldMoEMLP, sigmoid_router,
+                               softmax_router)
 
 LAYERS = (("window", "dense"), ("window", "experts"), ("window", "experts"),
           ("window", "experts"), ("full", "experts"))
@@ -39,6 +51,38 @@ def arch(held=HELD, layers=LAYERS):
                     held=held)
 
 
+MELLUM_LAYERS = (("window", "experts"),) * 3 + (("full", "experts"),)
+THETA = 500000.0
+YARN = mref.Yarn(theta=THETA, factor=16.0, original=16, beta_fast=32.0,
+                 beta_slow=1.0, attention_factor=1.2772588722239782)
+
+
+def mellum_config(dtype=jnp.float32, held=None, layers=MELLUM_LAYERS,
+                  full=Rotary(THETA, YARN.factor, YARN.original,
+                              YARN.beta_fast, YARN.beta_slow,
+                              YARN.attention_factor)):
+    return DecoderConfig(
+        vocab_size=VOCAB, hidden_size=64, num_heads=4, num_kv_heads=2,
+        head_dim=32, max_seq_len=128, layers=layers, ffn_hidden_size=0,
+        attention_window=WINDOW, expert_ffn_size=48, num_experts=EXPERTS,
+        experts_per_token=4, held_experts=held, rms_eps=1e-6, dtype=dtype,
+        param_dtype=jnp.float32, norms="pre", embedding_scale=False,
+        output_gate=False, router="softmax",
+        rotary=(("window", Rotary(THETA)), ("full", full)))
+
+
+def mellum_arch(held=None, layers=MELLUM_LAYERS):
+    return mref.Arch(4, 2, 32, layers, WINDOW, top_k=4, theta=THETA,
+                     yarn=YARN, held=held)
+
+
+# block -> (the program's configuration, the reference's, the
+# reference, the name the layer leaves its scores under)
+BLOCKS = {"afmoe": (config, arch, ref, "biased"),
+          "mellum": (mellum_config, mellum_arch, mref, "probs")}
+both_blocks = pytest.mark.parametrize("block", list(BLOCKS))
+
+
 def seeded(shapes, seed=0):
     """Weights that make every part matter: gains off 1, a selection
     bias that changes choices, a router that spreads its scores."""
@@ -56,28 +100,50 @@ def seeded(shapes, seed=0):
 
 
 @pytest.fixture(scope="module")
-def model_and_params():
-    model = PatternDecoder(config())
-    shapes = jax.eval_shape(
-        lambda k: model.init(k, jnp.zeros((1, 8), jnp.int32)),
-        jax.random.PRNGKey(0))
-    return model, seeded(shapes)
+def built():
+    """``block -> (model, params)``, each made once."""
+    made = {}
+
+    def get(block):
+        if block not in made:
+            model = PatternDecoder(BLOCKS[block][0]())
+            shapes = jax.eval_shape(
+                lambda k: model.init(k, jnp.zeros((1, 8), jnp.int32)),
+                jax.random.PRNGKey(0))
+            made[block] = model, seeded(shapes)
+        return made[block]
+
+    return get
+
+
+@pytest.fixture(scope="module")
+def model_and_params(built):
+    return built("afmoe")
 
 
 def tokens(n, seed=0):
     return np.random.default_rng(seed).integers(0, VOCAB, n).astype(np.int32)
 
 
-def test_full_forward_matches_the_reference(model_and_params):
-    model, params = model_and_params
+@both_blocks
+def test_full_forward_matches_the_reference(built, block):
+    """Float32 against float32: what is left is the order of the sums
+    (2e-5 of the largest logit; 1e-6 is what it reads)."""
+    model, params = built(block)
+    _, make_arch, reference, _ = BLOCKS[block]
     toks = tokens(40)
     got = model.apply(params, toks[None])[:, 0]
-    want, routing = ref.forward(params, toks, np.arange(40), arch(),
-                                row_block=16)
+    want, routing = reference.forward(params, toks, np.arange(40),
+                                      make_arch(), row_block=16)
     assert float(jnp.abs(got - want).max()) < 2e-5 * float(
         jnp.abs(want).max())
-    # the cut really leaves experts out, and really keeps some
-    assert all(0 < f["held_pairs"] < f["pairs"] for f in routing)
+    if block == "afmoe":
+        # the cut really leaves experts out, and really keeps some
+        assert all(0 < f["held_pairs"] < f["pairs"] for f in routing)
+    else:
+        # every expert is here: no pair is left out
+        assert len(routing) == 4 and all(
+            f["held_pairs"] == f["pairs"] == 40 * 4 for f in routing)
 
 
 def _engine(model, params, cfg, **kw):
@@ -100,14 +166,15 @@ def _serve(engine, cache, requests):
     return done
 
 
+@both_blocks
 @pytest.mark.parametrize("chunk", [None, 8, 5])
-def test_served_through_the_cache_matches_the_reference(model_and_params,
-                                                        chunk):
+def test_served_through_the_cache_matches_the_reference(built, block, chunk):
     """Prefill (whole, or in chunks that cross the window's edge at 8)
     then decode, several lengths in one batch, short of the window and
     past it: every served token is the reference's argmax of a full
     pass, and the pool is empty after the drain."""
-    model, params = model_and_params
+    model, params = built(block)
+    config, arch, ref, _ = BLOCKS[block]
     engine, cache = _engine(model, params, config(), prefill_chunk=chunk)
     requests = [serving.Request(id=i, prompt=tokens(n, 10 + i),
                                 max_new_tokens=m)
@@ -128,12 +195,14 @@ def test_served_through_the_cache_matches_the_reference(model_and_params,
 
 @pytest.mark.parametrize("start,length", [(0, 8), (4, 8), (7, 3), (8, 8),
                                           (9, 8), (13, 6), (26, 8)])
-def test_chunk_logits_across_the_window_edge(model_and_params, start,
-                                             length):
+@both_blocks
+def test_chunk_logits_across_the_window_edge(built, block, start, length):
     """``prefill_chunk`` at every kind of start around the window's
     edge (8) and a block's edge (4): the last row's logits against the
-    reference's full pass (logits, not tokens)."""
-    model, params = model_and_params
+    reference's full pass (logits, not tokens; float32 both, 2e-5 of
+    the largest logit for the order of the sums)."""
+    model, params = built(block)
+    config, arch, ref, _ = BLOCKS[block]
     cfg = config()
     cache = serving.KVCache.for_config(cfg, num_blocks=32, block_size=BLOCK)
     step = serving.make_decode_step(model, cache)
@@ -207,6 +276,93 @@ def test_router_chooses_by_biased_scores_and_weighs_by_plain_ones():
         np.asarray(w), chosen / chosen.sum(1, keepdims=True) * 2.448,
         rtol=1e-5)
     np.testing.assert_allclose(np.asarray(w).sum(1), 2.448, rtol=1e-5)
+
+
+def test_softmax_router_is_softmax_then_top_k_then_renormalised():
+    """Against one written by hand in numpy: the softmax over ALL the
+    experts, the top-k of it, the chosen renormalised to 1; and it is
+    not the unrenormalised thing."""
+    x = np.random.default_rng(0).normal(size=(6, 8)).astype(np.float32)
+    gate = np.random.default_rng(1).normal(size=(8, 12)).astype(np.float32)
+    w, ids, p = (np.asarray(t) for t in softmax_router(
+        jnp.asarray(x), jnp.asarray(gate), 3))
+    logits = x.astype(np.float64) @ gate
+    want = np.exp(logits - logits.max(1, keepdims=True))
+    want /= want.sum(1, keepdims=True)
+    np.testing.assert_allclose(p, want, rtol=1e-5)      # float32 rounding
+    np.testing.assert_allclose(p.sum(1), 1.0, rtol=1e-6)
+    order = np.argsort(-want, 1)[:, :3]
+    np.testing.assert_array_equal(ids, order)
+    chosen = np.take_along_axis(want, order, 1)
+    np.testing.assert_allclose(w, chosen / chosen.sum(1, keepdims=True),
+                               rtol=1e-5)
+    assert (chosen.sum(1) < 0.95).all()    # renormalising changed them
+
+
+def test_every_expert_held_drops_no_pair(monkeypatch):
+    """``held=None``: the grouped products' group sizes count every
+    routed pair, ``tokens x top_k`` rows over all the experts, and the
+    layer is the sum over the chosen of weight times expert, written
+    out by hand."""
+    cfg = HeldMoEConfig(hidden_size=64, expert_ffn_size=48,
+                        num_experts=EXPERTS, top_k=4, router="softmax",
+                        dtype=jnp.float32)
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(40, 64)),
+                    jnp.float32)
+    params = seeded(jax.eval_shape(
+        lambda k: HeldMoEMLP(cfg).init(k, x), jax.random.PRNGKey(0)), 1)
+    assert "select_bias" not in params["params"]
+    assert params["params"]["w_gate"].shape == (EXPERTS, 64, 48)
+    from apex_tpu.moe import held as held_module
+
+    sizes = []
+    real = held_module.lax.ragged_dot
+    monkeypatch.setattr(
+        held_module.lax, "ragged_dot",
+        lambda x, w, group_sizes: (sizes.append(np.asarray(group_sizes)),
+                                   real(x, w, group_sizes))[1])
+    out, (w, ids, _) = HeldMoEMLP(cfg).apply(params, x, return_routing=True)
+    assert len(sizes) == 3 and all(
+        g.shape == (EXPERTS,) and g.sum() == 40 * 4 for g in sizes)
+    p = jax.tree.map(np.asarray, params["params"])
+    want = np.zeros((40, 64))
+    for row in range(40):
+        for weight, e in zip(np.asarray(w)[row], np.asarray(ids)[row]):
+            h = np.asarray(x)[row] @ p["w_gate"][e]
+            h = h / (1 + np.exp(-h)) * (np.asarray(x)[row] @ p["w_up"][e])
+            want[row] += weight * (h @ p["w_down"][e])
+    np.testing.assert_allclose(np.asarray(out), want, atol=2e-5)
+
+
+def test_the_softmax_routers_shares_add_up_to_the_uncut_layer():
+    """64 experts top-8 in four shares of 16 (a training deployment's
+    cut): the parts the shares compute add up to the layer whole, as
+    the program computes it with ``held=None`` and as the reference
+    does."""
+    full = HeldMoEConfig(hidden_size=64, expert_ffn_size=48, num_experts=64,
+                         top_k=8, router="softmax", dtype=jnp.float32)
+    x = jnp.asarray(np.random.default_rng(2).normal(size=(3, 11, 64)),
+                    jnp.float32)
+    whole = seeded(jax.eval_shape(
+        lambda k: HeldMoEMLP(full).init(k, x), jax.random.PRNGKey(0)), 7)
+    a = mref.Arch(4, 2, 32, (), WINDOW, top_k=8, theta=THETA, yarn=YARN)
+    want, facts = mref._experts(x.reshape(-1, 64), whole["params"], a,
+                                None, ())
+    assert facts["held_pairs"] == facts["pairs"] == 33 * 8
+    total = 0.0
+    for first in range(0, 64, 16):
+        share = dict(whole["params"])
+        for name in ("w_gate", "w_up", "w_down"):
+            share[name] = whole["params"][name][first:first + 16]
+        cfg = HeldMoEConfig(**{**full.__dict__, "held": (first, 16)})
+        part = HeldMoEMLP(cfg).apply({"params": share}, x)
+        assert float(jnp.abs(part).max()) > 0
+        total = total + part
+    np.testing.assert_allclose(np.asarray(total).reshape(-1, 64),
+                               np.asarray(want), atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(HeldMoEMLP(full).apply(whole, x)).reshape(-1, 64),
+        np.asarray(want), atol=2e-5)
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
@@ -419,6 +575,42 @@ def test_gathered_counts_the_live_blocks():
     assert steps[-1]["full_live"] > steps[0]["full_live"]
 
 
+@both_blocks
+def test_held_counts_the_blocks_behind_the_window(built, block):
+    """``ContinuousBatcher.held``, one request on one lane (prompt 30,
+    12 new: 11 blocks of 4 reserved at admission): at each step's end
+    the live sequence's blocks times the pool's layers, and of them the
+    blocks that end at or before ``t - window + 1`` (``t`` the next
+    query's position) times the window layers; nothing once it ends."""
+    model, params = built(block)
+    cfg = BLOCKS[block][0]()
+    cache = serving.KVCache.for_config(cfg, num_blocks=96, block_size=BLOCK)
+    engine = serving.ContinuousBatcher(
+        model, params, cache, max_batch=1, min_width_bucket=2,
+        min_seq_bucket=4)
+    state = cache.init_state()
+    engine.submit(serving.Request(id=0, prompt=tokens(30),
+                                  max_new_tokens=12))
+    layers = len(cfg.layers)
+    window_layers = sum(a == "window" for a, _ in cfg.layers)
+    assert (layers, window_layers) == ((5, 4) if block == "afmoe"
+                                       else (4, 3))
+    seen = [dict(engine.held)]
+    while not engine.idle():
+        state, _ = engine.step(state)
+        seen.append(dict(engine.held))
+    steps = [{k: b[k] - a[k] for k in a} for a, b in zip(seen, seen[1:])]
+    assert steps[-1] == {"block_layers": 0, "behind_window": 0}  # it ended
+    live = steps[:-1]
+    assert len(live) == 10 and all(
+        s["block_layers"] == 11 * layers for s in live)
+    # the first step prefills and decodes once: the next query then
+    # stands at 31, a step later at 32, ...
+    assert [s["behind_window"] for s in live] == [
+        window_layers * ((t - WINDOW + 1) // BLOCK) for t in range(31, 41)]
+    assert cache.blocks_in_use == 0
+
+
 @pytest.mark.parametrize("impl", ["xla", "interpret"])
 def test_flash_attention_takes_a_lanes_own_positions(impl):
     from apex_tpu.ops.attention import flash_attention
@@ -444,29 +636,41 @@ def test_flash_attention_takes_a_lanes_own_positions(impl):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
-def _choice(model, params, toks):
+def _choice(model, params, toks, scores="biased"):
     """The program's own routing over ``toks``, as the benchmark's
-    driver reads it: ``(ids, biased scores)`` an expert layer."""
+    drivers read it: ``(ids, what they were chosen from)`` an expert
+    layer."""
     _, sown = model.apply(params, toks[None], mutable=["routing"])
     return [(np.asarray(sown["routing"][f"layer_{i}"]["mlp"]["ids"][0]),
-             np.asarray(sown["routing"][f"layer_{i}"]["mlp"]["biased"][0]))
-            for i, (_, mlp) in enumerate(LAYERS) if mlp == "experts"]
+             np.asarray(sown["routing"][f"layer_{i}"]["mlp"][scores][0]))
+            for i, (_, mlp) in enumerate(model.config.layers)
+            if mlp == "experts"]
 
 
 @pytest.fixture(scope="module")
-def served(model_and_params):
-    """Four requests served through the cache, and the program's own
-    routing over each one's teacher-forced tokens."""
-    model, params = model_and_params
-    engine, cache = _engine(model, params, config(), prefill_chunk=8)
-    requests = [serving.Request(id=i, prompt=tokens(n, 40 + i),
-                                max_new_tokens=90)
-                for i, n in enumerate([6, 11, 19, 30])]
-    done = _serve(engine, cache, requests)
-    return [(r.prompt, done[r.id].tokens,
-             _choice(model, params,
-                     ref.teacher_forced(r.prompt, done[r.id].tokens, 1)[0]))
-            for r in requests]
+def served(built):
+    """``block ->`` four requests served through the cache, and the
+    program's own routing over each one's teacher-forced tokens."""
+    made = {}
+
+    def get(block):
+        if block not in made:
+            model, params = built(block)
+            engine, cache = _engine(model, params, BLOCKS[block][0](),
+                                    prefill_chunk=8)
+            requests = [serving.Request(id=i, prompt=tokens(n, 40 + i),
+                                        max_new_tokens=90)
+                        for i, n in enumerate([6, 11, 19, 30])]
+            done = _serve(engine, cache, requests)
+            made[block] = [
+                (r.prompt, done[r.id].tokens, _choice(
+                    model, params,
+                    ref.teacher_forced(r.prompt, done[r.id].tokens, 1)[0],
+                    BLOCKS[block][3]))
+                for r in requests]
+        return made[block]
+
+    return get
 
 
 # the program here is float32, so its choice may differ from the
@@ -475,19 +679,25 @@ def served(model_and_params):
 LIMITS = dict(ulps=4.0, band=1e-5, slack=0.0, pad_to=1, dtype_eps=BF16_EPS)
 
 
-def test_the_program_leaves_its_choice_only_when_asked(model_and_params):
-    model, params = model_and_params
+@both_blocks
+def test_the_program_leaves_its_choice_only_when_asked(built, block):
+    model, params = built(block)
+    scores, k = BLOCKS[block][3], model.config.experts_per_token
     toks = tokens(24)
     plain = model.apply(params, toks[None])
     logits, sown = model.apply(params, toks[None], mutable=["routing"])
     np.testing.assert_array_equal(np.asarray(plain), np.asarray(logits))
     got = sown["routing"]
-    assert sorted(got) == ["layer_1", "layer_2", "layer_3", "layer_4"]
-    ids, biased = (got["layer_1"]["mlp"][k][0] for k in ("ids", "biased"))
-    assert ids.shape == (24, 2) and biased.shape == (24, EXPERTS)
+    assert sorted(got) == [
+        f"layer_{i}" for i, (_, mlp) in enumerate(model.config.layers)
+        if mlp == "experts"]
+    assert sorted(got["layer_1"]["mlp"]) == sorted(["ids", scores])
+    ids, chosen_from = (got["layer_1"]["mlp"][name][0]
+                        for name in ("ids", scores))
+    assert ids.shape == (24, k) and chosen_from.shape == (24, EXPERTS)
     np.testing.assert_array_equal(
         np.sort(np.asarray(ids), -1),
-        np.sort(np.argsort(-np.asarray(biased), -1)[:, :2], -1))
+        np.sort(np.argsort(-np.asarray(chosen_from), -1)[:, :k], -1))
     assert "routing" not in model.init(jax.random.PRNGKey(0), toks[None])
 
 
@@ -529,10 +739,12 @@ def test_held_margin_watches_the_held_experts_only():
     assert ref.held_margin(biased, 3, (0, 6)) == pytest.approx(0.01)
 
 
-def test_what_was_served_passes_given_the_programs_choice(served,
-                                                          model_and_params):
-    _, params = model_and_params
-    for prompt, toks, choice in served:
+@both_blocks
+def test_what_was_served_passes_given_the_programs_choice(served, built,
+                                                          block):
+    _, params = built(block)
+    _, arch, ref, _ = BLOCKS[block]
+    for prompt, toks, choice in served(block):
         out = ref.check_served(params, arch(), prompt, toks, choice=choice,
                                **LIMITS)
         assert out["ok"] and out["exact"] == out["rows"] == 90, out
@@ -554,26 +766,35 @@ def test_a_row_is_excused_only_if_it_trails_and_may_differ(margins, ok,
     assert out["exact"] == 3 and out["rows"] == 4
 
 
-@pytest.mark.parametrize("fault", [
-    "window_edge", "rope_on_full", "weight_by_biased", "bf16_router",
-    "choice_without_bias", "fp8_everywhere"])
-def test_the_comparison_refuses_a_wrong_model(model_and_params, served,
-                                              fault):
-    """A reference that is wrong in one way (a window one key too wide,
-    rotary positions on the full layer, weights from ``s + b``, the
-    choice made without ``b``, the router's product on bf16 inputs,
-    everything in fp8) against what the true program served, at the
-    cell's 4 bf16 ulps and given the program's choice: every request
-    is refused. (The router's product on bf16 inputs moves a score by
-    1e-3 or so, which at 16 experts and 64 wide changes a choice in a
-    row or two of a hundred: the four requests are refused as the one
-    run they are, by the two of them that meet such a row.)"""
-    _, params = model_and_params
+@pytest.mark.parametrize("block,fault", [
+    ("afmoe", f) for f in (
+        "window_edge", "rope_on_full", "weight_by_biased", "bf16_router",
+        "choice_without_bias", "fp8_everywhere")] + [
+    ("mellum", f) for f in (
+        "plain_rope_on_full", "yarn_on_window", "no_attention_factor",
+        "no_renormalisation", "window_edge", "bf16_router",
+        "fp8_everywhere")])
+def test_the_comparison_refuses_a_wrong_model(built, served, block, fault):
+    """A reference that is wrong in one way against what the true
+    program served, at the cells' 4 bf16 ulps and given the program's
+    choice: every request is refused. ``afmoe``: a window one key too
+    wide, rotary positions on the full layer, weights from ``s + b``,
+    the choice made without ``b``, the router's product on bf16 inputs,
+    everything in fp8. ``mellum``: the two rotary schemes each on the
+    other kind of layer (plain on the full layer, YaRN on the window
+    layers), YaRN without its ``attention_factor``, a top-4 that is
+    not renormalised, and the window, the bf16 router and fp8 as
+    above. (The router's product on bf16 inputs moves a score by 1e-3
+    or so, which at 16 experts and 64 wide changes a choice in a row
+    or two of a hundred: the four requests are refused as the one run
+    they are, by those of them that meet such a row.)"""
+    _, params = built(block)
+    _, arch, ref, _ = BLOCKS[block]
     wrong = dict(round_to=jnp.float8_e4m3fn) if fault == "fp8_everywhere" \
         else dict(faults=(fault,))
     verdicts = [ref.check_served(params, arch(), prompt, toks, choice=choice,
                                  **LIMITS, **wrong)
-                for prompt, toks, choice in served]
+                for prompt, toks, choice in served(block)]
     passed = [v["ok"] for v in verdicts]
     assert not (all(passed) if fault == "bf16_router" else any(passed)), [
         (v["worst_ulps"], v["refused"]) for v in verdicts]

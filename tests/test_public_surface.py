@@ -146,12 +146,16 @@ SURFACE = {
     ],
     "apex_tpu.models.gpt": ["GPTConfig", "GPTModel", "gpt_loss_fn"],
     # PR-28: the pattern decoder and the held-experts layer
-    "apex_tpu.models.decoder": ["DecoderConfig", "PatternDecoder"],
+    "apex_tpu.models.decoder": ["DecoderConfig", "PatternDecoder", "Rotary"],
     "apex_tpu.models.decoder_reference": ["Arch", "forward", "judge",
                                           "check_served", "held_margin",
                                           "teacher_forced"],
+    # PR-33: a second block through the pattern decoder (mellum)
+    "apex_tpu.models.decoder_reference_mellum": [
+        "Arch", "Yarn", "forward", "check_served", "yarn_inv_freq",
+        "inv_freq"],
     "apex_tpu.moe.held": ["HeldMoEConfig", "HeldMoEMLP", "sigmoid_router",
-                          "held_experts"],
+                          "softmax_router", "held_experts"],
     "apex_tpu.models.bert": None,     # module presence only
     "apex_tpu.models.t5": None,
     "apex_tpu.models.resnet": None,

@@ -454,3 +454,67 @@ def test_trinity_cut_programs_fit_and_gather_the_window(chip, monkeypatch,
     assert names.count("attention_window") == 4
     assert names.count("attention") == 1
     assert names.count("ragged-dot-none") == 12       # 3 products x 4 layers
+
+
+@pytest.mark.parametrize("fn,batch,seq", [
+    ("decode_step", 16, 1), ("prefill_chunk", 4, 1024)])
+def test_mellum2_cut_programs_fit_and_gather_the_tail(chip, monkeypatch,
+                                                      fn, batch, seq):
+    """``Mellum2-12B-A2.5B``'s eight-layer cut at the benchmark's own
+    sizes (``benchmark/configs/mellum2-12b-a2.5b.json``: hidden 2304,
+    8 query heads a KV head, 64 experts all held, a 98304-row head, a
+    pool of 9216 blocks), its decode program at 16 lanes and its chunk
+    program at 4 x 1024, at the widest tables (1024 blocks): they fit a
+    v5e beside nothing else, the six window layers gather and attend a
+    tail of 80 blocks (1280 positions) and the two full layers 16384,
+    and every layer's three grouped products are the compiler's own
+    kernels."""
+    import json
+    import re
+
+    from apex_tpu import serving
+    from apex_tpu.models.decoder import PatternDecoder
+    from benchmark.drivers import serve_mellum
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mellum2-12b-a2.5b.json")) as f:
+        config = json.load(f)
+    monkeypatch.setenv("APEX_TPU_IMPL", "pallas")
+    _backend.default_impl.cache_clear()
+    try:
+        cfg = serve_mellum.decoder_config(config)
+        model = PatternDecoder(cfg)
+        cache = serving.KVCache.for_config(
+            cfg, num_blocks=config["engine"]["num_blocks"],
+            block_size=config["engine"]["block_size"])
+        shapes = jax.eval_shape(
+            lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32)),
+            jax.random.PRNGKey(0))
+        params, state = jax.tree.map(
+            lambda x: chip(x.shape, x.dtype),
+            (shapes, jax.eval_shape(cache.init_state)))
+        width = 1024
+        tail = cache.window_width(cfg.attention_window, width)
+        compiled = serving.make_decode_step(model, cache).lower(
+            fn, params, state, batch, width, seq=seq,
+            window_table_width=tail).compile()
+    finally:
+        _backend.default_impl.cache_clear()
+    assert tail == 80 and cfg.hidden_size == 2304
+    assert cfg.num_heads // cfg.num_kv_heads == 8
+    n = sum(x.size for x in jax.tree.leaves(shapes))
+    assert 3.78e9 < n < 3.81e9          # 8 x 417.75M + 453.0M: 7.59 GB
+    assert chip_smoke.program_bytes(compiled) <= 0.8 * V5E_BYTES
+    text = compiled.as_text()
+    calls = re.findall(
+        r"%([\w-]+)\.?\d* = (\([^=]*?\)|\S+) custom-call\([^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"", text)
+    gathers = [res for name, res in calls if name == "kv_gather"]
+    assert sorted(g.count(f"bf16[{batch},4,1280,128]") for g in gathers) \
+        == [0, 0, 2, 2, 2, 2, 2, 2]
+    assert sum(g.count(f"bf16[{batch},4,16384,128]") for g in gathers) == 4
+    names = [name for name, _ in calls]
+    assert names.count("attention_window") == 6
+    assert names.count("attention") == 2
+    assert names.count("ragged-dot-none") == 24       # 3 products x 8 layers
