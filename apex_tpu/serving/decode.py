@@ -28,7 +28,7 @@ tracker: the hot loop is one dict lookup.
 Fused hot path (PAPERS.md "LLM Inference Acceleration via Efficient
 Operation Fusion" — the prefill/decode analog of PR 1's fused
 optimizer step): prefill runs embed -> L layers -> final norm -> LM
-head -> last-token logit gather -> cache scatter as one program;
+head -> last-token logit gather -> cache append as one program;
 decode runs, per layer inside the layer scan, one gather of that
 layer's context out of the pool (``ops/kv_gather.py``, straight into
 the flash kernel's layout) -> the token's own K/V into its slot of it

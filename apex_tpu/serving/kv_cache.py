@@ -20,11 +20,10 @@ GQA pays GQA-sized blocks: the pool is sized from the model's
 ``kv_heads`` (``GPTConfig.kv_heads``), not ``num_heads``, so a 4x
 grouped-query model holds 4x the sequences in the same HBM.
 
-Block 0 is the **trash block**: writes from padded batch slots or
-padded prompt tails land there (index clamping instead of predication
-keeps the scatter shape static), and unallocated block-table entries
-point at it so a short table gathers garbage that the attention mask
-then drops. No real sequence is ever given block 0.
+Block 0 is the **trash block**: writes from padded batch slots land
+there (their tables name nothing else), and unallocated block-table
+entries point at it so a short table gathers garbage that the
+attention mask then drops. No real sequence is ever given block 0.
 
 The allocator is host-side Python (the scheduler's admission control
 runs on the host between steps). The jitted programs write the pool
@@ -707,16 +706,31 @@ def append_kv(state: KVCacheState, k_new, v_new, tables,
     ``positions`` (batch,) the 0-based slot each token lands in. Rows
     whose table entry is the trash block (dummy batch slots) write
     harmlessly into it.
+
+    One update in place a lane and pool, all layers of the lane's
+    ``(1, 1, kv_heads, head_dim)`` rows at once, which leaves the pool
+    in the layout it arrived in (some 2 us an update on a v5e). One
+    scatter over all lanes is laid out apart from a pool whose rows
+    are not whole (8, 128) tiles (4 KV heads of bf16 fill half a
+    tile): the TPU compiler then converts the K and V pools whole,
+    there and back, in every call (PERF.md, PR 34).
     """
     import jax.numpy as jnp
+    from jax import lax
 
     bs = state.k.shape[2]
     w = tables.shape[1]
     blk = jnp.take_along_axis(
         tables, jnp.clip(positions[:, None] // bs, 0, w - 1), axis=1)[:, 0]
     slot = positions % bs
-    return KVCacheState(k=state.k.at[:, blk, slot].set(k_new),
-                        v=state.v.at[:, blk, slot].set(v_new))
+    k, v = state
+    for i in range(tables.shape[0]):
+        at = (0, blk[i], slot[i], 0, 0)
+        k = lax.dynamic_update_slice(
+            k, k_new[:, i, None, None].astype(k.dtype), at)
+        v = lax.dynamic_update_slice(
+            v, v_new[:, i, None, None].astype(v.dtype), at)
+    return KVCacheState(k=k, v=v)
 
 
 def append_kv_prefill(state: KVCacheState, k_new, v_new, tables,
@@ -724,8 +738,7 @@ def append_kv_prefill(state: KVCacheState, k_new, v_new, tables,
     """Write a whole prompt's K/V per sequence into the pool in place.
 
     ``k_new``/``v_new`` (num_layers, batch, kv_heads, seq, head_dim)
-    right-padded; positions ``>= lengths`` clamp to the trash block
-    (static scatter shape, no predication), so the pads' garbage K/V
+    right-padded; the pads' garbage K/V at positions ``>= lengths``
     never lands in a real block.
     """
     return append_kv_chunk(state, k_new, v_new, tables, None, lengths)
@@ -738,27 +751,62 @@ def append_kv_chunk(state: KVCacheState, k_new, v_new, tables, starts,
     The chunk-resumable generalization of :func:`append_kv_prefill`:
     chunk row ``i`` of sequence ``b`` lands at global position
     ``starts[b] + i`` (``starts=None`` means 0 — the monolithic
-    prefill). Rows ``i >= lengths[b]`` (chunk padding) clamp to the
-    trash block; the scatter shape stays static.
+    prefill; ``starts >= 0``). Rows ``i >= lengths[b]`` (chunk
+    padding) never land in a real block.
+
+    A loop over the lanes and, inside it, over the blocks a lane's
+    valid rows touch (none for a dummy lane), each turn an update in
+    place as in :func:`append_kv`: it takes the block's rows out of
+    the pool, puts the chunk's valid rows over them and writes the
+    block back. So the rows before a chunk that starts inside a block
+    (a prefix-cache fork: :func:`apply_copies`) stay, as do those
+    after a chunk that ends inside one, and the pads are not written
+    at all.
     """
     import jax.numpy as jnp
+    from jax import lax
 
-    bs = state.k.shape[2]
+    layers, _, bs, kv, d = state.k.shape
     b, w = tables.shape
     s = k_new.shape[3]
-    pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
-    valid = pos < lengths[:, None]
-    if starts is not None:
-        pos = pos + starts[:, None]
-    blk = jnp.take_along_axis(tables, jnp.clip(pos // bs, 0, w - 1), axis=1)
-    blk = jnp.where(valid, blk, TRASH_BLOCK)
-    slot = pos % bs
 
-    def one(pool, new):
-        # (L, b, kv, s, d) -> (L, b, s, kv, d) to match pool[:, blk, slot]
-        return pool.at[:, blk, slot].set(new.transpose(0, 1, 3, 2, 4))
+    def rows(new):
+        # (L, b, kv, s, d) -> (L, b, bs + s + pad, kv, d): rows as the
+        # pool holds them, a block of pads in front (a chunk may start
+        # inside a block) and behind, so that every block's slice of
+        # the chunk lies inside the array
+        pad = (bs, (-(-s // bs) + 1) * bs - s)
+        return jnp.pad(new.transpose(0, 1, 3, 2, 4).astype(state.k.dtype),
+                       ((0, 0), (0, 0), pad, (0, 0), (0, 0)))
 
-    return KVCacheState(k=one(state.k, k_new), v=one(state.v, v_new))
+    k_new, v_new = rows(k_new), rows(v_new)
+    slot = jnp.arange(bs, dtype=jnp.int32)
+    if starts is None:
+        starts = jnp.zeros((b,), jnp.int32)
+
+    def lane(i, pools):
+        n = jnp.minimum(lengths[i], s)
+        block, off = lax.div(starts[i], bs), lax.rem(starts[i], bs)
+
+        def turn(j, pools):
+            first = j * bs - off                # the chunk row of slot 0
+            valid = (first + slot >= 0) & (first + slot < n)
+            at = (0, tables[i, jnp.clip(block + j, 0, w - 1)], 0, 0, 0)
+
+            def one(pool, new):
+                old = lax.dynamic_slice(pool, at, (layers, 1, bs, kv, d))
+                new = lax.dynamic_slice(new, (0, i, bs + first, 0, 0),
+                                        (layers, 1, bs, kv, d))
+                return lax.dynamic_update_slice(
+                    pool, jnp.where(valid[:, None, None], new, old), at)
+
+            return one(pools[0], k_new), one(pools[1], v_new)
+
+        touched = jnp.where(n > 0, lax.div(off + n + bs - 1, bs), 0)
+        return lax.fori_loop(0, touched, turn, pools)
+
+    k, v = lax.fori_loop(0, b, lane, (state.k, state.v))
+    return KVCacheState(k=k, v=v)
 
 
 def apply_copies(state: KVCacheState,

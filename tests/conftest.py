@@ -68,6 +68,38 @@ def impl(request):
     return request.param
 
 
+@pytest.fixture
+def rehearsal_manifest(tmp_path):
+    """``(name, cell, real_cell) -> path``: a rehearsal manifest of
+    ``benchmark/tests/rehearsal/`` whose toy ``cell`` also reports
+    every per-layer metric that ``BENCHMARK.json`` lists for
+    ``real_cell`` and the rehearsal manifest lacks: what came to the
+    benchmark as data (a specification under
+    ``benchmark/layer_metrics/``) after the rehearsal was written."""
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def make(name, cell, real_cell):
+        rehearsal = os.path.join(root, "benchmark", "tests", "rehearsal")
+        with open(os.path.join(rehearsal, name)) as f:
+            manifest = json.load(f)
+        for config in manifest["configs"]:    # found from the manifest's
+            config["file"] = os.path.join(rehearsal, config["file"])
+        with open(os.path.join(root, "BENCHMARK.json")) as f:
+            real = json.load(f)["per_layer"]
+        have = {m["name"] for m in manifest["per_layer"]}
+        manifest["per_layer"] += [
+            dict(m, workloads=[cell]) for m in real
+            if m["name"] not in have
+            and real_cell in m.get("workloads", (real_cell,))]
+        path = tmp_path / name
+        path.write_text(json.dumps(manifest))
+        return str(path)
+
+    return make
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",

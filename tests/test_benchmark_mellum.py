@@ -24,23 +24,25 @@ def load(*parts):
         return json.load(f)
 
 
-def on_the_cpu(monkeypatch):
+def on_the_cpu(monkeypatch, manifest_path=None):
     from benchmark import run
 
     monkeypatch.setattr(run, "accelerator", lambda chips: jax.devices()[:1])
     monkeypatch.setattr(run, "peaks_for", lambda kind, dirs: {
         "bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11})
     monkeypatch.setattr(jax.config, "update", lambda *a, **k: None)
-    return dict(manifest_path=os.path.join(REHEARSAL,
-                                           "BENCHMARK.mellum.json"),
+    return dict(manifest_path=manifest_path or os.path.join(
+        REHEARSAL, "BENCHMARK.mellum.json"),
                 data_dirs=[REHEARSAL, run.BENCH_DIR])
 
 
 @pytest.mark.parametrize("trace", [0, 1])
-def test_rehearsal_of_the_mellum_driver(monkeypatch, capsys, trace):
+def test_rehearsal_of_the_mellum_driver(monkeypatch, capsys, trace,
+                                        rehearsal_manifest):
     from benchmark import run
 
-    where = on_the_cpu(monkeypatch)
+    where = on_the_cpu(monkeypatch, rehearsal_manifest(
+        "BENCHMARK.mellum.json", "toy-mellum.toy-codechat", CELL))
     rc = run.main(["--workload", "toy-mellum.toy-codechat", "--seed",
                    str(2**31 + 11), "--seconds", "0.5", "--trace",
                    str(trace)], **where)
@@ -55,7 +57,8 @@ def test_rehearsal_of_the_mellum_driver(monkeypatch, capsys, trace):
         assert 0 < line["metrics"]["pool_behind_window_pct"]["value"] < 75
         # no device plane on the CPU: the trace readers return nothing
         assert not {"moe_expert_pct", "attention_roofline.window1k",
-                    "prefill_call_ms.codechat"} & set(line["metrics"])
+                    "prefill_call_ms.codechat",
+                    "pool_relayout_pct"} & set(line["metrics"])
         assert any("drained: 0 block(s) held" in n for n in notes)
     else:
         assert set(line["metrics"]) == {"serve_tok_s", "itl_p95_ms",
@@ -201,10 +204,13 @@ def test_the_manifest_only_gained_entries():
     assert reported == {"serve_tok_s", "itl_p95_ms", "setup_s"}
     per_layer = [m["name"] for m in manifest["per_layer"]
                  if CELL in m.get("workloads", ())]
-    assert per_layer[-4:] == [
+    # PR 33's four, then what came as data since: PR 34's counter of
+    # whole-pool copies, which Trinity's cell reports too
+    assert per_layer[-5:] == [
         "prefill_call_ms.codechat", "attention_roofline.codechat",
-        "attention_roofline.window1k", "pool_behind_window_pct"]
-    assert set(per_layer[:-4]) == {
+        "attention_roofline.window1k", "pool_behind_window_pct",
+        "pool_relayout_pct"]
+    assert set(per_layer[:-5]) == {
         "decode_step_ms", "batch_fill_pct", "host_gap_ms",
         "host_gap_schedule_ms", "host_gap_build_ms", "host_gap_dispatch_ms",
         "host_gap_sync_ms", "engine_dispatches_per_step", "moe_expert_pct",
@@ -212,8 +218,71 @@ def test_the_manifest_only_gained_entries():
     for name in per_layer:
         assert os.path.exists(os.path.join(
             ROOT, "benchmark", "layer_metrics", name + ".json")), name
-    for m in manifest["per_layer"][-4:]:
+    for m in manifest["per_layer"][-5:-1]:
         assert m["workloads"] == [CELL]
+    assert manifest["per_layer"][-1] == {
+        "name": "pool_relayout_pct", "unit": "%", "better": "lower",
+        "source": "device_trace", "layer": "Server", "moves": "serve_tok_s",
+        "workloads": ["trinity-large-preview.serve-longmix", CELL]}
+    # the cells pool_copy_pct lists are the two this one does not
+    copy = next(m for m in manifest["per_layer"]
+                if m["name"] == "pool_copy_pct")
+    assert not set(copy["workloads"]) & set(
+        manifest["per_layer"][-1]["workloads"])
+
+
+POOL = "bf16[8,9217,16,4,128]"
+#: whole HLO instructions as a chip's trace names them, (text, ns)
+TOY_OPS = {
+    "relayout": (f"%copy.5850 = {POOL}{{4,0,3,2,1:T(8,128)(2,1)}} "
+                 "copy(%state_k.1)", 3000),
+    "slot_update": (f"%dynamic_update_slice_fusion.7 = {POOL}"
+                    "{4,3,2,1,0:T(4,128)(2,1)} fusion(%state_k.1, %p)", 500),
+    "prefetch": (f"%copy-start.3 = ({POOL}{{4,3,2,1,0}}, u32[]) "
+                 "copy-start(%w)", 700),
+    "small_copy": ("%copy.12 = bf16[16,4,128]{2,1,0:T(4,128)(2,1)} "
+                   "copy(%k_new)", 800),
+    "product": ("%ragged-dot-none.3 = bf16[128,2304]{1,0} "
+                "custom-call(%x, %w)", 5000),
+}
+
+
+@pytest.mark.parametrize("ops,want", [
+    (("relayout", "slot_update", "prefetch", "small_copy", "product"),
+     100 * 3000 / 10000),
+    (("slot_update", "prefetch", "small_copy", "product"), 0.0),
+    (("relayout", "relayout", "product"), 100 * 6000 / 11000),
+], ids=["a-pool-shaped-copy", "none", "k-and-v"])
+def test_pool_relayout_pct_counts_whole_pool_copies(tmp_path, ops, want):
+    """The metric's specification through its reader on a toy trace:
+    only a ``copy`` instruction of the pool's shape counts, not the
+    in-place slot updates, an asynchronous copy's start, a copy of
+    another shape or anything else; without a device plane the reader
+    gives nothing."""
+    import types
+
+    from benchmark import trace_reduce
+    from benchmark.readers import op_share
+
+    spec = load("benchmark", "layer_metrics", "pool_relayout_pct.json")
+    assert spec["reader"] == "op_share"
+    events, at = [], 1000
+    for name in ops:
+        text, ns = TOY_OPS[name]
+        events.append([text, at, ns, {}])
+        at += ns + 100
+    path = tmp_path / "trace.json"
+    path.write_text(json.dumps({"planes": [
+        {"name": "/host:CPU", "lines": [{"name": "python3", "events": [
+            ["bench.traced", 0, at, {}]]}]},
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Ops", "events": events}]}]}))
+    run = types.SimpleNamespace(
+        reduced=trace_reduce.reduce(trace_reduce.from_json(str(path))),
+        facts={"pool_blocks": 9217})
+    assert op_share.read(spec["params"], run) == pytest.approx(want)
+    nothing = types.SimpleNamespace(reduced=None, facts={})
+    assert op_share.read(spec["params"], nothing) is None
 
 
 def test_the_deck_is_what_its_generator_draws():

@@ -24,7 +24,7 @@ def load(*parts):
 
 
 @pytest.fixture
-def bench(monkeypatch):
+def bench(monkeypatch, rehearsal_manifest):
     from benchmark import run
 
     monkeypatch.setattr(run, "accelerator", lambda chips: jax.devices()[:1])
@@ -33,8 +33,9 @@ def bench(monkeypatch):
     monkeypatch.setattr(jax.config, "update", lambda *a, **k: None)
 
     def go(capsys, *argv):
-        rc = run.main(list(argv), manifest_path=os.path.join(
-            REHEARSAL, "BENCHMARK.trinity.json"),
+        rc = run.main(list(argv), manifest_path=rehearsal_manifest(
+            "BENCHMARK.trinity.json", "toy-trinity.toy-longmix",
+            "trinity-large-preview.serve-longmix"),
             data_dirs=[REHEARSAL, run.BENCH_DIR])
         assert rc == 0
         out = capsys.readouterr().out.strip().splitlines()
@@ -55,7 +56,8 @@ def test_rehearsal_of_the_pattern_driver(bench, capsys, trace):
                 "window_gather_pct"} <= set(line["metrics"])
         assert 0 < line["metrics"]["window_gather_pct"]["value"] < 100
         # no device plane on the CPU: the trace readers return nothing
-        assert "moe_expert_pct" not in line["metrics"]
+        assert not {"moe_expert_pct",
+                    "pool_relayout_pct"} & set(line["metrics"])
         assert any("drained: 0 block(s) held" in n for n in notes)
     else:
         assert set(line["metrics"]) == {"serve_tok_s", "itl_p95_ms",

@@ -27,6 +27,7 @@ from apex_tpu.serving.kv_cache import (  # noqa: E402
     KVCache,
     PoolExhausted,
     append_kv,
+    append_kv_chunk,
     append_kv_prefill,
     bucket,
     gather_kv,
@@ -261,6 +262,122 @@ class TestPoolOps:
         got = np.asarray(gk)[:, 0]            # (LAYERS, KV, 3*BS, d)
         for t, row in enumerate(rows):
             np.testing.assert_array_equal(got[:, :, t], row[:, 0])
+
+
+def _scatter_append(state, k_new, v_new, tables, positions):
+    """The append as one scatter a pool over all lanes, which the
+    in-place updates replaced: the plain reference."""
+    bs = state.k.shape[2]
+    w = tables.shape[1]
+    blk = jnp.take_along_axis(
+        tables, jnp.clip(positions[:, None] // bs, 0, w - 1), axis=1)[:, 0]
+    slot = positions % bs
+    return serving.KVCacheState(k=state.k.at[:, blk, slot].set(k_new),
+                                v=state.v.at[:, blk, slot].set(v_new))
+
+
+def _scatter_chunk(state, k_new, v_new, tables, starts, lengths):
+    """The chunk's rows by one scatter a pool, pads to the trash
+    block: the plain reference."""
+    bs = state.k.shape[2]
+    b, w = tables.shape
+    s = k_new.shape[3]
+    pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
+    valid = pos < lengths[:, None]
+    if starts is not None:
+        pos = pos + starts[:, None]
+    blk = jnp.take_along_axis(tables, jnp.clip(pos // bs, 0, w - 1), axis=1)
+    blk = jnp.where(valid, blk, serving.TRASH_BLOCK)
+    slot = pos % bs
+
+    def one(pool, new):
+        return pool.at[:, blk, slot].set(new.transpose(0, 1, 3, 2, 4))
+
+    return serving.KVCacheState(k=one(state.k, k_new), v=one(state.v, v_new))
+
+
+#: name -> (chunk length, starts or None, lengths); tables of 4 blocks
+#: of BS rows a lane, so a lane holds 4 * BS positions
+APPEND_CHUNKS = {
+    "from-a-block-edge": (2 * BS, [BS, 0, 2 * BS], [2 * BS, 2 * BS, 2 * BS]),
+    "inside-a-block-after-written-rows": (
+        2 * BS, [BS + 1, 3, 2 * BS - 1], [2 * BS, 2 * BS, 2 * BS]),
+    "lengths-end-inside-a-block-and-at-0": (
+        2 * BS, [BS, 2, 0], [BS + 1, 0, 2 * BS - 1]),
+    "two-lanes-of-unequal-length": (3 * BS, [0, BS + 2, 0], [3 * BS, 2, 0]),
+    "whole-prompt-prefill": (3 * BS + 1, None, [3 * BS + 1, 5, 0]),
+}
+
+
+@pytest.mark.parametrize("d", [8, 128], ids=["head-dim-8", "head-dim-128"])
+class TestAppendInPlace:
+    """``append_kv`` / ``append_kv_chunk`` write the pool by updates
+    in place, a lane (and a block) at a time; every real block then
+    holds bitwise what one scatter over all lanes left there. Only the
+    trash block's contents are free."""
+
+    @staticmethod
+    def pool(kv, d, seed):
+        rng = np.random.RandomState(seed)
+        shape = (LAYERS, BLOCKS + 1, BS, kv, d)
+        state = serving.KVCacheState(
+            k=jnp.asarray(rng.randn(*shape), jnp.bfloat16),
+            v=jnp.asarray(rng.randn(*shape), jnp.bfloat16))
+        # three lanes of four distinct real blocks each, the last lane's
+        # table half trash (a sequence that has not reserved them)
+        tables = 1 + rng.permutation(BLOCKS)[:12].reshape(3, 4)
+        tables[2, 2:] = serving.TRASH_BLOCK
+        return rng, state, jnp.asarray(tables, jnp.int32)
+
+    @staticmethod
+    def same_real_blocks(got, want):
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(
+                np.asarray(g.astype(jnp.float32))[:, 1:],
+                np.asarray(w.astype(jnp.float32))[:, 1:])
+
+    @pytest.mark.parametrize("kv", [2, 4, 8, 16])
+    def test_decode_with_dummy_lanes_on_the_trash_block(self, kv, d):
+        rng, state, tables = self.pool(kv, d, 11)
+        # lanes 1 and 3 are dummies: an all-trash table, position 0
+        tables = jnp.stack([tables[0], jnp.zeros(4, jnp.int32), tables[1],
+                            jnp.zeros(4, jnp.int32)])
+        positions = jnp.asarray([2 * BS + 1, 0, 4 * BS - 1, 0], jnp.int32)
+        k = jnp.asarray(rng.randn(LAYERS, 4, kv, d), jnp.bfloat16)
+        v = jnp.asarray(rng.randn(LAYERS, 4, kv, d), jnp.bfloat16)
+        got = jax.jit(append_kv)(state, k, v, tables, positions)
+        want = _scatter_append(state, k, v, tables, positions)
+        self.same_real_blocks(got, want)
+        assert not np.array_equal(np.asarray(got.k.astype(jnp.float32)),
+                                  np.asarray(state.k.astype(jnp.float32)))
+
+    @pytest.mark.parametrize("kv", [2, 4, 8, 16])
+    @pytest.mark.parametrize("case", list(APPEND_CHUNKS))
+    def test_chunk(self, case, kv, d):
+        s, starts, lengths = APPEND_CHUNKS[case]
+        rng, state, tables = self.pool(kv, d, 12)
+        k = jnp.asarray(rng.randn(LAYERS, 3, kv, s, d), jnp.bfloat16)
+        v = jnp.asarray(rng.randn(LAYERS, 3, kv, s, d), jnp.bfloat16)
+        lengths = jnp.asarray(lengths, jnp.int32)
+        if starts is None:
+            got = jax.jit(append_kv_prefill)(state, k, v, tables, lengths)
+        else:
+            starts = jnp.asarray(starts, jnp.int32)
+            got = jax.jit(append_kv_chunk)(state, k, v, tables, starts,
+                                           lengths)
+        want = _scatter_chunk(state, k, v, tables, starts, lengths)
+        self.same_real_blocks(got, want)
+        # the rows it had before a chunk that starts inside a block,
+        # and everything a lane of length 0 owns, are what they were
+        untouched = np.asarray(state.k.astype(jnp.float32))
+        after = np.asarray(got.k.astype(jnp.float32))
+        for lane, n in enumerate(np.asarray(lengths)):
+            at = 0 if starts is None else int(starts[lane])
+            for pos in range(4 * BS):
+                blk = int(tables[lane, pos // BS])
+                if blk != serving.TRASH_BLOCK and not at <= pos < at + n:
+                    np.testing.assert_array_equal(
+                        after[:, blk, pos % BS], untouched[:, blk, pos % BS])
 
 
 def _written_pool(seed, num_blocks=BLOCKS):

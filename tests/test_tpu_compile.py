@@ -67,6 +67,29 @@ def _no_compilation_cache():
     compilation_cache.reset_cache()
 
 
+def assert_pool_stays_where_it_lies(text, shape):
+    """A serving program's compiled text writes the donated pools in
+    place: no ``copy`` instruction has the pool's shape, every mention
+    of that shape carries the layout the parameter arrived in (an
+    operand constraint of a kernel names the order alone, without
+    tiles), and ``input_output_alias`` ties both pools to outputs."""
+    import re
+
+    pool = re.escape("bf16[%s]" % ",".join(str(n) for n in shape))
+    arrived = re.findall(rf"= {pool}(\{{[^}}]*\}}) parameter\((\d+)\)",
+                         text[text.index("ENTRY"):])
+    assert len(arrived) == 2 and arrived[0][0] == arrived[1][0], arrived
+    layout = arrived[0][0]                     # K and V, one layout
+    assert not re.findall(rf"%[\w.-]+ = {pool}\S* copy\(", text)
+    order = layout.split(":")[0].rstrip("}") + "}"
+    for mention in set(re.findall(rf"{pool}(\{{[^}}]*\}})", text)):
+        assert mention in (layout, order), (mention, layout)
+    (alias,) = re.findall(r"input_output_alias=\{(.*?) \}, entry", text)
+    for _, number in arrived:
+        assert re.search(rf"\{{\d+\}}: \({number}, \{{\}}, may-alias\)",
+                         alias), (number, alias)
+
+
 def _flash(sq, sk, *, causal, segs, b=16, h=16, hk=4, grad=False):
     from apex_tpu.ops.attention import flash_attention
 
@@ -454,6 +477,7 @@ def test_trinity_cut_programs_fit_and_gather_the_window(chip, monkeypatch,
     assert names.count("attention_window") == 4
     assert names.count("attention") == 1
     assert names.count("ragged-dot-none") == 12       # 3 products x 4 layers
+    assert_pool_stays_where_it_lies(text, state.k.shape)
 
 
 @pytest.mark.parametrize("fn,batch,seq", [
@@ -518,3 +542,6 @@ def test_mellum2_cut_programs_fit_and_gather_the_tail(chip, monkeypatch,
     assert names.count("attention_window") == 6
     assert names.count("attention") == 2
     assert names.count("ragged-dot-none") == 24       # 3 products x 8 layers
+    # four KV heads fill half a bf16 tile: one scatter over all lanes
+    # had the pools converted whole, there and back, in every call
+    assert_pool_stays_where_it_lies(text, state.k.shape)
