@@ -2,22 +2,30 @@
 
 ``models/gpt.py`` is one block repeated (and scanned). The models this
 file serves are not: window and full attention alternate, a leading
-dense layer precedes expert layers. So the layers are data
-(``DecoderConfig.layers``: for each layer its attention kind and its
-MLP kind) and the model is unrolled over them.
+dense layer precedes expert layers, nine state-space layers stand
+beside one attention layer. So the layers are data
+(``DecoderConfig.layers``: for each layer the kind of its mixer and
+its MLP kind) and the model is unrolled over them.
 
 The block is data too (``DecoderConfig``), because the models differ
 in it (``docs/serving.md`` "Window layers", ``docs/moe.md`` "Held
 experts"):
 
-- the embedding scaled by ``sqrt(hidden)`` or not; an untied head;
+- the embedding scaled by ``sqrt(hidden)``, by a multiplier of the
+  model's own, or not; the head untied or the embedding again; the
+  logits divided by a constant or not; what each half of a layer adds
+  to the stream multiplied by a constant or not;
 - where the norms stand: *sandwich*, four RMSNorms a layer
   (``y = x + n2(Attn(n1(x)))``, ``x' = y + n4(MLP(n3(y)))``), or *pre*,
   two (``y = x + Attn(n1(x))``, ``x' = y + MLP(n2(y))``);
-- attention with ``head_dim`` of its own (not ``hidden / heads``),
-  GQA and QK-norm (an RMSNorm over the head; both blocks have it, so
-  it is no option); a sigmoid output gate or none; *window* layers see
-  the last ``window`` keys, *full* layers every key;
+- attention with ``head_dim`` of its own (not ``hidden / heads``) and
+  GQA; QK-norm (an RMSNorm over the head) or none; scores scaled by
+  ``head_dim^-0.5`` or by the model's own multiplier; a sigmoid output
+  gate or none; *window* layers see the last ``window`` keys, *full*
+  layers every key;
+- a *mamba* layer has no attention at all: its mixer is
+  ``models/ssm.py``'s Mamba-2, whose state a sequence is a fixed size
+  (``DecoderConfig.mamba``), not keys that grow with the context;
 - a rotary scheme *for each kind of layer* (:class:`Rotary`): none,
   plain, or YaRN's blend of scaled and unscaled frequencies with its
   factor on cos and sin;
@@ -30,12 +38,20 @@ window layers and none on the full ones, the sigmoid router.
 JetBrains' ``mellum`` (``benchmark/drivers/serve_mellum.py``) is
 pre-norm with no scale and no gate, plain rotary on its window layers
 and YaRN on its full ones, the softmax router over experts all held.
+IBM's ``granitemoehybrid`` (``benchmark/drivers/serve_granite.py``) is
+pre-norm, nine ``mamba`` layers and one full attention layer a period,
+no QK-norm and no rotary at all, scores times 1/128, multipliers on
+the embedding, the residuals and the logits, a tied head, the softmax
+router with a shared expert.
 
 It offers the serving hooks ``GPTModel`` offers (``positions``,
 ``kv_ctx``, ``return_kv``; ``config.kv_heads`` / ``head_dim`` /
 ``max_seq_len`` / ``num_layers`` / ``attention_window``), so
 ``serving.make_decode_step`` and ``ContinuousBatcher`` take it as they
-take a ``GPTModel``. Single device: no mesh annotations.
+take a ``GPTModel``; a model with ``mamba`` layers also takes
+``state_ctx``, its lanes' recurrent states (``config.num_kv_layers``
+is then fewer than ``num_layers``: the paged pool holds only the
+layers that HAVE keys). Single device: no mesh annotations.
 """
 
 from __future__ import annotations
@@ -50,11 +66,13 @@ import jax.numpy as jnp
 
 from apex_tpu.models.cached_attention import (
     cached_attention, first_context)
+from apex_tpu.models.ssm import Mamba2Config, Mamba2Mixer
 from apex_tpu.moe.held import HeldMoEConfig, HeldMoEMLP, gated_mlp
 from apex_tpu.ops.layer_norm import fused_rms_norm
 from apex_tpu.ops.rope import fused_apply_rotary_pos_emb_cached
 
 ATTENTION_KINDS = ("full", "window")
+MIXER_KINDS = ATTENTION_KINDS + ("mamba",)
 MLP_KINDS = ("dense", "experts")
 NORM_PLACEMENTS = ("sandwich", "pre")
 
@@ -106,7 +124,7 @@ class DecoderConfig:
     num_kv_heads: int
     head_dim: int
     max_seq_len: int
-    # one (attention kind, MLP kind) a layer
+    # one (mixer kind, MLP kind) a layer
     layers: Tuple[Tuple[str, str], ...]
     ffn_hidden_size: int                      # the dense MLP's width
     attention_window: Optional[int] = None    # of the "window" layers
@@ -129,21 +147,33 @@ class DecoderConfig:
     # have; None: plain at rope_theta on window layers, none on full
     rotary: Optional[Tuple[Tuple[str, Optional[Rotary]], ...]] = None
     router: str = "sigmoid"                   # one of moe.held.ROUTERS
+    qk_norm: bool = True                      # an RMSNorm over q's, k's head
+    attention_scale: Optional[float] = None   # None: head_dim ** -0.5
+    # x0 = E[tokens] times this, for a model that does not scale by
+    # sqrt(hidden): given only with embedding_scale=False
+    embedding_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0          # y = x + this * Mixer(...)
+    logits_divisor: float = 1.0
+    tied_head: bool = False                   # logits = n(x) E^T
+    # the "mamba" layers' own sizes (the mixer takes its hidden size,
+    # types, eps and impl from here where it is built); set exactly
+    # when there is such a layer
+    mamba: Optional[Mamba2Config] = None
 
     def __post_init__(self):
         if self.norms not in NORM_PLACEMENTS:
             raise ValueError(f"norms is one of {NORM_PLACEMENTS}, "
                              f"got {self.norms!r}")
         if self.rotary is not None:
-            kinds = {a for a, _ in self.layers}
+            kinds = {a for a, _ in self.layers if a != "mamba"}
             if {k for k, _ in self.rotary} != kinds:
                 raise ValueError(
                     f"rotary names each kind of layer once ({sorted(kinds)}"
                     f"), got {[k for k, _ in self.rotary]}")
         for attention, mlp in self.layers:
-            if attention not in ATTENTION_KINDS or mlp not in MLP_KINDS:
+            if attention not in MIXER_KINDS or mlp not in MLP_KINDS:
                 raise ValueError(
-                    f"a layer is (one of {ATTENTION_KINDS}, one of "
+                    f"a layer is (one of {MIXER_KINDS}, one of "
                     f"{MLP_KINDS}), got {(attention, mlp)}")
         if self.num_heads % self.num_kv_heads:
             raise ValueError(f"num_kv_heads ({self.num_kv_heads}) must "
@@ -154,10 +184,46 @@ class DecoderConfig:
                              "layer is a window layer")
         if any(m == "experts" for _, m in self.layers):
             self.moe_cfg()                    # validates the expert sizes
+        if (self.mamba is not None) != bool(self.state_layers):
+            raise ValueError("mamba is set exactly when some layer is a "
+                             "mamba layer")
+        if self.embedding_scale and self.embedding_multiplier is not None:
+            raise ValueError(
+                "embedding_scale (x0 = E[tokens] sqrt(hidden)) and "
+                f"embedding_multiplier ({self.embedding_multiplier}) both "
+                "say what the embedding is scaled by: give one")
 
     @property
     def num_layers(self) -> int:
         return len(self.layers)
+
+    @property
+    def kv_layers(self) -> Tuple[int, ...]:
+        """The layers that have keys, in order: layer ``kv_layers[j]``
+        is layer ``j`` of the paged pool."""
+        return tuple(i for i, (a, _) in enumerate(self.layers)
+                     if a != "mamba")
+
+    @property
+    def num_kv_layers(self) -> int:
+        return len(self.kv_layers)
+
+    @property
+    def state_layers(self) -> Tuple[int, ...]:
+        """The layers that hold a recurrent state, in order: layer
+        ``state_layers[j]`` is layer ``j`` of a state slot."""
+        return tuple(i for i, (a, _) in enumerate(self.layers)
+                     if a == "mamba")
+
+    def state_shapes(self):
+        """What a sequence holds beside its K/V, a state slot:
+        ``((shape, dtype), ...)`` with the state layers leading, or
+        ``()`` for a model whose layers all have keys."""
+        if self.mamba is None:
+            return ()
+        n = len(self.state_layers)
+        return tuple(((n, *shape), dtype)
+                     for shape, dtype in self.mamba.state_shapes(self.dtype))
 
     @property
     def kv_heads(self) -> int:
@@ -181,6 +247,8 @@ class DecoderConfig:
 def _flash(cfg: DecoderConfig, *args, **kw):
     from apex_tpu.ops.attention import flash_attention
 
+    if cfg.attention_scale is not None:
+        kw["softmax_scale"] = cfg.attention_scale
     return flash_attention(*args, impl=cfg.softmax_impl, **kw)
 
 
@@ -247,8 +315,12 @@ class DecoderAttention(nn.Module):
                        cfg.param_dtype)
         q, k, v, *g = jnp.split(jnp.dot(x, w.astype(cfg.dtype)),
                                 cuts if gated else cuts[:2], axis=-1)
-        q = RMSNorm(cfg.rms_eps, name="q_norm")(q.reshape(b, s, nh, d))
-        k = RMSNorm(cfg.rms_eps, name="k_norm")(k.reshape(b, s, nkv, d))
+        q = q.reshape(b, s, nh, d)
+        if cfg.qk_norm:
+            q = RMSNorm(cfg.rms_eps, name="q_norm")(q)
+        k = k.reshape(b, s, nkv, d)
+        if cfg.qk_norm:
+            k = RMSNorm(cfg.rms_eps, name="k_norm")(k)
         v = v.reshape(b, s, nkv, d)
         rope = cfg.rotary_of(self.kind)
         if rope is not None:
@@ -292,25 +364,50 @@ class DenseMLP(nn.Module):
 
 class DecoderLayer(nn.Module):
     config: DecoderConfig
-    attention: str
+    attention: str                 # the mixer's kind, one of MIXER_KINDS
     mlp: str
 
     @nn.compact
-    def __call__(self, x, positions, *, kv_ctx=None):
+    def __call__(self, x, positions, *, kv_ctx=None, state_ctx=None,
+                 lengths=None):
+        """One layer. An attention layer returns ``(x', (k, v),
+        held)`` as ``DecoderAttention`` gives them; a ``mamba`` layer
+        ``(x', the state pools, ())``: ``state_ctx`` and ``lengths``
+        are ``ssm.Mamba2Mixer``'s (None: every lane from zeros, and
+        None comes back)."""
         cfg = self.config
         norm = lambda name: RMSNorm(cfg.rms_eps, name=name)  # noqa: E731
         # a sandwich also norms what each half adds to the stream
         after = norm if cfg.norms == "sandwich" else (
             lambda name: lambda t: t)
-        a, kv, held = DecoderAttention(cfg, self.attention, name="attention")(
-            norm("input_norm")(x), positions, kv_ctx=kv_ctx)
-        y = x + after("post_attention_norm")(a)
+        if cfg.residual_multiplier != 1.0:
+            # in float32: 0.22 is no bf16 number
+            scaled = lambda t: (  # noqa: E731
+                t.astype(jnp.float32) * cfg.residual_multiplier
+            ).astype(t.dtype)
+        else:
+            scaled = lambda t: t  # noqa: E731
+        if self.attention == "mamba":
+            with jax.named_scope("mamba_mixer"):
+                a, kv = Mamba2Mixer(
+                    cfg.mamba, hidden_size=cfg.hidden_size,
+                    rms_eps=cfg.rms_eps, dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype, impl=cfg.softmax_impl,
+                    name="mixer")(
+                    norm("input_norm")(x), state_ctx=state_ctx,
+                    lengths=lengths)
+            held = ()
+        else:
+            a, kv, held = DecoderAttention(
+                cfg, self.attention, name="attention")(
+                norm("input_norm")(x), positions, kv_ctx=kv_ctx)
+        y = x + scaled(after("post_attention_norm")(a))
         m = norm("pre_mlp_norm")(y)
         if self.mlp == "experts":
             m = HeldMoEMLP(cfg.moe_cfg(), name="mlp")(m)
         else:
             m = DenseMLP(cfg, name="mlp")(m)
-        return y + after("post_mlp_norm")(m), kv, held
+        return y + scaled(after("post_mlp_norm")(m)), kv, held
 
 
 class PatternDecoder(nn.Module):
@@ -321,21 +418,33 @@ class PatternDecoder(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, *, positions=None, kv_ctx=None,
-                 return_kv=False):
+                 state_ctx=None, return_kv=False):
         """``positions`` (b, s) or (s,): positions in the sequence
         (default ``arange(s)``); they turn the rotary embeddings and
         nothing else. ``kv_ctx = (k_pool, v_pool, tables,
         ctx_lens[, win])`` runs the cached paths
         (``models/cached_attention.py``); ``return_kv=True`` also
-        returns this call's K and V, each stacked (num_layers, b,
-        kv_heads, s, head_dim)."""
+        returns this call's K and V, each stacked (num_kv_layers, b,
+        kv_heads, s, head_dim): the layers that have keys, in order.
+
+        ``state_ctx = (pools, slots, lengths, fresh)``, for a model
+        with ``mamba`` layers: the state pools whole (``(slots + 1,
+        state layers, ...)`` each: ``serving/kv_cache.py``), each
+        lane's slot (b,), its real rows (b,) (None: all ``s``) and
+        whether it starts its sequence here (b,) bool: from zeros
+        then, whatever its slot held. Each such layer reads its lanes'
+        state out of the pools and writes the new one back; the pools
+        are then returned last. Without it every sequence starts from
+        zeros and its state is dropped."""
         cfg = self.config
         b, s = tokens.shape
         init = nn.initializers.normal(stddev=0.02)
         table = self.param("embedding", init,
                            (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
         x = table[tokens]
-        if cfg.embedding_scale:
+        if cfg.embedding_multiplier is not None:
+            x = x.astype(jnp.float32) * cfg.embedding_multiplier
+        elif cfg.embedding_scale:
             x = x.astype(jnp.float32) * (cfg.hidden_size ** 0.5)
         x = x.astype(cfg.dtype)
         if positions is None:
@@ -349,23 +458,40 @@ class PatternDecoder(nn.Module):
             windows = {"full": None, "window": cfg.attention_window}
             into = {kind: first_context(kv_ctx, window=windows[kind],
                                         zero=lambda *a: _zero_ctx(cfg, *a))
-                    for kind in dict.fromkeys(a for a, _ in cfg.layers)}
+                    for kind in dict.fromkeys(a for a, _ in cfg.layers)
+                    if kind != "mamba"}
+        pools = slots = lengths = fresh = None
+        if state_ctx is not None:
+            pools, slots, lengths, fresh = state_ctx
         for i, (attention, mlp) in enumerate(cfg.layers):
-            x, kv, into[attention] = DecoderLayer(
-                cfg, attention, mlp, name=f"layer_{i}")(
+            layer = DecoderLayer(cfg, attention, mlp, name=f"layer_{i}")
+            if attention == "mamba":
+                x, pools, _ = layer(
+                    x, positions, lengths=lengths,
+                    state_ctx=(None if pools is None else (
+                        pools, cfg.state_layers.index(i), slots, fresh)))
+                continue
+            # the layer's own layer of the paged pool: its place among
+            # the layers that have keys
+            x, kv, into[attention] = layer(
                 x, positions,
                 kv_ctx=(None if kv_ctx is None
-                        else (i, into[attention], *kv_ctx)))
+                        else (len(kvs), into[attention], *kv_ctx)))
             kvs.append(kv)
         x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
-        head = self.param("head", init, (cfg.vocab_size, cfg.hidden_size),
-                          cfg.param_dtype)
+        head = table if cfg.tied_head else self.param(
+            "head", init, (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
         logits = jnp.einsum("bsh,vh->sbv", x, head.astype(cfg.dtype),
                             preferred_element_type=jnp.float32)
+        if cfg.logits_divisor != 1.0:
+            logits = logits / cfg.logits_divisor
+        out = (logits,)
         if return_kv:
-            return logits, (jnp.stack([k for k, _ in kvs]),
-                            jnp.stack([v for _, v in kvs]))
-        return logits
+            out += ((jnp.stack([k for k, _ in kvs]),
+                     jnp.stack([v for _, v in kvs])),)
+        if state_ctx is not None:
+            out += (pools,)
+        return out if len(out) > 1 else logits
 
 
-__all__ = ["DecoderConfig", "PatternDecoder", "Rotary"]
+__all__ = ["DecoderConfig", "Mamba2Config", "PatternDecoder", "Rotary"]

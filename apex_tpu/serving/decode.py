@@ -3,7 +3,13 @@
 The serving analog of ``optimizers/train_step.py``: each step is ONE
 compiled program with the cache pools DONATED (``donate_argnums``), so
 a decode step appends K/V in place — the pool never holds two copies,
-and the hot loop allocates nothing. The per-shape compile cache is an
+and the hot loop allocates nothing. A model with recurrent layers
+(docs/serving.md "Recurrent state") has its state pools donated with
+them: each lane's ``state_slots`` entry names the slot its state is
+read from and written back to, and a lane that starts a sequence (a
+whole-prompt prefill, a chunk at position 0) starts from zeros whatever
+its slot held. Such an update is NOT idempotent as the K/V append is:
+no dispatch that has run may be replayed. The per-shape compile cache is an
 eviction-free dict keyed on the bucketed shapes:
 
 - decode: ``(batch_bucket, table_width)`` — the only dynamic shapes a
@@ -133,10 +139,13 @@ Layout = Tuple[Tuple[str, Tuple[int, ...]], ...]
 
 
 def packed_layout(fn: str, batch: int, table_width: int, seq: int = 1,
-                  window_table_width: Optional[int] = None) -> Layout:
+                  window_table_width: Optional[int] = None,
+                  state_slots: bool = False) -> Layout:
     """``(name, shape)`` of every field of program ``fn``'s one host
     argument, in the order they lie in the buffer: a function of the
-    program's key alone, the same with and without sampling arrays."""
+    program's key alone, the same with and without sampling arrays.
+    ``state_slots``: the model has recurrent layers, and each lane
+    names its state slot."""
     if fn not in _LANE_FIELDS:
         raise ValueError(f"unknown serving program {fn!r}")
     b = batch
@@ -146,6 +155,8 @@ def packed_layout(fn: str, batch: int, table_width: int, seq: int = 1,
     if window_table_width is not None:
         fields += [("window_tables", (b, window_table_width)),
                    ("window_first", (b,))]
+    if state_slots:
+        fields.append(("state_slots", (b,)))
     fields += [(name, (b,))
                for name in ("temps", "top_ks", "top_ps", "seeds")]
     return tuple(fields)
@@ -209,6 +220,23 @@ class DecodeStep:
                           "host_arrays_out": 0}
         cfg = model.config
         max_pos = cfg.max_seq_len - 1
+        # a model with recurrent layers: every dispatch names its
+        # lanes' state slots (read off the cache, like its pool)
+        self.recurrent = bool(cache.state_slots)
+
+        def apply(params, state, tokens, *, state_slots, lengths, fresh,
+                  **kw):
+            """The model over the cache: ``(logits, (k, v), state)``.
+            Where it has recurrent layers it is handed its lanes'
+            slots of the state pools and gives the pools back."""
+            if state_slots is None:
+                logits, kv = model.apply(params, tokens, return_kv=True,
+                                         **kw)
+                return logits, kv, state
+            logits, kv, pools = model.apply(
+                params, tokens, return_kv=True,
+                state_ctx=(state.state, state_slots, lengths, fresh), **kw)
+            return logits, kv, state._replace(state=pools)
 
         def tail(tables, first):
             # the window layers' tables, where the model has such
@@ -269,10 +297,11 @@ class DecodeStep:
                            jnp.stack([nxt, finite.astype(jnp.int32)]))
 
         def prefill_fn(params, state, *, tokens, lengths, tables, temps,
-                       top_ks, top_ps, seeds):
+                       top_ks, top_ps, seeds, state_slots=None):
             b, s = tokens.shape
-            logits, (k_new, v_new) = model.apply(
-                params, tokens, return_kv=True)
+            logits, (k_new, v_new), state = apply(
+                params, state, tokens, state_slots=state_slots,
+                lengths=lengths, fresh=jnp.ones((b,), bool))
             state = append_kv_prefill(state, k_new, v_new, tables, lengths)
             last = jnp.clip(lengths - 1, 0, s - 1)
             out = logits[last, jnp.arange(b)]          # (b, vocab)
@@ -283,7 +312,8 @@ class DecodeStep:
 
         def prefill_chunk_fn(params, state, *, tokens, starts, lengths,
                              tables, temps, top_ks, top_ps, seeds,
-                             window_tables=None, window_first=None):
+                             window_tables=None, window_first=None,
+                             state_slots=None):
             b, s = tokens.shape
             # the pools are read BEFORE the chunk's writes: each layer
             # gathers its own context, every previously-written
@@ -292,11 +322,11 @@ class DecodeStep:
             pos = jnp.clip(
                 starts[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :],
                 0, max_pos)
-            logits, (k_new, v_new) = model.apply(
-                params, tokens, positions=pos,
+            logits, (k_new, v_new), state = apply(
+                params, state, tokens, state_slots=state_slots,
+                lengths=lengths, fresh=starts == 0, positions=pos,
                 kv_ctx=(state.k, state.v, tables, starts,
-                        *tail(window_tables, window_first)),
-                return_kv=True)
+                        *tail(window_tables, window_first)))
             state = append_kv_chunk(state, k_new, v_new, tables, starts,
                                     lengths)
             last = jnp.clip(lengths - 1, 0, s - 1)
@@ -309,16 +339,17 @@ class DecodeStep:
 
         def decode_fn(params, state, *, tokens, positions, tables, temps,
                       top_ks, top_ps, seeds, window_tables=None,
-                      window_first=None):
+                      window_first=None, state_slots=None):
             pos2 = jnp.clip(positions, 0, max_pos)[:, None]   # (b, 1)
             # each layer gathers its own context from the pools and
             # attends with the token's K/V in slot positions[b] of it:
             # the slot append_kv writes below, which the table covers
-            logits, (k_new, v_new) = model.apply(
-                params, tokens[:, None], positions=pos2,
+            logits, (k_new, v_new), state = apply(
+                params, state, tokens[:, None], state_slots=state_slots,
+                lengths=None, fresh=jnp.zeros(tokens.shape, bool),
+                positions=pos2,
                 kv_ctx=(state.k, state.v, tables, positions,
-                        *tail(window_tables, window_first)),
-                return_kv=True)
+                        *tail(window_tables, window_first)))
             state = append_kv(state, k_new[:, :, :, 0], v_new[:, :, :, 0],
                               tables, positions)
             out = logits[0]                            # (b, vocab)
@@ -365,10 +396,12 @@ class DecodeStep:
                    kv_heads=self.cache.kv_heads,
                    head_dim=self.cache.head_dim,
                    num_layers=cfg.num_layers)
+        if self.recurrent:
+            sig.update(state_slots=self.cache.state_slots)
         return sig
 
     def _dispatch(self, fn: str, params, state, fields: Dict[str, Any],
-                  sampling, window=None) -> StepOut:
+                  sampling, window=None, slots=None) -> StepOut:
         """Pack ``fields`` (host arrays by name) with the sampling and
         window arrays into the program's one argument and run it. Hits
         are one dict lookup and never reach the compile tracker (the
@@ -382,6 +415,12 @@ class DecodeStep:
             win_tables, fields["window_first"] = window
             fields["window_tables"] = np.asarray(win_tables, np.int32)
             widths = (fields["window_tables"].shape[1],)
+        if self.recurrent:
+            if slots is None:
+                raise ValueError(
+                    f"{fn}: the model has recurrent layers, and the "
+                    "dispatch names no state slots (KVCache.slot_array)")
+            fields["state_slots"] = slots
         (fields["temps"], fields["top_ks"], fields["top_ps"],
          fields["seeds"]) = (greedy_sampling(b) if sampling is None
                              else sampling)
@@ -389,7 +428,8 @@ class DecodeStep:
         entry = self._compiled.get(key)
         new = entry is None
         if new:
-            layout = packed_layout(fn, b, width, *(seq or (1,)), *widths)
+            layout = packed_layout(fn, b, width, *(seq or (1,)),
+                                   *(widths or (None,)), self.recurrent)
             entry = (layout, self._program(fn, layout))
         layout, program = entry
         packed = pack(layout, fields)
@@ -440,7 +480,7 @@ class DecodeStep:
         import jax
 
         layout = packed_layout(fn, batch, table_width, seq,
-                               window_table_width)
+                               window_table_width, self.recurrent)
         return self._program(fn, layout).lower(
             params, state,
             jax.ShapeDtypeStruct((packed_size(layout),), np.int32))
@@ -448,7 +488,7 @@ class DecodeStep:
     # -- dispatchers ---------------------------------------------------------
 
     def prefill(self, params, state: KVCacheState, tokens, lengths,
-                tables, sampling=None) -> StepOut:
+                tables, sampling=None, slots=None) -> StepOut:
         """Run the full (right-padded) prompts, write their K/V into
         the pool, and return the LAST real token's logits — the first
         generated token's distribution — in one program.
@@ -457,16 +497,19 @@ class DecodeStep:
         ``tables`` (b, w) block tables (trash-padded); ``sampling``
         optional ``(temps, top_ks, top_ps, seeds)`` per-lane arrays
         (None = all-greedy). Dummy batch rows use length 0 and an
-        all-trash table.
+        all-trash table. ``slots`` (b,) are the lanes' state slots
+        (``KVCache.slot_array``; the trash slot for a dummy row) for a
+        model with recurrent layers, here and in the two below: a
+        prompt prefilled here starts from zeros whatever its slot held.
         """
         return self._dispatch(
             "prefill_step", params, state,
             {"tokens": tokens, "lengths": lengths, "tables": tables},
-            sampling)
+            sampling, slots=slots)
 
     def prefill_chunk(self, params, state: KVCacheState, tokens,
                       starts, lengths, tables,
-                      sampling=None, window=None) -> StepOut:
+                      sampling=None, window=None, slots=None) -> StepOut:
         """Resume prefill with one CHUNK per sequence: row ``i`` of
         lane ``b`` is the prompt token at global position
         ``starts[b] + i`` (``lengths[b]`` real rows, the rest pad).
@@ -477,15 +520,17 @@ class DecodeStep:
         One program, cache donated; the chunked-prefill hot path
         (docs/serving.md "Chunked prefill"). ``window`` is ``(tables,
         first)`` of ``KVCache.window_table_array`` for a model with
-        window layers (here and in :meth:`decode`).
+        window layers (here and in :meth:`decode`). A recurrent
+        model's pad rows (``>= lengths[b]``) do not advance its state,
+        and a chunk that starts at position 0 starts from zeros.
         """
         return self._dispatch(
             "prefill_chunk", params, state,
             {"tokens": tokens, "starts": starts, "lengths": lengths,
-             "tables": tables}, sampling, window)
+             "tables": tables}, sampling, window, slots)
 
     def decode(self, params, state: KVCacheState, tokens, positions,
-               tables, sampling=None, window=None) -> StepOut:
+               tables, sampling=None, window=None, slots=None) -> StepOut:
         """One token per sequence: gather each sequence's cache view,
         attend (single query, per-sequence length via the mask), emit
         logits + the selected next token, and append the new K/V at
@@ -500,7 +545,7 @@ class DecodeStep:
         return self._dispatch(
             "decode_step", params, state,
             {"tokens": tokens, "positions": positions, "tables": tables},
-            sampling, window)
+            sampling, window, slots)
 
 
 def make_decode_step(model, cache: KVCache) -> DecodeStep:

@@ -690,6 +690,9 @@ class FleetRouter:
         try:
             blocks, k, v = src.batcher.cache.export_blocks(
                 src.state, fl.seq_id, length=filled)
+            # a model with recurrent layers: the sequence's state slot
+            # travels with its blocks (None otherwise)
+            recurrent = src.batcher.cache.export_state(src.state, fl.seq_id)
         except Exception as e:  # noqa: BLE001 — keep the stream local
             handoffs.inc(outcome="export_error")
             self.handoff_stats["export_error"] += 1
@@ -734,7 +737,7 @@ class FleetRouter:
             dst.state = dst.batcher.install_prefilled(
                 dst.state, req, fl.generated, rk, rv,
                 t_submit=fl.t_submit, t_first=fl.t_first,
-                t_last=fl.t_last)
+                t_last=fl.t_last, recurrent=recurrent)
         except faults.EngineCrash as e:
             # the decode seat died mid-handoff: fence it NOW
             # (EngineCrash is on the give-up allowlist, so fencing is

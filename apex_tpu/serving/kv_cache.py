@@ -47,6 +47,23 @@ blocks a quarantined tenant dirtied are scrubbed before any reuse
 (the PR-9 NaN-scrub rule lifted to refcounted blocks: refcount zero →
 scrub → free list).
 
+Recurrent state (docs/serving.md "Recurrent state"): a model with
+state-space layers holds, beside the K/V of its attention layers, a
+state of FIXED size a sequence. The paged pool then has only the layers
+that HAVE keys (``num_layers`` here is the model's ``num_kv_layers``:
+the model maps its layer to the pool's), and beside it lies a pool of
+*state slots*,
+
+    state: (state_slots + 1, state layers, ...) an array of the state
+
+one slot a sequence, taken at :meth:`KVCache.allocate` /
+:meth:`KVCache.allocate_prefix`, returned at :meth:`KVCache.free`, the
+last one the trash slot of dummy lanes. ``KVCacheState.state`` holds
+those arrays and is ``None`` for a model without such layers (no leaf:
+its programs are what they were). A prompt of such a model takes NO
+prefix match and publishes nothing: a shared block says nothing of the
+state at its end.
+
 Reservation is staged: :meth:`KVCache.allocate_prefix` reserves only
 the span the caller names (a prefill chunk, or the full prompt +
 max_new span), and :meth:`KVCache.extend` grows the reservation
@@ -88,6 +105,10 @@ class KVCacheState(NamedTuple):
 
     k: Any    # (num_layers, num_blocks, block_size, kv_heads, head_dim)
     v: Any
+    # the recurrent state's pools, a tuple of (state_slots + 1, state
+    # layers, ...) arrays; None (no leaf) for a model all of whose
+    # layers have keys
+    state: Any = None
 
 
 class PrefixMatch(NamedTuple):
@@ -126,11 +147,18 @@ class KVCache:
 
     def __init__(self, num_layers: int, kv_heads: int, head_dim: int, *,
                  num_blocks: int, block_size: int = 16,
-                 dtype: Any = None):
+                 dtype: Any = None, state_slots: int = 0,
+                 state_shapes: Sequence[Tuple[Tuple[int, ...], Any]] = ()):
         if num_blocks < 1:
             raise ValueError(f"num_blocks must be >= 1, got {num_blocks}")
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
+        if bool(state_slots) != bool(state_shapes):
+            raise ValueError(
+                "state_slots and state_shapes go together: a model with "
+                "recurrent layers needs a slot a sequence, and no other "
+                f"model has any (got {state_slots} slots for "
+                f"{len(state_shapes)} state arrays)")
         import jax.numpy as jnp
 
         self.num_layers = int(num_layers)
@@ -157,23 +185,36 @@ class KVCache:
         self._dirty: Set[int] = set()
         self._pending_scrub: List[int] = []      # zero-ref dirty blocks
         self._fork_refs: Dict[Any, List[int]] = {}
+        # -- recurrent state (module docstring) ------------------------
+        self.state_slots = int(state_slots)
+        self.state_shapes = tuple((tuple(shape), dtype)
+                                  for shape, dtype in state_shapes)
+        self._free_slots: List[int] = list(range(self.state_slots - 1,
+                                                 -1, -1))
+        self._slots: Dict[Any, int] = {}
         self.prefix_hits = 0
         self.prefix_misses = 0
         self.prefix_tokens_saved = 0
 
     @classmethod
     def for_config(cls, cfg, *, num_blocks: int, block_size: int = 16,
-                   dtype: Any = None) -> "KVCache":
+                   dtype: Any = None, state_slots: int = 0) -> "KVCache":
         """Size the cache from a ``GPTConfig``-shaped model config:
         ``kv_heads`` (the GQA-narrowed count) x ``head_dim`` blocks —
         GQA pays GQA-sized blocks, never ``num_heads``-sized ones. The
         head size is the config's own ``head_dim`` where it has one
-        (it need not be ``hidden_size / num_heads``)."""
+        (it need not be ``hidden_size / num_heads``). The pool has the
+        layers that have keys (``num_kv_layers`` where the config
+        tells them apart), and ``state_slots`` slots of the config's
+        ``state_shapes()`` where it has recurrent layers."""
         head_dim = getattr(cfg, "head_dim", None)
-        return cls(cfg.num_layers, cfg.kv_heads,
+        shapes = cfg.state_shapes() if hasattr(cfg, "state_shapes") else ()
+        return cls(getattr(cfg, "num_kv_layers", cfg.num_layers),
+                   cfg.kv_heads,
                    head_dim or cfg.hidden_size // cfg.num_heads,
                    num_blocks=num_blocks, block_size=block_size,
-                   dtype=dtype if dtype is not None else cfg.dtype)
+                   dtype=dtype if dtype is not None else cfg.dtype,
+                   state_slots=state_slots, state_shapes=shapes)
 
     # -- pool ---------------------------------------------------------------
 
@@ -183,8 +224,24 @@ class KVCache:
 
         shape = (self.num_layers, self.num_blocks + 1, self.block_size,
                  self.kv_heads, self.head_dim)
+        state = tuple(jnp.zeros((self.state_slots + 1, *shape), dtype)
+                      for shape, dtype in self.state_shapes) or None
         return KVCacheState(k=jnp.zeros(shape, self.dtype),
-                            v=jnp.zeros(shape, self.dtype))
+                            v=jnp.zeros(shape, self.dtype), state=state)
+
+    def slot_bytes(self) -> int:
+        """Bytes of one sequence's recurrent state (0 without any)."""
+        import jax.numpy as jnp
+
+        return sum(int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+                   for shape, dtype in self.state_shapes)
+
+    def block_bytes(self) -> int:
+        """Bytes of one block, K and V, over the pool's layers."""
+        import jax.numpy as jnp
+
+        return (2 * self.num_layers * self.block_size * self.kv_heads
+                * self.head_dim * jnp.dtype(self.dtype).itemsize)
 
     def pool_bytes(self) -> int:
         import jax.numpy as jnp
@@ -219,6 +276,40 @@ class KVCache:
         with self._lock:
             return len(self._refs)
 
+    @property
+    def slots_in_use(self) -> int:
+        with self._lock:
+            return len(self._slots)
+
+    def _take_slot(self, seq_id) -> None:
+        """A state slot for ``seq_id``, where the model has recurrent
+        layers; before any block is taken, so a refusal leaks nothing.
+        Caller holds the lock."""
+        if not self.state_slots:
+            return
+        if not self._free_slots:
+            raise PoolExhausted(
+                f"state slots exhausted: sequence {seq_id!r} needs one, "
+                f"all {self.state_slots} are held",
+                needed=1, free=0, capacity=self.state_slots)
+        self._slots[seq_id] = self._free_slots.pop()
+
+    def _drop_slot(self, seq_id) -> None:
+        slot = self._slots.pop(seq_id, None)
+        if slot is not None:
+            self._free_slots.append(slot)
+
+    def slot_array(self, seq_ids: Sequence[Any],
+                   batch: Optional[int] = None) -> np.ndarray:
+        """The batch's state slots, ``(batch,)`` int32; dummy rows past
+        ``len(seq_ids)`` name the trash slot."""
+        b = len(seq_ids) if batch is None else int(batch)
+        out = np.full((b,), self.state_slots, np.int32)
+        with self._lock:
+            for i, sid in enumerate(seq_ids):
+                out[i] = self._slots[sid]
+        return out
+
     def _take_private(self, need: int, seq_id) -> List[int]:
         """Pop ``need`` fresh private blocks — free list first, then
         evict the LRU tail of the prefix cache. Caller holds the
@@ -252,7 +343,12 @@ class KVCache:
         with self._lock:
             if seq_id in self._tables:
                 raise ValueError(f"sequence {seq_id!r} already allocated")
-            blocks = self._take_private(need, seq_id)
+            self._take_slot(seq_id)
+            try:
+                blocks = self._take_private(need, seq_id)
+            except PoolExhausted:
+                self._drop_slot(seq_id)
+                raise
             self._tables[seq_id] = blocks
             return list(blocks)
 
@@ -274,13 +370,19 @@ class KVCache:
         token always prefills, so the first-token logits exist).
         Raises :class:`PoolExhausted` (leaking nothing) when the
         private remainder cannot be reserved.
+
+        A model with recurrent layers matches NOTHING, whatever is
+        published (and nothing is: :meth:`publish_prefix`): a shared
+        block holds keys, and says nothing of the state at its end.
+        The sequence takes its state slot here.
         """
-        prompt = tuple(int(t) for t in prompt)
         bs = self.block_size
+        if not self.state_slots:
+            prompt = tuple(int(t) for t in prompt)
         with self._lock:
             if seq_id in self._tables:
                 raise ValueError(f"sequence {seq_id!r} already allocated")
-            hashes = self._chain_hashes(prompt)
+            hashes = [] if self.state_slots else self._chain_hashes(prompt)
             max_full = (len(prompt) - 1) // bs
             shared: List[int] = []
             parent = b""
@@ -327,8 +429,10 @@ class KVCache:
             if fork_rows:
                 self._ref_locked(fork_src)   # pin src until the copy
             try:
+                self._take_slot(seq_id)
                 priv = self._take_private(need, seq_id)
             except PoolExhausted:
+                self._drop_slot(seq_id)
                 for blk in shared:           # leak nothing on refusal
                     self._unref_locked(blk, dirty=False)
                 if fork_rows:
@@ -384,6 +488,7 @@ class KVCache:
             blocks = self._tables.pop(seq_id, None)
             if blocks is None:
                 return 0
+            self._drop_slot(seq_id)
             for blk in self._fork_refs.pop(seq_id, []):
                 self._unref_locked(blk, dirty=False)
             for b in blocks:
@@ -444,7 +549,10 @@ class KVCache:
         the prefix index (later prompts with the same token blocks
         share them by reference); returns how many blocks were newly
         published. First publisher wins — blocks whose chain hash is
-        already indexed are left alone."""
+        already indexed are left alone. A model with recurrent layers
+        publishes nothing (:meth:`allocate_prefix`)."""
+        if self.state_slots:
+            return 0
         prompt = tuple(int(t) for t in prompt)
         bs = self.block_size
         published = 0
@@ -635,6 +743,33 @@ class KVCache:
         return (list(table), np.asarray(state.k[:, idx]),
                 np.asarray(state.v[:, idx]))
 
+    def export_state(self, state: KVCacheState, seq_id):
+        """A sequence's recurrent state to the host, for the same
+        handoff: a tuple of ``(state layers, ...)`` arrays, one a state
+        pool, or None where the model has no such layers."""
+        if not self.state_slots:
+            return None
+        slot = int(self.slot_array([seq_id])[0])
+        return tuple(np.asarray(pool[slot]) for pool in state.state)
+
+    def import_state(self, state: KVCacheState, seq_id,
+                     rows) -> KVCacheState:
+        """Install :meth:`export_state`'s rows into the slot of an
+        already-allocated ``seq_id``."""
+        import jax.numpy as jnp
+
+        if not self.state_slots:
+            return state
+        if rows is None or len(rows) != len(state.state):
+            raise ValueError(
+                f"import_state: sequence {seq_id!r} needs its recurrent "
+                f"state ({len(state.state)} arrays), the payload holds "
+                f"{0 if rows is None else len(rows)}")
+        slot = int(self.slot_array([seq_id])[0])
+        return state._replace(state=tuple(
+            pool.at[slot].set(jnp.asarray(r, pool.dtype))
+            for pool, r in zip(state.state, rows)))
+
     def import_blocks(self, state: KVCacheState, seq_id, k,
                       v) -> KVCacheState:
         """Install exported KV rows into THIS pool's blocks for an
@@ -655,7 +790,7 @@ class KVCache:
                 f"import_blocks: payload holds {n} blocks but sequence "
                 f"{seq_id!r} reserves only {len(table)}")
         idx = jnp.asarray(table[:n], jnp.int32)
-        return KVCacheState(
+        return state._replace(
             k=state.k.at[:, idx].set(jnp.asarray(k, state.k.dtype)),
             v=state.v.at[:, idx].set(jnp.asarray(v, state.v.dtype)))
 
@@ -723,14 +858,14 @@ def append_kv(state: KVCacheState, k_new, v_new, tables,
     blk = jnp.take_along_axis(
         tables, jnp.clip(positions[:, None] // bs, 0, w - 1), axis=1)[:, 0]
     slot = positions % bs
-    k, v = state
+    k, v = state.k, state.v
     for i in range(tables.shape[0]):
         at = (0, blk[i], slot[i], 0, 0)
         k = lax.dynamic_update_slice(
             k, k_new[:, i, None, None].astype(k.dtype), at)
         v = lax.dynamic_update_slice(
             v, v_new[:, i, None, None].astype(v.dtype), at)
-    return KVCacheState(k=k, v=v)
+    return state._replace(k=k, v=v)
 
 
 def append_kv_prefill(state: KVCacheState, k_new, v_new, tables,
@@ -806,7 +941,7 @@ def append_kv_chunk(state: KVCacheState, k_new, v_new, tables, starts,
         return lax.fori_loop(0, touched, turn, pools)
 
     k, v = lax.fori_loop(0, b, lane, (state.k, state.v))
-    return KVCacheState(k=k, v=v)
+    return state._replace(k=k, v=v)
 
 
 def apply_copies(state: KVCacheState,
@@ -821,7 +956,7 @@ def apply_copies(state: KVCacheState,
         rows = int(rows)
         k = k.at[:, int(dst), :rows].set(k[:, int(src), :rows])
         v = v.at[:, int(dst), :rows].set(v[:, int(src), :rows])
-    return KVCacheState(k=k, v=v)
+    return state._replace(k=k, v=v)
 
 
 def scrub_blocks(state: KVCacheState, blocks) -> KVCacheState:
@@ -832,8 +967,8 @@ def scrub_blocks(state: KVCacheState, blocks) -> KVCacheState:
     if len(blocks) == 0:
         return state
     b = jnp.asarray(sorted(int(x) for x in blocks), jnp.int32)
-    return KVCacheState(k=state.k.at[:, b].set(0),
-                        v=state.v.at[:, b].set(0))
+    return state._replace(k=state.k.at[:, b].set(0),
+                          v=state.v.at[:, b].set(0))
 
 
 __all__ = [

@@ -346,6 +346,14 @@ class ContinuousBatcher:
         # layers, and those of them that lie wholly behind a window
         # layer's window, which no later query reads (ROADMAP.md R3)
         self.held = {"block_layers": 0, "behind_window": 0}
+        if cache.state_slots:
+            # a model with recurrent layers (docs/serving.md "Recurrent
+            # state"), at the same steps' ends: the state slots in
+            # use, the bytes they hold, and the bytes of the K/V blocks
+            # the live sequences hold
+            self.held.update(state_slots=0, state_bytes=0, kv_bytes=0)
+            self._slot_bytes = cache.slot_bytes()
+            self._block_bytes = cache.block_bytes()
         # resilience plane (serving/resilience.py)
         self.preemption = preemption          # guard.PreemptionHandler
         self.snapshot_dir = snapshot_dir
@@ -621,7 +629,8 @@ class ContinuousBatcher:
                           generated: Sequence[int], k, v, *,
                           t_submit: float,
                           t_first: Optional[float] = None,
-                          t_last: Optional[float] = None):
+                          t_last: Optional[float] = None,
+                          recurrent=None):
         """Adopt a handed-off, prefill-complete request: reserve its
         FULL decode span (prompt + max_new — the can-never-die-
         mid-decode invariant holds from the first local step), install
@@ -631,7 +640,11 @@ class ContinuousBatcher:
         local prefix index, and join ``running`` directly — no queue,
         no prefill. ``t_submit``/``t_first``/``t_last`` carry the
         SOURCE engine's timestamps so TTFT/TPOT stay end-to-end
-        truthful. Raises :class:`PoolExhausted` (reserving nothing)
+        truthful. ``recurrent`` is the source's
+        ``KVCache.export_state`` for a model with recurrent layers,
+        installed into the sequence's fresh state slot (required
+        there: keys alone do not continue such a sequence). Raises
+        :class:`PoolExhausted` (reserving nothing)
         when the local pool cannot hold the span; returns the new
         device state. Engine-thread only, like ``step``."""
         total = len(req.prompt) + req.max_new_tokens
@@ -641,6 +654,7 @@ class ContinuousBatcher:
         self.cache.allocate(seq_id, total)
         try:
             state = self.cache.import_blocks(state, seq_id, k, v)
+            state = self.cache.import_state(state, seq_id, recurrent)
         except Exception:
             self.cache.free(seq_id)
             raise
@@ -1139,21 +1153,39 @@ class ContinuousBatcher:
         count("window", positions - first * bs, ww)
         return {"window": (tables, first)}
 
+    def _state_slots(self, seq_ids, batch: int) -> Dict[str, Any]:
+        """The ``slots=`` argument of a dispatch: the lanes' state
+        slots, where the model has recurrent layers (nothing
+        otherwise, and the dispatch is what it always was); a dummy
+        lane names the trash slot."""
+        if not self.cache.state_slots:
+            return {}
+        return {"slots": self.cache.slot_array(seq_ids, batch=batch)}
+
     def _count_held(self) -> None:
         """Add this step's end to ``held``, from the sequences' lengths,
         the window and the layer pattern alone: a sequence whose next
         query stands at position ``t`` reads, in a window layer, no key
         before ``t - window + 1``, so the blocks that end there or
-        earlier are held for nothing in each such layer."""
+        earlier are held for nothing in each such layer. Where the
+        model has recurrent layers, also the slots in use and what
+        slots and blocks hold in bytes (host arithmetic: no device
+        call)."""
         bs, window = self.cache.block_size, self.attention_window
         nexts = ([(f, f.position) for f in self.running]
                  + [(f, f.prefilled) for f in self.prefilling])
+        held = 0
         for f, t in nexts:
             blocks = len(self.cache.table(f.seq_id))
-            self.held["block_layers"] += blocks * self.cache.num_layers
+            held += blocks
             if window is not None:
                 self.held["behind_window"] += self._window_layers * min(
                     blocks, max(0, t - window + 1) // bs)
+        self.held["block_layers"] += held * self.cache.num_layers
+        if self.cache.state_slots:
+            self.held["state_slots"] += len(nexts)
+            self.held["state_bytes"] += len(nexts) * self._slot_bytes
+            self.held["kv_bytes"] += held * self._block_bytes
 
     def _tables_for(self, flights: List[_InFlight], batch: int):
         widths = [len(self.cache.table(f.seq_id)) for f in flights]
@@ -1194,12 +1226,13 @@ class ContinuousBatcher:
                     lengths[i] = len(f.req.prompt)
                 tables = self._tables_for(admitted, b)
                 sampling = self._sampling_for(admitted, b)
+                slots = self._state_slots([f.seq_id for f in admitted], b)
             t0 = self.clock()
             with self._ring_dispatch("prefill"):
                 with self._span("apex.serve.prefill.dispatch"):
                     out = self.step_fn.prefill(
                         self.params, state, tokens, lengths, tables,
-                        sampling=sampling)
+                        sampling=sampling, **slots)
                 with self._span("apex.serve.prefill.wait"):
                     host = host_tokens(out)
             now = self.clock()
@@ -1247,6 +1280,7 @@ class ContinuousBatcher:
                 seqs = [f.seq_id for f, _ in batchees]
                 tables = self.cache.table_array(seqs, width, batch=b)
                 window = self._window_tables(seqs, starts, width, b)
+                window.update(self._state_slots(seqs, b))
                 sampling = self._sampling_for([f for f, _ in batchees], b)
             with self._ring_dispatch("prefill_chunk"):
                 with self._span("apex.serve.chunk.dispatch"):
@@ -1438,6 +1472,7 @@ class ContinuousBatcher:
                 seqs = [f.seq_id for f in flights]
                 tables = self.cache.table_array(seqs, width, batch=b)
                 window = self._window_tables(seqs, positions, width, b)
+                window.update(self._state_slots(seqs, b))
                 sampling = self._sampling_for(flights, b)
             with self._ring_dispatch("decode"):
                 with self._span("apex.serve.decode.dispatch"):
@@ -1462,8 +1497,12 @@ class ContinuousBatcher:
         A dispatch exception triggers the binary split (the watchdog's
         localization idiom on the batch axis): each half retries as its
         own dispatch — the fault sites raise BEFORE the jitted call, so
-        the donated cache state is still live — and offenders bottom
-        out as singletons. Nonfinite logits need no split: the in-jit
+        the donated cache state is still live and, for a model with
+        recurrent layers, NO lane's state has been advanced: the state
+        update is not idempotent as the K/V append is, so nothing here
+        may replay a dispatch that has run (a lane the failed dispatch
+        never reached is advanced once, by its half's retry) — and
+        offenders bottom out as singletons. Nonfinite logits need no split: the in-jit
         per-lane finite flag names them directly."""
         try:
             state, ids, finite, now = self._decode_batch(

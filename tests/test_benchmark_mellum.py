@@ -193,11 +193,14 @@ def test_configuration_keeps_every_published_width():
 
 def test_the_manifest_only_gained_entries():
     manifest = load("BENCHMARK.json")
-    assert manifest["workloads"][-1] == {
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
+    assert cell == {
         "name": CELL, "config": "mellum2-12b-a2.5b",
-        "traffic": "serve-codechat", "chips": 1,
-        "why": manifest["workloads"][-1]["why"]}
-    assert manifest["configs"][-1]["name"] == "mellum2-12b-a2.5b"
+        "traffic": "serve-codechat", "chips": 1, "why": cell["why"]}
+    # the fourth configuration and the sixth cell: later PRs append
+    assert manifest["configs"][3]["name"] == "mellum2-12b-a2.5b"
+    assert manifest["workloads"][5] == cell
     assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
     reported = {m["name"] for m in manifest["end_to_end"]
                 if "workloads" not in m or CELL in m["workloads"]}
@@ -218,17 +221,18 @@ def test_the_manifest_only_gained_entries():
     for name in per_layer:
         assert os.path.exists(os.path.join(
             ROOT, "benchmark", "layer_metrics", name + ".json")), name
-    for m in manifest["per_layer"][-5:-1]:
-        assert m["workloads"] == [CELL]
-    assert manifest["per_layer"][-1] == {
+    for name in per_layer[-5:-1]:
+        assert by_name[name]["workloads"] == [CELL]
+    # a later cell's name is appended to a list, nothing else changes
+    relayout = by_name["pool_relayout_pct"]
+    assert relayout["workloads"][:2] == [
+        "trinity-large-preview.serve-longmix", CELL]
+    assert {k: v for k, v in relayout.items() if k != "workloads"} == {
         "name": "pool_relayout_pct", "unit": "%", "better": "lower",
-        "source": "device_trace", "layer": "Server", "moves": "serve_tok_s",
-        "workloads": ["trinity-large-preview.serve-longmix", CELL]}
+        "source": "device_trace", "layer": "Server", "moves": "serve_tok_s"}
     # the cells pool_copy_pct lists are the two this one does not
-    copy = next(m for m in manifest["per_layer"]
-                if m["name"] == "pool_copy_pct")
-    assert not set(copy["workloads"]) & set(
-        manifest["per_layer"][-1]["workloads"])
+    assert not set(by_name["pool_copy_pct"]["workloads"]) & set(
+        relayout["workloads"])
 
 
 POOL = "bf16[8,9217,16,4,128]"

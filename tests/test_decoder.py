@@ -13,7 +13,13 @@ parametrised over them:
   softmax router, window, window, window, full; plain rotary on the
   window layers and YaRN on the full one, trained (so the toy says) at
   16 positions, so that the scaled and the unscaled frequencies are
-  both in play within 40 tokens.
+  both in play within 40 tokens;
+- ``granite`` (``models/decoder_reference_granite.py``): pre-norm,
+  mamba, mamba, full, mamba (8 heads of 16 with a state of 16, blocks
+  of 8 rows in the chunked form), no QK-norm, no rotary, scores times
+  1/32, multipliers 12 / 0.22 / 16, a tied head, 16 experts top-4 with
+  8 held beside a shared MLP under the softmax router; beside its K/V
+  (ONE layer of the pool) a sequence holds a state slot.
 """
 
 import dataclasses
@@ -26,8 +32,11 @@ import pytest
 
 from apex_tpu import serving
 from apex_tpu.models import decoder_reference as ref
+from apex_tpu.models import decoder_reference_granite as gref
 from apex_tpu.models import decoder_reference_mellum as mref
-from apex_tpu.models.decoder import DecoderConfig, PatternDecoder, Rotary
+from apex_tpu.models import ssm
+from apex_tpu.models.decoder import (DecoderConfig, Mamba2Config,
+                                     PatternDecoder, Rotary)
 from apex_tpu.moe.held import (HeldMoEConfig, HeldMoEMLP, sigmoid_router,
                                softmax_router)
 
@@ -76,11 +85,42 @@ def mellum_arch(held=None, layers=MELLUM_LAYERS):
                      yarn=YARN, held=held)
 
 
+GRANITE_LAYERS = (("mamba", "experts"), ("mamba", "experts"),
+                  ("full", "experts"), ("mamba", "experts"))
+GRANITE_HELD = (4, 8)
+
+
+def granite_config(dtype=jnp.float32, held=GRANITE_HELD,
+                   layers=GRANITE_LAYERS):
+    return DecoderConfig(
+        vocab_size=VOCAB, hidden_size=64, num_heads=4, num_kv_heads=2,
+        head_dim=32, max_seq_len=128, layers=layers, ffn_hidden_size=0,
+        expert_ffn_size=48, num_experts=EXPERTS, experts_per_token=4,
+        held_experts=held, shared_ffn_size=48, dtype=dtype,
+        param_dtype=jnp.float32, norms="pre", output_gate=False,
+        router="softmax", qk_norm=False, attention_scale=1 / 32,
+        embedding_scale=False, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_divisor=16.0, tied_head=True,
+        mamba=Mamba2Config(num_heads=8, head_dim=16, state_size=16,
+                           chunk=8))
+
+
+def granite_arch(held=GRANITE_HELD, layers=GRANITE_LAYERS):
+    return gref.Arch(
+        4, 2, 32, tuple("mamba" if a == "mamba" else "attention"
+                        for a, _ in layers),
+        mamba_heads=8, mamba_head_dim=16, mamba_state=16, top_k=4,
+        attention_multiplier=1 / 32, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=16.0, held=held)
+
+
 # block -> (the program's configuration, the reference's, the
 # reference, the name the layer leaves its scores under)
 BLOCKS = {"afmoe": (config, arch, ref, "biased"),
-          "mellum": (mellum_config, mellum_arch, mref, "probs")}
-both_blocks = pytest.mark.parametrize("block", list(BLOCKS))
+          "mellum": (mellum_config, mellum_arch, mref, "probs"),
+          "granite": (granite_config, granite_arch, gref, "probs")}
+both_blocks = pytest.mark.parametrize("block", ["afmoe", "mellum"])
+all_blocks = pytest.mark.parametrize("block", list(BLOCKS))
 
 
 def seeded(shapes, seed=0):
@@ -91,9 +131,18 @@ def seeded(shapes, seed=0):
         key = jax.random.fold_in(jax.random.PRNGKey(seed),
                                  zlib.crc32(name.encode()) % 2**31)
         draw = jax.random.normal(key, x.shape, jnp.float32)
-        if name.endswith("scale"):
+        if name.endswith(("scale", "mixer/norm", "mixer/D")):
             return 1.0 + 0.1 * draw
         if name.endswith("select_bias"):
+            return 0.3 * draw
+        # the state-space layers' own: Mamba-2's published draws (a
+        # state that neither dies in a few tokens nor never forgets),
+        # a convolution whose kernel and bias both matter
+        if name.endswith("A_log"):
+            return ssm.a_log_init(key, x.shape)
+        if name.endswith("dt_bias"):
+            return ssm.dt_bias_init(key, x.shape)
+        if name.endswith(("conv_w", "conv_b")):
             return 0.3 * draw
         return (0.4 if name.endswith("router") else 0.06) * draw
     return jax.tree_util.tree_map_with_path(leaf, shapes)
@@ -125,7 +174,7 @@ def tokens(n, seed=0):
     return np.random.default_rng(seed).integers(0, VOCAB, n).astype(np.int32)
 
 
-@both_blocks
+@all_blocks
 def test_full_forward_matches_the_reference(built, block):
     """Float32 against float32: what is left is the order of the sums
     (2e-5 of the largest logit; 1e-6 is what it reads)."""
@@ -137,7 +186,7 @@ def test_full_forward_matches_the_reference(built, block):
                                       make_arch(), row_block=16)
     assert float(jnp.abs(got - want).max()) < 2e-5 * float(
         jnp.abs(want).max())
-    if block == "afmoe":
+    if block in ("afmoe", "granite"):
         # the cut really leaves experts out, and really keeps some
         assert all(0 < f["held_pairs"] < f["pairs"] for f in routing)
     else:
@@ -147,7 +196,9 @@ def test_full_forward_matches_the_reference(built, block):
 
 
 def _engine(model, params, cfg, **kw):
-    cache = serving.KVCache.for_config(cfg, num_blocks=96, block_size=BLOCK)
+    cache = serving.KVCache.for_config(
+        cfg, num_blocks=96, block_size=BLOCK,
+        state_slots=4 if getattr(cfg, "mamba", None) is not None else 0)
     engine = serving.ContinuousBatcher(
         model, params, cache, max_batch=4, min_width_bucket=2,
         min_seq_bucket=4, **kw)
@@ -166,7 +217,7 @@ def _serve(engine, cache, requests):
     return done
 
 
-@both_blocks
+@all_blocks
 @pytest.mark.parametrize("chunk", [None, 8, 5])
 def test_served_through_the_cache_matches_the_reference(built, block, chunk):
     """Prefill (whole, or in chunks that cross the window's edge at 8)
@@ -186,9 +237,17 @@ def test_served_through_the_cache_matches_the_reference(built, block, chunk):
                                ulps=0.01, dtype_eps=BF16_EPS)
         assert out["ok"] and out["exact"] == r.max_new_tokens, (r.id, out)
     assert cache.blocks_in_use == 0
+    keys = sorted(engine.step_fn._compiled)
+    if block == "granite":
+        # no window layer, no second table; every slot came back, and
+        # the pool has the ONE layer that has keys
+        assert engine.gathered["window"] == 0 < engine.gathered["full"]
+        assert all(len(k) == (3 if k[0] == "decode_step" else 4)
+                   for k in keys), keys
+        assert cache.slots_in_use == 0 and cache.num_layers == 1
+        return
     # window layers gathered less than the full layer did
     assert 0 < engine.gathered["window"] < engine.gathered["full"]
-    keys = sorted(engine.step_fn._compiled)
     assert all(len(k) == (4 if k[0] == "decode_step" else 5)
                for k in keys if k[0] != "prefill_step"), keys
 
@@ -679,7 +738,7 @@ def served(built):
 LIMITS = dict(ulps=4.0, band=1e-5, slack=0.0, pad_to=1, dtype_eps=BF16_EPS)
 
 
-@both_blocks
+@all_blocks
 def test_the_program_leaves_its_choice_only_when_asked(built, block):
     model, params = built(block)
     scores, k = BLOCKS[block][3], model.config.experts_per_token
@@ -739,7 +798,7 @@ def test_held_margin_watches_the_held_experts_only():
     assert ref.held_margin(biased, 3, (0, 6)) == pytest.approx(0.01)
 
 
-@both_blocks
+@all_blocks
 def test_what_was_served_passes_given_the_programs_choice(served, built,
                                                           block):
     _, params = built(block)
@@ -798,3 +857,415 @@ def test_the_comparison_refuses_a_wrong_model(built, served, block, fault):
     passed = [v["ok"] for v in verdicts]
     assert not (all(passed) if fault == "bf16_router" else any(passed)), [
         (v["worst_ulps"], v["refused"]) for v in verdicts]
+
+
+# -- the state-space layers: a recurrent state beside the paged K/V --------
+
+def _random_scan_inputs(b=2, s=24, H=4, P=8, N=8, seed=0):
+    rng = np.random.default_rng(seed)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731,E501
+    dt = jax.nn.softplus(f(b, s, H) - 2.0)
+    return dict(S=0.5 * f(b, H, P, N), x=f(b, s, H, P), dt=dt,
+                A=-jnp.exp(f(H)), B=f(b, s, N), C=f(b, s, N),
+                D=1.0 + 0.1 * f(H))
+
+
+@pytest.mark.parametrize("chunk", [4, 8, 32])
+def test_the_chunked_form_is_the_step_form_is_the_scan(chunk):
+    """One recurrence three ways, from a state that is not zero: the
+    chunked form (blocks of 4 and 8 rows of 24, and one block wider
+    than the rows), the one-step form a token at a time, and a plain
+    scan over tokens written out here. Float32 all: 1e-5 is the order
+    of the sums (the chunked form adds a block's products at once)."""
+    t = _random_scan_inputs()
+    y_chunked, S_chunked = ssm.ssm_scan(
+        t["S"], t["x"], t["dt"], t["A"], t["B"], t["C"], t["D"],
+        chunk=chunk, dtype=jnp.float32)
+    S, ys = t["S"], []
+    for i in range(t["x"].shape[1]):
+        y, S = ssm.ssm_step(S, t["x"][:, i], t["dt"][:, i], t["A"],
+                            t["B"][:, i], t["C"][:, i], t["D"])
+        ys.append(y)
+
+    def token(S, r):
+        x, dt, B, C = r
+        S = (jnp.exp(dt * t["A"])[:, :, None, None] * S
+             + jnp.einsum("bh,bhp,bn->bhpn", dt, x, B))
+        return S, jnp.einsum("bhpn,bn->bhp", S, C) + t["D"][:, None] * x
+
+    S_plain, y_plain = jax.lax.scan(token, t["S"], tuple(
+        jnp.moveaxis(t[k], 1, 0) for k in ("x", "dt", "B", "C")))
+    for got in (y_chunked, jnp.stack(ys, 1)):
+        np.testing.assert_allclose(np.asarray(got),
+                                   np.asarray(jnp.moveaxis(y_plain, 0, 1)),
+                                   atol=1e-5, rtol=1e-5)
+    for got in (S_chunked, S):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(S_plain),
+                                   atol=1e-5, rtol=1e-5)
+
+
+def test_pad_rows_stand_still_and_the_convolution_keeps_real_rows():
+    """A chunk of 16 rows of which a lane has 5 and 11 real (``d_t`` 0
+    on the pads): the state after it is the state after the real rows
+    alone, and the rows the convolution carries on are the last three
+    REAL rows, whatever the pads held."""
+    t = _random_scan_inputs(s=16)
+    lengths = jnp.array([5, 11])
+    real = jnp.arange(16)[None, :] < lengths[:, None]
+    dt = jnp.where(real[..., None], t["dt"], 0.0)
+    _, S_padded = ssm.ssm_scan(t["S"], t["x"], dt, t["A"], t["B"], t["C"],
+                               t["D"], chunk=8, dtype=jnp.float32)
+    for lane, n in enumerate([5, 11]):
+        _, S_real = ssm.ssm_scan(*(
+            t[k][lane:lane + 1, :n] if k in ("x", "dt", "B", "C")
+            else (t[k][lane:lane + 1] if k == "S" else t[k])
+            for k in ("S", "x", "dt", "A", "B", "C", "D")),
+            chunk=8, dtype=jnp.float32)
+        np.testing.assert_allclose(np.asarray(S_padded[lane]),
+                                   np.asarray(S_real[0]), atol=1e-6)
+    rng = np.random.default_rng(1)
+    rows = jnp.asarray(rng.normal(size=(2, 3, 6)), jnp.float32)
+    c = jnp.asarray(rng.normal(size=(2, 16, 6)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(6, 4)), jnp.float32)
+    out, carried = ssm.conv_rows(rows, c, w, jnp.zeros(6), lengths)
+    np.testing.assert_array_equal(np.asarray(carried[0]),
+                                  np.asarray(c[0, 2:5]))
+    np.testing.assert_array_equal(np.asarray(carried[1]),
+                                  np.asarray(c[1, 8:11]))
+    # a lane with no real row (a dummy) keeps what it carried
+    _, kept = ssm.conv_rows(rows, c, w, jnp.zeros(6), jnp.array([0, 2]))
+    np.testing.assert_array_equal(np.asarray(kept[0]), np.asarray(rows[0]))
+    np.testing.assert_array_equal(
+        np.asarray(kept[1]),
+        np.asarray(jnp.concatenate([rows[1, 2:], c[1, :2]])))
+    # and the one-step form is the chunked form's row
+    step, _ = ssm.conv_step(rows, c[:, 0], w, jnp.zeros(6))
+    np.testing.assert_allclose(np.asarray(step), np.asarray(out[:, 0]),
+                               rtol=1e-6, atol=1e-6)
+
+
+class _WrongProgram:
+    """The model with its lanes' state handed over wrongly: every row
+    of a chunk taken for real (``pads_advance``), or no lane ever
+    restarted from zeros (``slot_not_restarted``)."""
+
+    def __init__(self, model, how):
+        self.model, self.how, self.config = model, how, model.config
+
+    def apply(self, params, tokens, **kw):
+        pools, slots, lengths, fresh = kw["state_ctx"]
+        if self.how == "pads_advance":
+            lengths = None
+        if self.how == "slot_not_restarted":
+            fresh = jnp.zeros_like(fresh)
+        return self.model.apply(params, tokens, **{
+            **kw, "state_ctx": (pools, slots, lengths, fresh)})
+
+
+def _through_the_slots(model, params, cfg, sequences, *, wrong=None):
+    """Two sequences through ``DecodeStep`` as the engine drives it,
+    logits kept: ``a`` is prefilled whole (13 of 16 rows real), ``b``
+    in chunks of unequal real lengths with pad rows (7 of 8, 8 of 8, 4
+    of 8), both then decode four steps in one batch and SWAP LANES
+    between steps; ``a`` ends, and ``c`` takes its slot as ``a`` left
+    it, prefilled in one padded chunk beside ``b``'s decode. Returns
+    ``{name: (positions, logits)}``: every row whose logits the
+    programs gave, against the position in the sequence it stands at."""
+    cache = serving.KVCache.for_config(cfg, num_blocks=64, block_size=BLOCK,
+                                       state_slots=2)
+    step = serving.make_decode_step(
+        model if wrong is None else _WrongProgram(model, wrong), cache)
+    state = cache.init_state()
+    a, b, c = (np.asarray(sequences[k]) for k in "abc")
+    got = {k: ([], []) for k in "abc"}
+    width = 8
+
+    def keep(name, position, logits):
+        got[name][0].append(position)
+        got[name][1].append(np.asarray(logits))
+
+    def lanes(names):
+        return dict(tables=cache.table_array(names, width),
+                    slots=cache.slot_array(names))
+
+    cache.allocate("a", len(a))
+    cache.allocate("b", len(b))
+    assert list(cache.slot_array(["a", "b"])) == [0, 1]
+    out = step.prefill(params, state, np.pad(a[:13], (0, 3))[None],
+                       np.array([13], np.int32), **lanes(["a"]))
+    keep("a", 12, out.logits[0])
+    state = out.cache
+    at = 0
+    for n in (7, 8, 4):
+        out = step.prefill_chunk(
+            params, state, np.pad(b[at:at + n], (0, 8 - n))[None],
+            np.array([at], np.int32), np.array([n], np.int32),
+            **lanes(["b"]))
+        at += n
+        keep("b", at - 1, out.logits[0])
+        state = out.cache
+    pos = {"a": 13, "b": 19}
+    seqs = {"a": a, "b": b, "c": c}
+    for i in range(4):
+        order = ["a", "b"] if i % 2 == 0 else ["b", "a"]    # lanes swap
+        out = step.decode(
+            params, state, np.array([seqs[k][pos[k]] for k in order]),
+            np.array([pos[k] for k in order], np.int32), **lanes(order))
+        for lane, k in enumerate(order):
+            keep(k, pos[k], out.logits[lane])
+            pos[k] += 1
+        state = out.cache
+    cache.free("a")
+    cache.allocate("c", len(c))
+    assert int(cache.slot_array(["c"])[0]) == 0             # a's slot
+    out = step.prefill_chunk(
+        params, state, np.pad(c[:6], (0, 2))[None], np.zeros(1, np.int32),
+        np.array([6], np.int32), **lanes(["c"]))
+    keep("c", 5, out.logits[0])
+    state = out.cache
+    pos["c"] = 6
+    for i in range(3):
+        order = ["b", "c"] if i % 2 == 0 else ["c", "b"]
+        out = step.decode(
+            params, state, np.array([seqs[k][pos[k]] for k in order]),
+            np.array([pos[k] for k in order], np.int32), **lanes(order))
+        for lane, k in enumerate(order):
+            keep(k, pos[k], out.logits[lane])
+            pos[k] += 1
+        state = out.cache
+    return {k: (np.asarray(p), np.stack(l)) for k, (p, l) in got.items()}
+
+
+SEQUENCES = {"a": tokens(17, 61), "b": tokens(26, 62), "c": tokens(9, 63)}
+# float32 against float32: what is left is the order of the sums (the
+# chunked form against a scan over tokens). 2e-6 of the largest logit:
+# the true program reads 2.5e-7; the nearest wrong model is a state
+# carried in bfloat16 over these 26 tokens, 7.3e-6; every other one
+# reads 2e-3 or more
+SLOT_LIMIT = 2e-6
+
+
+def _worst_logit_error(params, got, arch, **wrong):
+    worst = 0.0
+    for name, (positions, logits) in got.items():
+        want, _ = gref.forward(params, SEQUENCES[name], positions, arch,
+                               **wrong)
+        worst = max(worst, float(np.abs(logits - np.asarray(want)).max()
+                                 / np.abs(np.asarray(want)).max()))
+    return worst
+
+
+@pytest.fixture(scope="module")
+def through_the_slots(built):
+    model, params = built("granite")
+    return _through_the_slots(model, params, granite_config(), SEQUENCES)
+
+
+def test_prefill_chunks_and_decode_through_the_slots(built,
+                                                     through_the_slots):
+    """Logits, not tokens: a whole-prompt prefill with pad rows, chunks
+    of unequal real lengths with pad rows, decode with the two lanes
+    swapped between steps, and a slot taken over by a new sequence as
+    its first owner left it, each row against the reference's full
+    pass over its sequence."""
+    _, params = built("granite")
+    assert {k: len(p) for k, (p, _) in through_the_slots.items()} == {
+        "a": 5, "b": 10, "c": 4}
+    assert _worst_logit_error(params, through_the_slots,
+                              granite_arch()) < SLOT_LIMIT
+
+
+@pytest.mark.parametrize("fault", list(gref.FAULTS) + [
+    "fp8_everywhere", "pads_advance", "slot_not_restarted"])
+def test_the_logits_refuse_a_wrong_granite(built, through_the_slots, fault):
+    """A model that is wrong in one way reads over twice the limit the
+    true one passes by a factor of eight. Wrong references against what the true
+    program gave: the gate after the norm, no ``dt_bias``, no
+    convolution bias, scores scaled by ``head_dim^-0.5`` (32^-0.5, not
+    1/32), residuals added whole, the state carried in bfloat16,
+    everything in fp8. Wrong programs against the true reference: pad
+    rows that advance the state, a reused slot that is not restarted."""
+    model, params = built("granite")
+    if fault in ("pads_advance", "slot_not_restarted"):
+        got = _through_the_slots(model, params, granite_config(), SEQUENCES,
+                                 wrong=fault)
+        error = _worst_logit_error(params, got, granite_arch())
+    else:
+        wrong = dict(round_to=jnp.float8_e4m3fn) \
+            if fault == "fp8_everywhere" else dict(faults=(fault,))
+        error = _worst_logit_error(params, through_the_slots,
+                                   granite_arch(), **wrong)
+    assert error > 2 * SLOT_LIMIT, error
+
+
+def test_a_split_dispatch_advances_every_state_once(built, monkeypatch):
+    """``_isolate``'s binary split after a fault at the decode site:
+    the site raises BEFORE the jitted call, so the failed dispatch
+    advanced no state, and the halves' retries advance each lane's
+    once: the served tokens are those of a run with no fault. (A
+    replayed dispatch would advance a state twice, and the tokens
+    would differ.)"""
+    from apex_tpu.resilience import faults
+
+    model, params = built("granite")
+    cfg = granite_config()
+    requests = lambda: [  # noqa: E731
+        serving.Request(id=i, prompt=tokens(n, 70 + i), max_new_tokens=8)
+        for i, n in enumerate([6, 11, 9, 14])]
+    engine, cache = _engine(model, params, cfg, prefill_chunk=8)
+    plain = _serve(engine, cache, requests())
+    calls = {"n": 0}
+    check = faults.check
+
+    def failing(site):
+        if site == "decode_step":
+            calls["n"] += 1
+            if calls["n"] in (2, 5):         # two top-level dispatches
+                raise faults.FaultError("injected: decode_step")
+        return check(site)
+
+    monkeypatch.setattr(faults, "check", failing)
+    engine, cache = _engine(model, params, cfg, prefill_chunk=8)
+    split = _serve(engine, cache, requests())
+    assert calls["n"] > 8
+    for i in range(4):
+        assert split[i].finish_reason == "length"
+        assert split[i].tokens == plain[i].tokens, i
+    assert cache.slots_in_use == 0 and cache.blocks_in_use == 0
+
+
+def test_a_recurrent_model_matches_no_prefix_and_holds_a_slot(built):
+    """The stated rule: a prompt of a model with recurrent layers takes
+    no prefix match and publishes nothing, though a second request
+    repeats the first one's prompt; each live sequence holds one slot;
+    ``held`` counts slots, their bytes and the K/V blocks' bytes at the
+    steps' ends; the slots come back at the end."""
+    model, params = built("granite")
+    cfg = granite_config()
+    engine, cache = _engine(model, params, cfg, prefill_chunk=8)
+    assert cache.state_slots == 4 and cache.num_layers == 1
+    state = cache.init_state()
+    assert [p.shape for p in state.state] == [(5, 3, 8, 16, 16),
+                                              (5, 3, 3 * 160)]
+    prompt = tokens(21, 80)
+    engine.submit(serving.Request(id=0, prompt=prompt, max_new_tokens=4))
+    for _ in range(2):
+        state, _ = engine.step(state)
+    engine.submit(serving.Request(id=1, prompt=prompt, max_new_tokens=4))
+    seen = []
+    while not engine.idle():
+        state, report = engine.step(state)
+        seen.append((cache.slots_in_use, dict(engine.held)))
+    assert cache.prefix_stats()["hits"] == 0
+    assert cache.prefix_stats()["published_blocks"] == 0
+    assert max(n for n, _ in seen) == 2 and cache.slots_in_use == 0
+    held = engine.held
+    slot = 3 * (8 * 16 * 16 + 3 * 160) * 4          # float32, three layers
+    assert cache.slot_bytes() == slot
+    assert held["state_bytes"] == held["state_slots"] * slot > 0
+    assert held["kv_bytes"] == (held["block_layers"]
+                                * cache.block_bytes()) > 0
+    done = {r.id: r for r in engine.drain()}
+    assert done[0].tokens == done[1].tokens
+
+
+def test_a_plain_models_state_has_no_new_leaf(built):
+    """A model all of whose layers have keys: the cache's state is the
+    two pools and nothing else (no leaf: its programs are unchanged),
+    its packed layouts have no slot field, the engine counts no slot."""
+    model, params = built("mellum")
+    engine, cache = _engine(model, params, mellum_config())
+    state = cache.init_state()
+    assert state.state is None and len(jax.tree.leaves(state)) == 2
+    assert cache.state_slots == 0 and cache.slot_array(["x"][:0]).size == 0
+    assert set(engine.held) == {"block_layers", "behind_window"}
+    from apex_tpu.serving.decode import packed_layout
+
+    for fn in ("decode_step", "prefill_step", "prefill_chunk"):
+        plain = [n for n, _ in packed_layout(fn, 4, 8, 16, 5)]
+        slotted = [n for n, _ in packed_layout(fn, 4, 8, 16, 5, True)]
+        assert "state_slots" not in plain
+        assert [n for n in slotted if n != "state_slots"] == plain
+    with pytest.raises(ValueError, match="go together"):
+        serving.KVCache(1, 2, 32, num_blocks=4, state_slots=2)
+
+
+def test_one_setting_says_what_scales_the_embedding():
+    """``embedding_scale`` (sqrt(hidden)) and ``embedding_multiplier``
+    are one decision: a configuration that gives both is refused, not
+    read as the multiplier; and the mixer's sizes are all that
+    ``Mamba2Config`` holds (the model's width, eps and types are the
+    model's)."""
+    cfg = granite_config()
+    assert not cfg.embedding_scale and cfg.embedding_multiplier == 12.0
+    with pytest.raises(ValueError, match="give one"):
+        dataclasses.replace(cfg, embedding_scale=True)
+    assert {f.name for f in dataclasses.fields(Mamba2Config)} == {
+        "num_heads", "head_dim", "state_size", "conv_width", "chunk"}
+
+
+def test_the_nine_expert_shares_add_up_to_the_uncut_layer():
+    """72 experts top-10 in eight shares of nine (the cell's cut of
+    ``granite-4.0-h-small``'s layer): the routed parts the shares
+    compute, plus the shared MLP counted ONCE, equal the uncut layer as
+    the reference computes it and as the program does with
+    ``held=None``."""
+    full = HeldMoEConfig(hidden_size=32, expert_ffn_size=8, num_experts=72,
+                         top_k=10, router="softmax", shared_ffn_size=16,
+                         dtype=jnp.float32)
+    x = jnp.asarray(np.random.default_rng(3).normal(size=(2, 13, 32)),
+                    jnp.float32)
+    whole = seeded(jax.eval_shape(
+        lambda k: HeldMoEMLP(full).init(k, x), jax.random.PRNGKey(0)), 9)
+    a = granite_arch(held=None)._replace(top_k=10)
+    want, facts = gref._experts(x.reshape(-1, 32), whole["params"], a, None)
+    assert facts["held_pairs"] == facts["pairs"] == 26 * 10
+    total = 0.0
+    for first in range(0, 72, 9):
+        share = dict(whole["params"])
+        for name in ("w_gate", "w_up", "w_down"):
+            share[name] = whole["params"][name][first:first + 9]
+        if first:                                # the shared MLP once
+            for name in ("shared_gate", "shared_up", "shared_down"):
+                share[name] = jnp.zeros_like(share[name])
+        cfg = HeldMoEConfig(**{**full.__dict__, "held": (first, 9)})
+        part = HeldMoEMLP(cfg).apply({"params": share}, x)
+        assert float(jnp.abs(part).max()) > 0
+        total = total + part
+    np.testing.assert_allclose(np.asarray(total).reshape(-1, 32),
+                               np.asarray(want), atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(HeldMoEMLP(full).apply(whole, x)).reshape(-1, 32),
+        np.asarray(want), atol=2e-5)
+
+
+def test_the_step_kernel_updates_the_slots_where_they_lie():
+    """``ops/ssm_step.py`` interpreted against the gather, update and
+    scatter it replaces, at a size the kernel takes (32 heads of 8 with
+    a state of 128): four lanes over six slots and two layers, a fresh
+    lane (from zeros, though its slot holds NaN), two dummies that
+    share the trash slot; only the named slots of the named layer
+    change. Float32 both: the same products, a sum in another order."""
+    from apex_tpu.ops.ssm_step import ssm_step_by_slot
+
+    rng = np.random.default_rng(4)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731,E501
+    pool = f(6, 2, 32, 8, 128).at[3, 1].set(jnp.nan)
+    slots = jnp.array([2, 3, 5, 5])
+    fresh = jnp.array([False, True, False, False])
+    args = (f(4, 32, 8), jax.nn.softplus(f(4, 32)), -jnp.exp(f(32)),
+            f(4, 128), f(4, 128), 1.0 + 0.1 * f(32))
+    want_y, want = ssm_step_by_slot(pool, slots, fresh, 1, *args, impl="xla")
+    got_y, got = ssm_step_by_slot(pool, slots, fresh, 1, *args,
+                                  impl="interpret")
+    np.testing.assert_allclose(np.asarray(got_y[:3]), np.asarray(want_y[:3]),
+                               rtol=1e-5, atol=1e-5)
+    for slot in (2, 3):
+        np.testing.assert_allclose(np.asarray(got[slot, 1]),
+                                   np.asarray(want[slot, 1]), rtol=1e-6,
+                                   atol=1e-6)
+    untouched = np.ones((6, 2), bool)
+    untouched[[2, 3, 5], 1] = False
+    np.testing.assert_array_equal(np.asarray(got)[untouched],
+                                  np.asarray(pool)[untouched])
+    assert np.isfinite(np.asarray(got[3, 1])).all()

@@ -186,11 +186,30 @@ def test_every_span_lies_inside_a_step(runs):
 
 def test_the_children_of_a_step_cover_it(runs):
     steps = [e for e in runs.events if e[0] == "apex.serve.step"]
-    # by the median step: one stall of the test machine between two
-    # spans must not decide it
-    cover = sorted(sum(c[2] - c[1] for c in children(runs.events, s))
-                   / (s[2] - s[1]) for s in steps)
-    assert cover[len(cover) // 2] >= 0.9
+    # By the summed time of all five steps: of the time the engine
+    # spends in steps, nine tenths lies in a child span (0.915 is what
+    # it reads here, where a step takes 0.1-4 ms; what lies between the
+    # children is the step's own bookkeeping, 0.02-0.3 ms a step). A
+    # single step's ratio is one of two short wall-clock times, and a
+    # stall of the test machine between two spans decided the median
+    # of five of them. The same five steps were traced twice (with and
+    # without a ring): a stall only lengthens what it hits, so each
+    # step's covered and uncovered time is the lesser of its two
+    # readings. What lies outside a child anywhere still counts, by
+    # the time it takes.
+    def split(events):
+        out = []
+        for s in (e for e in events if e[0] == "apex.serve.step"):
+            inside = sum(c[2] - c[1] for c in children(
+                [e for e in events if e[0].startswith("apex.")], s))
+            out.append((inside, s[2] - s[1] - inside))
+        return out
+
+    both = list(zip(split(runs.events), split(runs.events_with_ring)))
+    assert len(both) == STEPS
+    covered = sum(min(a[0], b[0]) for a, b in both)
+    outside = sum(min(a[1], b[1]) for a, b in both)
+    assert covered >= 0.9 * (covered + outside)
     for s in steps:
         names = [c[0] for c in children(runs.events, s)]
         assert names[:2] == ["apex.serve.housekeep", "apex.serve.admit"]

@@ -331,7 +331,7 @@ class TestAppendInPlace:
 
     @staticmethod
     def same_real_blocks(got, want):
-        for g, w in zip(got, want):
+        for g, w in zip((got.k, got.v), (want.k, want.v)):
             np.testing.assert_array_equal(
                 np.asarray(g.astype(jnp.float32))[:, 1:],
                 np.asarray(w.astype(jnp.float32))[:, 1:])

@@ -545,3 +545,90 @@ def test_mellum2_cut_programs_fit_and_gather_the_tail(chip, monkeypatch,
     # four KV heads fill half a bf16 tile: one scatter over all lanes
     # had the pools converted whole, there and back, in every call
     assert_pool_stays_where_it_lies(text, state.k.shape)
+
+
+@pytest.mark.parametrize("fn,batch,seq", [
+    ("decode_step", 64, 1), ("prefill_chunk", 2, 1024),
+    ("prefill_chunk", 1, 1024), ("prefill_step", 1, 512)])
+def test_granite_cut_programs_fit_and_leave_the_state_where_it_lies(
+        chip, monkeypatch, fn, batch, seq):
+    """``granite-4.0-h-small``'s ten-layer cut at the benchmark's own
+    sizes (``benchmark/configs/granite-4.0-h-small.json``: hidden 4096,
+    nine Mamba-2 layers of 128 heads x 64 with a state of 128 and one
+    attention layer of 32 / 8 heads, 9 of 72 experts held beside a
+    shared MLP, a tied head of 100352 rows, 64 state slots, a pool of
+    16384 blocks with ONE layer), its decode program at 64 lanes, a
+    chunk program at two lanes and at one, and a whole-prompt program
+    at one, tables of 512 blocks: they fit a v5e, the one attention
+    layer gathers and attends 8192 positions, every layer's three
+    grouped products are the compiler's own kernels, the decode
+    program steps its nine state layers by the ``ssm_step`` kernel, and
+    no ``copy`` has the shape of a K/V pool or of a state pool (a
+    one-lane program used to lay the 2.45 GB pool out anew around its
+    one slice; the slot count with its trash slot, 65, is the leading
+    dimension of the state pools and of parts of them alone:
+    ``ssm_state_copy_pct`` finds the state by it)."""
+    import json
+    import re
+
+    from apex_tpu import serving
+    from apex_tpu.models.decoder import PatternDecoder
+    from benchmark.drivers import serve_granite
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "granite-4.0-h-small.json")) as f:
+        config = json.load(f)
+    engine = config["engine"]
+    monkeypatch.setenv("APEX_TPU_IMPL", "pallas")
+    _backend.default_impl.cache_clear()
+    try:
+        cfg = serve_granite.decoder_config(config)
+        model = PatternDecoder(cfg)
+        cache = serving.KVCache.for_config(
+            cfg, num_blocks=engine["num_blocks"],
+            block_size=engine["block_size"],
+            state_slots=engine["state_slots"])
+        shapes = jax.eval_shape(
+            lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32)),
+            jax.random.PRNGKey(0))
+        params, state = jax.tree.map(
+            lambda x: chip(x.shape, x.dtype),
+            (shapes, jax.eval_shape(cache.init_state)))
+        compiled = serving.make_decode_step(model, cache).lower(
+            fn, params, state, batch, engine["min_width_bucket"],
+            seq=seq).compile()
+    finally:
+        _backend.default_impl.cache_clear()
+    n = sum(x.size for x in jax.tree.leaves(shapes))
+    # 9 x 121.5M + 61.1M outside the experts, 10 x 85.0M held, 411M: 4.83 GB
+    assert 2.40e9 < n < 2.43e9
+    assert state.k.shape == (1, 16385, 16, 8, 128)
+    assert [s.shape for s in state.state] == [(65, 9, 128, 64, 128),
+                                              (65, 9, 3 * 8448)]
+    assert chip_smoke.program_bytes(compiled) <= 0.85 * V5E_BYTES
+    text = compiled.as_text()
+    calls = re.findall(
+        r"%([\w-]+)\.?\d* = (\([^=]*?\)|\S+) custom-call\([^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"", text)
+    names = [name for name, _ in calls]
+    assert names.count("ragged-dot-none") == 30       # 3 products x 10
+    assert names.count("attention") == 1
+    assert names.count("ssm_step") == (9 if fn == "decode_step" else 0)
+    if fn != "prefill_step":                          # over the cache
+        gathers = [res for name, res in calls if name == "kv_gather"]
+        assert [g.count(f"bf16[{batch},8,8192,128]") for g in gathers] == [2]
+    assert_pool_stays_where_it_lies(text, state.k.shape)
+    # the state pools: donated, aliased to outputs, never copied whole
+    for pool in state.state:
+        shape = ",".join(str(d) for d in pool.shape)
+        assert not re.findall(
+            rf"%[\w.-]+ = \w+\[{shape}\]\S* copy\(", text), shape
+    entry = text[text.index("ENTRY"):]
+    assert len(re.findall(r"= \w+\[65,[\d,]*\]\S* parameter\(", entry)) == 2
+    # 65 is the leading dimension of the state pools (and of parts of
+    # them) and no other array's
+    for dims in set(re.findall(r"\w+\[([\d,]+)\]", text)):
+        if "65" in dims.split(","):
+            assert dims.split(",").index("65") == 0 and dims.split(
+                ",")[-1] in ("128", "25344"), dims
