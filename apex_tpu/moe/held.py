@@ -24,6 +24,22 @@ kind, where the model has one, is computed by every chip alike.
 
 ``held=None`` is the layer whole: every expert is here, no pair is
 dropped, and the grouped products see ``tokens x top_k`` rows.
+
+The held experts' three products take one of two forms, chosen from
+the call's static shapes by ``expert_form`` and by nothing else.
+*Grouped* (``grouped_experts``): the pairs sorted by expert, three
+``lax.ragged_dot`` over the held pairs, the results scattered back;
+it reads only the experts some pair chose, which is what a prefill or
+chunk call (hundreds of rows) and a call with under a pair an expert
+want. *Dense* (``dense_experts``): every held expert multiplies every
+row once, three plain batched matmuls over weights read where they
+lie, the unchosen (row, expert) results dropped by a select; it is
+what a decode call with a pair or more an expert and at most 128
+rows wants, where nearly every held expert is touched anyway and the
+grouped product at a few rows a group runs far under its bytes'
+bound. Both compute the same sum, bf16 operands under float32
+accumulation with float32 combine weights; the dense form rounds less
+on the way.
 """
 
 from __future__ import annotations
@@ -105,13 +121,64 @@ def softmax_router(x, gate_kernel, k: int):
     return weights, ids.astype(jnp.int32), probs
 
 
+#: the most rows a dense expert call may have. The dense products do
+#: ``rows`` FLOPs a byte of bf16 weights and a v5e's ridge is 240 FLOP/B
+#: (197 TFLOP/s over 819 GB/s), so up to 128 rows the MXU's time stays
+#: under about half of the bytes' time; every prefill and chunk program
+#: of the benchmark's cells has 512 rows or more.
+DENSE_MAX_ROWS = 128
+
+
+def expert_form(rows: int, top_k: int, num_experts: int) -> str:
+    """Which form the held experts' three products take in a call of
+    ``rows`` tokens, each routed to ``top_k`` of the router's
+    ``num_experts``: ``"dense"`` or ``"grouped"``. A function of the
+    call's static shapes alone; no field, variable or argument
+    overrides it.
+
+    *dense* where ``rows * top_k >= num_experts`` and ``rows <=
+    DENSE_MAX_ROWS``; *grouped* otherwise.
+
+    With a mean of one pair or more an expert, at least 63% of the
+    held experts are touched in expectation (``1 - 1/e``; 86% at two
+    pairs), so a product over every held expert reads at most ~1.6
+    times the bytes a grouped one must, and reads them as a plain
+    batched matmul, where the grouped product at a few rows a group
+    runs far under its bytes' bound. Below one pair an expert the
+    grouped form's skipping of the untouched experts wins by
+    multiples. The second edge is ``DENSE_MAX_ROWS``'s: the dense
+    form computes ``num_experts / top_k`` times the pairs' work, which
+    costs nothing while the weights' bytes bound the call.
+
+    Measured, ms a layer's three products alone, grouped / dense (one
+    v5e, bf16, near-even routing; PERF.md section 5, PR 36):
+    16 rows x top-8 over 64, all held, 793 MB: 3.72 / 1.12 (dense at
+    86% of the bytes' bound); 64 x top-10 over 72, 9 held, 170 MB:
+    0.49 / 0.29; 16 x top-4 over 256, 32 held, 1812 MB (a quarter of a
+    pair an expert): 0.44 / 2.51; at 128 rows the first two read
+    5.52 / 1.12 and 0.63 / 0.36."""
+    if rows * top_k >= num_experts and rows <= DENSE_MAX_ROWS:
+        return "dense"
+    return "grouped"
+
+
 def held_experts(x, weights, ids, w_gate, w_up, w_down,
-                 held: Tuple[int, int], dtype):
+                 held: Tuple[int, int], dtype, *, num_experts: int):
     """What the held experts add: ``sum_e w_e Expert_e(x)`` over the
     chosen experts ``e`` in ``[first, first + count)``.
 
-    x (n, h); weights / ids (n, k); w_gate / w_up (count, h, f) and
-    w_down (count, f, h), the held experts' own. Pairs are sorted by
+    x (n, h); weights / ids (n, k), the ids over the router's
+    ``num_experts``; w_gate / w_up (count, h, f) and w_down (count, f,
+    h), the held experts' own. The form of the three products is
+    ``expert_form``'s, from ``n``, ``k`` and ``num_experts``."""
+    form = expert_form(x.shape[0], ids.shape[1], num_experts)
+    return EXPERT_FORMS[form](x, weights, ids, w_gate, w_up, w_down, held,
+                              dtype)
+
+
+def grouped_experts(x, weights, ids, w_gate, w_up, w_down,
+                    held: Tuple[int, int], dtype):
+    """``held_experts`` by grouped products. Pairs are sorted by
     local expert with the absent ones last, and the group sizes count
     the held pairs only: the grouped products (``lax.ragged_dot``, on
     a TPU the compiler's grouped matmul, which visits the tiles its
@@ -137,6 +204,41 @@ def held_experts(x, weights, ids, w_gate, w_up, w_down,
     out = jnp.where((flat[order] < count)[:, None],
                     out.astype(jnp.float32) * w_sorted[:, None], 0.0)
     return out[inv].reshape(n, k, h).sum(axis=1).astype(dtype)
+
+
+def dense_experts(x, weights, ids, w_gate, w_up, w_down,
+                  held: Tuple[int, int], dtype):
+    """``held_experts`` by batched dense products: every held expert
+    multiplies every row once, the weights read where they lie as
+    three plain batched matmuls, and a row's result is the sum over
+    its chosen, held experts. No sort, no gather of rows, no scatter
+    back. Operands in ``dtype``, accumulation and the combine weights
+    in float32, one cast at the end."""
+    first, count = held
+    f32 = jnp.float32
+    # (n, k, count): pair j of row i chose held expert e. A pair whose
+    # expert lives elsewhere matches none
+    hit = (ids - first)[:, :, None] == jnp.arange(count, dtype=ids.dtype)
+    chosen = hit.any(axis=1).T                                # (count, n)
+    combine = jnp.where(hit, weights.astype(f32)[:, :, None],
+                        0.0).sum(axis=1).T                    # (count, n)
+    rows = jnp.broadcast_to(x.astype(dtype), (count, *x.shape))
+    with jax.named_scope("moe_experts"):
+        gate = jnp.einsum("enh,ehf->enf", rows, w_gate.astype(dtype),
+                          preferred_element_type=f32)
+        up = jnp.einsum("enh,ehf->enf", rows, w_up.astype(dtype),
+                        preferred_element_type=f32)
+        out = jnp.einsum("enf,efh->enh",
+                         (jax.nn.silu(gate) * up).astype(dtype),
+                         w_down.astype(dtype), preferred_element_type=f32)
+    # an expert a row did not choose was computed all the same:
+    # whatever lies there is dropped by the mask, not multiplied by a
+    # zero weight
+    out = jnp.where(chosen[:, :, None], out * combine[:, :, None], 0.0)
+    return out.sum(axis=0).astype(dtype)
+
+
+EXPERT_FORMS = {"dense": dense_experts, "grouped": grouped_experts}
 
 
 def gated_mlp(x, w_gate, w_up, w_down, dtype):
@@ -190,7 +292,8 @@ class HeldMoEMLP(nn.Module):
                 self.sow("routing", "biased",
                          scores + bias.astype(jnp.float32))
         out = held_experts(x2, weights, ids, w_gate, w_up, w_down,
-                           (first, count), cfg.dtype)
+                           (first, count), cfg.dtype,
+                           num_experts=cfg.num_experts)
         if cfg.shared_ffn_size:
             fs = cfg.shared_ffn_size
             out = out + gated_mlp(
@@ -205,5 +308,6 @@ class HeldMoEMLP(nn.Module):
         return out
 
 
-__all__ = ["HeldMoEConfig", "HeldMoEMLP", "gated_mlp", "held_experts",
+__all__ = ["EXPERT_FORMS", "HeldMoEConfig", "HeldMoEMLP", "dense_experts",
+           "expert_form", "gated_mlp", "grouped_experts", "held_experts",
            "sigmoid_router", "softmax_router"]

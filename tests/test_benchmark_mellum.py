@@ -246,7 +246,9 @@ TOY_OPS = {
                  "copy-start(%w)", 700),
     "small_copy": ("%copy.12 = bf16[16,4,128]{2,1,0:T(4,128)(2,1)} "
                    "copy(%k_new)", 800),
-    "product": ("%ragged-dot-none.3 = bf16[128,2304]{1,0} "
+    # a chunk call's grouped product, 1024 rows x top-8 (since PR 36 a
+    # decode call's experts are dense fusions and no custom call)
+    "product": ("%ragged-dot-none.3 = bf16[8192,2304]{1,0} "
                 "custom-call(%x, %w)", 5000),
 }
 

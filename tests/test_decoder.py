@@ -37,8 +37,10 @@ from apex_tpu.models import decoder_reference_mellum as mref
 from apex_tpu.models import ssm
 from apex_tpu.models.decoder import (DecoderConfig, Mamba2Config,
                                      PatternDecoder, Rotary)
-from apex_tpu.moe.held import (HeldMoEConfig, HeldMoEMLP, sigmoid_router,
-                               softmax_router)
+from apex_tpu.moe import held as held_module
+from apex_tpu.moe.held import (EXPERT_FORMS, HeldMoEConfig, HeldMoEMLP,
+                               dense_experts, expert_form, held_experts,
+                               sigmoid_router, softmax_router)
 
 LAYERS = (("window", "dense"), ("window", "experts"), ("window", "experts"),
           ("window", "experts"), ("full", "experts"))
@@ -358,39 +360,65 @@ def test_softmax_router_is_softmax_then_top_k_then_renormalised():
     assert (chosen.sum(1) < 0.95).all()    # renormalising changed them
 
 
-def test_every_expert_held_drops_no_pair(monkeypatch):
-    """``held=None``: the grouped products' group sizes count every
-    routed pair, ``tokens x top_k`` rows over all the experts, and the
-    layer is the sum over the chosen of weight times expert, written
-    out by hand."""
-    cfg = HeldMoEConfig(hidden_size=64, expert_ffn_size=48,
-                        num_experts=EXPERTS, top_k=4, router="softmax",
-                        dtype=jnp.float32)
-    x = jnp.asarray(np.random.default_rng(4).normal(size=(40, 64)),
-                    jnp.float32)
-    params = seeded(jax.eval_shape(
-        lambda k: HeldMoEMLP(cfg).init(k, x), jax.random.PRNGKey(0)), 1)
-    assert "select_bias" not in params["params"]
-    assert params["params"]["w_gate"].shape == (EXPERTS, 64, 48)
-    from apex_tpu.moe import held as held_module
-
+def _spy_on_group_sizes(monkeypatch):
+    """The group sizes of every ``lax.ragged_dot`` the layer makes from
+    here on."""
     sizes = []
     real = held_module.lax.ragged_dot
     monkeypatch.setattr(
         held_module.lax, "ragged_dot",
         lambda x, w, group_sizes: (sizes.append(np.asarray(group_sizes)),
                                    real(x, w, group_sizes))[1])
+    return sizes
+
+
+def _experts_by_hand(x, p, w, ids, first=0, count=EXPERTS):
+    """The sum over a row's chosen experts in ``[first, first +
+    count)`` of weight times expert, a pair at a time in float64."""
+    x, w, ids = (np.asarray(t) for t in (x, w, ids))
+    p = {k: np.asarray(v, np.float64) for k, v in p.items()}
+    want = np.zeros(x.shape, np.float64)
+    for row in range(len(x)):
+        for weight, e in zip(w[row], ids[row] - first):
+            if 0 <= e < count:
+                h = x[row] @ p["w_gate"][e]
+                h = h / (1 + np.exp(-h)) * (x[row] @ p["w_up"][e])
+                want[row] += weight * (h @ p["w_down"][e])
+    return want
+
+
+# rows on each side of the rule's second edge (held.DENSE_MAX_ROWS)
+both_forms = pytest.mark.parametrize("rows,form", [(40, "dense"),
+                                                   (136, "grouped")])
+
+
+@both_forms
+def test_every_expert_held_drops_no_pair(monkeypatch, rows, form):
+    """``held=None``, in both forms of the three products (the form is
+    the rule's, from the rows): the grouped products' group sizes count
+    every routed pair, ``tokens x top_k`` rows over all the experts,
+    the dense products sort nothing, and either way the layer is the
+    sum over the chosen of weight times expert, written out by hand."""
+    cfg = HeldMoEConfig(hidden_size=64, expert_ffn_size=48,
+                        num_experts=EXPERTS, top_k=4, router="softmax",
+                        dtype=jnp.float32)
+    assert expert_form(rows, 4, EXPERTS) == form
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(rows, 64)),
+                    jnp.float32)
+    params = seeded(jax.eval_shape(
+        lambda k: HeldMoEMLP(cfg).init(k, x), jax.random.PRNGKey(0)), 1)
+    assert "select_bias" not in params["params"]
+    assert params["params"]["w_gate"].shape == (EXPERTS, 64, 48)
+    sizes = _spy_on_group_sizes(monkeypatch)
     out, (w, ids, _) = HeldMoEMLP(cfg).apply(params, x, return_routing=True)
-    assert len(sizes) == 3 and all(
-        g.shape == (EXPERTS,) and g.sum() == 40 * 4 for g in sizes)
-    p = jax.tree.map(np.asarray, params["params"])
-    want = np.zeros((40, 64))
-    for row in range(40):
-        for weight, e in zip(np.asarray(w)[row], np.asarray(ids)[row]):
-            h = np.asarray(x)[row] @ p["w_gate"][e]
-            h = h / (1 + np.exp(-h)) * (np.asarray(x)[row] @ p["w_up"][e])
-            want[row] += weight * (h @ p["w_down"][e])
-    np.testing.assert_allclose(np.asarray(out), want, atol=2e-5)
+    if form == "grouped":
+        assert len(sizes) == 3 and all(
+            g.shape == (EXPERTS,) and g.sum() == rows * 4 for g in sizes)
+    else:
+        assert not sizes
+    np.testing.assert_allclose(
+        np.asarray(out), _experts_by_hand(x, params["params"], w, ids),
+        atol=2e-5)
 
 
 def test_the_softmax_routers_shares_add_up_to_the_uncut_layer():
@@ -456,36 +484,121 @@ def test_the_shares_add_up_to_the_uncut_layer():
                                np.asarray(want), atol=2e-5)
 
 
-def test_absent_pairs_are_dropped_before_the_grouped_products(monkeypatch):
-    """The grouped products' group sizes count the held pairs only."""
+@both_forms
+def test_absent_pairs_are_dropped_before_the_grouped_products(
+        monkeypatch, rows, form):
+    """A share of the experts held, in both forms: the grouped
+    products' group sizes count the held pairs only, and either form
+    is the by-hand sum over the chosen experts that are held."""
     cfg = HeldMoEConfig(hidden_size=64, expert_ffn_size=48,
                         num_experts=EXPERTS, top_k=2, held=HELD,
                         dtype=jnp.float32)
-    x = jnp.asarray(np.random.default_rng(4).normal(size=(40, 64)),
+    assert expert_form(rows, 2, EXPERTS) == form
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(rows, 64)),
                     jnp.float32)
     params = seeded(jax.eval_shape(
         lambda k: HeldMoEMLP(cfg).init(k, x), jax.random.PRNGKey(0)), 1)
-    from apex_tpu.moe import held as held_module
-
-    sizes = []
-    real = held_module.lax.ragged_dot
-    monkeypatch.setattr(
-        held_module.lax, "ragged_dot",
-        lambda x, w, group_sizes: (sizes.append(np.asarray(group_sizes)),
-                                   real(x, w, group_sizes))[1])
-    _, (w, ids, _) = HeldMoEMLP(cfg).apply(params, x, return_routing=True)
+    sizes = _spy_on_group_sizes(monkeypatch)
+    out, (w, ids, _) = HeldMoEMLP(cfg).apply(params, x, return_routing=True)
     held = (np.asarray(ids) >= 4) & (np.asarray(ids) < 8)
     assert 0 < held.sum() < held.size
-    assert len(sizes) == 3 and all(
-        g.shape == (4,) and g.sum() == held.sum() for g in sizes)
+    if form == "grouped":
+        assert len(sizes) == 3 and all(
+            g.shape == (4,) and g.sum() == held.sum() for g in sizes)
+    else:
+        assert not sizes
+    p = params["params"]
+    np.testing.assert_allclose(
+        np.asarray(out), _experts_by_hand(x, p, w, ids, *HELD), atol=2e-5)
     # an expert outside the held range changes nothing: zero its pairs'
     # weights by hand and the result is the same
-    out = HeldMoEMLP(cfg).apply(params, x)
-    from apex_tpu.moe.held import held_experts
-    p = params["params"]
     again = held_experts(x, jnp.where(held, w, 0.0), ids, p["w_gate"],
-                         p["w_up"], p["w_down"], HELD, jnp.float32)
+                         p["w_up"], p["w_down"], HELD, jnp.float32,
+                         num_experts=EXPERTS)
     np.testing.assert_allclose(np.asarray(out), np.asarray(again), atol=1e-6)
+
+
+def _routed(rows, held, dtype, seed=5, top_k=4):
+    """Rows, a softmax router's choice over ``EXPERTS`` and the held
+    experts' seeded weights, as ``held_experts`` takes them."""
+    first, count = held
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(rows, 64)), dtype)
+    w, ids, _ = softmax_router(
+        x, jnp.asarray(rng.normal(size=(64, EXPERTS)), jnp.float32), top_k)
+    mats = [jnp.asarray(rng.normal(size=shape) * 0.3, dtype) for shape in
+            [(count, 64, 48), (count, 64, 48), (count, 48, 64)]]
+    return x, w, ids, mats
+
+
+@pytest.mark.parametrize("held", [(0, EXPERTS), HELD])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_two_forms_agree(held, dtype):
+    """The same rows, choice and weights through both bodies: the same
+    sum to float32 rounding in float32. In bfloat16 (operands rounded,
+    sums in float32) the dense form, which rounds the activation and
+    the result and nothing else, stays within one bf16 ulp of the
+    row's largest of the by-hand sum; the grouped one rounds each
+    product's result too and reads up to 1.2 there, so the two lie
+    within two of each other, and the dense one is the nearer."""
+    x, w, ids, mats = _routed(24, held, dtype)
+    dense, grouped = (np.asarray(EXPERT_FORMS[form](
+        x, w, ids, *mats, held, dtype).astype(jnp.float32))
+        for form in ("dense", "grouped"))
+    assert np.abs(grouped).max() > 0.1
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(dense, grouped, atol=2e-5)
+        return
+    want = _experts_by_hand(
+        x.astype(jnp.float32),
+        dict(zip(("w_gate", "w_up", "w_down"), mats)), w, ids, *held)
+    ulp = BF16_EPS * np.abs(want).max(-1, keepdims=True)
+    assert (np.abs(dense - want) <= ulp).all()
+    assert (np.abs(dense - grouped) <= 2 * ulp).all()
+    assert np.abs(dense - want).max() < np.abs(grouped - want).max()
+
+
+@pytest.mark.parametrize("poison", [np.nan, np.inf])
+@pytest.mark.parametrize("leaf", [0, 1, 2])
+def test_an_unchosen_experts_weights_do_not_reach_the_dense_sum(leaf,
+                                                                  poison):
+    """Every held expert multiplies every row, the unchosen with them:
+    a NaN or an Inf in the weights of an expert that NO row chose is
+    dropped by the select on the chosen mask (a zero weight would turn
+    it into a NaN, and the sampler's finite flag would quarantine the
+    lane); in the weights of a chosen one it is seen."""
+    x, w, ids, mats = _routed(6, (0, EXPERTS), jnp.float32, top_k=2)
+    chosen = np.unique(np.asarray(ids))
+    unchosen = sorted(set(range(EXPERTS)) - set(chosen.tolist()))
+    assert unchosen
+    clean = dense_experts(x, w, ids, *mats, (0, EXPERTS), jnp.float32)
+
+    def planted(e):
+        bad = list(mats)
+        bad[leaf] = bad[leaf].at[e].set(poison)
+        return np.asarray(dense_experts(
+            x, w, ids, *bad, (0, EXPERTS), jnp.float32))
+
+    np.testing.assert_array_equal(planted(unchosen[0]), np.asarray(clean))
+    assert not np.isfinite(planted(int(chosen[0]))).all()
+
+
+@pytest.mark.parametrize("cell,rows,top_k,experts,form", [
+    ("mellum2 decode", 16, 8, 64, "dense"),
+    ("mellum2 least prefill", 512, 8, 64, "grouped"),
+    ("granite decode", 64, 10, 72, "dense"),
+    ("granite least prefill", 512, 10, 72, "grouped"),
+    ("trinity decode", 16, 4, 256, "grouped"),
+    ("trinity least prefill", 512, 4, 256, "grouped"),
+    ("a pair an expert", 8, 8, 64, "dense"),
+    ("under a pair an expert", 7, 8, 64, "grouped"),
+    ("the most rows", 128, 8, 64, "dense"),
+    ("past the most rows", 129, 8, 64, "grouped"),
+])
+def test_expert_form_at_the_cells_shapes(cell, rows, top_k, experts, form):
+    """The rule at the benchmark's three cells' decode calls and least
+    prefill-type calls, and on each side of its two edges."""
+    assert expert_form(rows, top_k, experts) == form
 
 
 @pytest.mark.parametrize("window", [None, 8])
@@ -632,6 +745,40 @@ def test_gathered_counts_the_live_blocks():
         assert BLOCK * (WINDOW // BLOCK) <= b["window_live"] <= WINDOW + BLOCK
         assert b["full_live"] <= b["full"] and b["window_live"] <= b["window"]
     assert steps[-1]["full_live"] > steps[0]["full_live"]
+
+
+@pytest.mark.parametrize("block", [*BLOCKS, "no experts"])
+def test_expert_calls_are_the_dispatches_arithmetic(built, block):
+    """``ContinuousBatcher.expert_calls`` over a toy deck (whole
+    prompts, chunks of 8, decodes on four lanes): every dispatch adds
+    the model's expert layers under the form ``expert_form`` gives for
+    the rows of its program, pads and dummy lanes with them; a model
+    with no expert layer adds nothing."""
+    if block == "no experts":
+        model, params, cfg = _kernel_model("pattern")
+    else:
+        (model, params), cfg = built(block), BLOCKS[block][0]()
+    engine, cache = _engine(model, params, cfg, prefill_chunk=8)
+    rows = []
+    for name in ("prefill", "prefill_chunk", "decode"):
+        def counted(params, state, toks, *args,
+                    _real=getattr(engine.step_fn, name), **kw):
+            rows.append(np.asarray(toks).size)
+            return _real(params, state, toks, *args, **kw)
+        setattr(engine.step_fn, name, counted)
+    _serve(engine, cache, [
+        serving.Request(id=i, prompt=tokens(n, 30 + i), max_new_tokens=m)
+        for i, (n, m) in enumerate([(8, 6), (21, 7), (5, 4)])])
+    layers = [mlp for _, mlp in cfg.layers].count("experts")
+    want = {"dense": 0, "grouped": 0}
+    for n in rows:
+        want[expert_form(n, cfg.experts_per_token, cfg.num_experts)] += layers
+    assert len(rows) > 10 and engine.expert_calls == want
+    assert sum(want.values()) == layers * len(rows)
+    if block == "afmoe":       # 4 lanes x top-2 over 16: under a pair each
+        assert want["grouped"] > 0 and want["dense"] > 0
+    if block == "no experts":
+        assert layers == 0
 
 
 @both_blocks

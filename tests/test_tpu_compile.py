@@ -90,6 +90,30 @@ def assert_pool_stays_where_it_lies(text, shape):
                          alias), (number, alias)
 
 
+def assert_experts_are_read_where_they_lie(text, *shapes):
+    """A decode program whose expert layers take the dense form
+    (``moe/held.py`` ``expert_form``) multiplies the held experts'
+    weights as they arrived: no ``copy`` or ``transpose`` instruction
+    has the shape of an expert weight array, and every mention of such
+    a shape carries the tiling and order its parameters arrived in. The
+    memory space is no part of the comparison: the compiler may fetch
+    a weight into ``S(1)`` by ``copy-start`` / ``copy-done``, which is
+    the read itself."""
+    import re
+
+    entry = text[text.index("ENTRY"):]
+    for shape in shapes:
+        weight = re.escape("bf16[%s]" % ",".join(str(n) for n in shape))
+        arrived = set(re.findall(
+            rf"= {weight}(\{{[^}}]*\}}) parameter\(", entry))
+        assert len(arrived) == 1, (shape, arrived)
+        assert not re.findall(
+            rf"%[\w.-]+ = {weight}\S* (?:copy|transpose)\(", text), shape
+        mentions = {re.sub(r"S\(\d+\)", "", m) for m in re.findall(
+            rf"{weight}(\{{[^}}]*\}})", text)}
+        assert mentions == arrived, (shape, mentions, arrived)
+
+
 def _flash(sq, sk, *, causal, segs, b=16, h=16, hk=4, grad=False):
     from apex_tpu.ops.attention import flash_attention
 
@@ -491,8 +515,9 @@ def test_mellum2_cut_programs_fit_and_gather_the_tail(chip, monkeypatch,
     program at 4 x 1024, at the widest tables (1024 blocks): they fit a
     v5e beside nothing else, the six window layers gather and attend a
     tail of 80 blocks (1280 positions) and the two full layers 16384,
-    and every layer's three grouped products are the compiler's own
-    kernels."""
+    every layer's three expert products are the compiler's own grouped
+    kernels in the chunk program and, in the decode program, batched
+    dense products over weights that stay where they lie."""
     import json
     import re
 
@@ -541,7 +566,13 @@ def test_mellum2_cut_programs_fit_and_gather_the_tail(chip, monkeypatch,
     names = [name for name, _ in calls]
     assert names.count("attention_window") == 6
     assert names.count("attention") == 2
-    assert names.count("ragged-dot-none") == 24       # 3 products x 8 layers
+    if fn == "decode_step":
+        # 16 rows x top-8 over 64: two pairs an expert, the dense form
+        assert names.count("ragged-dot-none") == 0
+        assert_experts_are_read_where_they_lie(
+            text, (64, 2304, 896), (64, 896, 2304))
+    else:
+        assert names.count("ragged-dot-none") == 24   # 3 products x 8 layers
     # four KV heads fill half a bf16 tile: one scatter over all lanes
     # had the pools converted whole, there and back, in every call
     assert_pool_stays_where_it_lies(text, state.k.shape)
@@ -561,7 +592,9 @@ def test_granite_cut_programs_fit_and_leave_the_state_where_it_lies(
     chunk program at two lanes and at one, and a whole-prompt program
     at one, tables of 512 blocks: they fit a v5e, the one attention
     layer gathers and attends 8192 positions, every layer's three
-    grouped products are the compiler's own kernels, the decode
+    expert products are the compiler's own grouped kernels in the
+    prefill programs and batched dense products over weights that stay
+    where they lie in the decode program, the decode
     program steps its nine state layers by the ``ssm_step`` kernel, and
     no ``copy`` has the shape of a K/V pool or of a state pool (a
     one-lane program used to lay the 2.45 GB pool out anew around its
@@ -612,7 +645,13 @@ def test_granite_cut_programs_fit_and_leave_the_state_where_it_lies(
         r"%([\w-]+)\.?\d* = (\([^=]*?\)|\S+) custom-call\([^\n]*"
         r"custom_call_target=\"tpu_custom_call\"", text)
     names = [name for name, _ in calls]
-    assert names.count("ragged-dot-none") == 30       # 3 products x 10
+    if fn == "decode_step":
+        # 64 rows x top-10 over 72: 8.9 pairs an expert, the dense form
+        assert names.count("ragged-dot-none") == 0
+        assert_experts_are_read_where_they_lie(
+            text, (9, 4096, 768), (9, 768, 4096))
+    else:
+        assert names.count("ragged-dot-none") == 30   # 3 products x 10
     assert names.count("attention") == 1
     assert names.count("ssm_step") == (9 if fn == "decode_step" else 0)
     if fn != "prefill_step":                          # over the cache
