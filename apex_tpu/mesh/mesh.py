@@ -354,14 +354,20 @@ class MeshTrainStep:
         vg = state.space.grad_fn(self._loss_fn, with_value=True,
                                  has_aux=self._has_aux)
 
+        # the update is the ``optimizer`` part of the step
+        # (telemetry.compiled.PARTS), with the unpack of the master and
+        # its transpose, the gradient into the flat space
+        # (``FlatSpace.grad_fn``)
         if self._has_aux:
             def step(state, tokens, labels):
                 (loss, aux), g = vg(state.master, tokens, labels)
-                return self._update(state, g), loss, aux
+                with jax.named_scope("optimizer"):
+                    return self._update(state, g), loss, aux
         else:
             def step(state, tokens, labels):
                 loss, g = vg(state.master, tokens, labels)
-                return self._update(state, g), loss
+                with jax.named_scope("optimizer"):
+                    return self._update(state, g), loss
 
         if self.plan.is_identity():
             jitted = jax.jit(step, donate_argnums=(0,))
@@ -453,7 +459,10 @@ class MeshTrainStep:
             from apex_tpu.telemetry import compiled as _compiled
             from apex_tpu.telemetry import sharding as _sharding
 
-            _compiled.observe(self.FN, self._signature(state, tokens))
+            signature = self._signature(state, tokens)
+            _compiled.observe(self.FN, signature)
+            _compiled.register_program("jit_step", signature, jitted,
+                                       (state, tokens, labels))
             _sharding.publish_shardings(_sharding.jitted_shardings(
                 jitted, state, tokens, labels, fn=self.FN))
             with _compiled.label(self.FN):

@@ -52,7 +52,8 @@ def first_context(kv_ctx, *, zero, window=None):
     k_pool, _, tables, _, *win = kv_ctx
     if window is not None:
         tables = win[0][0]
-    return zero(k_pool, tables)
+    with jax.named_scope("cache"):
+        return zero(k_pool, tables)
 
 
 def _decode(flash, q, k_all, v_all, kv_seg):
@@ -91,29 +92,34 @@ def cached_attention(q, k_new, v_new, kv_ctx, *, flash, gather, dtype,
     layer, into, k_pool, v_pool, tables, ctx_lens, *rest = kv_ctx
     win = rest[0] if rest else None
     b, _, s, _ = q.shape
-    if window is not None:
-        if win is None:
-            raise ValueError(
-                "a window layer needs the tail tables in kv_ctx: "
-                "(layer, into, k_pool, v_pool, tables, ctx_lens, "
-                "(win_tables, win_first))")
-        tables, first = win
-        base = first * k_pool.shape[2]           # the tail's first position
-    # positions of the gathered arrays that hold written keys: none
-    # behind the tail's first, and the masks below hide the rest
-    lens = ctx_lens if window is None else ctx_lens - base
-    k_all, v_all = gather(k_pool, v_pool, layer, tables, lens, into)
-    if sow is not None:
-        sow((k_all, v_all))
-    k_all, v_all = k_all.astype(dtype), v_all.astype(dtype)
-    if s == 1:
-        k_all = _into_slot(k_all, k_new, lens)
-        v_all = _into_slot(v_all, v_new, lens)
-    # the arrays as they are now: the token's slot is updated in place,
-    # and handing on what they were before would keep a copy of that
-    held = ()
-    if into:
-        held = k_all.astype(k_pool.dtype), v_all.astype(k_pool.dtype)
+    if window is not None and win is None:
+        raise ValueError(
+            "a window layer needs the tail tables in kv_ctx: "
+            "(layer, into, k_pool, v_pool, tables, ctx_lens, "
+            "(win_tables, win_first))")
+    # the cache's machinery is the ``cache`` part of the model
+    # (telemetry.compiled.PARTS); the masks and the kernel below are
+    # the caller's ``attention``
+    with jax.named_scope("cache"):
+        if window is not None:
+            tables, first = win
+            base = first * k_pool.shape[2]       # the tail's first position
+        # positions of the gathered arrays that hold written keys: none
+        # behind the tail's first, and the masks below hide the rest
+        lens = ctx_lens if window is None else ctx_lens - base
+        k_all, v_all = gather(k_pool, v_pool, layer, tables, lens, into)
+        if sow is not None:
+            sow((k_all, v_all))
+        k_all, v_all = k_all.astype(dtype), v_all.astype(dtype)
+        if s == 1:
+            k_all = _into_slot(k_all, k_new, lens)
+            v_all = _into_slot(v_all, v_new, lens)
+        # the arrays as they are now: the token's slot is updated in
+        # place, and handing on what they were before would keep a copy
+        # of that
+        held = ()
+        if into:
+            held = k_all.astype(k_pool.dtype), v_all.astype(k_pool.dtype)
     slot = jnp.arange(k_all.shape[2], dtype=jnp.int32)[None, :]
     if window is None and s == 1:
         # segment masking only: the written prefix and the token

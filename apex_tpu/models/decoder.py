@@ -387,27 +387,34 @@ class DecoderLayer(nn.Module):
             ).astype(t.dtype)
         else:
             scaled = lambda t: t  # noqa: E731
+        # each half of the layer under its part of the model
+        # (telemetry.compiled.PARTS), norms and residual with it
         if self.attention == "mamba":
-            with jax.named_scope("mamba_mixer"):
-                a, kv = Mamba2Mixer(
-                    cfg.mamba, hidden_size=cfg.hidden_size,
-                    rms_eps=cfg.rms_eps, dtype=cfg.dtype,
-                    param_dtype=cfg.param_dtype, impl=cfg.softmax_impl,
-                    name="mixer")(
-                    norm("input_norm")(x), state_ctx=state_ctx,
-                    lengths=lengths)
+            with jax.named_scope("mixer"):
+                with jax.named_scope("mamba_mixer"):
+                    a, kv = Mamba2Mixer(
+                        cfg.mamba, hidden_size=cfg.hidden_size,
+                        rms_eps=cfg.rms_eps, dtype=cfg.dtype,
+                        param_dtype=cfg.param_dtype, impl=cfg.softmax_impl,
+                        name="mixer")(
+                        norm("input_norm")(x), state_ctx=state_ctx,
+                        lengths=lengths)
+                y = x + scaled(after("post_attention_norm")(a))
             held = ()
         else:
-            a, kv, held = DecoderAttention(
-                cfg, self.attention, name="attention")(
-                norm("input_norm")(x), positions, kv_ctx=kv_ctx)
-        y = x + scaled(after("post_attention_norm")(a))
-        m = norm("pre_mlp_norm")(y)
-        if self.mlp == "experts":
-            m = HeldMoEMLP(cfg.moe_cfg(), name="mlp")(m)
-        else:
-            m = DenseMLP(cfg, name="mlp")(m)
-        return y + scaled(after("post_mlp_norm")(m)), kv, held
+            with jax.named_scope("attention"):
+                a, kv, held = DecoderAttention(
+                    cfg, self.attention, name="attention")(
+                    norm("input_norm")(x), positions, kv_ctx=kv_ctx)
+                y = x + scaled(after("post_attention_norm")(a))
+        # an expert layer's shared MLP opens ``mlp`` inside (moe/held.py)
+        with jax.named_scope("experts" if self.mlp == "experts" else "mlp"):
+            m = norm("pre_mlp_norm")(y)
+            if self.mlp == "experts":
+                m = HeldMoEMLP(cfg.moe_cfg(), name="mlp")(m)
+            else:
+                m = DenseMLP(cfg, name="mlp")(m)
+            return y + scaled(after("post_mlp_norm")(m)), kv, held
 
 
 class PatternDecoder(nn.Module):
@@ -441,15 +448,16 @@ class PatternDecoder(nn.Module):
         init = nn.initializers.normal(stddev=0.02)
         table = self.param("embedding", init,
                            (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
-        x = table[tokens]
-        if cfg.embedding_multiplier is not None:
-            x = x.astype(jnp.float32) * cfg.embedding_multiplier
-        elif cfg.embedding_scale:
-            x = x.astype(jnp.float32) * (cfg.hidden_size ** 0.5)
-        x = x.astype(cfg.dtype)
-        if positions is None:
-            positions = jnp.arange(s, dtype=jnp.int32)
-        positions = jnp.broadcast_to(jnp.asarray(positions), (b, s))
+        with jax.named_scope("embed"):
+            x = table[tokens]
+            if cfg.embedding_multiplier is not None:
+                x = x.astype(jnp.float32) * cfg.embedding_multiplier
+            elif cfg.embedding_scale:
+                x = x.astype(jnp.float32) * (cfg.hidden_size ** 0.5)
+            x = x.astype(cfg.dtype)
+            if positions is None:
+                positions = jnp.arange(s, dtype=jnp.int32)
+            positions = jnp.broadcast_to(jnp.asarray(positions), (b, s))
         kvs = []
         # what the next layer of each kind gathers its context into
         # (cached_attention): zeros before the first
@@ -478,17 +486,20 @@ class PatternDecoder(nn.Module):
                 kv_ctx=(None if kv_ctx is None
                         else (len(kvs), into[attention], *kv_ctx)))
             kvs.append(kv)
-        x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
-        head = table if cfg.tied_head else self.param(
-            "head", init, (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
-        logits = jnp.einsum("bsh,vh->sbv", x, head.astype(cfg.dtype),
-                            preferred_element_type=jnp.float32)
-        if cfg.logits_divisor != 1.0:
-            logits = logits / cfg.logits_divisor
+        with jax.named_scope("head"):
+            x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
+            head = table if cfg.tied_head else self.param(
+                "head", init, (cfg.vocab_size, cfg.hidden_size),
+                cfg.param_dtype)
+            logits = jnp.einsum("bsh,vh->sbv", x, head.astype(cfg.dtype),
+                                preferred_element_type=jnp.float32)
+            if cfg.logits_divisor != 1.0:
+                logits = logits / cfg.logits_divisor
         out = (logits,)
         if return_kv:
-            out += ((jnp.stack([k for k, _ in kvs]),
-                     jnp.stack([v for _, v in kvs])),)
+            with jax.named_scope("cache"):
+                out += ((jnp.stack([k for k, _ in kvs]),
+                         jnp.stack([v for _, v in kvs])),)
         if state_ctx is not None:
             out += (pools,)
         return out if len(out) > 1 else logits

@@ -480,31 +480,39 @@ class GPTLayer(nn.Module):
     def __call__(self, x, *, positions=None, deterministic=True,
                  kv_ctx=None, return_kv=False):
         cfg = self.config
-        a = ParallelAttention(cfg, name="attention")(
-            _LayerNorm(cfg.hidden_size, name="input_norm")(x),
-            positions=positions, deterministic=deterministic,
-            kv_ctx=kv_ctx, return_kv=return_kv,
-        )
-        if return_kv:
-            a, kv, held = a
-        if cfg.hidden_dropout > 0.0 and not deterministic:
-            a = nn.Dropout(rate=cfg.hidden_dropout)(a, deterministic=False)
-        x = x + a
+        # each half of the block under its part of the model
+        # (telemetry.compiled.PARTS), norm and residual with it
+        with jax.named_scope("attention"):
+            a = ParallelAttention(cfg, name="attention")(
+                _LayerNorm(cfg.hidden_size, name="input_norm")(x),
+                positions=positions, deterministic=deterministic,
+                kv_ctx=kv_ctx, return_kv=return_kv,
+            )
+            if return_kv:
+                a, kv, held = a
+            if cfg.hidden_dropout > 0.0 and not deterministic:
+                a = nn.Dropout(rate=cfg.hidden_dropout)(
+                    a, deterministic=False)
+            x = x + a
         use_moe = (self.moe if self.moe is not None
                    else cfg.is_moe_layer(0) and cfg.moe_layer_freq == 1)
-        if use_moe:
-            from apex_tpu.moe import MoEMLP
+        # (a GPT block's MoE MLP counts as ``mlp`` too: its module is
+        # named so, and the innermost name on a path is its part)
+        with jax.named_scope("mlp"):
+            if use_moe:
+                from apex_tpu.moe import MoEMLP
 
-            m = MoEMLP(cfg.moe_cfg(), impl=cfg.moe_impl, name="mlp")(
-                _LayerNorm(cfg.hidden_size, name="post_norm")(x)
-            )
-        else:
-            m = ParallelMLP(cfg, name="mlp")(
-                _LayerNorm(cfg.hidden_size, name="post_norm")(x)
-            )
-        if cfg.hidden_dropout > 0.0 and not deterministic:
-            m = nn.Dropout(rate=cfg.hidden_dropout)(m, deterministic=False)
-        y = x + m
+                m = MoEMLP(cfg.moe_cfg(), impl=cfg.moe_impl, name="mlp")(
+                    _LayerNorm(cfg.hidden_size, name="post_norm")(x)
+                )
+            else:
+                m = ParallelMLP(cfg, name="mlp")(
+                    _LayerNorm(cfg.hidden_size, name="post_norm")(x)
+                )
+            if cfg.hidden_dropout > 0.0 and not deterministic:
+                m = nn.Dropout(rate=cfg.hidden_dropout)(
+                    m, deterministic=False)
+            y = x + m
         return (y, kv, held) if return_kv else y
 
 
@@ -578,21 +586,22 @@ class GPTModel(nn.Module):
             num_embeddings=cfg.vocab_size, embedding_dim=cfg.hidden_size,
             param_dtype=cfg.param_dtype, dtype=cfg.dtype, name="embedding",
         )
-        x = emb(tokens)                                   # (b, s, h)
-        pos = self.param(
-            "position_embedding",
-            nn.initializers.normal(stddev=0.02),
-            (cfg.max_seq_len, cfg.hidden_size), cfg.param_dtype,
-        )
-        if positions is None:
-            pos_emb = pos[None, :s]
-        else:
-            positions = jnp.asarray(positions)
-            pos_emb = jnp.take(pos, positions, axis=0)
-            if positions.ndim == 1:
-                pos_emb = pos_emb[None]                   # (1, s, h)
-        x = _gspmd.constrain_batch_major(x + pos_emb.astype(cfg.dtype))
-        x = _gspmd.constrain_hidden(x.transpose(1, 0, 2))  # (s, b, h)
+        with jax.named_scope("embed"):
+            x = emb(tokens)                               # (b, s, h)
+            pos = self.param(
+                "position_embedding",
+                nn.initializers.normal(stddev=0.02),
+                (cfg.max_seq_len, cfg.hidden_size), cfg.param_dtype,
+            )
+            if positions is None:
+                pos_emb = pos[None, :s]
+            else:
+                positions = jnp.asarray(positions)
+                pos_emb = jnp.take(pos, positions, axis=0)
+                if positions.ndim == 1:
+                    pos_emb = pos_emb[None]               # (1, s, h)
+            x = _gspmd.constrain_batch_major(x + pos_emb.astype(cfg.dtype))
+            x = _gspmd.constrain_hidden(x.transpose(1, 0, 2))  # (s, b, h)
 
         if cfg.sequence_parallel and _inside_axis(TENSOR_AXIS):
             from apex_tpu.transformer.tensor_parallel import (
@@ -620,9 +629,13 @@ class GPTModel(nn.Module):
                     length=cfg.num_layers,
                     in_axes=(0, nn.broadcast, nn.broadcast),
                 )
-                (x, _), kvs = scan(cfg, deterministic, name="layers")(
-                    (x, into), jnp.arange(cfg.num_layers, dtype=jnp.int32),
-                    kv_ctx, positions)
+                # the scan's own work (stacking what a layer returns,
+                # its counter) is no part's: it reads ``layer_scan``
+                with jax.named_scope("layer_scan"):
+                    (x, _), kvs = scan(cfg, deterministic, name="layers")(
+                        (x, into),
+                        jnp.arange(cfg.num_layers, dtype=jnp.int32),
+                        kv_ctx, positions)
             else:
                 scan = nn.scan(
                     _GPTScanBlock,
@@ -631,7 +644,9 @@ class GPTModel(nn.Module):
                     length=cfg.num_layers,
                     in_axes=nn.broadcast,
                 )
-                x, _ = scan(cfg, deterministic, name="layers")(x, positions)
+                with jax.named_scope("layer_scan"):
+                    x, _ = scan(cfg, deterministic, name="layers")(
+                        x, positions)
         else:
             per_layer = []
             for i in range(cfg.num_layers):
@@ -644,9 +659,11 @@ class GPTModel(nn.Module):
                     x, kv, into = x
                     per_layer.append(kv)
             if serving:
-                kvs = (jnp.stack([kv[0] for kv in per_layer]),
-                       jnp.stack([kv[1] for kv in per_layer]))
-        x = _LayerNorm(cfg.hidden_size, name="final_norm")(x)
+                with jax.named_scope("cache"):
+                    kvs = (jnp.stack([kv[0] for kv in per_layer]),
+                           jnp.stack([kv[1] for kv in per_layer]))
+        with jax.named_scope("head"):
+            x = _LayerNorm(cfg.hidden_size, name="final_norm")(x)
 
         if cfg.sequence_parallel and _inside_axis(TENSOR_AXIS):
             from apex_tpu.transformer.tensor_parallel import (
@@ -664,10 +681,11 @@ class GPTModel(nn.Module):
             )
             x = copy_to_tensor_model_parallel_region(x)
         table = emb.variables["params"]["embedding"]
-        logits = _gspmd.constrain_logits(jnp.einsum(
-            "sbh,vh->sbv", x.astype(jnp.float32),
-            table.astype(jnp.float32),
-        ))
+        with jax.named_scope("head"):
+            logits = _gspmd.constrain_logits(jnp.einsum(
+                "sbh,vh->sbv", x.astype(jnp.float32),
+                table.astype(jnp.float32),
+            ))
         if return_kv:
             return logits, kvs
         return logits
@@ -678,15 +696,17 @@ def gpt_loss_fn(logits, labels, axis_name: str = TENSOR_AXIS):
 
     logits: (s, b, vocab[/tp]) ; labels: (b, s)
     """
-    labels_sb = labels.transpose(1, 0)
-    if _inside_axis(axis_name):
-        losses = vocab_parallel_cross_entropy(logits, labels_sb,
-                                              axis_name=axis_name)
-    else:
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, labels_sb[..., None], -1)[..., 0]
-        losses = lse - tgt
-    return jnp.mean(losses)
+    with jax.named_scope("loss"):
+        labels_sb = labels.transpose(1, 0)
+        if _inside_axis(axis_name):
+            losses = vocab_parallel_cross_entropy(logits, labels_sb,
+                                                  axis_name=axis_name)
+        else:
+            lse = jax.scipy.special.logsumexp(logits, axis=-1)
+            tgt = jnp.take_along_axis(logits, labels_sb[..., None],
+                                      -1)[..., 0]
+            losses = lse - tgt
+        return jnp.mean(losses)
 
 
 # -- partition specs -------------------------------------------------------

@@ -193,7 +193,7 @@ def grouped_experts(x, weights, ids, w_gate, w_up, w_down,
     inv = jnp.argsort(order)
     rows = x.astype(dtype)[order // k]                    # (n*k, h) sorted
     sizes = jnp.bincount(flat, length=count + 1)[:count].astype(jnp.int32)
-    with jax.named_scope("moe_experts"):
+    with jax.named_scope("moe_experts"), jax.named_scope("grouped"):
         gate = lax.ragged_dot(rows, w_gate.astype(dtype), sizes)
         up = lax.ragged_dot(rows, w_up.astype(dtype), sizes)
         out = lax.ragged_dot(jax.nn.silu(gate) * up, w_down.astype(dtype),
@@ -223,7 +223,7 @@ def dense_experts(x, weights, ids, w_gate, w_up, w_down,
     combine = jnp.where(hit, weights.astype(f32)[:, :, None],
                         0.0).sum(axis=1).T                    # (count, n)
     rows = jnp.broadcast_to(x.astype(dtype), (count, *x.shape))
-    with jax.named_scope("moe_experts"):
+    with jax.named_scope("moe_experts"), jax.named_scope("dense"):
         gate = jnp.einsum("enh,ehf->enf", rows, w_gate.astype(dtype),
                           preferred_element_type=f32)
         up = jnp.einsum("enh,ehf->enf", rows, w_up.astype(dtype),
@@ -273,36 +273,46 @@ class HeldMoEMLP(nn.Module):
         w_up = self.param("w_up", init, (count, h, f), cfg.param_dtype)
         w_down = self.param("w_down", init, (count, f, h), cfg.param_dtype)
         lead = x.shape[:-1]
-        x2 = x.reshape(-1, h)
         softmax = cfg.router == "softmax"
-        with jax.named_scope("moe_router"):
-            if softmax:
-                weights, ids, scores = softmax_router(x2, gate, cfg.top_k)
-            else:
-                bias = self.param("select_bias", nn.initializers.zeros,
-                                  (cfg.num_experts,), jnp.float32)
-                weights, ids, scores = sigmoid_router(
-                    x2, gate, bias, cfg.top_k, route_scale=cfg.route_scale)
-        if (self.is_mutable_collection("routing")
-                and not self.is_initializing()):
-            self.sow("routing", "ids", ids)
-            if softmax:
-                self.sow("routing", "probs", scores)
-            else:
-                self.sow("routing", "biased",
-                         scores + bias.astype(jnp.float32))
-        out = held_experts(x2, weights, ids, w_gate, w_up, w_down,
-                           (first, count), cfg.dtype,
-                           num_experts=cfg.num_experts)
+        # the router and the routed experts are the ``experts`` part of
+        # the model, the shared expert its ``mlp``
+        # (telemetry.compiled.PARTS)
+        with jax.named_scope("experts"):
+            x2 = x.reshape(-1, h)
+            with jax.named_scope("moe_router"):
+                if softmax:
+                    weights, ids, scores = softmax_router(x2, gate,
+                                                          cfg.top_k)
+                else:
+                    bias = self.param("select_bias", nn.initializers.zeros,
+                                      (cfg.num_experts,), jnp.float32)
+                    weights, ids, scores = sigmoid_router(
+                        x2, gate, bias, cfg.top_k,
+                        route_scale=cfg.route_scale)
+            if (self.is_mutable_collection("routing")
+                    and not self.is_initializing()):
+                self.sow("routing", "ids", ids)
+                if softmax:
+                    self.sow("routing", "probs", scores)
+                else:
+                    self.sow("routing", "biased",
+                             scores + bias.astype(jnp.float32))
+            out = held_experts(x2, weights, ids, w_gate, w_up, w_down,
+                               (first, count), cfg.dtype,
+                               num_experts=cfg.num_experts)
         if cfg.shared_ffn_size:
             fs = cfg.shared_ffn_size
-            out = out + gated_mlp(
-                x2,
-                self.param("shared_gate", init, (h, fs), cfg.param_dtype),
-                self.param("shared_up", init, (h, fs), cfg.param_dtype),
-                self.param("shared_down", init, (fs, h), cfg.param_dtype),
-                cfg.dtype)
-        out = out.reshape(*lead, h)
+            with jax.named_scope("mlp"):
+                out = out + gated_mlp(
+                    x2,
+                    self.param("shared_gate", init, (h, fs),
+                               cfg.param_dtype),
+                    self.param("shared_up", init, (h, fs), cfg.param_dtype),
+                    self.param("shared_down", init, (fs, h),
+                               cfg.param_dtype),
+                    cfg.dtype)
+        with jax.named_scope("experts"):
+            out = out.reshape(*lead, h)
         if return_routing:
             return out, (weights, ids, scores)
         return out

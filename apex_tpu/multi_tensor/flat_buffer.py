@@ -131,7 +131,12 @@ class FlatSpace:
         flat function; ``has_aux`` passes through to the transform.
         """
         def flat_loss(master, *args, **kwargs):
-            return loss_fn(self.unpack(master), *args, **kwargs)
+            # the optimizer's share of a step (telemetry.compiled.PARTS):
+            # the cast and unpack of the master and, as its transpose,
+            # every leaf's gradient into the one flat buffer
+            with jax.named_scope("optimizer"):
+                tree = self.unpack(master)
+            return loss_fn(tree, *args, **kwargs)
 
         if with_value:
             return jax.value_and_grad(flat_loss, has_aux=has_aux)

@@ -161,7 +161,10 @@ class TrainStep:
             # are untouched.
             from apex_tpu.telemetry import compiled as _compiled
 
-            _compiled.observe("train_step", self._signature(state))
+            signature = self._signature(state)
+            _compiled.observe("train_step", signature)
+            _compiled.register_program("jit_jitted", signature,
+                                       self._jitted, args)
             with _compiled.label("train_step"):
                 return self._dispatch(args)
         return self._dispatch(args)
@@ -503,13 +506,17 @@ def make_train_step(
 
         @functools.partial(jax.jit, donate_argnums=donate)
         def jitted(state, flat_grads, scaler_state, lr):
-            return body(state, flat_grads, scaler_state, lr)
+            # the whole program is the ``optimizer`` part of a step
+            # (telemetry.compiled.PARTS)
+            with jax.named_scope("optimizer"):
+                return body(state, flat_grads, scaler_state, lr)
     else:
         donate = (0,) + ((1,) if donate_grads else ())
 
         @functools.partial(jax.jit, donate_argnums=donate)
         def jitted(state, flat_grads, lr):
-            return body(state, flat_grads, None, lr)
+            with jax.named_scope("optimizer"):
+                return body(state, flat_grads, None, lr)
 
     step = TrainStep(opt, scaler, jitted, body, options=dict(
         max_grad_norm=mgn, skip_if_nonfinite=skip, impl=impl,

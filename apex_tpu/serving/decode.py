@@ -299,16 +299,21 @@ class DecodeStep:
         def prefill_fn(params, state, *, tokens, lengths, tables, temps,
                        top_ks, top_ps, seeds, state_slots=None):
             b, s = tokens.shape
+            with jax.named_scope("embed"):
+                fresh = jnp.ones((b,), bool)
             logits, (k_new, v_new), state = apply(
                 params, state, tokens, state_slots=state_slots,
-                lengths=lengths, fresh=jnp.ones((b,), bool))
-            state = append_kv_prefill(state, k_new, v_new, tables, lengths)
-            last = jnp.clip(lengths - 1, 0, s - 1)
-            out = logits[last, jnp.arange(b)]          # (b, vocab)
-            # the emitted token lands at sequence index == prompt len
-            nxt = select_token(out, (temps, top_ks, top_ps, seeds),
-                               lengths)
-            return step_out(out, nxt, state)
+                lengths=lengths, fresh=fresh)
+            with jax.named_scope("cache"):
+                state = append_kv_prefill(state, k_new, v_new, tables,
+                                          lengths)
+            with jax.named_scope("head"):
+                last = jnp.clip(lengths - 1, 0, s - 1)
+                out = logits[last, jnp.arange(b)]      # (b, vocab)
+                # the emitted token lands at sequence index == prompt len
+                nxt = select_token(out, (temps, top_ks, top_ps, seeds),
+                                   lengths)
+                return step_out(out, nxt, state)
 
         def prefill_chunk_fn(params, state, *, tokens, starts, lengths,
                              tables, temps, top_ks, top_ps, seeds,
@@ -319,44 +324,52 @@ class DecodeStep:
             # gathers its own context, every previously-written
             # position (< starts); the chunk's own K/V rides kv_new
             # inside the attention
-            pos = jnp.clip(
-                starts[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :],
-                0, max_pos)
+            with jax.named_scope("embed"):
+                pos = jnp.clip(
+                    starts[:, None]
+                    + jnp.arange(s, dtype=jnp.int32)[None, :], 0, max_pos)
+                fresh = starts == 0
             logits, (k_new, v_new), state = apply(
                 params, state, tokens, state_slots=state_slots,
-                lengths=lengths, fresh=starts == 0, positions=pos,
+                lengths=lengths, fresh=fresh, positions=pos,
                 kv_ctx=(state.k, state.v, tables, starts,
                         *tail(window_tables, window_first)))
-            state = append_kv_chunk(state, k_new, v_new, tables, starts,
-                                    lengths)
-            last = jnp.clip(lengths - 1, 0, s - 1)
-            out = logits[last, jnp.arange(b)]          # (b, vocab)
-            # only meaningful on a prompt-completing chunk: the
-            # emitted token's index is starts + chunk length
-            nxt = select_token(out, (temps, top_ks, top_ps, seeds),
-                               starts + lengths)
-            return step_out(out, nxt, state)
+            with jax.named_scope("cache"):
+                state = append_kv_chunk(state, k_new, v_new, tables, starts,
+                                        lengths)
+            with jax.named_scope("head"):
+                last = jnp.clip(lengths - 1, 0, s - 1)
+                out = logits[last, jnp.arange(b)]      # (b, vocab)
+                # only meaningful on a prompt-completing chunk: the
+                # emitted token's index is starts + chunk length
+                nxt = select_token(out, (temps, top_ks, top_ps, seeds),
+                                   starts + lengths)
+                return step_out(out, nxt, state)
 
         def decode_fn(params, state, *, tokens, positions, tables, temps,
                       top_ks, top_ps, seeds, window_tables=None,
                       window_first=None, state_slots=None):
-            pos2 = jnp.clip(positions, 0, max_pos)[:, None]   # (b, 1)
+            with jax.named_scope("embed"):
+                pos2 = jnp.clip(positions, 0, max_pos)[:, None]   # (b, 1)
+                tokens = tokens[:, None]
+                fresh = jnp.zeros(positions.shape, bool)
             # each layer gathers its own context from the pools and
             # attends with the token's K/V in slot positions[b] of it:
             # the slot append_kv writes below, which the table covers
             logits, (k_new, v_new), state = apply(
-                params, state, tokens[:, None], state_slots=state_slots,
-                lengths=None, fresh=jnp.zeros(tokens.shape, bool),
-                positions=pos2,
+                params, state, tokens, state_slots=state_slots,
+                lengths=None, fresh=fresh, positions=pos2,
                 kv_ctx=(state.k, state.v, tables, positions,
                         *tail(window_tables, window_first)))
-            state = append_kv(state, k_new[:, :, :, 0], v_new[:, :, :, 0],
-                              tables, positions)
-            out = logits[0]                            # (b, vocab)
-            # the emitted token lands at positions + 1
-            nxt = select_token(out, (temps, top_ks, top_ps, seeds),
-                               positions + 1)
-            return step_out(out, nxt, state)
+            with jax.named_scope("cache"):
+                state = append_kv(state, k_new[:, :, :, 0],
+                                  v_new[:, :, :, 0], tables, positions)
+            with jax.named_scope("head"):
+                out = logits[0]                        # (b, vocab)
+                # the emitted token lands at positions + 1
+                nxt = select_token(out, (temps, top_ks, top_ps, seeds),
+                                   positions + 1)
+                return step_out(out, nxt, state)
 
         # what each program computes from its fields, by name
         bodies = self._bodies = {"prefill_step": prefill_fn,
@@ -370,7 +383,12 @@ class DecodeStep:
             body = bodies[fn]
 
             def run(params, state, packed):
-                return body(params, state, **unpack(packed, layout))
+                # every operation of a program lies under one part of
+                # the model (telemetry.compiled.PARTS): taking the host
+                # argument apart is the cache's table arithmetic
+                with jax.named_scope("cache"):
+                    fields = unpack(packed, layout)
+                return body(params, state, **fields)
 
             # the program's name in a device trace (jit_decode_fn,
             # jit_prefill_fn, ...): what a trace's readers find it by
@@ -440,7 +458,11 @@ class DecodeStep:
             self._compiled[key] = entry
             from apex_tpu.telemetry import compiled as _compiled
 
-            _compiled.observe(fn, self._signature(fn, key))
+            signature = self._signature(fn, key)
+            _compiled.observe(fn, signature)
+            _compiled.register_program(
+                "jit_" + program.__name__, signature, program,
+                (params, state, packed))
             from apex_tpu.mesh import mesh as _gspmd_mesh
 
             if _gspmd_mesh.mesh_initialized() \

@@ -125,7 +125,6 @@ import numpy as np
 
 from apex_tpu.serving.decode import (DecodeStep, host_tokens,
                                      make_decode_step)
-from apex_tpu.moe.held import expert_form
 from apex_tpu.ops.kv_gather import live_blocks
 from apex_tpu.serving.kv_cache import KVCache, PoolExhausted, bucket
 from apex_tpu.telemetry import timeline as _timeline
@@ -347,16 +346,6 @@ class ContinuousBatcher:
         # layers, and those of them that lie wholly behind a window
         # layer's window, which no later query reads (ROADMAP.md R3)
         self.held = {"block_layers": 0, "behind_window": 0}
-        # the expert-layer calls dispatched so far, by the form their
-        # three products took (host side): a dispatch adds the model's
-        # expert layers under what moe/held.py expert_form, the
-        # function the layer itself calls, gives for the dispatch's
-        # rows; nothing for a model with no such layer
-        self.expert_calls = {"dense": 0, "grouped": 0}
-        self._expert_layers = [mlp for _, mlp in getattr(
-            config, "layers", ())].count("experts")
-        if self._expert_layers:
-            self._routing = (config.experts_per_token, config.num_experts)
         if cache.state_slots:
             # a model with recurrent layers (docs/serving.md "Recurrent
             # state"), at the same steps' ends: the state slots in
@@ -1164,14 +1153,6 @@ class ContinuousBatcher:
         count("window", positions - first * bs, ww)
         return {"window": (tables, first)}
 
-    def _count_expert_calls(self, rows: int) -> None:
-        """Add a dispatch of ``rows`` tokens (lanes times the positions
-        a lane, pads and dummy lanes with them: the program's static
-        shape) to ``expert_calls``."""
-        if self._expert_layers:
-            self.expert_calls[expert_form(rows, *self._routing)] \
-                += self._expert_layers
-
     def _state_slots(self, seq_ids, batch: int) -> Dict[str, Any]:
         """The ``slots=`` argument of a dispatch: the lanes' state
         slots, where the model has recurrent layers (nothing
@@ -1246,7 +1227,6 @@ class ContinuousBatcher:
                 tables = self._tables_for(admitted, b)
                 sampling = self._sampling_for(admitted, b)
                 slots = self._state_slots([f.seq_id for f in admitted], b)
-                self._count_expert_calls(b * s)
             t0 = self.clock()
             with self._ring_dispatch("prefill"):
                 with self._span("apex.serve.prefill.dispatch"):
@@ -1302,7 +1282,6 @@ class ContinuousBatcher:
                 window = self._window_tables(seqs, starts, width, b)
                 window.update(self._state_slots(seqs, b))
                 sampling = self._sampling_for([f for f, _ in batchees], b)
-                self._count_expert_calls(b * s)
             with self._ring_dispatch("prefill_chunk"):
                 with self._span("apex.serve.chunk.dispatch"):
                     faults.maybe_prefill_chunk_exception(cidx)
@@ -1495,7 +1474,6 @@ class ContinuousBatcher:
                 window = self._window_tables(seqs, positions, width, b)
                 window.update(self._state_slots(seqs, b))
                 sampling = self._sampling_for(flights, b)
-                self._count_expert_calls(b)
             with self._ring_dispatch("decode"):
                 with self._span("apex.serve.decode.dispatch"):
                     faults.maybe_decode_exception(idx)

@@ -35,6 +35,17 @@ slow"). This module is that plane:
   ``APEX_TPU_RECOMPILE_STORM_N`` (default 3) and
   ``APEX_TPU_RECOMPILE_STORM_WINDOW`` (default 100 steps).
 
+- **Scope tables** (PR 37): every operation of a step lies under one
+  *part* of the model, a ``jax.named_scope`` from :data:`PARTS`. The
+  entry points :func:`register_program` each new program on the same
+  cold paths (its name as a trace prints it, its signature, the jitted
+  function, its abstract arguments); :func:`scope_tables`
+  pulls each one's compiled text (an in-memory hit: nothing compiles),
+  parses it once and hands out ``{instruction name -> op_name}``, so
+  a profiler trace's ``fusion.70`` can be given to the part of the
+  model it belongs to (:func:`part_of`). Armed or not, a registration
+  is one dict write a *new* program; no text is pulled until asked.
+
 Everything is host-side and disarmed by default: with no tracker
 enabled, :func:`observe` is one module-global read and :func:`label`
 returns a shared null context — the instrumented entry points only
@@ -50,10 +61,11 @@ import contextlib
 import hashlib
 import json
 import os
+import re
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 _STORM_N_ENV = "APEX_TPU_RECOMPILE_STORM_N"
 _STORM_WINDOW_ENV = "APEX_TPU_RECOMPILE_STORM_WINDOW"
@@ -375,15 +387,298 @@ def observe(fn: str, signature: Dict[str, Any], *,
         return "error"
 
 
+# ---------------------------------------------------------------------------
+# Scope tables: which part of the model a compiled instruction belongs to
+# ---------------------------------------------------------------------------
+
+#: The parts of a step, each a ``jax.named_scope`` the models, the
+#: serving programs and the trainers open around their work
+#: (docs/observability.md "Scope tables"). An operation belongs to the
+#: INNERMOST part on its ``op_name`` path: a Flax module's own name is
+#: on the path too (``layer_3/attention/cache/kv_gather`` is the
+#: cache's, ``layer_1/mlp/experts/moe_router`` the experts').
+PARTS = ("embed", "attention", "cache", "mixer", "experts", "mlp", "head",
+         "loss", "optimizer")
+UNSCOPED = "unscoped"
+
+# a path segment under transformations, ``transpose(jvp(attention))``:
+# the wrappers, then the name
+_WRAPPED = re.compile(r"^((?:[A-Za-z_][\w.]*\()*)([^()]*)\)*$")
+
+
+def part_of(op_name: Optional[str]) -> Optional[str]:
+    """The part of :data:`PARTS` an ``op_name`` path lies under, or
+    None: its innermost segment that names one, also inside ``jvp(...)``
+    and ``transpose(...)`` (the backward pass) and under ``while/body``.
+    A jitted function's own segment (``jit(loss)``) is no scope. Where
+    the compiler joined several paths with ``;`` the first that has a
+    part speaks."""
+    for path in (op_name or "").split(";"):
+        for seg in reversed(path.split("/")):
+            m = _WRAPPED.match(seg)
+            if m and m.group(2) in PARTS and "jit(" not in m.group(1):
+                return m.group(2)
+    return None
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*)$")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_CALLED = re.compile(
+    r"\b(calls|body|condition|to_apply|true_computation|false_computation)"
+    r"=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+_REFERENCE = re.compile(r"%([\w.\-]+)")
+_QUOTED = re.compile(r'"([^"\n]*)"')
+# the opcodes whose called computations run as instructions of their
+# own (a trace shows them); a fusion's run inside it, a reduce's
+# ``to_apply`` is a scalar rule
+_CONTROL = ("while", "call", "conditional", "async-start")
+# how far an instruction nobody named looks for its consumers
+_CONSUMER_DEPTH = 4
+#: instructions that do no work of their own: what the 95%-under-a-part
+#: rule of the tests leaves out
+PLUMBING = ("parameter", "constant", "tuple", "get-tuple-element",
+            "bitcast")
+
+
+def _group(text: str) -> int:
+    """Where the parenthesis ``text`` opens with closes."""
+    depth = 0
+    for i, ch in enumerate(text):
+        depth += (ch == "(") - (ch == ")")
+        if depth == 0:
+            return i
+    return len(text) - 1
+
+
+def _split_instruction(rest: str) -> Tuple[str, str, List[str]]:
+    """``(result shape, opcode, operands' names)`` of what follows
+    ``%name = ``."""
+    if rest.startswith("("):             # a tuple result: to its close
+        i = _group(rest)
+        result, tail = rest[:i + 1], rest[i + 1:].lstrip()
+    else:
+        result, _, tail = rest.partition(" ")
+    opcode, paren, operands = tail.partition("(")
+    operands = (paren + operands)[:_group(paren + operands) + 1]
+    return result, opcode, _REFERENCE.findall(operands)
+
+
+def parse_hlo_text(text: str) -> Dict[str, Any]:
+    """One compiled program's text (``compiled.as_text()``) as a scope
+    table: ``ops`` ``{instruction name -> op_name}``, ``opcodes`` and
+    ``results`` (the result's shape as printed) by the same names, for
+    the entry computation and every computation a ``while``, ``call``,
+    conditional or asynchronous start of it runs; ``fusion_parts``
+    ``{fusion's name -> the parts its fused computation holds}``; and
+    ``parts`` ``{instruction name -> its part}`` for the instructions
+    that have one: by their own ``op_name`` (:func:`part_of`), else a
+    fusion's by the one part its computation holds, else (a weight's
+    prefetch, a layout copy: the compiler's own, named by nobody) by
+    the one part that consumes the result."""
+    comps: Dict[str, List[Tuple[str, str, str, str, List[str]]]] = {}
+    operands: Dict[str, List[str]] = {}
+    entry, current = None, None
+    for line in text.splitlines():
+        if current is None:
+            m = _COMPUTATION.match(line)
+            if m:
+                current = comps.setdefault(m.group(1), [])
+                if line.startswith("ENTRY"):
+                    entry = m.group(1)
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        result, opcode, operands[m.group(1)] = _split_instruction(m.group(2))
+        called = [c for _, c in _CALLED.findall(line)]
+        for group in _BRANCHES.findall(line):
+            called += [c.strip().lstrip("%") for c in group.split(",")]
+        found = _OP_NAME.search(line)
+        current.append((m.group(1), opcode, result,
+                        found.group(1) if found else "", called))
+
+    def parts_inside(comp: str, seen: set) -> set:
+        out = set()
+        if comp in seen:
+            return out
+        seen.add(comp)
+        for _, _, _, op_name, called in comps.get(comp, ()):
+            part = part_of(op_name)
+            if part:
+                out.add(part)
+            for c in called:
+                out |= parts_inside(c, seen)
+        return out
+
+    table: Dict[str, Any] = {"ops": {}, "opcodes": {}, "results": {},
+                             "fusion_parts": {}, "parts": {}}
+    todo, done = [entry] if entry else [], set()
+    while todo:
+        comp = todo.pop()
+        if comp in done:
+            continue
+        done.add(comp)
+        for name, opcode, result, op_name, called in comps.get(comp, ()):
+            table["ops"][name] = op_name
+            table["opcodes"][name] = opcode
+            table["results"][name] = result
+            if opcode == "fusion":
+                inside = set()
+                for c in called:
+                    inside |= parts_inside(c, set())
+                table["fusion_parts"][name] = sorted(inside)
+            elif opcode in _CONTROL:
+                todo += called
+    parts = table["parts"]
+    for name, op_name in table["ops"].items():
+        inside = table["fusion_parts"].get(name, ())
+        part = part_of(op_name) or (inside[0] if len(inside) == 1 else None)
+        if part:
+            parts[name] = part
+    users: Dict[str, List[str]] = {}
+    for name in table["ops"]:
+        for operand in operands[name]:
+            users.setdefault(operand, []).append(name)
+    for name in table["ops"]:
+        if name in parts or table["opcodes"][name] in PLUMBING:
+            continue
+        # the parts of the nearest consumers that have one, through
+        # those that have none
+        found, seen, front = set(), {name}, [name]
+        for _ in range(_CONSUMER_DEPTH):
+            front = [u for n in front for u in users.get(n, ())
+                     if u not in seen and not seen.add(u)]
+            found |= {parts[u] for u in front if u in parts}
+            front = [u for u in front if u not in parts]
+        if len(found) == 1:
+            parts[name] = found.pop()
+    return table
+
+
+def _abstract(tree):
+    """``tree`` with every array replaced by its shape, dtype and
+    sharding: what a program can be lowered from again once its donated
+    arguments are gone."""
+    import jax
+
+    def leaf(x):
+        if not hasattr(x, "shape") or not hasattr(x, "dtype"):
+            return x                    # None, a Python scalar
+        # an uncommitted array's sharding is no part of the program's
+        # key: naming it here would compile a second program
+        placed = getattr(x, "committed", False)
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding if placed else None,
+            weak_type=bool(getattr(x, "weak_type", False)))
+
+    return jax.tree.map(leaf, tree)
+
+
+# (name, signature key) -> what register_program was told; process-wide,
+# armed or not (the tables are asked for after the fact), the newest
+# MAX_PROGRAMS of them
+_PROGRAMS: Dict[Tuple[str, str], Dict[str, Any]] = {}
+_PROGRAMS_LOCK = threading.Lock()
+#: The registry keeps the jitted functions themselves, and no more than
+#: this many: whoever asks for the tables asks after the fact, when the
+#: step that ran the programs may be gone (a benchmark's reader runs
+#: once its driver has returned and dropped the engine), so a weak
+#: hold hands out nothing. A function holds its closures and jax's
+#: traces of it, no array; past the bound the oldest goes.
+MAX_PROGRAMS = 128
+
+
+def register_program(name: str, signature: Dict[str, Any], jitted,
+                     args: tuple) -> None:
+    """The entry point is about to run a NEW program: ``name`` as a
+    device trace prints it (``jit_decode_fn``), its abstract
+    ``signature`` (:meth:`CompileTracker.observe`'s), the jitted
+    function and the arguments of the call, kept as shapes, dtypes and
+    shardings alone (the state is donated). One dict write; never
+    raises."""
+    try:
+        key = (str(name), json.dumps(signature, sort_keys=True, default=str))
+        if not callable(getattr(jitted, "lower", None)):
+            return
+        entry = {"name": str(name), "signature": dict(signature),
+                 "jitted": jitted, "args": _abstract(args)}
+        with _PROGRAMS_LOCK:
+            _PROGRAMS.pop(key, None)
+            _PROGRAMS[key] = entry
+            while len(_PROGRAMS) > MAX_PROGRAMS:
+                del _PROGRAMS[next(iter(_PROGRAMS))]
+    except Exception:  # noqa: BLE001 — observability must not stop a step
+        pass
+
+
+def _scope_table(entry: Dict[str, Any]) -> Dict[str, Any]:
+    lowered = entry["jitted"].lower(*entry["args"])
+    table = parse_hlo_text(lowered.compile().as_text())
+    # what this tree's source opens, from the lowering's locations: an
+    # executable loaded from a persistent-cache entry an older tree
+    # wrote carries THAT tree's scopes (metadata is not in the key)
+    opened = {part_of(path) for path in set(_QUOTED.findall(
+        lowered.as_text(debug_info=True)))} - {None}
+    held = set(table["parts"].values())
+    for inside in table["fusion_parts"].values():
+        held |= set(inside)
+    table.update(name=entry["name"], signature=entry["signature"],
+                 missing_parts=sorted(opened - held))
+    return table
+
+
+def scope_tables() -> List[Dict[str, Any]]:
+    """One scope table (:func:`parse_hlo_text`, with ``name``,
+    ``signature`` and ``missing_parts``: the parts this tree's source
+    opens and the compiled text lacks) for every registered program,
+    oldest first. Lowering and compiling from the registered shapes is
+    an in-memory hit on a program that has run: nothing compiles. Each
+    text is parsed once; the table is then kept in the function's
+    place. A program that cannot be lowered any more is left out."""
+    with _PROGRAMS_LOCK:
+        entries = list(_PROGRAMS.values())
+    out = []
+    for entry in entries:
+        if "table" not in entry:
+            try:
+                entry["table"] = _scope_table(entry)
+            except Exception:  # noqa: BLE001 — a reader gets what there is
+                continue
+            del entry["jitted"], entry["args"]
+        out.append(entry["table"])
+    return out
+
+
+def forget_programs() -> None:
+    """Empty the registry (a test's clean slate; a long-lived process
+    that has rebuilt its steps)."""
+    with _PROGRAMS_LOCK:
+        _PROGRAMS.clear()
+
+
 __all__ = [
     "BACKEND_COMPILE_EVENT",
     "CompileTracker",
+    "MAX_PROGRAMS",
+    "PARTS",
+    "PLUMBING",
+    "UNSCOPED",
     "abstract_signature",
     "current_label",
     "disable",
     "enable",
+    "forget_programs",
     "get_tracker",
     "label",
     "observe",
+    "parse_hlo_text",
+    "part_of",
+    "register_program",
+    "scope_tables",
     "signature_diff",
 ]

@@ -60,6 +60,21 @@ def rng():
     return np.random.RandomState(42)
 
 
+@pytest.fixture(autouse=True)
+def _a_trace_directory_of_its_own(request, monkeypatch, tmp_path_factory):
+    """A traced rehearsal of the benchmark empties ``<ROOT>/.bench_trace``
+    and fills it again (``benchmark/common.py`` ``traced``). Four test
+    files make such runs, and under several workers one emptied the
+    directory another was about to read (``no .xplane.pb``). Each test
+    of those files gets a root of its own for what ``run.py`` writes;
+    the files it reads are found from ``BENCH_DIR`` and the manifest's
+    path as before."""
+    if request.module.__name__.startswith("test_benchmark"):
+        from benchmark import run
+
+        monkeypatch.setattr(run, "ROOT", str(tmp_path_factory.mktemp("run")))
+
+
 @pytest.fixture(params=["xla", "interpret"])
 def impl(request):
     """Every fused op runs both the XLA reference path and the Pallas

@@ -277,6 +277,19 @@ def test_the_manifest_only_gained_entries():
     assert reported == {"serve_tok_s", "itl_p95_ms", "setup_s"}
     per_layer = [m["name"] for m in manifest["per_layer"]
                  if CELL in m.get("workloads", ())]
+    # PR 37's split of a call by part of the model came as data, after
+    # this cell's own six
+    split = [name for name in per_layer if "_call_ms." in name
+             and not name.startswith("prefill_call_ms.")]
+    assert split == [
+        "decode_call_ms.attention", "decode_call_ms.cache",
+        "decode_call_ms.experts", "decode_call_ms.mixer",
+        "decode_call_ms.mlp", "decode_call_ms.head",
+        "decode_call_ms.unscoped", "chunk_call_ms.experts",
+        "chunk_call_ms.head"] == per_layer[-9:]
+    own = len(manifest["per_layer"]) - 13
+    assert own == 36
+    per_layer = per_layer[:-9]
     assert per_layer[-6:] == [
         "ssm_state_pct", "ssm_state_copy_pct", "state_share_of_cache_pct",
         "prefill_call_ms.sessions", "attention_roofline.sessions",
@@ -289,10 +302,10 @@ def test_the_manifest_only_gained_entries():
     for name in per_layer:
         assert os.path.exists(os.path.join(
             ROOT, "benchmark", "layer_metrics", name + ".json")), name
-    for m in manifest["per_layer"][-6:]:
+    for m in manifest["per_layer"][own - 6:own]:
         assert m["workloads"] == [CELL]
     # a list that gained the cell gained it at its end
-    for m in manifest["end_to_end"] + manifest["per_layer"][:-6]:
+    for m in manifest["end_to_end"] + manifest["per_layer"][:own - 6]:
         if CELL in m.get("workloads", ()):
             assert m["workloads"][-1] == CELL, m["name"]
 

@@ -207,6 +207,14 @@ def test_the_manifest_only_gained_entries():
     assert reported == {"serve_tok_s", "itl_p95_ms", "setup_s"}
     per_layer = [m["name"] for m in manifest["per_layer"]
                  if CELL in m.get("workloads", ())]
+    # last, PR 37's split of a call by part of the model (no mixer and
+    # no dense MLP in this model)
+    assert per_layer[-7:] == [
+        "decode_call_ms.attention", "decode_call_ms.cache",
+        "decode_call_ms.experts", "decode_call_ms.head",
+        "decode_call_ms.unscoped", "chunk_call_ms.experts",
+        "chunk_call_ms.head"]
+    per_layer = per_layer[:-7]
     # PR 33's four, then what came as data since: PR 34's counter of
     # whole-pool copies, which Trinity's cell reports too
     assert per_layer[-5:] == [

@@ -747,38 +747,206 @@ def test_gathered_counts_the_live_blocks():
     assert steps[-1]["full_live"] > steps[0]["full_live"]
 
 
-@pytest.mark.parametrize("block", [*BLOCKS, "no experts"])
-def test_expert_calls_are_the_dispatches_arithmetic(built, block):
-    """``ContinuousBatcher.expert_calls`` over a toy deck (whole
-    prompts, chunks of 8, decodes on four lanes): every dispatch adds
-    the model's expert layers under the form ``expert_form`` gives for
-    the rows of its program, pads and dummy lanes with them; a model
-    with no expert layer adds nothing."""
-    if block == "no experts":
-        model, params, cfg = _kernel_model("pattern")
-    else:
-        (model, params), cfg = built(block), BLOCKS[block][0]()
-    engine, cache = _engine(model, params, cfg, prefill_chunk=8)
-    rows = []
-    for name in ("prefill", "prefill_chunk", "decode"):
-        def counted(params, state, toks, *args,
-                    _real=getattr(engine.step_fn, name), **kw):
-            rows.append(np.asarray(toks).size)
-            return _real(params, state, toks, *args, **kw)
-        setattr(engine.step_fn, name, counted)
-    _serve(engine, cache, [
-        serving.Request(id=i, prompt=tokens(n, 30 + i), max_new_tokens=m)
-        for i, (n, m) in enumerate([(8, 6), (21, 7), (5, 4)])])
-    layers = [mlp for _, mlp in cfg.layers].count("experts")
-    want = {"dense": 0, "grouped": 0}
-    for n in rows:
-        want[expert_form(n, cfg.experts_per_token, cfg.num_experts)] += layers
-    assert len(rows) > 10 and engine.expert_calls == want
-    assert sum(want.values()) == layers * len(rows)
-    if block == "afmoe":       # 4 lanes x top-2 over 16: under a pair each
-        assert want["grouped"] > 0 and want["dense"] > 0
-    if block == "no experts":
-        assert layers == 0
+# -- scope tables: which part of the model a compiled instruction is ----
+
+SCOPED_KINDS = {
+    # kind -> the parts its programs hold (telemetry.compiled.PARTS)
+    "gpt": {"embed", "attention", "cache", "mlp", "head"},
+    "gpt-window": {"embed", "attention", "cache", "mlp", "head"},
+    "afmoe": {"embed", "attention", "cache", "experts", "mlp", "head"},
+    "mellum": {"embed", "attention", "cache", "experts", "head"},
+    "granite": {"embed", "attention", "cache", "mixer", "experts", "mlp",
+                "head"},
+}
+PROGRAMS = {"prefill_step": "jit_prefill_fn",
+            "prefill_chunk": "jit_prefill_chunk_fn",
+            "decode_step": "jit_decode_fn"}
+
+
+def _scoped_model(kind, built):
+    if kind in BLOCKS:
+        (model, params), cfg = built(kind), BLOCKS[kind][0]()
+        return model, params, cfg
+    from apex_tpu.models.gpt import GPTConfig, GPTModel
+
+    cfg = GPTConfig(vocab_size=VOCAB, max_seq_len=64, hidden_size=64,
+                    num_layers=2, num_heads=4, num_kv_heads=2,
+                    attention_window=8 if kind == "gpt-window" else None,
+                    dtype=jnp.float32)
+    model = GPTModel(cfg)
+    shapes = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 8), jnp.int32)),
+        jax.random.PRNGKey(0))
+    return model, seeded(shapes), cfg
+
+
+@pytest.fixture(scope="module")
+def scoped(built):
+    """``kind -> (engine, tables by program, compiles fired by the
+    pull)``: a toy deck served once a kind (whole prompts, chunks of 8,
+    decodes), then every registered program's scope table pulled."""
+    import gc
+
+    from apex_tpu.telemetry import compiled
+
+    made = {}
+
+    def get(kind):
+        if kind in made:
+            return made[kind]
+        gc.collect()
+        compiled.forget_programs()
+        model, params, cfg = _scoped_model(kind, built)
+        engine, cache = _engine(model, params, cfg, prefill_chunk=8)
+        _serve(engine, cache, [
+            serving.Request(id=i, prompt=tokens(n, 30 + i), max_new_tokens=m)
+            for i, (n, m) in enumerate([(8, 6), (21, 7), (5, 4)])])
+        keys = engine.step_fn.compile_keys()
+        fired = []
+
+        def listen(name, secs, **kw):
+            if name == compiled.BACKEND_COMPILE_EVENT:
+                fired.append(name)
+
+        jax.monitoring.register_event_duration_secs_listener(listen)
+        try:
+            tables = compiled.scope_tables()
+        finally:
+            jax.monitoring.unregister_event_duration_listener(listen)
+        by_fn = {}
+        for table in tables:
+            by_fn.setdefault(table["signature"]["fn"], []).append(table)
+        made[kind] = engine, by_fn, len(fired), keys
+        return made[kind]
+
+    return get
+
+
+@pytest.mark.parametrize("program", list(PROGRAMS))
+@pytest.mark.parametrize("kind", list(SCOPED_KINDS))
+def test_every_instruction_knows_its_part(scoped, kind, program):
+    """After a dispatch of each of a ``DecodeStep``'s programs
+    ``compiled.scope_tables()`` holds one table a program, under the
+    name a trace prints; at least 95% of the instructions that do work
+    and that the source named lie under a part (what is left is the
+    layer scan's own counter), and 85% with the compiler's unnamed ones
+    counted (the CPU's copies of a loop's carry, which nothing but the
+    ``while`` consumes); every part is one of the list, and the kind's
+    parts are all there (nothing the source opens is missing)."""
+    from apex_tpu.telemetry import compiled
+
+    engine, by_fn, _, keys = scoped(kind)
+    tables = by_fn[program]
+    assert len(tables) == keys[program] > 0
+    for table in tables:
+        assert table["name"] == PROGRAMS[program]
+        assert table["missing_parts"] == []
+        work = [name for name, opcode in table["opcodes"].items()
+                if opcode not in compiled.PLUMBING]
+        parts = [table["parts"].get(name) for name in work]
+        assert len(work) > 50
+        left = [(n, table["opcodes"][n], table["ops"][n]) for n in work
+                if n not in table["parts"]]
+        named = [n for n in work if table["ops"][n]]
+        assert len([u for u in left if u[2]]) <= 0.05 * len(named), left
+        assert len(left) <= 0.15 * len(work), left
+        assert set(parts) - {None} == SCOPED_KINDS[kind]
+        # one part an instruction: its own op_name's innermost, and the
+        # paths of one part never read as another's
+        for name, op_name in table["ops"].items():
+            own = compiled.part_of(op_name)
+            if own is not None:
+                assert table["parts"][name] == own
+        scoped_ops = [op for op in table["ops"].values()
+                      if compiled.part_of(op)]
+        assert all(compiled.part_of(op) == "cache" for op in scoped_ops
+                   if "/kv_gather" in op or "/zero_context" in op)
+        assert all(compiled.part_of(op) == "experts" for op in scoped_ops
+                   if "/moe_router/" in op or "/moe_experts/" in op)
+        assert all(compiled.part_of(op) == "mixer" for op in scoped_ops
+                   if "/mamba_mixer/" in op)
+
+
+@pytest.mark.parametrize("kind", list(SCOPED_KINDS))
+def test_pulling_the_tables_compiles_nothing(scoped, kind):
+    """Lowering and compiling a program that has run from its
+    registered shapes is an in-memory hit: no backend compile fires,
+    and the step's own compile cache is as it was."""
+    engine, by_fn, fired, keys = scoped(kind)
+    assert fired == 0
+    assert engine.step_fn.compile_keys() == keys
+    assert sum(len(t) for t in by_fn.values()) == sum(keys.values())
+
+
+@pytest.mark.parametrize("block", ["mellum", "granite"])
+def test_the_compiled_program_holds_the_form_the_rule_gives(built, block):
+    """``expert_form`` in the compiled text, not a host tally: a decode
+    call of four lanes holds ``moe_experts/dense`` and no grouped
+    product, a whole-prompt call of 144 rows ``moe_experts/grouped``
+    and no dense one."""
+    import gc
+
+    from apex_tpu.telemetry import compiled
+
+    gc.collect()
+    compiled.forget_programs()
+    (model, params), cfg = built(block), BLOCKS[block][0]()
+    engine, cache = _engine(model, params, cfg)
+    step, state = engine.step_fn, cache.init_state()
+    b, s = 2, 72
+    assert expert_form(b * s, cfg.experts_per_token,
+                       cfg.num_experts) == "grouped"
+    assert expert_form(4, cfg.experts_per_token, cfg.num_experts) == "dense"
+    recurrent = getattr(cfg, "mamba", None) is not None
+    slots = {"slots": np.zeros(b, np.int32)} if recurrent else {}
+    out = step.prefill(params, state, np.zeros((b, s), np.int32),
+                       np.full(b, s, np.int32), np.zeros((b, 18), np.int32),
+                       **slots)
+    window = {} if recurrent else {
+        "window": (np.zeros((4, 3), np.int32), np.zeros(4, np.int32))}
+    slots = {"slots": np.zeros(4, np.int32)} if recurrent else {}
+    step.decode(params, out.cache, np.zeros(4, np.int32),
+                np.zeros(4, np.int32), np.zeros((4, 2), np.int32),
+                **window, **slots)
+    forms = {}
+    for table in compiled.scope_tables():
+        text = " ".join(table["ops"].values())
+        forms[table["name"]] = {form for form in EXPERT_FORMS
+                                if f"/moe_experts/{form}/" in text}
+    assert forms == {"jit_prefill_fn": {"grouped"},
+                     "jit_decode_fn": {"dense"}}
+
+
+def test_a_dropped_decode_step_leaves_its_tables_until_the_bound(
+        built, monkeypatch):
+    """Whoever asks for the tables asks after the fact: a step that was
+    dropped (as a benchmark's driver drops its engine before any reader
+    runs) still has its programs' tables, the registry holds no more
+    than ``MAX_PROGRAMS`` of them, oldest out first, and
+    ``forget_programs`` empties it."""
+    import gc
+
+    from apex_tpu.telemetry import compiled
+
+    compiled.forget_programs()
+    (model, params), cfg = built("afmoe"), BLOCKS["afmoe"][0]()
+    engine, cache = _engine(model, params, cfg)
+    _serve(engine, cache, [serving.Request(id=0, prompt=tokens(5, 1),
+                                           max_new_tokens=2)])
+    n = sum(engine.step_fn.compile_keys().values())
+    del engine, cache
+    gc.collect()
+    tables = compiled.scope_tables()
+    assert len(tables) == n >= 2
+    assert all(t["parts"] and not t["missing_parts"] for t in tables)
+    monkeypatch.setattr(compiled, "MAX_PROGRAMS", n)
+    newest = jax.jit(lambda x: x + 1)
+    compiled.register_program("jit_newest", {"fn": "newest"}, newest,
+                              (jnp.ones(3),))
+    assert [t["name"] for t in compiled.scope_tables()] == [
+        *(t["name"] for t in tables[1:]), "jit_newest"]
+    compiled.forget_programs()
+    assert compiled.scope_tables() == []
 
 
 @both_blocks
