@@ -28,12 +28,16 @@ dropped, and the grouped products see ``tokens x top_k`` rows.
 The held experts' three products take one of two forms, chosen from
 the call's static shapes by ``expert_form`` and by nothing else.
 *Grouped* (``grouped_experts``): the pairs sorted by expert, three
-``lax.ragged_dot`` over the held pairs, the results scattered back;
-it reads only the experts some pair chose, which is what a prefill or
-chunk call (hundreds of rows) and a call with under a pair an expert
-want. *Dense* (``dense_experts``): every held expert multiplies every
-row once, three plain batched matmuls over weights read where they
-lie, the unchosen (row, expert) results dropped by a select; it is
+grouped products over the held pairs (``ops/moe_grouped.py``
+``moe_grouped``: ``lax.ragged_dot`` as one Pallas kernel, entered
+through one jit so that a program lowers one kernel body a shape, not
+one a call site, which every warm start would pay for again), the
+results scattered back; it reads only the experts some pair chose,
+which is what a prefill or chunk call (hundreds of rows) and a call
+with under a pair an expert want. *Dense* (``dense_experts``): every
+held expert multiplies every row once, three plain batched matmuls
+over weights read where they lie, the unchosen (row, expert) results
+dropped by a select; it is
 what a decode call with a pair or more an expert and at most 128
 rows wants, where nearly every held expert is touched anyway and the
 grouped product at a few rows a group runs far under its bytes'
@@ -51,6 +55,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from apex_tpu.ops.moe_grouped import moe_grouped
 
 
 ROUTERS = ("sigmoid", "softmax")
@@ -156,7 +162,12 @@ def expert_form(rows: int, top_k: int, num_experts: int) -> str:
     86% of the bytes' bound); 64 x top-10 over 72, 9 held, 170 MB:
     0.49 / 0.29; 16 x top-4 over 256, 32 held, 1812 MB (a quarter of a
     pair an expert): 0.44 / 2.51; at 128 rows the first two read
-    5.52 / 1.12 and 0.63 / 0.36."""
+    5.52 / 1.12 and 0.63 / 0.36. The grouped products by
+    ``moe_grouped`` at the prefill-type shapes, ms, ``ragged_dot`` ->
+    kernel (PR 39; docs/moe.md has the table): 1024 rows x top-8 over
+    64, 7.69 -> 1.78; x top-10 over 72, 9 held, 0.92 -> 0.44; x top-4
+    over 256, 32 held, 5.86 -> 2.56; Trinity's decode shape (16 rows)
+    0.54 -> 0.49, which leaves it grouped by this rule."""
     if rows * top_k >= num_experts and rows <= DENSE_MAX_ROWS:
         return "dense"
     return "grouped"
@@ -180,9 +191,9 @@ def grouped_experts(x, weights, ids, w_gate, w_up, w_down,
                     held: Tuple[int, int], dtype):
     """``held_experts`` by grouped products. Pairs are sorted by
     local expert with the absent ones last, and the group sizes count
-    the held pairs only: the grouped products (``lax.ragged_dot``, on
-    a TPU the compiler's grouped matmul, which visits the tiles its
-    group sizes cover) stop there."""
+    the held pairs only: the grouped products (``moe_grouped``, on a
+    TPU a kernel that visits the row tiles its group sizes cover and
+    reads each touched expert's weights once) stop there."""
     first, count = held
     n, h = x.shape
     k = ids.shape[1]
@@ -194,10 +205,10 @@ def grouped_experts(x, weights, ids, w_gate, w_up, w_down,
     rows = x.astype(dtype)[order // k]                    # (n*k, h) sorted
     sizes = jnp.bincount(flat, length=count + 1)[:count].astype(jnp.int32)
     with jax.named_scope("moe_experts"), jax.named_scope("grouped"):
-        gate = lax.ragged_dot(rows, w_gate.astype(dtype), sizes)
-        up = lax.ragged_dot(rows, w_up.astype(dtype), sizes)
-        out = lax.ragged_dot(jax.nn.silu(gate) * up, w_down.astype(dtype),
-                             sizes)
+        gate = moe_grouped(rows, w_gate.astype(dtype), sizes)
+        up = moe_grouped(rows, w_up.astype(dtype), sizes)
+        out = moe_grouped(jax.nn.silu(gate) * up, w_down.astype(dtype),
+                          sizes)
     # rows past the held pairs were never computed: whatever lies there
     # is dropped by the mask, not multiplied by a zero weight
     w_sorted = weights.reshape(-1)[order]

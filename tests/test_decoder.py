@@ -361,12 +361,12 @@ def test_softmax_router_is_softmax_then_top_k_then_renormalised():
 
 
 def _spy_on_group_sizes(monkeypatch):
-    """The group sizes of every ``lax.ragged_dot`` the layer makes from
-    here on."""
+    """The group sizes of every grouped product (``moe_grouped``) the
+    layer makes from here on."""
     sizes = []
-    real = held_module.lax.ragged_dot
+    real = held_module.moe_grouped
     monkeypatch.setattr(
-        held_module.lax, "ragged_dot",
+        held_module, "moe_grouped",
         lambda x, w, group_sizes: (sizes.append(np.asarray(group_sizes)),
                                    real(x, w, group_sizes))[1])
     return sizes

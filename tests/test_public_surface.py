@@ -156,6 +156,8 @@ SURFACE = {
         "inv_freq"],
     "apex_tpu.moe.held": ["HeldMoEConfig", "HeldMoEMLP", "sigmoid_router",
                           "softmax_router", "held_experts"],
+    # PR-39: the held experts' grouped product as one kernel
+    "apex_tpu.ops.moe_grouped": ["moe_grouped"],
     "apex_tpu.models.bert": None,     # module presence only
     "apex_tpu.models.t5": None,
     "apex_tpu.models.resnet": None,
