@@ -45,15 +45,15 @@ def _into_slot(ctx, new, at):
 def first_context(kv_ctx, *, zero, window=None):
     """``into`` for the first of a program call's layers with this
     ``window``: zeros in the gathered layout for the tables such layers
-    gather through. ``kv_ctx`` as the model takes it, ``(k_pool,
-    v_pool, tables, ctx_lens[, win])``; ``zero`` the caller's
+    gather through. ``kv_ctx`` as the model takes it, ``(pools,
+    tables, ctx_lens[, win])``; ``zero(pools, tables)`` the caller's
     ``kv_gather.zero_context`` (under a mesh, its ``on_shards``
     island), which gives nothing where nothing is gathered into."""
-    k_pool, _, tables, _, *win = kv_ctx
+    pools, tables, _, *win = kv_ctx
     if window is not None:
         tables = win[0][0]
     with jax.named_scope("cache"):
-        return zero(k_pool, tables)
+        return zero(pools, tables)
 
 
 def _decode(flash, q, k_all, v_all, kv_seg):
@@ -75,12 +75,13 @@ def cached_attention(q, k_new, v_new, kv_ctx, *, flash, gather, dtype,
     their own keys and values. Returns (b, heads, s, d) and what the
     next layer of this kind gathers into.
 
-    ``kv_ctx = (layer, into, k_pool, v_pool, tables, ctx_lens[, win])``:
-    the pools whole, this layer's index into them, the block tables
-    (b, w) and how many positions of each lane are written. A window
-    layer also needs ``win = (win_tables, win_first)``: the tail of
-    each lane's table (b, ww) and the index, in the lane's own table,
-    of the tail's first block (b,). ``into`` is the (K, V) this layer
+    ``kv_ctx = (layer, into, (k_pool, v_pool), tables, ctx_lens[,
+    win])``: the pools whole, this layer's index into them, the block
+    tables (b, w) and how many positions of each lane are written. A
+    window layer also needs ``win = (win_tables, win_first)``: the tail
+    of each lane's table (b, ww) and the index, in the lane's own
+    table, of the tail's first block (b,). ``into`` is the (K, V) this
+    layer
     gathers into (module docstring): ``first_context``'s or the last
     such layer's second result, and ``()`` where that was.
 
@@ -89,13 +90,14 @@ def cached_attention(q, k_new, v_new, kv_ctx, *, flash, gather, dtype,
     have run). ``s > 1`` is a prefill chunk over ``[ctx | chunk]``.
     ``flash`` and ``gather`` are the caller's ``flash_attention`` and
     ``kv_gather`` (under a mesh, their ``on_shards`` islands)."""
-    layer, into, k_pool, v_pool, tables, ctx_lens, *rest = kv_ctx
+    layer, into, pools, tables, ctx_lens, *rest = kv_ctx
+    k_pool = pools[0]
     win = rest[0] if rest else None
     b, _, s, _ = q.shape
     if window is not None and win is None:
         raise ValueError(
             "a window layer needs the tail tables in kv_ctx: "
-            "(layer, into, k_pool, v_pool, tables, ctx_lens, "
+            "(layer, into, (k_pool, v_pool), tables, ctx_lens, "
             "(win_tables, win_first))")
     # the cache's machinery is the ``cache`` part of the model
     # (telemetry.compiled.PARTS); the masks and the kernel below are
@@ -107,7 +109,7 @@ def cached_attention(q, k_new, v_new, kv_ctx, *, flash, gather, dtype,
         # positions of the gathered arrays that hold written keys: none
         # behind the tail's first, and the masks below hide the rest
         lens = ctx_lens if window is None else ctx_lens - base
-        k_all, v_all = gather(k_pool, v_pool, layer, tables, lens, into)
+        k_all, v_all = gather(pools, layer, tables, lens, into)
         if sow is not None:
             sow((k_all, v_all))
         k_all, v_all = k_all.astype(dtype), v_all.astype(dtype)
@@ -151,3 +153,40 @@ def cached_attention(q, k_new, v_new, kv_ctx, *, flash, gather, dtype,
             [jnp.where(pos < ctx_lens[:, None], pos, _NEVER), q_pos], axis=1)
         return flash(q, k_all, v_all, causal=True, window_size=window,
                      q_positions=q_pos, kv_positions=k_pos), held
+
+
+def cached_latent_attention(row_new, kv_ctx, *, expanded, absorbed, gather,
+                            dtype):
+    """A ``latent`` layer's new tokens against their cached context
+    plus themselves (``models/decoder.py`` ``LatentAttention``).
+    ``row_new`` (b, 1, s, W) is their rows ``[c_kv | k_r | 0]``;
+    ``kv_ctx = (layer, into, pools, tables, ctx_lens)`` as
+    :func:`cached_attention` takes it, with the latent
+    pool alone in ``pools``. The layer gathers its rows (one array, key
+    and value both); what it does with them is the caller's:
+
+    - ``s == 1`` is decode: the token's row goes into its slot of the
+      gathered rows and ``absorbed(rows, lens)`` attends over the first
+      ``lens = ctx_lens + 1`` of them;
+    - ``s > 1`` is a chunk over ``[ctx | chunk]``: ``expanded(rows,
+      kv_segment_ids=...)`` up-projects the rows and attends causally,
+      the segment ids dropping ctx slots past the written prefix.
+
+    Returns what the caller's form returns and what the next latent
+    layer gathers into."""
+    layer, into, pools, tables, ctx_lens = kv_ctx
+    s = row_new.shape[2]
+    with jax.named_scope("cache"):
+        (rows,) = gather(pools, layer, tables, ctx_lens, into)
+        rows = rows.astype(dtype)
+        if s == 1:
+            rows = _into_slot(rows, row_new, ctx_lens)
+        held = (rows.astype(pools[0].dtype),) if into else ()
+    if s == 1:
+        return absorbed(rows, ctx_lens + 1), held
+    slot = jnp.arange(rows.shape[2], dtype=jnp.int32)[None, :]
+    kv_seg = jnp.concatenate(
+        [(slot >= ctx_lens[:, None]).astype(jnp.int32),
+         jnp.zeros((row_new.shape[0], s), jnp.int32)], axis=1)
+    return expanded(jnp.concatenate([rows, row_new], axis=2),
+                    kv_segment_ids=kv_seg), held

@@ -23,6 +23,12 @@ experts"):
   ``head_dim^-0.5`` or by the model's own multiplier; a sigmoid output
   gate or none; *window* layers see the last ``window`` keys, *full*
   layers every key;
+- a *latent* layer is DeepSeek-V2/V3's multi-head latent attention
+  (MLA, ``DecoderConfig.mla``): queries through a low-rank latent,
+  keys and values up-projected from one normed latent a token
+  ``c_kv`` beside one rotated key ``k_r`` that every head shares; what
+  it caches is the row ``[c_kv | k_r | 0]`` (:class:`LatentAttention`,
+  docs/serving.md "Latent attention");
 - a *mamba* layer has no attention at all: its mixer is
   ``models/ssm.py``'s Mamba-2, whose state a sequence is a fixed size
   (``DecoderConfig.mamba``), not keys that grow with the context;
@@ -43,6 +49,11 @@ pre-norm, nine ``mamba`` layers and one full attention layer a period,
 no QK-norm and no rotary at all, scores times 1/128, multipliers on
 the embedding, the residuals and the logits, a tied head, the softmax
 router with a shared expert.
+Zhipu's ``glm4_moe_lite`` (GLM-4.7-Flash,
+``benchmark/drivers/serve_glm.py``) is pre-norm, ``latent`` layers
+throughout with rotary on the shared key's 64 dimensions only (pairs
+interleaved), one leading dense layer, then the sigmoid router over
+64 experts with a shared expert, an untied head.
 
 It offers the serving hooks ``GPTModel`` offers (``positions``,
 ``kv_ctx``, ``return_kv``; ``config.kv_heads`` / ``head_dim`` /
@@ -65,13 +76,13 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.models.cached_attention import (
-    cached_attention, first_context)
+    cached_attention, cached_latent_attention, first_context)
 from apex_tpu.models.ssm import Mamba2Config, Mamba2Mixer
 from apex_tpu.moe.held import HeldMoEConfig, HeldMoEMLP, gated_mlp
 from apex_tpu.ops.layer_norm import fused_rms_norm
 from apex_tpu.ops.rope import fused_apply_rotary_pos_emb_cached
 
-ATTENTION_KINDS = ("full", "window")
+ATTENTION_KINDS = ("full", "window", "latent")
 MIXER_KINDS = ATTENTION_KINDS + ("mamba",)
 MLP_KINDS = ("dense", "experts")
 NORM_PLACEMENTS = ("sandwich", "pre")
@@ -117,6 +128,32 @@ class Rotary:
 
 
 @dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """The sizes of a ``latent`` layer (DeepSeek-V2/V3's MLA, under
+    their own keys): the query's latent, the keys' and values' latent
+    ``c_kv``, each head's unrotated and rotated parts of q and k, and
+    its value. The rotated key is one a token, shared by the heads."""
+
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def row_width(self) -> int:
+        """The cached row ``[c_kv | k_r | 0]``: ``c_kv`` and ``k_r``
+        padded with zeros to whole 128-lane tiles (576 -> 640), the
+        width the device would lay it out at anyway and the one
+        ``ops/kv_gather.py`` copies whole."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+
+@dataclasses.dataclass(frozen=True)
 class DecoderConfig:
     vocab_size: int
     hidden_size: int
@@ -159,6 +196,9 @@ class DecoderConfig:
     # types, eps and impl from here where it is built); set exactly
     # when there is such a layer
     mamba: Optional[Mamba2Config] = None
+    # the "latent" layers' sizes; set exactly when there is such a
+    # layer, and then every layer that has keys is one
+    mla: Optional[MLAConfig] = None
 
     def __post_init__(self):
         if self.norms not in NORM_PLACEMENTS:
@@ -187,6 +227,13 @@ class DecoderConfig:
         if (self.mamba is not None) != bool(self.state_layers):
             raise ValueError("mamba is set exactly when some layer is a "
                              "mamba layer")
+        kinds = {a for a, _ in self.layers if a != "mamba"}
+        if (self.mla is not None) != ("latent" in kinds) or (
+                "latent" in kinds and kinds != {"latent"}):
+            raise ValueError(
+                "mla is set exactly when some layer is a latent layer, and "
+                "then every layer with keys is one (the paged pool holds "
+                "one kind of row)")
         if self.embedding_scale and self.embedding_multiplier is not None:
             raise ValueError(
                 "embedding_scale (x0 = E[tokens] sqrt(hidden)) and "
@@ -229,9 +276,19 @@ class DecoderConfig:
     def kv_heads(self) -> int:
         return self.num_kv_heads
 
+    def cache_row(self) -> Tuple[int, int, int]:
+        """What the paged pool holds a token a layer
+        (``serving.KVCache.for_config``): ``(kv_heads, width, pools)``,
+        a K and a V row of ``kv_heads x head_dim``, or a latent model's
+        one row of ``mla.row_width`` in one pool."""
+        if self.mla is not None:
+            return 1, self.mla.row_width, 1
+        return self.num_kv_heads, self.head_dim, 2
+
     def rotary_of(self, kind: str) -> Optional[Rotary]:
         if self.rotary is None:
-            return Rotary(self.rope_theta) if kind == "window" else None
+            return (Rotary(self.rope_theta) if kind in ("window", "latent")
+                    else None)
         return dict(self.rotary)[kind]
 
     def moe_cfg(self) -> HeldMoEConfig:
@@ -348,6 +405,122 @@ class DecoderAttention(nn.Module):
         return jnp.dot(o, wo.astype(cfg.dtype)), (k, v), held
 
 
+def interleaved(t):
+    """DeepSeek-V3's rotary layout, in the rotate-half convention
+    :func:`rotary` computes: the pairs it turns together are adjacent
+    dimensions ``(2i, 2i + 1)``, so they are brought to ``i`` and
+    ``i + d / 2`` first. Queries and keys are reordered alike, so
+    their products are the interleaved form's."""
+    return jnp.concatenate([t[..., 0::2], t[..., 1::2]], axis=-1)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (``DecoderConfig.mla``):
+
+        c_q = n_q(x W_DQ);  [q_nope | q_rope] = c_q W_UQ   (per head)
+        [c_kv | k_r] = x W_DKV;  c_kv <- n_kv(c_kv)
+        [k_nope | v] = c_kv W_UKV                          (per head)
+        q_rope, k_r rotated (interleaved pairs); k_r shared by heads
+        scores (q_nope . k_nope + q_rope . k_r) (qk_head_dim)^-0.5
+        o = softmax(scores) v, causal;  out = o W_O
+
+    What a token leaves in the cache is the row ``[c_kv | k_r | 0]``
+    (``MLAConfig.row_width``). A call of several tokens (a whole
+    prompt, a chunk) takes the *expanded* form: the context's rows and
+    its own are up-projected into per-head K and V and the flash
+    kernel attends. A decode call takes the *absorbed* form:
+    ``q_lat = q_nope W_UK^T`` scores the rows as they are, each head's
+    value is ``c_kv`` itself (``ops/mla_decode.py`` reads the rows once
+    for both), and ``W_UV`` goes on the result. The form is chosen by
+    the call's shape alone."""
+
+    config: DecoderConfig
+
+    @nn.compact
+    def __call__(self, x, positions, *, kv_ctx=None):
+        """As ``DecoderAttention``: (b, s, hidden) out, this call's
+        rows ``(b, 1, s, row_width)`` (the one pool's), and what the
+        next latent layer gathers into."""
+        cfg, mla = self.config, self.config.mla
+        b, s, _ = x.shape
+        nh, dt = cfg.num_heads, cfg.dtype
+        lat, nope, rope = (mla.kv_lora_rank, mla.qk_nope_head_dim,
+                           mla.qk_rope_head_dim)
+        vd, qk, width = mla.v_head_dim, mla.qk_head_dim, mla.row_width
+        scale = qk ** -0.5
+        init = nn.initializers.normal(stddev=0.02)
+
+        def param(name, *shape):
+            return self.param(name, init, shape, cfg.param_dtype).astype(dt)
+
+        c_q = RMSNorm(cfg.rms_eps, name="q_norm")(
+            jnp.dot(x, param("q_down", cfg.hidden_size, mla.q_lora_rank)))
+        q = jnp.dot(c_q.astype(dt), param("q_up", mla.q_lora_rank, nh * qk))
+        q_nope, q_rope = jnp.split(q.reshape(b, s, nh, qk), [nope], axis=-1)
+        ckr = jnp.dot(x, param("kv_down", cfg.hidden_size, lat + rope))
+        c_kv = RMSNorm(cfg.rms_eps, name="kv_norm")(ckr[..., :lat])
+        rot = cfg.rotary_of("latent")
+        inv_freq = rot.inv_freq(rope)
+        q_rope = rotary(interleaved(q_rope), positions, inv_freq,
+                        rot.attention_factor).astype(dt)
+        k_r = rotary(interleaved(ckr[..., lat:])[:, :, None, :], positions,
+                     inv_freq, rot.attention_factor)[:, :, 0]
+        row = jnp.concatenate(
+            [c_kv.astype(dt), k_r.astype(dt),
+             jnp.zeros((b, s, width - lat - rope), dt)], axis=-1)[:, None]
+        w_ukv = param("kv_up", lat, nh * (nope + vd))
+        # a flash call needs one size for q, k and v: the narrower is
+        # padded with zeros, which change no score and no value
+        d = max(qk, vd)
+
+        def pad(t):
+            return t if t.shape[-1] == d else jnp.pad(
+                t, [(0, 0)] * (t.ndim - 1) + [(0, d - t.shape[-1])])
+
+        def expanded(rows, **kw):
+            with jax.named_scope("mla_expanded"):
+                n = rows.shape[2]
+                kv = jnp.dot(rows[:, 0, :, :lat], w_ukv).reshape(
+                    b, n, nh, nope + vd)
+                k_shared = jnp.broadcast_to(
+                    rows[:, 0, :, None, lat:lat + rope], (b, n, nh, rope))
+                k = jnp.concatenate([kv[..., :nope], k_shared], axis=-1)
+                qf = jnp.concatenate([q_nope, q_rope], axis=-1)
+                qf, k, v = (pad(t).transpose(0, 2, 1, 3)
+                            for t in (qf, k, kv[..., nope:]))
+                o = _flash(cfg, qf, k, v, causal=True, softmax_scale=scale,
+                           **kw)
+                return o[..., :vd].transpose(0, 2, 1, 3)
+
+        def absorbed(rows, lens):
+            from apex_tpu.ops.mla_decode import mla_decode
+
+            with jax.named_scope("mla_absorbed"):
+                w = w_ukv.reshape(lat, nh, nope + vd)
+                q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0],
+                                   w[..., :nope],
+                                   preferred_element_type=jnp.float32)
+                qf = jnp.concatenate(
+                    [q_lat.astype(dt), q_rope[:, 0],
+                     jnp.zeros((b, nh, width - lat - rope), dt)], axis=-1)
+                o = mla_decode(qf, rows, lens, value_dim=lat, scale=scale,
+                               impl=cfg.softmax_impl)
+                o = jnp.einsum("bhc,chv->bhv", o.astype(dt), w[..., nope:],
+                               preferred_element_type=jnp.float32)
+                return o.astype(dt)[:, None]
+
+        held = ()
+        if kv_ctx is not None:
+            o, held = cached_latent_attention(
+                row, kv_ctx, dtype=dt, expanded=expanded, absorbed=absorbed,
+                gather=lambda *a: _gather(cfg, *a))
+        else:
+            o = expanded(row)
+        o = o.reshape(b, s, nh * vd).astype(dt)
+        return jnp.dot(o, param("proj", nh * vd, cfg.hidden_size)), (
+            row,), held
+
+
 class DenseMLP(nn.Module):
     config: DecoderConfig
 
@@ -403,9 +576,12 @@ class DecoderLayer(nn.Module):
             held = ()
         else:
             with jax.named_scope("attention"):
-                a, kv, held = DecoderAttention(
-                    cfg, self.attention, name="attention")(
-                    norm("input_norm")(x), positions, kv_ctx=kv_ctx)
+                mixer = (LatentAttention(cfg, name="attention")
+                         if self.attention == "latent" else
+                         DecoderAttention(cfg, self.attention,
+                                          name="attention"))
+                a, kv, held = mixer(norm("input_norm")(x), positions,
+                                    kv_ctx=kv_ctx)
                 y = x + scaled(after("post_attention_norm")(a))
         # an expert layer's shared MLP opens ``mlp`` inside (moe/held.py)
         with jax.named_scope("experts" if self.mlp == "experts" else "mlp"):
@@ -428,11 +604,12 @@ class PatternDecoder(nn.Module):
                  state_ctx=None, return_kv=False):
         """``positions`` (b, s) or (s,): positions in the sequence
         (default ``arange(s)``); they turn the rotary embeddings and
-        nothing else. ``kv_ctx = (k_pool, v_pool, tables,
-        ctx_lens[, win])`` runs the cached paths
-        (``models/cached_attention.py``); ``return_kv=True`` also
-        returns this call's K and V, each stacked (num_kv_layers, b,
-        kv_heads, s, head_dim): the layers that have keys, in order.
+        nothing else. ``kv_ctx = (pools, tables, ctx_lens[, win])``
+        runs the cached paths (``models/cached_attention.py``):
+        ``pools`` the paged pools, K and V, or a ``latent`` model's one
+        pool of rows. ``return_kv=True`` also returns this call's rows
+        of each pool, stacked (num_kv_layers, b, kv_heads, s,
+        head_dim): the layers that have keys, in order.
 
         ``state_ctx = (pools, slots, lengths, fresh)``, for a model
         with ``mamba`` layers: the state pools whole (``(slots + 1,
@@ -463,9 +640,10 @@ class PatternDecoder(nn.Module):
         # (cached_attention): zeros before the first
         into = {}
         if kv_ctx is not None:
-            windows = {"full": None, "window": cfg.attention_window}
-            into = {kind: first_context(kv_ctx, window=windows[kind],
-                                        zero=lambda *a: _zero_ctx(cfg, *a))
+            windows = {"window": cfg.attention_window}
+            into = {kind: first_context(
+                        kv_ctx, window=windows.get(kind),
+                        zero=lambda *a: _zero_ctx(cfg, *a))
                     for kind in dict.fromkeys(a for a, _ in cfg.layers)
                     if kind != "mamba"}
         pools = slots = lengths = fresh = None
@@ -498,11 +676,11 @@ class PatternDecoder(nn.Module):
         out = (logits,)
         if return_kv:
             with jax.named_scope("cache"):
-                out += ((jnp.stack([k for k, _ in kvs]),
-                         jnp.stack([v for _, v in kvs])),)
+                out += (tuple(jnp.stack(rows) for rows in zip(*kvs)),)
         if state_ctx is not None:
             out += (pools,)
         return out if len(out) > 1 else logits
 
 
-__all__ = ["DecoderConfig", "Mamba2Config", "PatternDecoder", "Rotary"]
+__all__ = ["DecoderConfig", "MLAConfig", "Mamba2Config", "PatternDecoder",
+           "Rotary"]

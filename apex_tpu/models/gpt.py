@@ -208,10 +208,11 @@ def _paged_specs():
             P(BATCH_AXIS, MODEL_AXIS, None, None))
 
 
-def _gather_ctx(cfg: "GPTConfig", k_pool, v_pool, layer, tables, lens, into):
-    """Layer ``layer`` of the paged pools (layers, blocks, block_size,
-    kv_heads, head_dim) through the block ``tables`` (b, w), as K and
-    V in ``_flash``'s (b, kv_heads, w * block_size, head_dim) layout,
+def _gather_ctx(cfg: "GPTConfig", pools, layer, tables, lens, into):
+    """Layer ``layer`` of the paged ``pools`` (K and V, each (layers,
+    blocks, block_size, kv_heads, head_dim)) through the block
+    ``tables`` (b, w), as K and V in ``_flash``'s (b, kv_heads, w *
+    block_size, head_dim) layout,
     as far as each lane's ``lens`` (b,) and into the pair ``into``
     (``_zero_ctx``'s or the last layer's): ``ops/kv_gather.py``, one
     pass over the live context. Lanes and kv heads are independent, so
@@ -219,29 +220,34 @@ def _gather_ctx(cfg: "GPTConfig", k_pool, v_pool, layer, tables, lens, into):
     ``_flash``'s does."""
     from apex_tpu.ops.kv_gather import kv_gather
 
-    def gather(k_pool, v_pool, layer, tables, lens, *into):
-        return kv_gather(k_pool, v_pool, layer, tables, lens, into,
+    n = len(pools)
+
+    def gather(*args):
+        return kv_gather(args[:n], *args[n:n + 3], args[n + 3:],
                          impl=cfg.softmax_impl)
 
     pool, rows, ctx = _paged_specs()
     return _gspmd.on_shards(
         gather, cfg.softmax_impl,
-        (pool, pool, P(), rows, P(rows[0])) + (ctx,) * len(into),
-        (ctx, ctx))(k_pool, v_pool, layer, tables, lens, *into)
+        (pool,) * n + (P(), rows, P(rows[0])) + (ctx,) * len(into),
+        (ctx,) * n)(*pools, layer, tables, lens, *into)
 
 
-def _zero_ctx(cfg: "GPTConfig", k_pool, tables):
+def _zero_ctx(cfg: "GPTConfig", pools, tables):
     """What a program call's first layer gathers its context into
     (``ops/kv_gather.py`` ``zero_context``), made per shard like the
     gather that writes into it."""
     from apex_tpu.ops.kv_gather import zero_context
 
-    def zero(k_pool, tables):
-        return zero_context(k_pool, tables, impl=cfg.softmax_impl)
+    def zero(pool, tables):
+        # zero_context reads the pools' count, shape and type alone:
+        # one of them is all the island is handed
+        return zero_context((pool,) * len(pools), tables,
+                            impl=cfg.softmax_impl)
 
     pool, rows, ctx = _paged_specs()
     return _gspmd.on_shards(
-        zero, cfg.softmax_impl, (pool, rows), ctx)(k_pool, tables)
+        zero, cfg.softmax_impl, (pool, rows), ctx)(pools[0], tables)
 
 
 class _LayerNorm(nn.Module):
@@ -276,7 +282,7 @@ class ParallelAttention(nn.Module):
       kernel ``(b, kv_local, s, head_dim)`` layout — what a prefill
       step writes into the paged cache — and, third, what the next
       layer gathers its context into (``()`` without ``kv_ctx``).
-    - ``kv_ctx=(layer, into, k_pool, v_pool, tables, ctx_lens[, win])``
+    - ``kv_ctx=(layer, into, (k_pool, v_pool), tables, ctx_lens[, win])``
       is the cached path (``models/cached_attention.py``; with
       ``attention_window`` the layer gathers through ``win``, the tail
       of each lane's table, and masks by true positions). ``into`` is
@@ -571,7 +577,7 @@ class GPTModel(nn.Module):
         single-token forward at position t needs only ``positions`` and
         the cache, never the full prefix.
 
-        ``kv_ctx=(k_pool, v_pool, tables, ctx_lens[, win])`` — the paged
+        ``kv_ctx=((k_pool, v_pool), tables, ctx_lens[, win])`` — the paged
         pools (num_layers, blocks, block_size, kv_heads, head_dim),
         the block tables (b, w) and the cached length of each lane
         (b,) — runs the cached paths: every layer gathers its own

@@ -1,4 +1,4 @@
-"""Gather one layer's paged K or V into the flash kernel's layout.
+"""Gather one layer of the paged pools into the flash kernel's layout.
 
 The paged pool keeps a block as ``(block_size, kv_heads, head_dim)``
 rows (serving/kv_cache.py); the flash kernel reads keys as
@@ -51,13 +51,28 @@ from apex_tpu._backend import interpret_flag, resolve_impl
 from apex_tpu.ops.attention import _pick_block
 
 
+def _pack(pool) -> int:
+    """Rows of the pool's type in one 32-bit sublane."""
+    return max(1, 4 // pool.dtype.itemsize)
+
+
+def _one_head(pool) -> bool:
+    """Whether the pool has one head whose blocks the kernel copies as
+    ``(block_size, head_dim)``: the TPU compiler lays such a pool out
+    with its unit head dimension outside the block's rows (a latent
+    pool, ``serving/kv_cache.py``, is one), so those are whole tiles
+    where ``block_size`` fills a tile's sublanes."""
+    bs, kv, d = pool.shape[2:]
+    return kv == 1 and d % 128 == 0 and bs % (8 * _pack(pool)) == 0
+
+
 def _whole_tiles(pool) -> bool:
     """Whether one block's ``(kv_heads, head_dim)`` rows are whole
     tiles of the pool as it lies in HBM, which the kernel's copy of a
     block needs: every lane of a row, and all the rows packed into one
-    sublane (two of bf16)."""
+    sublane (two of bf16); or a pool of one head (:func:`_one_head`)."""
     kv, d = pool.shape[3:]
-    return d % 128 == 0 and kv % max(1, 4 // pool.dtype.itemsize) == 0
+    return _one_head(pool) or (d % 128 == 0 and kv % _pack(pool) == 0)
 
 
 def _gather_xla(pool, layer, tables):
@@ -87,22 +102,25 @@ def _live_groups(blocks, group):
     return lax.max(lax.div(lax.add(blocks, group - 1), group), 1)
 
 
-def _kernel(tbl_ref, lyr_ref, live_ref, k_pool, v_pool, k_into, v_into,
-            k_out, v_out, k_buf, v_buf, sem, *, group):
-    # grid step n gathers blocks tbl[n * group : (n + 1) * group] of the
-    # flattened table: lane n // (w / group), its next `group` blocks,
-    # as many of them as are live (live_ref[lane] blocks from the
-    # lane's first). k_into / v_into are the arrays the results alias:
-    # what this kernel does not write keeps their bytes.
+def _kernel(tbl_ref, lyr_ref, live_ref, *refs, group, pools):
+    # refs: the ``pools`` pools, the arrays the results alias, the
+    # results, a VMEM buffer a pool and the semaphores. Grid step n
+    # gathers blocks tbl[n * group : (n + 1) * group] of the flattened
+    # table: lane n // (w / group), its next `group` blocks, as many of
+    # them as are live (live_ref[lane] blocks from the lane's first).
+    # What this kernel does not write of a result keeps the bytes of
+    # the array it aliases.
     # Loops, not unrolled copies: tracing an operation costs the host
     # more than a loop costs the chip, and every program traces this
     # body twice (the layer scan does).
-    del k_into, v_into
+    pool_refs = refs[:pools]
+    outs = refs[2 * pools:3 * pools]
+    bufs, sem = refs[3 * pools:4 * pools], refs[4 * pools]
     lane, j, groups = pl.program_id(0), pl.program_id(1), pl.num_programs(1)
     n = lax.add(lax.mul(lane, groups), j)
     ahead = lax.add(n, 1)
     last = lax.mul(pl.num_programs(0), groups)
-    layer, bs = lyr_ref[0], k_buf.shape[2]
+    layer, bs = lyr_ref[0], bufs[0].shape[2]
 
     def live(lane, j):
         return lax.clamp(0, lax.sub(live_ref[lane], lax.mul(j, group)), group)
@@ -112,7 +130,7 @@ def _kernel(tbl_ref, lyr_ref, live_ref, k_pool, v_pool, k_into, v_into,
 
         def body(t, carry):
             blk = tbl_ref[lax.add(first, t)]
-            for pool, buf, which in ((k_pool, k_buf, 0), (v_pool, v_buf, 1)):
+            for pool, buf, which in zip(pool_refs, bufs, range(pools)):
                 act(pltpu.make_async_copy(pool.at[layer, blk],
                                           buf.at[slot, t],
                                           sem.at[which, slot]))
@@ -140,13 +158,17 @@ def _kernel(tbl_ref, lyr_ref, live_ref, k_pool, v_pool, k_into, v_into,
 
     def turn(t, carry):
         # (block_size, kv, d) -> (kv, block_size, d): the block's rows
-        # of one head become contiguous keys of that head
-        for buf, out in ((k_buf, k_out), (v_buf, v_out)):
-            out[0, :, at(t), :] = jnp.swapaxes(buf[slot, t], 0, 1)
+        # of one head become contiguous keys of that head; a block of
+        # a one-head pool (block_size, d) is its head's already
+        for buf, out in zip(bufs, outs):
+            if buf.ndim == 4:
+                out[0, 0, at(t), :] = buf[slot, t]
+            else:
+                out[0, :, at(t), :] = jnp.swapaxes(buf[slot, t], 0, 1)
         return carry
 
     def blank(t, carry):
-        for out in (k_out, v_out):
+        for out in outs:
             out[0, :, at(t), :] = jnp.zeros(
                 (out.shape[1], bs, out.shape[3]), out.dtype)
         return carry
@@ -166,45 +188,50 @@ def _uses_kernel(pool, impl) -> bool:
     return impl == "interpret" or (impl == "pallas" and _whole_tiles(pool))
 
 
-def _zero(k_out, v_out):
-    k_out[...] = jnp.zeros(k_out.shape, k_out.dtype)
-    v_out[...] = jnp.zeros(v_out.shape, v_out.dtype)
+def _zero(*outs):
+    for out in outs:
+        out[...] = jnp.zeros(out.shape, out.dtype)
 
 
-def zero_context(k_pool, tables, *, impl=None):
-    """What the first ``kv_gather`` of a program call writes into: a K
-    and a V of zeros in the gathered layout for ``tables``; nothing,
-    ``()``, where XLA gathers (it writes into nothing). From a kernel,
+def zero_context(pools, tables, *, impl=None):
+    """What the first ``kv_gather`` of a program call writes into: one
+    array of zeros a pool (K and V, or a latent pool's rows alone) in
+    the gathered layout for ``tables``; nothing, ``()``, where XLA
+    gathers (it writes into nothing). ``pools`` are read for their
+    count, shape and type alone. From a kernel,
     because its results are pinned to HBM as the gather's are: a layer
     scan that starts from XLA's own zeros keeps the arrays it carries
     in VMEM and copies them out and back around every layer's gather.
     Under a mesh it needs an ``on_shards`` island, as the gather does."""
-    if not _uses_kernel(k_pool, impl):
+    pool, n = pools[0], len(pools)
+    if not _uses_kernel(pool, impl):
         return ()
-    _, _, bs, kv, d = k_pool.shape
+    _, _, bs, kv, d = pool.shape
     b, w = tables.shape
-    # blocks of about 2 MB
-    rows = _pick_block(
-        w * bs, max(bs, 2**21 // (kv * d * k_pool.dtype.itemsize)))
+    # blocks of about 2 MB, a power of two rows
+    rows = _pick_block(w * bs, max(bs, 1 << (
+        2**21 // (kv * d * pool.dtype.itemsize)).bit_length() - 1))
     spec = pl.BlockSpec((1, kv, rows, d), lambda i, j: (i, 0, j, 0))
-    out = pltpu.HBM((b, kv, w * bs, d), k_pool.dtype)
+    out = pltpu.HBM((b, kv, w * bs, d), pool.dtype)
     return tuple(pl.pallas_call(
         _zero,
         grid=(b, w * bs // rows),
-        out_specs=[spec, spec],
-        out_shape=[out, out],
+        out_specs=[spec] * n,
+        out_shape=[out] * n,
         interpret=interpret_flag(resolve_impl(impl)),
         name="zero_context",
     )())
 
 
-def kv_gather(k_pool, v_pool, layer, tables, lens, into=(), *, impl=None,
+def kv_gather(pools, layer, tables, lens, into=(), *, impl=None,
               group: int = 16):
-    """``k_pool[layer, tables]`` and ``v_pool[layer, tables]``, each as
-    ``(batch, kv_heads, width * block_size, head_dim)``, as far as each
-    lane's keys go.
+    """``pool[layer, tables]`` for each of ``pools`` (K and V, or a
+    latent pool alone: ``serving/kv_cache.py``), as a tuple of
+    ``(batch, kv_heads, width * block_size, head_dim)`` arrays, as far
+    as each lane's keys go.
 
-    The pools (layers, blocks, block_size, kv_heads, head_dim);
+    The pools (layers, blocks, block_size, kv_heads, head_dim), of one
+    shape;
     ``layer`` an int32 scalar (traced inside a layer scan); ``tables``
     (batch, width) int32 block ids, every one of them a block of the
     pool (nothing is clamped or filled); ``lens`` (batch,) int32, how
@@ -216,9 +243,9 @@ def kv_gather(k_pool, v_pool, layer, tables, lens, into=(), *, impl=None,
     The rest is zeros or stale K/V and has to be masked by the caller:
     the kernel neither reads nor writes the groups past a lane's last
     live one (a lane's first group counts as live whatever its length),
-    so they keep the bytes of ``into``, the pair of arrays the results
+    so they keep the bytes of ``into``, the arrays the results
     are written into in place (``zero_context``'s where none is given;
-    a caller with several layers makes that pair itself and hands each
+    a caller with several layers makes them itself and hands each
     layer the last one's results, ``models/cached_attention.py``), and
     it zeroes the dead blocks of the last live group. Never-written
     memory must not come back: a masked key still meets the attention
@@ -231,39 +258,47 @@ def kv_gather(k_pool, v_pool, layer, tables, lens, into=(), *, impl=None,
     """
     impl = resolve_impl(impl)
     layer = jnp.asarray(layer, jnp.int32)
-    if not _uses_kernel(k_pool, impl):
-        return (_gather_xla(k_pool, layer, tables),
-                _gather_xla(v_pool, layer, tables))
-    _, _, bs, kv, d = k_pool.shape
+    pools = tuple(pools)
+    pool = pools[0]
+    if not _uses_kernel(pool, impl):
+        return tuple(_gather_xla(p, layer, tables) for p in pools)
+    _, _, bs, kv, d = pool.shape
     b, w = tables.shape
     group = _pick_block(w, group)
-    out = pltpu.HBM((b, kv, w * bs, d), k_pool.dtype)
+    out = pltpu.HBM((b, kv, w * bs, d), pool.dtype)
     if not into:
-        into = zero_context(k_pool, tables, impl=impl)
+        into = zero_context(pools, tables, impl=impl)
     blocks = live_blocks(lens.astype(jnp.int32), bs, w)
     anywhere = pl.BlockSpec(memory_space=pl.ANY)
     out_spec = pl.BlockSpec(
         (1, kv, group * bs, d),
         lambda i, j, tbl, lyr, blocks: (
             i, 0, lax.min(j, lax.sub(_live_groups(blocks[i], group), 1)), 0))
-    buf = pltpu.VMEM((2, group, bs, kv, d), k_pool.dtype)
-    return pl.pallas_call(
-        functools.partial(_kernel, group=group),
+    if _one_head(pool):
+        # blocks as (block_size, d): the unit head dimension lies
+        # outside the rows in HBM, so these are the same bytes
+        pools = tuple(p.reshape(*p.shape[:3], d) for p in pools)
+        buf = pltpu.VMEM((2, group, bs, d), pool.dtype)
+    else:
+        buf = pltpu.VMEM((2, group, bs, kv, d), pool.dtype)
+    n = len(pools)
+    return tuple(pl.pallas_call(
+        functools.partial(_kernel, group=group, pools=n),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, w // group),
-            in_specs=[anywhere] * 4,
-            out_specs=[out_spec, out_spec],
-            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))]),
-        out_shape=[out, out],
+            in_specs=[anywhere] * (2 * n),
+            out_specs=[out_spec] * n,
+            scratch_shapes=[buf] * n + [pltpu.SemaphoreType.DMA((n, 2))]),
+        out_shape=[out] * n,
         # operands count the prefetched scalars: into -> the results
-        input_output_aliases={5: 0, 6: 1},
+        input_output_aliases={3 + n + i: i for i in range(n)},
         # the next group's copies are started a step ahead: in order
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret_flag(impl),
         name="kv_gather",
-    )(tables.reshape(-1), layer.reshape(1), blocks, k_pool, v_pool, *into)
+    )(tables.reshape(-1), layer.reshape(1), blocks, *pools, *into))
 
 
 __all__ = ["kv_gather", "live_blocks", "zero_context"]

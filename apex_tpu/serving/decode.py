@@ -226,7 +226,8 @@ class DecodeStep:
 
         def apply(params, state, tokens, *, state_slots, lengths, fresh,
                   **kw):
-            """The model over the cache: ``(logits, (k, v), state)``.
+            """The model over the cache: ``(logits, new, state)``,
+            ``new`` this call's rows, one array a pool.
             Where it has recurrent layers it is handed its lanes'
             slots of the state pools and gives the pools back."""
             if state_slots is None:
@@ -301,12 +302,11 @@ class DecodeStep:
             b, s = tokens.shape
             with jax.named_scope("embed"):
                 fresh = jnp.ones((b,), bool)
-            logits, (k_new, v_new), state = apply(
+            logits, new, state = apply(
                 params, state, tokens, state_slots=state_slots,
                 lengths=lengths, fresh=fresh)
             with jax.named_scope("cache"):
-                state = append_kv_prefill(state, k_new, v_new, tables,
-                                          lengths)
+                state = append_kv_prefill(state, new, tables, lengths)
             with jax.named_scope("head"):
                 last = jnp.clip(lengths - 1, 0, s - 1)
                 out = logits[last, jnp.arange(b)]      # (b, vocab)
@@ -329,14 +329,13 @@ class DecodeStep:
                     starts[:, None]
                     + jnp.arange(s, dtype=jnp.int32)[None, :], 0, max_pos)
                 fresh = starts == 0
-            logits, (k_new, v_new), state = apply(
+            logits, new, state = apply(
                 params, state, tokens, state_slots=state_slots,
                 lengths=lengths, fresh=fresh, positions=pos,
-                kv_ctx=(state.k, state.v, tables, starts,
+                kv_ctx=(state.pools, tables, starts,
                         *tail(window_tables, window_first)))
             with jax.named_scope("cache"):
-                state = append_kv_chunk(state, k_new, v_new, tables, starts,
-                                        lengths)
+                state = append_kv_chunk(state, new, tables, starts, lengths)
             with jax.named_scope("head"):
                 last = jnp.clip(lengths - 1, 0, s - 1)
                 out = logits[last, jnp.arange(b)]      # (b, vocab)
@@ -356,14 +355,14 @@ class DecodeStep:
             # each layer gathers its own context from the pools and
             # attends with the token's K/V in slot positions[b] of it:
             # the slot append_kv writes below, which the table covers
-            logits, (k_new, v_new), state = apply(
+            logits, new, state = apply(
                 params, state, tokens, state_slots=state_slots,
                 lengths=None, fresh=fresh, positions=pos2,
-                kv_ctx=(state.k, state.v, tables, positions,
+                kv_ctx=(state.pools, tables, positions,
                         *tail(window_tables, window_first)))
             with jax.named_scope("cache"):
-                state = append_kv(state, k_new[:, :, :, 0],
-                                  v_new[:, :, :, 0], tables, positions)
+                state = append_kv(state, tuple(t[:, :, :, 0] for t in new),
+                                  tables, positions)
             with jax.named_scope("head"):
                 out = logits[0]                        # (b, vocab)
                 # the emitted token lands at positions + 1

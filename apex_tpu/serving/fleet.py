@@ -566,28 +566,35 @@ class FleetRouter:
         from apex_tpu.telemetry import comms as _comms
         return _comms.instrument(self._wire_col)
 
-    def _ship(self, k: np.ndarray, v: np.ndarray):
-        out = self._wire().broadcast_from(0, [k, v])
-        return np.asarray(out[0]), np.asarray(out[1])
+    def _ship(self, payloads: Sequence[np.ndarray]):
+        out = self._wire().broadcast_from(0, list(payloads))
+        return tuple(np.asarray(p) for p in out)
 
     @staticmethod
-    def _manifest(blocks: Sequence[int], k: np.ndarray,
-                  v: np.ndarray) -> Dict[str, Any]:
-        """Per-block sha256 manifest of an exported payload. Hashes
-        cover the k+v bytes of each block in payload order; ``root``
-        keys the transfer (the idempotent re-send identity)."""
-        per = [hashlib.sha256(
-            np.ascontiguousarray(k[:, i]).tobytes()
-            + np.ascontiguousarray(v[:, i]).tobytes()).hexdigest()
-            for i in range(k.shape[1])]
+    def _block_hash(payloads: Sequence[np.ndarray], i: int) -> str:
+        """sha256 of block ``i``'s bytes in every payload, in order."""
+        return hashlib.sha256(b"".join(
+            np.ascontiguousarray(p[:, i]).tobytes()
+            for p in payloads)).hexdigest()
+
+    @classmethod
+    def _manifest(cls, blocks: Sequence[int],
+                  payloads: Sequence[np.ndarray]) -> Dict[str, Any]:
+        """Per-block sha256 manifest of an exported payload (one array
+        a pool: K and V, or a latent pool's rows). Hashes cover each
+        block's bytes of every pool in payload order; ``root`` keys the
+        transfer (the idempotent re-send identity)."""
+        per = [cls._block_hash(payloads, i)
+               for i in range(payloads[0].shape[1])]
         root = hashlib.sha256(",".join(per).encode()).hexdigest()
         return {"root": root, "blocks": per,
                 "src_blocks": [int(b) for b in blocks],
-                "shape": list(k.shape), "dtype": str(k.dtype)}
+                "shape": list(payloads[0].shape),
+                "dtype": str(payloads[0].dtype)}
 
-    @staticmethod
-    def _verify_blocks(manifest: Dict[str, Any], k: np.ndarray,
-                       v: np.ndarray,
+    @classmethod
+    def _verify_blocks(cls, manifest: Dict[str, Any],
+                       payloads: Sequence[np.ndarray],
                        log: List[Dict[str, Any]]) -> List[int]:
         """Block-by-block manifest check of a RECEIVED payload;
         returns the corrupt block indices. ``log`` is overwritten with
@@ -596,10 +603,7 @@ class FleetRouter:
         bad: List[int] = []
         entries: List[Dict[str, Any]] = []
         for i, want in enumerate(manifest["blocks"]):
-            got = hashlib.sha256(
-                np.ascontiguousarray(k[:, i]).tobytes()
-                + np.ascontiguousarray(v[:, i]).tobytes()).hexdigest()
-            ok = got == want
+            ok = cls._block_hash(payloads, i) == want
             entries.append({"block": i, "ok": ok})
             if not ok:
                 bad.append(i)
@@ -607,7 +611,7 @@ class FleetRouter:
         return bad
 
     def _transfer_once(self, hid: int, manifest: Dict[str, Any],
-                       k: np.ndarray, v: np.ndarray,
+                       payloads: Sequence[np.ndarray],
                        verify_log: List[Dict[str, Any]]):
         """ONE wire attempt: ship the payload, apply the kv-transfer
         fault clauses to the RECEIVED copy, then verify every block
@@ -620,23 +624,23 @@ class FleetRouter:
         if fault == "timeout":
             raise faults.FaultError(
                 f"injected kv transfer timeout (handoff {hid})")
-        rk, rv = self._ship(k, v)
+        got = self._ship(payloads)
         if fault == "corrupt":
-            rk = np.array(rk, copy=True)
-            if rk.nbytes:
-                rk.view(np.uint8).reshape(-1)[0] ^= 0xFF
+            first = np.array(got[0], copy=True)
+            if first.nbytes:
+                first.view(np.uint8).reshape(-1)[0] ^= 0xFF
+            got = (first,) + got[1:]
         elif fault == "partial":
-            rk = np.array(rk, copy=True)
-            rv = np.array(rv, copy=True)
-            rk[:, -1] = 0
-            rv[:, -1] = 0
-        bad = self._verify_blocks(manifest, rk, rv, verify_log)
+            got = tuple(np.array(p, copy=True) for p in got)
+            for p in got:
+                p[:, -1] = 0
+        bad = self._verify_blocks(manifest, got, verify_log)
         if bad:
             raise faults.FaultError(
                 f"kv handoff verify refused install (handoff {hid}, "
                 f"manifest {manifest['root'][:12]}): corrupt blocks "
                 f"{bad}")
-        return rk, rv
+        return got
 
     def _handoff_phase(self, idx: int) -> None:
         """Move every prefill-complete stream off the prefill seats.
@@ -688,7 +692,7 @@ class FleetRouter:
         # NEXT append (scheduler position semantics)
         filled = len(req.prompt) + len(fl.generated) - 1
         try:
-            blocks, k, v = src.batcher.cache.export_blocks(
+            blocks, payloads = src.batcher.cache.export_blocks(
                 src.state, fl.seq_id, length=filled)
             # a model with recurrent layers: the sequence's state slot
             # travels with its blocks (None otherwise)
@@ -701,8 +705,8 @@ class FleetRouter:
                 src=src.name, error=f"{type(e).__name__}: {e}")
             src.batcher.running.append(fl)
             return False
-        manifest = self._manifest(blocks, k, v)
-        payload_bytes = int(k.nbytes + v.nbytes)
+        manifest = self._manifest(blocks, payloads)
+        payload_bytes = int(sum(p.nbytes for p in payloads))
         if faults.should_orphan_handoff():
             # the drill where the handoff is abandoned AFTER export
             # with the payload in flight: the exported blocks are
@@ -725,17 +729,17 @@ class FleetRouter:
             attempts[0] += 1
             faults.check("kv_handoff")
             faults.maybe_engine_crash(idx, dst.index)
-            return self._transfer_once(hid, manifest, k, v, verify_log)
+            return self._transfer_once(hid, manifest, payloads, verify_log)
 
         try:
-            rk, rv = retry_call(
+            received = retry_call(
                 attempt, retries=self.handoff_retries,
                 base_delay=self.retry_base_delay, jitter=0.0,
                 retry_on=(faults.FaultError, OSError),
                 give_up_on=(faults.EngineCrash,), sleep=self.sleep,
                 site="kv_handoff")
             dst.state = dst.batcher.install_prefilled(
-                dst.state, req, fl.generated, rk, rv,
+                dst.state, req, fl.generated, received,
                 t_submit=fl.t_submit, t_first=fl.t_first,
                 t_last=fl.t_last, recurrent=recurrent)
         except faults.EngineCrash as e:
@@ -898,15 +902,15 @@ class FleetRouter:
         shape = (c.num_layers, 1, c.block_size, c.kv_heads, c.head_dim)
         # non-zero probe bytes: a zeroed-tail (partial) wire must not
         # hash clean and unlatch a still-degraded fleet
-        k = np.ones(shape, np.float32)
-        v = np.ones(shape, np.float32)
-        manifest = self._manifest([0], k, v)
+        payloads = tuple(np.ones(shape, np.float32)
+                         for _ in range(c.num_pools))
+        manifest = self._manifest([0], payloads)
         probes = self._registry.counter(
             "fleet_handoff_probes",
             "colocated-fallback health probes by outcome")
         try:
             faults.check("kv_handoff")
-            self._transfer_once(-1, manifest, k, v, [])
+            self._transfer_once(-1, manifest, payloads, [])
         except Exception:  # noqa: BLE001 — still degraded, stay latched
             probes.inc(outcome="failed")
             return

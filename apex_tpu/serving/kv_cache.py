@@ -64,6 +64,15 @@ its programs are what they were). A prompt of such a model takes NO
 prefix match and publishes nothing: a shared block says nothing of the
 state at its end.
 
+The pools (``KVCacheState.pools``) are a tuple of arrays of one shape,
+and every operation below maps over it: a K and a V pool for a model
+that caches keys and values, ONE pool for a model whose attention
+layers are latent (DeepSeek-V2/V3's MLA, docs/serving.md "Latent
+attention"), which caches one row a token a layer, ``[c_kv | k_r |
+0]``, its key and its value both, as one head of the row's width
+(``KVCache(pools=1)``). Every count of bytes reads
+:meth:`KVCache.row_bytes`.
+
 Reservation is staged: :meth:`KVCache.allocate_prefix` reserves only
 the span the caller names (a prefill chunk, or the full prompt +
 max_new span), and :meth:`KVCache.extend` grows the reservation
@@ -103,8 +112,9 @@ class KVCacheState(NamedTuple):
     """The device-side pools — a pytree the decode step DONATES, so
     appends run in place and the cache never holds two copies."""
 
-    k: Any    # (num_layers, num_blocks, block_size, kv_heads, head_dim)
-    v: Any
+    # (K, V), or a latent pool alone (module docstring): each
+    # (num_layers, num_blocks, block_size, kv_heads, head_dim)
+    pools: Tuple[Any, ...]
     # the recurrent state's pools, a tuple of (state_slots + 1, state
     # layers, ...) arrays; None (no leaf) for a model all of whose
     # layers have keys
@@ -148,7 +158,8 @@ class KVCache:
     def __init__(self, num_layers: int, kv_heads: int, head_dim: int, *,
                  num_blocks: int, block_size: int = 16,
                  dtype: Any = None, state_slots: int = 0,
-                 state_shapes: Sequence[Tuple[Tuple[int, ...], Any]] = ()):
+                 state_shapes: Sequence[Tuple[Tuple[int, ...], Any]] = (),
+                 pools: int = 2):
         if num_blocks < 1:
             raise ValueError(f"num_blocks must be >= 1, got {num_blocks}")
         if block_size < 1:
@@ -164,6 +175,9 @@ class KVCache:
         self.num_layers = int(num_layers)
         self.kv_heads = int(kv_heads)
         self.head_dim = int(head_dim)
+        # how many pools of that shape: K and V, or a latent pool's
+        # one row a token that is key and value both
+        self.num_pools = int(pools)
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.dtype = dtype if dtype is not None else jnp.float32
@@ -206,15 +220,21 @@ class KVCache:
         (it need not be ``hidden_size / num_heads``). The pool has the
         layers that have keys (``num_kv_layers`` where the config
         tells them apart), and ``state_slots`` slots of the config's
-        ``state_shapes()`` where it has recurrent layers."""
+        ``state_shapes()`` where it has recurrent layers. A config
+        with a ``cache_row()`` names its row itself: ``(kv_heads,
+        width, pools)``, a latent model's one pool of one head of its
+        latent row."""
         head_dim = getattr(cfg, "head_dim", None)
+        row = (cfg.cache_row() if hasattr(cfg, "cache_row") else
+               (cfg.kv_heads, head_dim or cfg.hidden_size // cfg.num_heads,
+                2))
         shapes = cfg.state_shapes() if hasattr(cfg, "state_shapes") else ()
         return cls(getattr(cfg, "num_kv_layers", cfg.num_layers),
-                   cfg.kv_heads,
-                   head_dim or cfg.hidden_size // cfg.num_heads,
+                   row[0], row[1],
                    num_blocks=num_blocks, block_size=block_size,
                    dtype=dtype if dtype is not None else cfg.dtype,
-                   state_slots=state_slots, state_shapes=shapes)
+                   state_slots=state_slots, state_shapes=shapes,
+                   pools=row[2])
 
     # -- pool ---------------------------------------------------------------
 
@@ -226,8 +246,10 @@ class KVCache:
                  self.kv_heads, self.head_dim)
         state = tuple(jnp.zeros((self.state_slots + 1, *shape), dtype)
                       for shape, dtype in self.state_shapes) or None
-        return KVCacheState(k=jnp.zeros(shape, self.dtype),
-                            v=jnp.zeros(shape, self.dtype), state=state)
+        return KVCacheState(
+            pools=tuple(jnp.zeros(shape, self.dtype)
+                        for _ in range(self.num_pools)),
+            state=state)
 
     def slot_bytes(self) -> int:
         """Bytes of one sequence's recurrent state (0 without any)."""
@@ -236,19 +258,20 @@ class KVCache:
         return sum(int(np.prod(shape)) * jnp.dtype(dtype).itemsize
                    for shape, dtype in self.state_shapes)
 
-    def block_bytes(self) -> int:
-        """Bytes of one block, K and V, over the pool's layers."""
+    def row_bytes(self) -> int:
+        """Bytes one token holds over the pool's layers: a row of
+        ``kv_heads x head_dim`` a layer in each pool."""
         import jax.numpy as jnp
 
-        return (2 * self.num_layers * self.block_size * self.kv_heads
+        return (self.num_pools * self.num_layers * self.kv_heads
                 * self.head_dim * jnp.dtype(self.dtype).itemsize)
 
-    def pool_bytes(self) -> int:
-        import jax.numpy as jnp
+    def block_bytes(self) -> int:
+        """Bytes of one block over the pool's layers."""
+        return self.block_size * self.row_bytes()
 
-        n = (self.num_layers * (self.num_blocks + 1) * self.block_size
-             * self.kv_heads * self.head_dim)
-        return 2 * n * jnp.dtype(self.dtype).itemsize
+    def pool_bytes(self) -> int:
+        return (self.num_blocks + 1) * self.block_bytes()
 
     # -- allocator ----------------------------------------------------------
 
@@ -726,22 +749,23 @@ class KVCache:
 
     def export_blocks(self, state: KVCacheState, seq_id, *,
                       length: Optional[int] = None
-                      ) -> Tuple[List[int], np.ndarray, np.ndarray]:
+                      ) -> Tuple[List[int], Tuple[np.ndarray, ...]]:
         """Extract a sequence's KV rows to the host for a cross-engine
-        handoff: ``(blocks, k, v)`` where ``blocks`` is the sequence's
-        block table (source indices, for the manifest) and ``k``/``v``
-        are ``(num_layers, n, block_size, kv_heads, head_dim)`` host
-        arrays. ``length`` bounds the export to the blocks that
-        actually hold tokens (``blocks_for(length)``) so the wire never
-        carries the unwritten decode-span tail; ``None`` exports the
-        whole reservation. Read-only on both the table (a locked copy)
-        and the pool — shared prefix blocks export fine."""
+        handoff: ``(blocks, payloads)`` where ``blocks`` is the
+        sequence's block table (source indices, for the manifest) and
+        ``payloads`` one ``(num_layers, n, block_size, kv_heads,
+        head_dim)`` host array a pool. ``length`` bounds the export
+        to the blocks that actually hold tokens (``blocks_for(length)``)
+        so the wire never carries the unwritten decode-span tail;
+        ``None`` exports the whole reservation. Read-only on both the
+        table (a locked copy) and the pool — shared prefix blocks
+        export fine."""
         table = self.table(seq_id)           # locked copy; raises unknown
         if length is not None:
             table = table[:self.blocks_for(length)]
         idx = np.asarray(table, np.int32)
-        return (list(table), np.asarray(state.k[:, idx]),
-                np.asarray(state.v[:, idx]))
+        return list(table), tuple(np.asarray(pool[:, idx])
+                                  for pool in state.pools)
 
     def export_state(self, state: KVCacheState, seq_id):
         """A sequence's recurrent state to the host, for the same
@@ -770,29 +794,33 @@ class KVCache:
             pool.at[slot].set(jnp.asarray(r, pool.dtype))
             for pool, r in zip(state.state, rows)))
 
-    def import_blocks(self, state: KVCacheState, seq_id, k,
-                      v) -> KVCacheState:
-        """Install exported KV rows into THIS pool's blocks for an
-        already-allocated ``seq_id`` (the receiving side of a handoff):
-        row block ``i`` of the payload lands in the sequence's block
-        ``table[i]``. The verify-before-install discipline is the
-        CALLER's (serving/fleet.py hashes every block against the
-        manifest first) — this method trusts its inputs. Returns the
-        new device state; the table/refcounts are untouched."""
+    def import_blocks(self, state: KVCacheState, seq_id,
+                      payloads) -> KVCacheState:
+        """Install exported KV rows (:meth:`export_blocks`'s payloads,
+        one a pool) into THIS pool's blocks for an already-allocated
+        ``seq_id`` (the receiving side of a handoff): row block ``i``
+        of the payload lands in the sequence's block ``table[i]``. The
+        verify-before-install discipline is the CALLER's
+        (serving/fleet.py hashes every block against the manifest
+        first) — this method trusts its inputs. Returns the new device
+        state; the table/refcounts are untouched."""
         import jax.numpy as jnp
 
         table = self.table(seq_id)
-        k = np.asarray(k)
-        v = np.asarray(v)
-        n = k.shape[1]
+        payloads = [np.asarray(p) for p in payloads]
+        if len(payloads) != len(state.pools):
+            raise ValueError(
+                f"import_blocks: {len(payloads)} payloads for "
+                f"{len(state.pools)} pools")
+        n = payloads[0].shape[1]
         if n > len(table):
             raise ValueError(
                 f"import_blocks: payload holds {n} blocks but sequence "
                 f"{seq_id!r} reserves only {len(table)}")
         idx = jnp.asarray(table[:n], jnp.int32)
-        return state._replace(
-            k=state.k.at[:, idx].set(jnp.asarray(k, state.k.dtype)),
-            v=state.v.at[:, idx].set(jnp.asarray(v, state.v.dtype)))
+        return state._replace(pools=tuple(
+            pool.at[:, idx].set(jnp.asarray(p, pool.dtype))
+            for pool, p in zip(state.pools, payloads)))
 
 
 def bucket(n: int, minimum: int = 1) -> int:
@@ -813,8 +841,8 @@ def gather_kv(state: KVCacheState, tables):
     """Gather each sequence's blocks into contiguous per-batch views,
     all layers at once.
 
-    ``tables`` (batch, width) int32 -> two ``(num_layers, batch,
-    kv_heads, width * block_size, head_dim)`` arrays. Pure data
+    ``tables`` (batch, width) int32 -> one ``(num_layers, batch,
+    kv_heads, width * block_size, head_dim)`` array a pool. Pure data
     movement — the bytes written by :func:`append_kv` come back
     bitwise (tests/test_serving.py pins it). Unallocated table entries
     gather the trash block; the caller's attention mask drops them.
@@ -830,14 +858,14 @@ def gather_kv(state: KVCacheState, tables):
         layers, b, w, bs, kv, d = g.shape
         return g.transpose(0, 1, 4, 2, 3, 5).reshape(layers, b, kv,
                                                      w * bs, d)
-    return one(state.k), one(state.v)
+    return tuple(one(pool) for pool in state.pools)
 
 
-def append_kv(state: KVCacheState, k_new, v_new, tables,
+def append_kv(state: KVCacheState, new, tables,
               positions) -> KVCacheState:
-    """Write one token's K/V per sequence into the pool in place.
+    """Write one token's K/V per sequence into the pools in place.
 
-    ``k_new``/``v_new`` (num_layers, batch, kv_heads, head_dim);
+    ``new`` one (num_layers, batch, kv_heads, head_dim) array a pool;
     ``positions`` (batch,) the 0-based slot each token lands in. Rows
     whose table entry is the trash block (dummy batch slots) write
     harmlessly into it.
@@ -853,33 +881,33 @@ def append_kv(state: KVCacheState, k_new, v_new, tables,
     import jax.numpy as jnp
     from jax import lax
 
-    bs = state.k.shape[2]
+    bs = state.pools[0].shape[2]
     w = tables.shape[1]
     blk = jnp.take_along_axis(
         tables, jnp.clip(positions[:, None] // bs, 0, w - 1), axis=1)[:, 0]
     slot = positions % bs
-    k, v = state.k, state.v
+    pools = state.pools
     for i in range(tables.shape[0]):
         at = (0, blk[i], slot[i], 0, 0)
-        k = lax.dynamic_update_slice(
-            k, k_new[:, i, None, None].astype(k.dtype), at)
-        v = lax.dynamic_update_slice(
-            v, v_new[:, i, None, None].astype(v.dtype), at)
-    return state._replace(k=k, v=v)
+        pools = tuple(
+            lax.dynamic_update_slice(
+                pool, rows[:, i, None, None].astype(pool.dtype), at)
+            for pool, rows in zip(pools, new))
+    return state._replace(pools=pools)
 
 
-def append_kv_prefill(state: KVCacheState, k_new, v_new, tables,
+def append_kv_prefill(state: KVCacheState, new, tables,
                       lengths) -> KVCacheState:
-    """Write a whole prompt's K/V per sequence into the pool in place.
+    """Write a whole prompt's K/V per sequence into the pools in place.
 
-    ``k_new``/``v_new`` (num_layers, batch, kv_heads, seq, head_dim)
-    right-padded; the pads' garbage K/V at positions ``>= lengths``
-    never lands in a real block.
+    ``new`` one (num_layers, batch, kv_heads, seq, head_dim) array a
+    pool, right-padded; the pads' garbage K/V at positions ``>=
+    lengths`` never lands in a real block.
     """
-    return append_kv_chunk(state, k_new, v_new, tables, None, lengths)
+    return append_kv_chunk(state, new, tables, None, lengths)
 
 
-def append_kv_chunk(state: KVCacheState, k_new, v_new, tables, starts,
+def append_kv_chunk(state: KVCacheState, new, tables, starts,
                     lengths) -> KVCacheState:
     """Write one prefill CHUNK's K/V per sequence into the pool.
 
@@ -901,20 +929,21 @@ def append_kv_chunk(state: KVCacheState, k_new, v_new, tables, starts,
     import jax.numpy as jnp
     from jax import lax
 
-    layers, _, bs, kv, d = state.k.shape
+    layers, _, bs, kv, d = state.pools[0].shape
     b, w = tables.shape
-    s = k_new.shape[3]
+    s = new[0].shape[3]
 
-    def rows(new):
+    def rows(t):
         # (L, b, kv, s, d) -> (L, b, bs + s + pad, kv, d): rows as the
         # pool holds them, a block of pads in front (a chunk may start
         # inside a block) and behind, so that every block's slice of
         # the chunk lies inside the array
         pad = (bs, (-(-s // bs) + 1) * bs - s)
-        return jnp.pad(new.transpose(0, 1, 3, 2, 4).astype(state.k.dtype),
+        return jnp.pad(t.transpose(0, 1, 3, 2, 4).astype(dtype),
                        ((0, 0), (0, 0), pad, (0, 0), (0, 0)))
 
-    k_new, v_new = rows(k_new), rows(v_new)
+    dtype = state.pools[0].dtype
+    new = tuple(rows(t) for t in new)
     slot = jnp.arange(bs, dtype=jnp.int32)
     if starts is None:
         starts = jnp.zeros((b,), jnp.int32)
@@ -935,13 +964,12 @@ def append_kv_chunk(state: KVCacheState, k_new, v_new, tables, starts,
                 return lax.dynamic_update_slice(
                     pool, jnp.where(valid[:, None, None], new, old), at)
 
-            return one(pools[0], k_new), one(pools[1], v_new)
+            return tuple(one(pool, chunk) for pool, chunk in zip(pools, new))
 
         touched = jnp.where(n > 0, lax.div(off + n + bs - 1, bs), 0)
         return lax.fori_loop(0, touched, turn, pools)
 
-    k, v = lax.fori_loop(0, b, lane, (state.k, state.v))
-    return state._replace(k=k, v=v)
+    return state._replace(pools=lax.fori_loop(0, b, lane, state.pools))
 
 
 def apply_copies(state: KVCacheState,
@@ -951,12 +979,12 @@ def apply_copies(state: KVCacheState,
     ``rows`` rows of ``src`` — the common token prefix with the
     divergence block — are copied into the fresh private ``dst``; the
     shared source is never written."""
-    k, v = state.k, state.v
+    pools = state.pools
     for src, dst, rows in copies:
         rows = int(rows)
-        k = k.at[:, int(dst), :rows].set(k[:, int(src), :rows])
-        v = v.at[:, int(dst), :rows].set(v[:, int(src), :rows])
-    return state._replace(k=k, v=v)
+        pools = tuple(pool.at[:, int(dst), :rows].set(
+            pool[:, int(src), :rows]) for pool in pools)
+    return state._replace(pools=pools)
 
 
 def scrub_blocks(state: KVCacheState, blocks) -> KVCacheState:
@@ -967,8 +995,8 @@ def scrub_blocks(state: KVCacheState, blocks) -> KVCacheState:
     if len(blocks) == 0:
         return state
     b = jnp.asarray(sorted(int(x) for x in blocks), jnp.int32)
-    return state._replace(k=state.k.at[:, b].set(0),
-                          v=state.v.at[:, b].set(0))
+    return state._replace(
+        pools=tuple(pool.at[:, b].set(0) for pool in state.pools))
 
 
 __all__ = [

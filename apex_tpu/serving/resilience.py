@@ -318,11 +318,8 @@ def poison_lane_kv(state, cache, seq_id, position: int):
     bs = cache.block_size
     blk = table[int(position) // bs]
     slot = int(position) % bs
-    nan_row = jnp.full((state.k.shape[0], cache.kv_heads,
-                        cache.head_dim), jnp.nan, state.k.dtype)
-    return state._replace(
-        k=state.k.at[:, blk, slot].set(nan_row),
-        v=state.v.at[:, blk, slot].set(nan_row))
+    return state._replace(pools=tuple(
+        pool.at[:, blk, slot].set(jnp.nan) for pool in state.pools))
 
 
 # ---------------------------------------------------------------------------

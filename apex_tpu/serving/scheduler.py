@@ -626,7 +626,7 @@ class ContinuousBatcher:
         return out
 
     def install_prefilled(self, state, req: Request,
-                          generated: Sequence[int], k, v, *,
+                          generated: Sequence[int], payloads, *,
                           t_submit: float,
                           t_first: Optional[float] = None,
                           t_last: Optional[float] = None,
@@ -634,8 +634,8 @@ class ContinuousBatcher:
         """Adopt a handed-off, prefill-complete request: reserve its
         FULL decode span (prompt + max_new — the can-never-die-
         mid-decode invariant holds from the first local step), install
-        the already-VERIFIED KV payload into the fresh blocks
-        (``KVCache.import_blocks``; verification is the caller's job,
+        the already-VERIFIED KV payloads (one a pool) into the fresh
+        blocks (``KVCache.import_blocks``; verification is the caller's job,
         before this is called), publish the prompt blocks into the
         local prefix index, and join ``running`` directly — no queue,
         no prefill. ``t_submit``/``t_first``/``t_last`` carry the
@@ -653,7 +653,7 @@ class ContinuousBatcher:
             seq_id = ("h", self._seq_counter, req.id)
         self.cache.allocate(seq_id, total)
         try:
-            state = self.cache.import_blocks(state, seq_id, k, v)
+            state = self.cache.import_blocks(state, seq_id, payloads)
             state = self.cache.import_state(state, seq_id, recurrent)
         except Exception:
             self.cache.free(seq_id)

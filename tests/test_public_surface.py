@@ -146,7 +146,8 @@ SURFACE = {
     ],
     "apex_tpu.models.gpt": ["GPTConfig", "GPTModel", "gpt_loss_fn"],
     # PR-28: the pattern decoder and the held-experts layer
-    "apex_tpu.models.decoder": ["DecoderConfig", "PatternDecoder", "Rotary"],
+    "apex_tpu.models.decoder": ["DecoderConfig", "PatternDecoder", "Rotary",
+                                "MLAConfig"],
     "apex_tpu.models.decoder_reference": ["Arch", "forward", "judge",
                                           "check_served", "held_margin",
                                           "teacher_forced"],
@@ -158,6 +159,10 @@ SURFACE = {
                           "softmax_router", "held_experts"],
     # PR-39: the held experts' grouped product as one kernel
     "apex_tpu.ops.moe_grouped": ["moe_grouped"],
+    # latent attention (docs/serving.md "Latent attention")
+    "apex_tpu.ops.mla_decode": ["mla_decode"],
+    "apex_tpu.models.decoder_reference_glm": [
+        "Arch", "FAULTS", "forward", "check_served"],
     "apex_tpu.models.bert": None,     # module presence only
     "apex_tpu.models.t5": None,
     "apex_tpu.models.resnet": None,

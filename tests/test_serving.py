@@ -216,7 +216,7 @@ class TestPoolOps:
             c.allocate(i, s)
         tables = jnp.asarray(c.table_array([0, 1], width=3))
         lengths = jnp.asarray([s, 7], jnp.int32)
-        state = append_kv_prefill(state, k, v, tables, lengths)
+        state = append_kv_prefill(state, (k, v), tables, lengths)
         gk, gv = gather_kv(state, tables)
         assert gk.shape == (LAYERS, b, KV, 3 * BS, d)
         # bitwise: the gathered prefix IS the written bytes
@@ -235,12 +235,12 @@ class TestPoolOps:
         k = jnp.asarray(rng.randn(LAYERS, 1, KV, s, d), jnp.float32)
         # write the victim's full 8 slots first
         vt = jnp.asarray(c.table_array(["victim"], width=2))
-        state = append_kv_prefill(state, k, k, vt,
+        state = append_kv_prefill(state, (k, k), vt,
                                   jnp.asarray([s], jnp.int32))
         before = np.asarray(gather_kv(state, vt)[0])
         # now a short prefill on "real": positions >= length are pads
         rt = jnp.asarray(c.table_array(["real"], width=2))
-        state = append_kv_prefill(state, k, k, rt,
+        state = append_kv_prefill(state, (k, k), rt,
                                   jnp.asarray([3], jnp.int32))
         after = np.asarray(gather_kv(state, vt)[0])
         np.testing.assert_array_equal(before, after)
@@ -256,7 +256,7 @@ class TestPoolOps:
         for t in range(2 * BS + 1):      # crosses a block boundary
             kt = jnp.asarray(rng.randn(LAYERS, 1, KV, d), jnp.float32)
             rows.append(np.asarray(kt))
-            state = append_kv(state, kt, kt, tables,
+            state = append_kv(state, (kt, kt), tables,
                               jnp.asarray([t], jnp.int32))
         gk, _ = gather_kv(state, tables)
         got = np.asarray(gk)[:, 0]            # (LAYERS, KV, 3*BS, d)
@@ -267,19 +267,20 @@ class TestPoolOps:
 def _scatter_append(state, k_new, v_new, tables, positions):
     """The append as one scatter a pool over all lanes, which the
     in-place updates replaced: the plain reference."""
-    bs = state.k.shape[2]
+    bs = state.pools[0].shape[2]
     w = tables.shape[1]
     blk = jnp.take_along_axis(
         tables, jnp.clip(positions[:, None] // bs, 0, w - 1), axis=1)[:, 0]
     slot = positions % bs
-    return serving.KVCacheState(k=state.k.at[:, blk, slot].set(k_new),
-                                v=state.v.at[:, blk, slot].set(v_new))
+    k, v = state.pools
+    return serving.KVCacheState(pools=(k.at[:, blk, slot].set(k_new),
+                                       v.at[:, blk, slot].set(v_new)))
 
 
 def _scatter_chunk(state, k_new, v_new, tables, starts, lengths):
     """The chunk's rows by one scatter a pool, pads to the trash
     block: the plain reference."""
-    bs = state.k.shape[2]
+    bs = state.pools[0].shape[2]
     b, w = tables.shape
     s = k_new.shape[3]
     pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None, :], (b, s))
@@ -293,7 +294,8 @@ def _scatter_chunk(state, k_new, v_new, tables, starts, lengths):
     def one(pool, new):
         return pool.at[:, blk, slot].set(new.transpose(0, 1, 3, 2, 4))
 
-    return serving.KVCacheState(k=one(state.k, k_new), v=one(state.v, v_new))
+    k, v = state.pools
+    return serving.KVCacheState(pools=(one(k, k_new), one(v, v_new)))
 
 
 #: name -> (chunk length, starts or None, lengths); tables of 4 blocks
@@ -320,9 +322,8 @@ class TestAppendInPlace:
     def pool(kv, d, seed):
         rng = np.random.RandomState(seed)
         shape = (LAYERS, BLOCKS + 1, BS, kv, d)
-        state = serving.KVCacheState(
-            k=jnp.asarray(rng.randn(*shape), jnp.bfloat16),
-            v=jnp.asarray(rng.randn(*shape), jnp.bfloat16))
+        state = serving.KVCacheState(pools=tuple(
+            jnp.asarray(rng.randn(*shape), jnp.bfloat16) for _ in "kv"))
         # three lanes of four distinct real blocks each, the last lane's
         # table half trash (a sequence that has not reserved them)
         tables = 1 + rng.permutation(BLOCKS)[:12].reshape(3, 4)
@@ -331,7 +332,7 @@ class TestAppendInPlace:
 
     @staticmethod
     def same_real_blocks(got, want):
-        for g, w in zip((got.k, got.v), (want.k, want.v)):
+        for g, w in zip(got.pools, want.pools):
             np.testing.assert_array_equal(
                 np.asarray(g.astype(jnp.float32))[:, 1:],
                 np.asarray(w.astype(jnp.float32))[:, 1:])
@@ -345,11 +346,12 @@ class TestAppendInPlace:
         positions = jnp.asarray([2 * BS + 1, 0, 4 * BS - 1, 0], jnp.int32)
         k = jnp.asarray(rng.randn(LAYERS, 4, kv, d), jnp.bfloat16)
         v = jnp.asarray(rng.randn(LAYERS, 4, kv, d), jnp.bfloat16)
-        got = jax.jit(append_kv)(state, k, v, tables, positions)
+        got = jax.jit(append_kv)(state, (k, v), tables, positions)
         want = _scatter_append(state, k, v, tables, positions)
         self.same_real_blocks(got, want)
-        assert not np.array_equal(np.asarray(got.k.astype(jnp.float32)),
-                                  np.asarray(state.k.astype(jnp.float32)))
+        assert not np.array_equal(
+            np.asarray(got.pools[0].astype(jnp.float32)),
+            np.asarray(state.pools[0].astype(jnp.float32)))
 
     @pytest.mark.parametrize("kv", [2, 4, 8, 16])
     @pytest.mark.parametrize("case", list(APPEND_CHUNKS))
@@ -360,17 +362,17 @@ class TestAppendInPlace:
         v = jnp.asarray(rng.randn(LAYERS, 3, kv, s, d), jnp.bfloat16)
         lengths = jnp.asarray(lengths, jnp.int32)
         if starts is None:
-            got = jax.jit(append_kv_prefill)(state, k, v, tables, lengths)
+            got = jax.jit(append_kv_prefill)(state, (k, v), tables, lengths)
         else:
             starts = jnp.asarray(starts, jnp.int32)
-            got = jax.jit(append_kv_chunk)(state, k, v, tables, starts,
+            got = jax.jit(append_kv_chunk)(state, (k, v), tables, starts,
                                            lengths)
         want = _scatter_chunk(state, k, v, tables, starts, lengths)
         self.same_real_blocks(got, want)
         # the rows it had before a chunk that starts inside a block,
         # and everything a lane of length 0 owns, are what they were
-        untouched = np.asarray(state.k.astype(jnp.float32))
-        after = np.asarray(got.k.astype(jnp.float32))
+        untouched = np.asarray(state.pools[0].astype(jnp.float32))
+        after = np.asarray(got.pools[0].astype(jnp.float32))
         for lane, n in enumerate(np.asarray(lengths)):
             at = 0 if starts is None else int(starts[lane])
             for pos in range(4 * BS):
@@ -384,11 +386,10 @@ def _written_pool(seed, num_blocks=BLOCKS):
     """A pool whose every row differs, and ragged block tables over
     it (trash-padded, one lane all trash)."""
     cache = fresh_cache(num_blocks=num_blocks)
-    shape = cache.init_state().k.shape
+    shape = cache.init_state().pools[0].shape
     rng = np.random.RandomState(seed)
-    state = serving.KVCacheState(
-        k=jnp.asarray(rng.randn(*shape), jnp.float32),
-        v=jnp.asarray(rng.randn(*shape), jnp.float32))
+    state = serving.KVCacheState(pools=tuple(
+        jnp.asarray(rng.randn(*shape), jnp.float32) for _ in "kv"))
     for i, n in enumerate([3 * BS, BS + 1, 4 * BS]):
         cache.allocate(i, n)
     tables = np.concatenate(
@@ -445,7 +446,7 @@ class TestLayerGather:
         for layer in range(LAYERS):
             # a traced layer index, as the layer scan passes it
             got = jax.jit(lambda st, l: kv_gather(
-                st.k, st.v, l, tables, lens, handed, impl=impl,
+                st.pools, l, tables, lens, handed, impl=impl,
                 group=group))(state, jnp.int32(layer))
             for g, want, into in zip(got, (want_k, want_v), handed):
                 _assert_gathered(
@@ -470,9 +471,9 @@ class TestLayerGather:
         np.testing.assert_array_equal(
             want, live_blocks(jnp.asarray(lens, jnp.int32), BS, width))
         assert want.dtype == np.int32 and want.max() <= width
-        kv, d = state.k.shape[3:]
+        kv, d = state.pools[0].shape[3:]
         handed = jnp.full((len(lens), kv, width * BS, d), 7.0)
-        got, _ = kv_gather(state.k, state.v, 1, tables,
+        got, _ = kv_gather(state.pools, 1, tables,
                            jnp.asarray(lens, jnp.int32), (handed, handed),
                            impl="interpret", group=1)
         written = (np.asarray(got) != 7.0).any(axis=(1, 3))   # (b, w * BS)
@@ -487,7 +488,7 @@ class TestLayerGather:
         state, tables = _written_pool(4)
         want = gather_kv(state, tables)
         lens = jnp.asarray([9, BS, 4 * BS, 0], jnp.int32)
-        got = kv_gather(state.k, state.v, 1, tables, lens, impl=impl,
+        got = kv_gather(state.pools, 1, tables, lens, impl=impl,
                         group=1)
         for g, w in zip(got, want):
             _assert_gathered(g, w[1], lens, handed=jnp.zeros_like(g),
@@ -508,7 +509,7 @@ class TestLayerGather:
         pos = lens[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
         _, grown = model.apply(
             params, toks, positions=pos,
-            kv_ctx=(state.k, state.v, tables, lens), return_kv=True,
+            kv_ctx=(state.pools, tables, lens), return_kv=True,
             mutable=["intermediates"])
         sown = grown["intermediates"]
         if scan_layers:
@@ -521,7 +522,7 @@ class TestLayerGather:
         want_k, want_v = gather_kv(state, tables)
         # past a lane's live blocks: zeros, or what an earlier layer
         # left there (its context, its token's own K/V)
-        bound = max(float(jnp.abs(t).max()) for t in (state.k, state.v)) + 1e3
+        bound = max(float(jnp.abs(t).max()) for t in state.pools) + 1e3
         for got, want in ((got_k, want_k), (got_v, want_v)):
             for layer in range(LAYERS):
                 _assert_gathered(got[layer], want[layer], lens)

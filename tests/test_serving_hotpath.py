@@ -805,10 +805,8 @@ class TestOneArrayBoundary:
         for name in ("logits", "next_token", "finite", "packed"):
             np.testing.assert_array_equal(np.asarray(getattr(got, name)),
                                           np.asarray(getattr(want, name)))
-        np.testing.assert_array_equal(np.asarray(got.cache.k),
-                                      np.asarray(want.cache.k))
-        np.testing.assert_array_equal(np.asarray(got.cache.v),
-                                      np.asarray(want.cache.v))
+        for g, w in zip(got.cache.pools, want.cache.pools, strict=True):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
         np.testing.assert_array_equal(
             np.asarray(got.packed),
             np.stack([np.asarray(got.next_token),

@@ -148,14 +148,36 @@ def _kv_gather(width, lanes=8, kv_heads=16, blocks=1025, layers=24):
 
         def gather(k_pool, v_pool, layer, tables, lens):
             # two layers: the second writes into the first one's result
-            first = kv_gather(k_pool, v_pool, layer, tables, lens,
+            first = kv_gather((k_pool, v_pool), layer, tables, lens,
                               impl="pallas")
-            return kv_gather(k_pool, v_pool, layer + 1, tables, lens, first,
-                             impl="pallas")
+            return kv_gather((k_pool, v_pool), layer + 1, tables, lens,
+                             first, impl="pallas")
 
         return gather, (pool, pool, chip((), jnp.int32),
                         chip((lanes, width), jnp.int32),
                         chip((lanes,), jnp.int32))
+
+    return build
+
+
+def _latent_gather(width, lanes=32, blocks=65537, layers=6):
+    """glm-4.7-flash's latent pool (one 640-wide row a token, one pool) at
+    its decode program's lanes, then the absorbed form over what was
+    gathered (``ops/mla_decode.py``): 20 heads, values of 512."""
+    from apex_tpu.ops.kv_gather import kv_gather
+    from apex_tpu.ops.mla_decode import mla_decode
+
+    def build(chip):
+        def decode(pool, layer, tables, lens, q):
+            (rows,) = kv_gather((pool,), layer, tables, lens,
+                                impl="pallas")
+            return mla_decode(q, rows, lens, value_dim=512,
+                              scale=256 ** -0.5, impl="pallas")
+
+        return decode, (chip((layers, blocks, 16, 1, 640), jnp.bfloat16),
+                        chip((), jnp.int32), chip((lanes, width), jnp.int32),
+                        chip((lanes,), jnp.int32),
+                        chip((lanes, 20, 640), jnp.bfloat16))
 
     return build
 
@@ -254,6 +276,9 @@ KERNELS = {
         1024, lanes=16, kv_heads=8, blocks=8192, layers=5),
     "kv gather width 320, 16 lanes": _kv_gather(
         320, lanes=16, kv_heads=8, blocks=8192, layers=5),
+    # glm-4.7-flash's decode calls at the narrowest and widest tables
+    "latent gather + mla_decode width 512, 32 lanes": _latent_gather(512),
+    "latent gather + mla_decode width 4096, 32 lanes": _latent_gather(4096),
     "layer norm fwd+bwd": _layer_norm,
     "xentropy fwd+bwd vocab 50304": _xentropy,
     "fused adam, flat 8M": _fused_adam,
@@ -506,7 +531,7 @@ def test_trinity_cut_programs_fit_and_gather_the_window(chip, monkeypatch,
     assert names.count("attention") == 1
     assert names.count("moe_grouped") == 12           # 3 products x 4 layers
     assert "ragged-dot-none" not in names
-    assert_pool_stays_where_it_lies(text, state.k.shape)
+    assert_pool_stays_where_it_lies(text, state.pools[0].shape)
 
 
 def _mellum2_program(chip, monkeypatch, fn, batch, seq):
@@ -609,7 +634,7 @@ def test_mellum2_cut_programs_fit_and_gather_the_tail(chip, monkeypatch,
         text, (64, 2304, 896), (64, 896, 2304))
     # four KV heads fill half a bf16 tile: one scatter over all lanes
     # had the pools converted whole, there and back, in every call
-    assert_pool_stays_where_it_lies(text, state.k.shape)
+    assert_pool_stays_where_it_lies(text, state.pools[0].shape)
 
 
 @pytest.mark.parametrize("fn,batch,seq", [
@@ -670,7 +695,7 @@ def test_granite_cut_programs_fit_and_leave_the_state_where_it_lies(
     n = sum(x.size for x in jax.tree.leaves(shapes))
     # 9 x 121.5M + 61.1M outside the experts, 10 x 85.0M held, 411M: 4.83 GB
     assert 2.40e9 < n < 2.43e9
-    assert state.k.shape == (1, 16385, 16, 8, 128)
+    assert state.pools[0].shape == (1, 16385, 16, 8, 128)
     assert [s.shape for s in state.state] == [(65, 9, 128, 64, 128),
                                               (65, 9, 3 * 8448)]
     assert chip_smoke.program_bytes(compiled) <= 0.85 * V5E_BYTES
@@ -690,7 +715,7 @@ def test_granite_cut_programs_fit_and_leave_the_state_where_it_lies(
     if fn != "prefill_step":                          # over the cache
         gathers = [res for name, res in calls if name == "kv_gather"]
         assert [g.count(f"bf16[{batch},8,8192,128]") for g in gathers] == [2]
-    assert_pool_stays_where_it_lies(text, state.k.shape)
+    assert_pool_stays_where_it_lies(text, state.pools[0].shape)
     # the state pools: donated, aliased to outputs, never copied whole
     for pool in state.state:
         shape = ",".join(str(d) for d in pool.shape)
