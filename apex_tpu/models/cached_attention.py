@@ -8,7 +8,9 @@ tail: the blocks that hold the last ``window`` positions (built on the
 host, ``KVCache.window_table_array``), so what it gathers and attends
 stops growing at the window. Each lane's tail starts at a block edge
 of its own, so the window layer's masks come from true positions, not
-from where a key lies in the gathered array.
+from where a key lies in the gathered array. A *latent* layer gathers
+its rows for a chunk, and in a decode call gathers nothing: its kernel
+reads the pool through the table (:func:`cached_latent_attention`).
 
 The gather stops at what a lane has written (``lens``), so what lies
 past it in the gathered arrays is whatever was there before. The layers
@@ -162,28 +164,30 @@ def cached_latent_attention(row_new, kv_ctx, *, expanded, absorbed, gather,
     ``row_new`` (b, 1, s, W) is their rows ``[c_kv | k_r | 0]``;
     ``kv_ctx = (layer, into, pools, tables, ctx_lens)`` as
     :func:`cached_attention` takes it, with the latent
-    pool alone in ``pools``. The layer gathers its rows (one array, key
-    and value both); what it does with them is the caller's:
+    pool alone in ``pools``. What the layer does with its rows is the
+    caller's:
 
-    - ``s == 1`` is decode: the token's row goes into its slot of the
-      gathered rows and ``absorbed(rows, lens)`` attends over the first
-      ``lens = ctx_lens + 1`` of them;
-    - ``s > 1`` is a chunk over ``[ctx | chunk]``: ``expanded(rows,
-      kv_segment_ids=...)`` up-projects the rows and attends causally,
-      the segment ids dropping ctx slots past the written prefix.
+    - ``s == 1`` is decode: ``absorbed(pools, layer, tables, ctx_lens,
+      row_new)`` attends over each lane's ``ctx_lens`` rows where they
+      lie in the pool, then the token's own row (``ops/mla_decode.py``):
+      nothing is gathered, and nothing is handed to the next layer
+      (``into`` is ``()``: ``models/decoder.py`` makes none for a
+      decode call);
+    - ``s > 1`` is a chunk over ``[ctx | chunk]``: the layer gathers its
+      rows (one array, key and value both) and ``expanded(rows,
+      kv_segment_ids=...)`` up-projects them and attends causally, the
+      segment ids dropping ctx slots past the written prefix.
 
     Returns what the caller's form returns and what the next latent
     layer gathers into."""
     layer, into, pools, tables, ctx_lens = kv_ctx
     s = row_new.shape[2]
+    if s == 1:
+        return absorbed(pools, layer, tables, ctx_lens, row_new), ()
     with jax.named_scope("cache"):
         (rows,) = gather(pools, layer, tables, ctx_lens, into)
         rows = rows.astype(dtype)
-        if s == 1:
-            rows = _into_slot(rows, row_new, ctx_lens)
         held = (rows.astype(pools[0].dtype),) if into else ()
-    if s == 1:
-        return absorbed(rows, ctx_lens + 1), held
     slot = jnp.arange(rows.shape[2], dtype=jnp.int32)[None, :]
     kv_seg = jnp.concatenate(
         [(slot >= ctx_lens[:, None]).astype(jnp.int32),

@@ -430,9 +430,10 @@ class LatentAttention(nn.Module):
     its own are up-projected into per-head K and V and the flash
     kernel attends. A decode call takes the *absorbed* form:
     ``q_lat = q_nope W_UK^T`` scores the rows as they are, each head's
-    value is ``c_kv`` itself (``ops/mla_decode.py`` reads the rows once
-    for both), and ``W_UV`` goes on the result. The form is chosen by
-    the call's shape alone."""
+    value is ``c_kv`` itself (``ops/mla_decode.py`` reads each lane's
+    live rows once for both, where they lie in the pool, and the
+    token's own row last), and ``W_UV`` goes on the result. The form is
+    chosen by the call's shape alone."""
 
     config: DecoderConfig
 
@@ -492,7 +493,7 @@ class LatentAttention(nn.Module):
                            **kw)
                 return o[..., :vd].transpose(0, 2, 1, 3)
 
-        def absorbed(rows, lens):
+        def absorbed(pools, layer, tables, lens, row_new):
             from apex_tpu.ops.mla_decode import mla_decode
 
             with jax.named_scope("mla_absorbed"):
@@ -503,7 +504,8 @@ class LatentAttention(nn.Module):
                 qf = jnp.concatenate(
                     [q_lat.astype(dt), q_rope[:, 0],
                      jnp.zeros((b, nh, width - lat - rope), dt)], axis=-1)
-                o = mla_decode(qf, rows, lens, value_dim=lat, scale=scale,
+                o = mla_decode(qf, pools[0], layer, tables, lens, row_new,
+                               value_dim=lat, scale=scale,
                                impl=cfg.softmax_impl)
                 o = jnp.einsum("bhc,chv->bhv", o.astype(dt), w[..., nope:],
                                preferred_element_type=jnp.float32)
@@ -637,13 +639,14 @@ class PatternDecoder(nn.Module):
             positions = jnp.broadcast_to(jnp.asarray(positions), (b, s))
         kvs = []
         # what the next layer of each kind gathers its context into
-        # (cached_attention): zeros before the first
+        # (cached_attention): zeros before the first; nothing for the
+        # latent layers of a decode call, which read the pool in place
         into = {}
         if kv_ctx is not None:
             windows = {"window": cfg.attention_window}
-            into = {kind: first_context(
-                        kv_ctx, window=windows.get(kind),
-                        zero=lambda *a: _zero_ctx(cfg, *a))
+            into = {kind: () if kind == "latent" and s == 1 else
+                    first_context(kv_ctx, window=windows.get(kind),
+                                  zero=lambda *a: _zero_ctx(cfg, *a))
                     for kind in dict.fromkeys(a for a, _ in cfg.layers)
                     if kind != "mamba"}
         pools = slots = lengths = fresh = None

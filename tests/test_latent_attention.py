@@ -13,6 +13,7 @@ pattern models': that file alone is the longest stretch of the suite
 under one worker.
 """
 
+import dataclasses
 import zlib
 
 import jax
@@ -99,10 +100,12 @@ def test_latent_rows_through_the_pool_and_a_handoff(built):
     gathered rows), then decode (the absorbed form), then the
     sequence's rows exported and installed into another engine's pool
     (a disaggregated handoff) where it decodes on. Every row of logits
-    is the reference's full pass's. The pool is one 128-wide row a
-    token a layer in one pool; the handoff carries that one pool's
-    payload; a scrub zeroes the rows and a quarantine's poison lands in
-    them."""
+    is the reference's full pass's. Every decode step is served again
+    over the paged form (``mla_decode`` interpreted, reading the pool
+    through the table) and gives the gathered form's token and logits.
+    The pool is one 128-wide row a token a layer in one pool; the
+    handoff carries that one pool's payload; a scrub zeroes the rows and
+    a quarantine's poison lands in them."""
     from apex_tpu.serving.resilience import poison_lane_kv
 
     model, params = built
@@ -114,6 +117,21 @@ def test_latent_rows_through_the_pool_and_a_handoff(built):
     caches = [serving.KVCache.for_config(cfg, num_blocks=16, block_size=8)
               for _ in range(2)]
     step = [serving.make_decode_step(model, c) for c in caches]
+    paged_model = PatternDecoder(
+        dataclasses.replace(cfg, softmax_impl="interpret"))
+    paged = [serving.make_decode_step(paged_model, c) for c in caches]
+
+    def decode(i, state, p, tables):
+        # the paged form on a copy (a step's state is donated), then the
+        # gathered one: token for token, and the same logits
+        args = (toks[p:p + 1], np.array([p]), tables)
+        mine = paged[i].decode(params, jax.tree.map(jnp.copy, state), *args)
+        out = step[i].decode(params, state, *args)
+        assert int(mine.next_token[0]) == int(out.next_token[0])
+        np.testing.assert_allclose(np.asarray(mine.logits),
+                                   np.asarray(out.logits), rtol=1e-5,
+                                   atol=1e-5 * float(jnp.abs(want).max()))
+        return out
     a, b = caches
     assert a.row_bytes() == 3 * 128 * 4 and a.block_bytes() == 8 * 1536
     state = a.init_state()
@@ -132,8 +150,7 @@ def test_latent_rows_through_the_pool_and_a_handoff(built):
     got.append(out.logits[0])
     state = out.cache
     for p in range(25, 31):
-        out = step[0].decode(params, state, toks[p:p + 1], np.array([p]),
-                             table(a, 0))
+        out = decode(0, state, p, table(a, 0))
         state = out.cache
         got.append(out.logits[0])
     blocks, payloads = a.export_blocks(state, 0, length=31)
@@ -143,8 +160,7 @@ def test_latent_rows_through_the_pool_and_a_handoff(built):
     assert b.table(7)[:4] != blocks
     other = b.import_blocks(b.init_state(), 7, payloads)
     for p in range(31, 39):
-        out = step[1].decode(params, other, toks[p:p + 1], np.array([p]),
-                             table(b, 7))
+        out = decode(1, other, p, table(b, 7))
         other = out.cache
         got.append(out.logits[0])
     rows = [11, 24] + list(range(25, 39))
@@ -179,30 +195,83 @@ def test_the_references_experts_take_their_rows_256_a_call(built):
                                rtol=1e-6, atol=1e-6)
 
 
-@pytest.mark.parametrize("length", [256, 2048])
-def test_mla_decode_kernel_is_the_plain_form(length):
-    """``ops/mla_decode.py`` interpreted against its plain ``jnp`` form
-    at two widths, ragged lengths (one key, a third, all of them, a
-    length inside a block), 20 heads over 640-wide rows: the kernel's
-    online softmax over blocks of rows and its bf16 ``p`` agree with
-    the float32 softmax to bf16's rounding."""
+def paged_case(width, poisoned, seed=0):
+    """A latent pool of two layers (blocks of 16 rows of 640, bf16) and
+    six lanes reading layer 1 through random tables of ``width``
+    blocks: ids out of order, each table filled out past its live
+    blocks with one trash block all lanes share. The lanes' lengths:
+    none (a dummy lane: its own row alone), a block edge, a group edge
+    (``GROUP`` blocks, where the table reaches it), mid-block, one row
+    short of the full width, and the full width. ``poisoned``: every
+    block no lane's live prefix names is NaN, layer 0 whole."""
+    from apex_tpu.ops.mla_decode import GROUP
+
+    rng = np.random.default_rng(seed)
+    blocks, bs, trash = 3 * width + 1, 16, 3 * width
+    rows = width * bs
+    lens = np.array([0, 3 * bs, min(GROUP, width - 1) * bs, rows // 2 + 5,
+                     rows - 1, rows], np.int32)
+    live = -(-lens // bs)
+    tables = np.full((6, width), trash, np.int32)
+    for i, n in enumerate(live):
+        tables[i, :n] = rng.choice(trash, n, replace=False)
+    pool = rng.normal(size=(2, blocks, bs, 1, 640)).astype(np.float32)
+    if poisoned:
+        named = np.zeros(blocks, bool)
+        for i, n in enumerate(live):
+            named[tables[i, :n]] = True
+        pool[1, ~named] = np.nan
+        pool[0] = np.nan
+    q = rng.normal(size=(6, 20, 640)).astype(np.float32)
+    row = rng.normal(size=(6, 1, 1, 640)).astype(np.float32)
+    bf16 = lambda x: jnp.asarray(x, jnp.bfloat16)  # noqa: E731
+    return bf16(q), bf16(pool), tables, lens, bf16(row)
+
+
+def plain_form(q, pool, tables, lens, row, *, value_dim, scale):
+    """What a lane attends, in float64: its rows of layer 1 below its
+    length, then its own row."""
+    q, pool, row = (np.asarray(x, np.float64) for x in (q, pool, row))
+    out = []
+    for i, n in enumerate(lens):
+        keys = np.concatenate(
+            [pool[1, tables[i]].reshape(-1, 640)[:n], row[i, 0]])
+        s = scale * q[i] @ keys.T
+        p = np.exp(s - s.max(axis=-1, keepdims=True))
+        out.append(p / p.sum(axis=-1, keepdims=True) @ keys[:, :value_dim])
+    return np.stack(out)
+
+
+@pytest.mark.parametrize("poisoned", [False, True],
+                         ids=["pool", "poisoned pool"])
+@pytest.mark.parametrize("width", [16, 160])
+@pytest.mark.parametrize("impl", ["interpret", "xla"])
+def test_mla_decode_kernel_is_the_plain_form(impl, width, poisoned):
+    """``ops/mla_decode.py`` through the block tables, interpreted and
+    in its ``xla`` form, against the plain form: random tables with a
+    shared trash block, lengths of none, at a block edge, at a group
+    edge, mid-block and at the table's whole width (one group of 16
+    blocks, or two: 128 blocks and 32), 20 heads over 640-wide rows. The
+    kernel's online softmax over groups of rows and its bf16 ``p``
+    agree with the float64 softmax to bf16's rounding. Over a pool
+    whose every block outside the lanes' live prefixes is NaN the
+    result is the same and finite: no dead block is read."""
     from apex_tpu.ops.mla_decode import mla_decode
 
-    k1, k2 = jax.random.split(jax.random.PRNGKey(length))
-    q = jax.random.normal(k1, (4, 20, 640), jnp.float32).astype(jnp.bfloat16)
-    rows = jax.random.normal(k2, (4, 1, length, 640),
-                             jnp.float32).astype(jnp.bfloat16)
-    lens = jnp.array([1, length // 3, length, length - 7], jnp.int32)
+    q, pool, tables, lens, row = paged_case(width, poisoned)
     kw = dict(value_dim=512, scale=256 ** -0.5)
-    want = mla_decode(q, rows, lens, impl="xla", **kw)
-    got = mla_decode(q, rows, lens, impl="interpret", **kw)
-    assert got.shape == (4, 20, 512) and got.dtype == jnp.float32
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-2 * float(jnp.abs(want).max()))
-    # one key: its value, exactly
+    got = mla_decode(q, pool, 1, jnp.asarray(tables), jnp.asarray(lens),
+                     row, impl=impl, **kw)
+    clean = paged_case(width, False)
+    want = plain_form(*clean, **kw)
+    assert got.shape == (6, 20, 512) and got.dtype == jnp.float32
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(np.asarray(got), want,
+                               atol=2e-2 * float(np.abs(want).max()))
+    # a dummy lane's one key, its own row: its value, exactly
     np.testing.assert_allclose(
         np.asarray(got[0]),
-        np.broadcast_to(np.asarray(rows[0, 0, 0, :512], np.float32),
+        np.broadcast_to(np.asarray(row[0, 0, 0, :512], np.float32),
                         (20, 512)), rtol=1e-6)
 
 

@@ -162,22 +162,22 @@ def _kv_gather(width, lanes=8, kv_heads=16, blocks=1025, layers=24):
 
 def _latent_gather(width, lanes=32, blocks=65537, layers=6):
     """glm-4.7-flash's latent pool (one 640-wide row a token, one pool) at
-    its decode program's lanes, then the absorbed form over what was
-    gathered (``ops/mla_decode.py``): 20 heads, values of 512."""
-    from apex_tpu.ops.kv_gather import kv_gather
+    its decode program's lanes, read through the tables by the absorbed
+    form (``ops/mla_decode.py``), the token's own row last: 20 heads,
+    values of 512."""
     from apex_tpu.ops.mla_decode import mla_decode
 
     def build(chip):
-        def decode(pool, layer, tables, lens, q):
-            (rows,) = kv_gather((pool,), layer, tables, lens,
-                                impl="pallas")
-            return mla_decode(q, rows, lens, value_dim=512,
-                              scale=256 ** -0.5, impl="pallas")
+        def decode(pool, layer, tables, lens, q, row):
+            return mla_decode(q, pool, layer, tables, lens, row,
+                              value_dim=512, scale=256 ** -0.5,
+                              impl="pallas")
 
         return decode, (chip((layers, blocks, 16, 1, 640), jnp.bfloat16),
                         chip((), jnp.int32), chip((lanes, width), jnp.int32),
                         chip((lanes,), jnp.int32),
-                        chip((lanes, 20, 640), jnp.bfloat16))
+                        chip((lanes, 20, 640), jnp.bfloat16),
+                        chip((lanes, 1, 1, 640), jnp.bfloat16))
 
     return build
 
@@ -534,6 +534,67 @@ def test_trinity_cut_programs_fit_and_gather_the_window(chip, monkeypatch,
     assert_pool_stays_where_it_lies(text, state.pools[0].shape)
 
 
+def _glm_program(chip, monkeypatch, fn, batch, seq, width):
+    """``GLM-4.7-Flash``'s six-layer cut at the benchmark's own sizes,
+    program ``fn`` at ``batch`` x ``seq`` over tables of ``width``
+    blocks, lowered for one described v5e: ``(cache state shapes, the
+    lowered program)``."""
+    import json
+
+    from apex_tpu import serving
+    from apex_tpu.models.decoder import PatternDecoder
+    from benchmark.drivers import serve_glm
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "glm-4.7-flash.json")) as f:
+        config = json.load(f)
+    monkeypatch.setenv("APEX_TPU_IMPL", "pallas")
+    _backend.default_impl.cache_clear()
+    try:
+        cfg = serve_glm.decoder_config(config)
+        model = PatternDecoder(cfg)
+        cache = serving.KVCache.for_config(
+            cfg, num_blocks=config["engine"]["num_blocks"],
+            block_size=config["engine"]["block_size"])
+        shapes = jax.eval_shape(
+            lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32)),
+            jax.random.PRNGKey(0))
+        params, state = jax.tree.map(
+            lambda x: chip(x.shape, x.dtype),
+            (shapes, jax.eval_shape(cache.init_state)))
+        lowered = serving.make_decode_step(model, cache).lower(
+            fn, params, state, batch, width, seq=seq)
+    finally:
+        _backend.default_impl.cache_clear()
+    return state, lowered
+
+
+@pytest.mark.parametrize("fn,batch,seq,width", [
+    ("decode_step", 32, 1, 4096), ("prefill_chunk", 1, 1024, 512)])
+def test_glm_decode_program_reads_the_pool_in_place(chip, monkeypatch, fn,
+                                                    batch, seq, width):
+    """``GLM-4.7-Flash``'s programs at the benchmark's own sizes: the
+    decode program's six latent layers read their rows through the
+    tables inside ``mla_decode`` (one kernel body), and nothing gathers
+    or zeroes a context, nor holds one as wide as the table; a chunk
+    program, the expanded form, gathers each layer's rows into the
+    array ``zero_context`` made, as before."""
+    import re
+
+    state, lowered = _glm_program(chip, monkeypatch, fn, batch, seq, width)
+    text = lowered.as_text()
+    kernels = re.findall(r'kernel_name = "(\w+)"', text)
+    assert state.pools[0].shape == (6, 65537, 16, 1, 640)
+    if fn == "decode_step":
+        assert kernels == ["mla_decode"]
+        assert f"x{width * 16}x640x" not in text
+    else:
+        assert {"kv_gather", "zero_context"} <= set(kernels)
+        assert "mla_decode" not in kernels
+        assert f"tensor<{batch}x1x{width * 16}x640xbf16>" in text
+
+
 def _mellum2_program(chip, monkeypatch, fn, batch, seq):
     """``Mellum2-12B-A2.5B``'s eight-layer cut at the benchmark's own
     sizes, program ``fn`` at ``batch`` x ``seq`` over the widest tables
@@ -589,6 +650,18 @@ def test_mellum2_chunk_program_lowers_two_kernel_bodies(chip, monkeypatch):
     assert re.findall(r'kernel_name = "(\w+)"', text).count(
         "moe_grouped") == 2
     assert "ragged_dot" not in text
+
+
+def test_gqa_decode_program_still_gathers(chip, monkeypatch):
+    """A GQA model's decode program keeps its gather: Mellum2's lowers
+    the flash kernel, ``kv_gather`` and ``zero_context``, and no latent
+    kernel (the latent decode path is the latent kind's alone)."""
+    import re
+
+    *_, lowered, _ = _mellum2_program(chip, monkeypatch, "decode_step",
+                                      16, 1)
+    kernels = re.findall(r'kernel_name = "(\w+)"', lowered.as_text())
+    assert set(kernels) == {"kernel", "kv_gather", "zero_context"}
 
 
 @pytest.mark.parametrize("fn,batch,seq", [
