@@ -31,7 +31,9 @@ experts"):
   docs/serving.md "Latent attention");
 - a *mamba* layer has no attention at all: its mixer is
   ``models/ssm.py``'s Mamba-2, whose state a sequence is a fixed size
-  (``DecoderConfig.mamba``), not keys that grow with the context;
+  (``DecoderConfig.mamba``), not keys that grow with the context; a
+  *mamba1* layer is the same with Mamba-1's selective scan, a decay for
+  every channel and state (``DecoderConfig.selective``);
 - a rotary scheme *for each kind of layer* (:class:`Rotary`): none,
   plain, or YaRN's blend of scaled and unscaled frequencies with its
   factor on cos and sin;
@@ -49,6 +51,11 @@ pre-norm, nine ``mamba`` layers and one full attention layer a period,
 no QK-norm and no rotary at all, scores times 1/128, multipliers on
 the embedding, the residuals and the logits, a tied head, the softmax
 router with a shared expert.
+AI21's ``jamba`` (Jamba2-3B, ``benchmark/drivers/serve_jamba.py``) is
+pre-norm, thirteen ``mamba1`` layers (with RMSNorms on ``dt``, ``B``
+and ``C`` inside the mixer) and one full attention layer a period,
+one KV head, no QK-norm and no rotary, a dense MLP on every layer, a
+tied head.
 Zhipu's ``glm4_moe_lite`` (GLM-4.7-Flash,
 ``benchmark/drivers/serve_glm.py``) is pre-norm, ``latent`` layers
 throughout with rotary on the shared key's 64 dimensions only (pairs
@@ -59,7 +66,7 @@ It offers the serving hooks ``GPTModel`` offers (``positions``,
 ``kv_ctx``, ``return_kv``; ``config.kv_heads`` / ``head_dim`` /
 ``max_seq_len`` / ``num_layers`` / ``attention_window``), so
 ``serving.make_decode_step`` and ``ContinuousBatcher`` take it as they
-take a ``GPTModel``; a model with ``mamba`` layers also takes
+take a ``GPTModel``; a model with ``mamba`` or ``mamba1`` layers also takes
 ``state_ctx``, its lanes' recurrent states (``config.num_kv_layers``
 is then fewer than ``num_layers``: the paged pool holds only the
 layers that HAVE keys). Single device: no mesh annotations.
@@ -77,13 +84,16 @@ import jax.numpy as jnp
 
 from apex_tpu.models.cached_attention import (
     cached_attention, cached_latent_attention, first_context)
-from apex_tpu.models.ssm import Mamba2Config, Mamba2Mixer
+from apex_tpu.models.ssm import (Mamba2Config, Mamba2Mixer, SelectiveMixer,
+                                 SelectiveSSMConfig)
 from apex_tpu.moe.held import HeldMoEConfig, HeldMoEMLP, gated_mlp
 from apex_tpu.ops.layer_norm import fused_rms_norm
 from apex_tpu.ops.rope import fused_apply_rotary_pos_emb_cached
 
 ATTENTION_KINDS = ("full", "window", "latent")
-MIXER_KINDS = ATTENTION_KINDS + ("mamba",)
+# the kinds whose layers hold a recurrent state, not keys
+STATE_KINDS = ("mamba", "mamba1")
+MIXER_KINDS = ATTENTION_KINDS + STATE_KINDS
 MLP_KINDS = ("dense", "experts")
 NORM_PLACEMENTS = ("sandwich", "pre")
 
@@ -199,13 +209,17 @@ class DecoderConfig:
     # the "latent" layers' sizes; set exactly when there is such a
     # layer, and then every layer that has keys is one
     mla: Optional[MLAConfig] = None
+    # the "mamba1" layers' sizes (Mamba-1's selective scan), as
+    # ``mamba`` is for the "mamba" layers; a model has one kind or the
+    # other (a state slot holds one shape)
+    selective: Optional[SelectiveSSMConfig] = None
 
     def __post_init__(self):
         if self.norms not in NORM_PLACEMENTS:
             raise ValueError(f"norms is one of {NORM_PLACEMENTS}, "
                              f"got {self.norms!r}")
         if self.rotary is not None:
-            kinds = {a for a, _ in self.layers if a != "mamba"}
+            kinds = {a for a, _ in self.layers if a not in STATE_KINDS}
             if {k for k, _ in self.rotary} != kinds:
                 raise ValueError(
                     f"rotary names each kind of layer once ({sorted(kinds)}"
@@ -224,10 +238,16 @@ class DecoderConfig:
                              "layer is a window layer")
         if any(m == "experts" for _, m in self.layers):
             self.moe_cfg()                    # validates the expert sizes
-        if (self.mamba is not None) != bool(self.state_layers):
-            raise ValueError("mamba is set exactly when some layer is a "
-                             "mamba layer")
-        kinds = {a for a, _ in self.layers if a != "mamba"}
+        for kind, sizes in (("mamba", self.mamba),
+                            ("mamba1", self.selective)):
+            if (sizes is not None) != any(a == kind for a, _ in self.layers):
+                raise ValueError(
+                    f"{'selective' if kind == 'mamba1' else 'mamba'} is set "
+                    f"exactly when some layer is a {kind} layer")
+        if self.mamba is not None and self.selective is not None:
+            raise ValueError("mamba and mamba1 layers in one model: a state "
+                             "slot holds one kind of state")
+        kinds = {a for a, _ in self.layers if a not in STATE_KINDS}
         if (self.mla is not None) != ("latent" in kinds) or (
                 "latent" in kinds and kinds != {"latent"}):
             raise ValueError(
@@ -249,7 +269,7 @@ class DecoderConfig:
         """The layers that have keys, in order: layer ``kv_layers[j]``
         is layer ``j`` of the paged pool."""
         return tuple(i for i, (a, _) in enumerate(self.layers)
-                     if a != "mamba")
+                     if a not in STATE_KINDS)
 
     @property
     def num_kv_layers(self) -> int:
@@ -260,17 +280,18 @@ class DecoderConfig:
         """The layers that hold a recurrent state, in order: layer
         ``state_layers[j]`` is layer ``j`` of a state slot."""
         return tuple(i for i, (a, _) in enumerate(self.layers)
-                     if a == "mamba")
+                     if a in STATE_KINDS)
 
     def state_shapes(self):
         """What a sequence holds beside its K/V, a state slot:
         ``((shape, dtype), ...)`` with the state layers leading, or
         ``()`` for a model whose layers all have keys."""
-        if self.mamba is None:
+        sizes = self.mamba if self.mamba is not None else self.selective
+        if sizes is None:
             return ()
         n = len(self.state_layers)
         return tuple(((n, *shape), dtype)
-                     for shape, dtype in self.mamba.state_shapes(self.dtype))
+                     for shape, dtype in sizes.state_shapes(self.dtype))
 
     @property
     def kv_heads(self) -> int:
@@ -546,10 +567,10 @@ class DecoderLayer(nn.Module):
     def __call__(self, x, positions, *, kv_ctx=None, state_ctx=None,
                  lengths=None):
         """One layer. An attention layer returns ``(x', (k, v),
-        held)`` as ``DecoderAttention`` gives them; a ``mamba`` layer
-        ``(x', the state pools, ())``: ``state_ctx`` and ``lengths``
-        are ``ssm.Mamba2Mixer``'s (None: every lane from zeros, and
-        None comes back)."""
+        held)`` as ``DecoderAttention`` gives them; a ``mamba`` or
+        ``mamba1`` layer ``(x', the state pools, ())``: ``state_ctx``
+        and ``lengths`` are ``ssm.Mamba2Mixer``'s (None: every lane from
+        zeros, and None comes back)."""
         cfg = self.config
         norm = lambda name: RMSNorm(cfg.rms_eps, name=name)  # noqa: E731
         # a sandwich also norms what each half adds to the stream
@@ -564,11 +585,14 @@ class DecoderLayer(nn.Module):
             scaled = lambda t: t  # noqa: E731
         # each half of the layer under its part of the model
         # (telemetry.compiled.PARTS), norms and residual with it
-        if self.attention == "mamba":
+        if self.attention in STATE_KINDS:
             with jax.named_scope("mixer"):
                 with jax.named_scope("mamba_mixer"):
-                    a, kv = Mamba2Mixer(
-                        cfg.mamba, hidden_size=cfg.hidden_size,
+                    mixer, sizes = ((Mamba2Mixer, cfg.mamba)
+                                    if self.attention == "mamba" else
+                                    (SelectiveMixer, cfg.selective))
+                    a, kv = mixer(
+                        sizes, hidden_size=cfg.hidden_size,
                         rms_eps=cfg.rms_eps, dtype=cfg.dtype,
                         param_dtype=cfg.param_dtype, impl=cfg.softmax_impl,
                         name="mixer")(
@@ -614,7 +638,7 @@ class PatternDecoder(nn.Module):
         head_dim): the layers that have keys, in order.
 
         ``state_ctx = (pools, slots, lengths, fresh)``, for a model
-        with ``mamba`` layers: the state pools whole (``(slots + 1,
+        with ``mamba`` or ``mamba1`` layers: the state pools whole (``(slots + 1,
         state layers, ...)`` each: ``serving/kv_cache.py``), each
         lane's slot (b,), its real rows (b,) (None: all ``s``) and
         whether it starts its sequence here (b,) bool: from zeros
@@ -648,13 +672,13 @@ class PatternDecoder(nn.Module):
                     first_context(kv_ctx, window=windows.get(kind),
                                   zero=lambda *a: _zero_ctx(cfg, *a))
                     for kind in dict.fromkeys(a for a, _ in cfg.layers)
-                    if kind != "mamba"}
+                    if kind not in STATE_KINDS}
         pools = slots = lengths = fresh = None
         if state_ctx is not None:
             pools, slots, lengths, fresh = state_ctx
         for i, (attention, mlp) in enumerate(cfg.layers):
             layer = DecoderLayer(cfg, attention, mlp, name=f"layer_{i}")
-            if attention == "mamba":
+            if attention in STATE_KINDS:
                 x, pools, _ = layer(
                     x, positions, lengths=lengths,
                     state_ctx=(None if pools is None else (
@@ -686,4 +710,4 @@ class PatternDecoder(nn.Module):
 
 
 __all__ = ["DecoderConfig", "MLAConfig", "Mamba2Config", "PatternDecoder",
-           "Rotary"]
+           "Rotary", "SelectiveSSMConfig"]

@@ -1,5 +1,6 @@
-"""The Mamba-2 mixer (state-space duality, Dao & Gu 2024) in two forms
-over one parameter tree: the *chunked* form for ``s > 1`` tokens a lane
+"""The state-space mixers, each in two forms over one parameter tree:
+Mamba-2 (state-space duality, Dao & Gu 2024) and, below it, Mamba-1's
+selective scan (Gu & Dao 2023, :class:`SelectiveMixer`). Mamba-2's: the *chunked* form for ``s > 1`` tokens a lane
 and the *one-step* recurrence for decode. Both take a state in and give
 a state out, so a sequence can be prefilled in chunks, decoded a token
 at a time, and moved between lanes between calls.
@@ -276,6 +277,210 @@ class Mamba2Mixer(nn.Module):
                                          rows.reshape(b, -1)))
 
 
-__all__ = ["Mamba2Config", "Mamba2Mixer", "a_log_init", "conv_rows",
-           "conv_step", "dt_bias_init", "ssm_scan",
-           "ssm_step"]
+#: the lanes of a vector register: a selective mixer's channels are
+#: held in groups of this many, minor-most (``SelectiveSSMConfig``)
+LANES = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class SelectiveSSMConfig:
+    """Mamba-1's selective scan (Gu & Dao 2023) as Jamba runs it, one
+    decay for every (channel, state) pair::
+
+        [x | z] = u W_in                                 (E each)
+        x_t <- silu(b_conv + sum_j w_conv[:, j] x_{t-3+j})  (depthwise)
+        [d | B | C] = x_t W_x                            (R, N, N)
+        d, B, C <- RMSNorm each (Jamba's inner norms)
+        Delta_t = softplus(d W_dt + b_dt)                (E,)
+        S_t[e, n] = exp(Delta_t[e] A[e, n]) S_{t-1}[e, n]
+                    + Delta_t[e] B_t[n] x_t[e]           float32
+        y_t[e] = sum_n S_t[e, n] C_t[n] + D[e] x_t[e]
+        o = (y_t * silu(z_t)) W_out                      (no norm)
+
+    With a decay for every channel and state there is no scalar decay
+    a head to factor out of the products, so the SSD chunk form of
+    Mamba-2 does not apply: a chunk is a scan over its rows
+    (``ops/ssm_scan.py``), a decode step one turn of it
+    (``ops/ssm_step.py``). A lane's state is ``S`` held with the
+    channels minor-most, ``(E / 128, N, 128)``: ``N`` (16) as the minor
+    dimension would pad every tile to 128 lanes, eight times the bytes.
+    """
+
+    inner: int                       # E
+    state_size: int                  # N
+    dt_rank: int                     # R
+    conv_width: int = 4
+
+    def __post_init__(self):
+        if self.inner % LANES:
+            raise ValueError(f"the inner width ({self.inner}) is held in "
+                             f"groups of {LANES} channels: a multiple of it")
+
+    def state_shapes(self, dtype):
+        """A lane's state: ``S`` as ``(E / 128, N, 128)`` float32, and
+        the convolution's carried rows of ``x`` flat in ``dtype``."""
+        return (((self.inner // LANES, self.state_size, LANES), jnp.float32),
+                (((self.conv_width - 1) * self.inner,), dtype))
+
+
+def a_log_range_init(key, shape, dtype=jnp.float32):
+    """``A[e] = -(1, 2, ..., N)`` for every channel: Mamba-1's published
+    initialisation (S4D-real). ``shape`` is ``(E, N)``."""
+    del key
+    return jnp.log(jnp.broadcast_to(
+        jnp.arange(1, shape[1] + 1, dtype=jnp.float32), shape)).astype(dtype)
+
+
+def symmetric_uniform(bound: float):
+    """``U(-bound, bound)``: Mamba-1's convolution (torch's default at
+    fan-in 4) and ``W_dt`` (``R^-0.5``)."""
+    def init(key, shape, dtype=jnp.float32):
+        return jax.random.uniform(key, shape, jnp.float32, -bound,
+                                  bound).astype(dtype)
+    return init
+
+
+def channel_groups(t):
+    """``(..., E)`` -> ``(..., E / 128, 1, 128)``: a channel's value
+    where it lies in the state, broadcast along ``N``."""
+    return t.reshape(*t.shape[:-1], t.shape[-1] // LANES, 1, LANES)
+
+
+def decay_table(A):
+    """``A`` (E, N) -> ``(E / 128, N, 128)``, the state's layout."""
+    E, N = A.shape
+    return A.reshape(E // LANES, LANES, N).transpose(0, 2, 1)
+
+
+def selective_step(S, x, delta, A, B, C):
+    """One token of the selective scan. ``S`` (b, E/128, N, 128);
+    ``x``, ``delta`` (b, E), ``A`` (E, N), ``B`` / ``C`` (b, N), all
+    float32 -> (``sum_n S' C`` (b, E) float32, ``S'`` in ``S``'s type).
+    ``D x`` and the gate are the caller's."""
+    d = channel_groups(delta)
+    new = (jnp.exp(d * decay_table(A)) * S.astype(jnp.float32)
+           + (d * channel_groups(x)) * B[:, None, :, None])
+    # a sum of products, not a dot (ssm_step's reason)
+    y = (new * C[:, None, :, None]).sum(-2)
+    return y.reshape(x.shape), new.astype(S.dtype)
+
+
+def selective_scan(S, x, delta, A, B, C, D, z):
+    """``s`` tokens a lane, a scan over them. ``S`` (b, E/128, N, 128);
+    ``x``, ``delta`` (b, s, E) float32, ``delta`` 0 on pad rows (they
+    stand still); ``z`` (b, s, E); ``B`` / ``C`` (b, s, N) float32.
+    Returns (``(sum_n S_t C_t + D x_t) silu(z_t)`` (b, s, E) float32,
+    the state after the last row in ``S``'s type)."""
+    def token(S, t):
+        x_t, d_t, B_t, C_t = t
+        y, S = selective_step(S, x_t, d_t, A, B_t, C_t)
+        return S, y
+
+    S, y = lax.scan(token, S, tuple(jnp.moveaxis(t, 1, 0)
+                                    for t in (x, delta, B, C)))
+    y = jnp.moveaxis(y, 0, 1) + D * x
+    return y * jax.nn.silu(z.astype(jnp.float32)), S
+
+
+def _rms(t, gain, eps):
+    return t * lax.rsqrt((t * t).mean(-1, keepdims=True) + eps) * gain
+
+
+class SelectiveMixer(nn.Module):
+    """Mamba-1's mixer (:class:`SelectiveSSMConfig`): ``u`` (b, s,
+    hidden) -> (b, s, hidden) and the state pools, over the same
+    ``state_ctx`` and ``lengths`` as :class:`Mamba2Mixer`. ``s == 1``
+    runs one turn on the lanes' states where they lie
+    (``ops/ssm_step.py`` ``selective_step_by_slot``), anything longer
+    the scan (``ops/ssm_scan.py``) between :func:`read_lanes` and
+    :func:`write_lanes` of ``ops/ssm_step.py``. A pad row's ``Delta``
+    is 0: its decay is 1 and it adds nothing, and the convolution
+    carries the last real rows on."""
+
+    config: SelectiveSSMConfig
+    hidden_size: int
+    rms_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    impl: Optional[str] = None       # the kernels' (ops/ssm_*.py)
+
+    @nn.compact
+    def __call__(self, u, *, state_ctx=None, lengths=None):
+        from apex_tpu.ops.ssm_scan import ssm_scan as scan_kernel
+        from apex_tpu.ops.ssm_step import (read_lanes,
+                                           selective_step_by_slot,
+                                           write_lanes)
+
+        cfg, f32, dt = self.config, jnp.float32, self.dtype
+        b, s, _ = u.shape
+        E, N, R, k = cfg.inner, cfg.state_size, cfg.dt_rank, cfg.conv_width
+        init = nn.initializers.normal(stddev=0.02)
+        conv = symmetric_uniform(0.5)
+
+        def param(name, shape, how=init, dtype=self.param_dtype):
+            return self.param(name, how, shape, dtype)
+
+        w_in = param("in_proj", (self.hidden_size, 2 * E))
+        conv_w = param("conv_w", (E, k), conv)
+        conv_b = param("conv_b", (E,), conv)
+        w_x = param("x_proj", (E, R + 2 * N))
+        gains = [param(name, (n,), nn.initializers.ones, f32)
+                 for name, n in (("dt_norm", R), ("B_norm", N),
+                                 ("C_norm", N))]
+        w_dt = param("dt_proj", (R, E), symmetric_uniform(R ** -0.5))
+        dt_bias = param("dt_bias", (E,), dt_bias_init, f32)
+        a_log = param("A_log", (E, N), a_log_range_init, f32)
+        d_skip = param("D", (E,), nn.initializers.ones, f32)
+        w_out = param("out_proj", (E, self.hidden_size))
+        if state_ctx is None:          # one slot a lane, made here
+            layer, slots = 0, jnp.arange(b)
+            fresh = jnp.ones((b,), bool)
+            S_pool, rows_pool = (
+                jnp.zeros((b, 1, *shape), dtype)
+                for shape, dtype in cfg.state_shapes(dt))
+        else:
+            (S_pool, rows_pool), layer, slots, fresh = state_ctx
+        rows = read_lanes(rows_pool, layer, slots, fresh).reshape(b, k - 1, E)
+        x, z = jnp.split(jnp.dot(u, w_in.astype(dt)), 2, axis=-1)
+        if s == 1:
+            x, rows = conv_step(rows, x[:, 0], conv_w, conv_b)
+            x = x[:, None]
+        else:
+            if lengths is None:
+                lengths = jnp.full((b,), s, jnp.int32)
+            x, rows = conv_rows(rows, x, conv_w, conv_b, lengths)
+        d, B, C = jnp.split(
+            jnp.dot(x.astype(dt), w_x.astype(dt), preferred_element_type=f32),
+            [R, R + N], axis=-1)
+        d, B, C = (_rms(t, g, self.rms_eps) for t, g in zip((d, B, C), gains))
+        delta = jax.nn.softplus(
+            jnp.dot(d.astype(dt), w_dt.astype(dt), preferred_element_type=f32)
+            + dt_bias)
+        A = -jnp.exp(a_log)
+        if s == 1:
+            with jax.named_scope("ssm_step"):
+                y, S_pool = selective_step_by_slot(
+                    S_pool, slots, fresh, layer, x[:, 0], delta[:, 0], A,
+                    B[:, 0], C[:, 0], impl=self.impl)
+            y = ((y + d_skip * x[:, 0])
+                 * jax.nn.silu(z[:, 0].astype(f32)))[:, None]
+        else:
+            real = jnp.arange(s)[None, :] < lengths[:, None]
+            delta = jnp.where(real[..., None], delta, 0.0)  # pads stand still
+            with jax.named_scope("ssm_scan"):
+                y, S = scan_kernel(read_lanes(S_pool, layer, slots, fresh),
+                                   x, delta, A, B, C, d_skip, z,
+                                   impl=self.impl)
+            S_pool = write_lanes(S_pool, layer, slots, S)
+        out = jnp.dot(y.astype(dt), w_out.astype(dt))
+        if state_ctx is None:
+            return out, None
+        return out, (S_pool, write_lanes(rows_pool, layer, slots,
+                                         rows.reshape(b, -1)))
+
+
+__all__ = ["LANES", "Mamba2Config", "Mamba2Mixer", "SelectiveMixer",
+           "SelectiveSSMConfig", "a_log_init", "a_log_range_init",
+           "channel_groups", "conv_rows", "conv_step", "decay_table",
+           "dt_bias_init", "selective_scan", "selective_step", "ssm_scan",
+           "ssm_step", "symmetric_uniform"]

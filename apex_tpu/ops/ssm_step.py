@@ -27,9 +27,21 @@ tenant left does not come through).
 The kernel is named ``ssm_step`` in the compiled program, which is what
 the benchmark's readers find it by (``ssm_state_pct``,
 ``ssm_step_roofline``).
+
+:func:`selective_step_by_slot` is the same walk for Mamba-1's
+recurrence (``models/ssm.py`` :class:`SelectiveMixer`), a decay for
+each channel and state: ``S' = exp(Delta A) S + Delta x B`` and ``y =
+S' C`` over a pool ``(slots + 1, state layers, E / 128, N, 128)``,
+the channels minor-most, so that ``N`` (16) pads no tile. A grid step
+is a lane's whole state of one layer (``SELECTIVE_GROUPS`` groups of
+128 channels); ``exp(Delta A)`` is computed in the kernel. It is named
+``ssm_step_selective``: the readers' ``^ssm_step`` and ``^ssm_`` find
+it too.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -164,4 +176,80 @@ def ssm_step_by_slot(pool, slots, fresh, layer: int, x, dt, A, B, C, D, *,
     return y + D[None, :, None] * x, pool
 
 
-__all__ = ["read_lanes", "ssm_step_by_slot", "write_lanes"]
+#: groups of 128 channels a grid step of the selective step: Jamba's
+#: 5120 channels whole, 320 KiB of a lane's state in and out a step
+SELECTIVE_GROUPS = 40
+
+
+def _selective_kernel(slots_ref, fresh_ref, dx_ref, bc_ref, a_ref, s_ref,
+                      y_ref, out_ref):
+    """One lane's groups of channels. ``dx_ref`` (2, groups, 128) holds
+    ``Delta`` and ``x`` a channel, ``bc_ref`` (N, 2) the token's ``B``
+    and ``C`` as columns: a column broadcasts along the channels."""
+    del slots_ref                      # the index maps' alone
+    fresh = fresh_ref[pl.program_id(0)] != 0
+    B, C = bc_ref[:, 0:1], bc_ref[:, 1:2]                  # (N, 1)
+    for g in range(s_ref.shape[0]):
+        S = s_ref[g]                                       # (N, 128)
+        S = jnp.where(fresh, jnp.zeros_like(S), S)
+        d, x = dx_ref[0, g:g + 1, :], dx_ref[1, g:g + 1, :]
+        new = jnp.exp(d * a_ref[g]) * S + B * (d * x)
+        out_ref[g] = new
+        y_ref[g:g + 1, :] = jnp.sum(new * C, axis=0, keepdims=True)
+
+
+def selective_step_by_slot(pool, slots, fresh, layer: int, x, delta, A, B,
+                           C, *, impl=None):
+    """``pool`` (slots + 1, layers, E/128, N, 128); ``slots`` (b,) int32
+    and ``fresh`` (b,) bool a lane; ``layer`` the state layer (static);
+    ``x``, ``delta`` (b, E), ``A`` (E, N), ``B`` / ``C`` (b, N),
+    float32. Returns ``(sum_n S' C (b, E) float32, the pool with the
+    lanes' slots of ``layer`` advanced one token)``; ``D x`` and the
+    gate are the caller's. The pool must be donated for the update to
+    be in place."""
+    from apex_tpu.models import ssm
+
+    impl = resolve_impl(impl)
+    if impl == "xla":
+        y, new = ssm.selective_step(read_lanes(pool, layer, slots, fresh),
+                                    x, delta, A, B, C)
+        return y, write_lanes(pool, layer, slots, new)
+    b, E = x.shape
+    _, _, G, N, L = pool.shape
+    groups = math.gcd(G, SELECTIVE_GROUPS)
+    f32 = jnp.float32
+    dx = jnp.stack([delta, x], axis=1).astype(f32).reshape(b, 2, G, L)
+    bc = jnp.stack([B, C], axis=-1).astype(f32)            # (b, N, 2)
+    lanes = pl.BlockSpec((None, groups, L),
+                         lambda i, g, slots, fresh: (i, g, 0))
+    state = pl.BlockSpec((None, None, groups, N, L),
+                         lambda i, g, slots, fresh: (slots[i], layer, g, 0, 0))
+    y, pool = pl.pallas_call(
+        _selective_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b, G // groups),
+            in_specs=[
+                pl.BlockSpec((None, 2, groups, L),
+                             lambda i, g, slots, fresh: (i, 0, g, 0)),
+                pl.BlockSpec((None, N, 2),
+                             lambda i, g, slots, fresh: (i, 0, 0)),
+                pl.BlockSpec((groups, N, L),
+                             lambda i, g, slots, fresh: (g, 0, 0)),
+                state],
+            out_specs=[lanes, state]),
+        out_shape=[jax.ShapeDtypeStruct((b, G, L), f32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        # operands count the prefetched scalars: the pool -> the pool
+        input_output_aliases={5: 1},
+        # dummy lanes follow one another through the trash slot: in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret_flag(impl),
+        name="ssm_step_selective",
+    )(slots.astype(jnp.int32), fresh.astype(jnp.int32), dx, bc,
+      ssm.decay_table(A.astype(f32)), pool)
+    return y.reshape(b, E), pool
+
+
+__all__ = ["SELECTIVE_GROUPS", "read_lanes", "selective_step_by_slot",
+           "ssm_step_by_slot", "write_lanes"]

@@ -147,7 +147,7 @@ SURFACE = {
     "apex_tpu.models.gpt": ["GPTConfig", "GPTModel", "gpt_loss_fn"],
     # PR-28: the pattern decoder and the held-experts layer
     "apex_tpu.models.decoder": ["DecoderConfig", "PatternDecoder", "Rotary",
-                                "MLAConfig"],
+                                "MLAConfig", "SelectiveSSMConfig"],
     "apex_tpu.models.decoder_reference": ["Arch", "forward", "judge",
                                           "check_served", "held_margin",
                                           "teacher_forced"],
@@ -162,6 +162,14 @@ SURFACE = {
     # latent attention (docs/serving.md "Latent attention")
     "apex_tpu.ops.mla_decode": ["mla_decode"],
     "apex_tpu.models.decoder_reference_glm": [
+        "Arch", "FAULTS", "forward", "check_served"],
+    # Mamba-1's selective scan (docs/serving.md "Recurrent state")
+    "apex_tpu.ops.ssm_scan": ["ssm_scan"],
+    "apex_tpu.ops.ssm_step": ["read_lanes", "write_lanes",
+                              "ssm_step_by_slot", "selective_step_by_slot"],
+    "apex_tpu.models.ssm": ["Mamba2Config", "Mamba2Mixer",
+                            "SelectiveSSMConfig", "SelectiveMixer"],
+    "apex_tpu.models.decoder_reference_jamba": [
         "Arch", "FAULTS", "forward", "check_served"],
     "apex_tpu.models.bert": None,     # module presence only
     "apex_tpu.models.t5": None,

@@ -802,3 +802,75 @@ def test_granite_cut_programs_fit_and_leave_the_state_where_it_lies(
         if "65" in dims.split(","):
             assert dims.split(",").index("65") == 0 and dims.split(
                 ",")[-1] in ("128", "25344"), dims
+
+
+@pytest.mark.parametrize("fn,batch,seq", [
+    ("decode_step", 256, 1), ("prefill_chunk", 1, 1024)])
+def test_jamba_programs_scan_and_step_the_state_where_it_lies(
+        chip, monkeypatch, fn, batch, seq):
+    """``ai21-jamba2-3b`` at the benchmark's widths and engine
+    (``benchmark/configs/ai21-jamba2-3b.json``), its first eight layers
+    (seven ``mamba1`` layers and the attention layer at 7; the whole
+    28 are the same programs, longer): the 256-lane decode program
+    steps each Mamba layer by the ``ssm_step_selective`` kernel and a
+    one-lane chunk of 1024 rows scans it by ``ssm_scan``; no ``copy``
+    has the shape of a K/V pool of ONE KV head (its unit head
+    dimension lies outside the block's rows, and the append and the
+    chunk loop convert nothing) or of a state pool; the state is held
+    channels minor-most, ``(E / 128, N, 128)``, whole tiles with no
+    lane padding; the slot count with its trash slot, 257, leads the
+    state pools and parts of them and no other array."""
+    import json
+    import re
+
+    from apex_tpu import serving
+    from apex_tpu.models.decoder import PatternDecoder
+    from benchmark.drivers import serve_jamba
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "ai21-jamba2-3b.json")) as f:
+        config = json.load(f)
+    config["num_hidden_layers"] = 8
+    engine = config["engine"]
+    monkeypatch.setenv("APEX_TPU_IMPL", "pallas")
+    _backend.default_impl.cache_clear()
+    try:
+        cfg = serve_jamba.decoder_config(config)
+        model = PatternDecoder(cfg)
+        cache = serving.KVCache.for_config(
+            cfg, num_blocks=engine["num_blocks"],
+            block_size=engine["block_size"],
+            state_slots=engine["state_slots"])
+        shapes = jax.eval_shape(
+            lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32)),
+            jax.random.PRNGKey(0))
+        params, state = jax.tree.map(
+            lambda x: chip(x.shape, x.dtype),
+            (shapes, jax.eval_shape(cache.init_state)))
+        compiled = serving.make_decode_step(model, cache).lower(
+            fn, params, state, batch, engine["min_width_bucket"],
+            seq=seq).compile()
+    finally:
+        _backend.default_impl.cache_clear()
+    assert state.pools[0].shape == (1, 65537, 16, 1, 128)
+    assert [s.shape for s in state.state] == [(257, 7, 40, 16, 128),
+                                              (257, 7, 3 * 5120)]
+    assert chip_smoke.program_bytes(compiled) <= 0.85 * V5E_BYTES
+    text = compiled.as_text()
+    names = re.findall(
+        r"%([\w-]+)\.?\d* = (?:\([^=]*?\)|\S+) custom-call\([^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"", text)
+    assert names.count("ssm_step_selective") == (7 if seq == 1 else 0)
+    assert names.count("ssm_scan") == (0 if seq == 1 else 7)
+    assert names.count("attention") == 1
+    assert_pool_stays_where_it_lies(text, state.pools[0].shape)
+    for pool in state.state:
+        shape = ",".join(str(d) for d in pool.shape)
+        assert not re.findall(
+            rf"%[\w.-]+ = \w+\[{shape}\]\S* copy\(", text), shape
+    assert re.search(r"f32\[257,7,40,16,128\]\{4,3,2,1,0:T\(8,128\)\}",
+                     text)
+    for dims in set(re.findall(r"\w+\[([\d,]+)\]", text)):
+        if "257" in dims.split(","):
+            assert dims.split(",").index("257") == 0, dims
